@@ -1,4 +1,4 @@
-"""TPC-DS-shaped benchmark suite (BASELINE.json configs 3-5).
+"""TPC-DS-shaped benchmark suite (configs 3-5).
 
 The reference publishes no benchmark numbers (SURVEY.md §6); the
 driver-set north star is TPC-DS-style relational work: single-chip

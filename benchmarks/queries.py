@@ -1,8 +1,8 @@
 """TPC-DS q5/q23/q64-shaped queries over the op library.
 
 Not the literal TPC-DS SQL (whose dimension DDL is far wider) but the
-same operator DAGs at the same shapes — the structures BASELINE.json
-configs 4-5 name:
+same operator DAGs at the same shapes — the structures configs 4-5
+name:
 
 * q5-shape:  multi-channel fact union -> date filter -> dimension join
              -> rollup aggregation.

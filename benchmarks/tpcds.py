@@ -2,7 +2,7 @@
 plus scan-driven q5/q23/q64 pipelines with pandas oracles.
 
 Round-4 VERDICT item 6: the in-memory DAGs in benchmarks/queries.py
-prove operator shapes, but BASELINE.json configs 4-5 call for REAL
+prove operator shapes, but configs 4-5 call for REAL
 Parquet scans — decimals, strings, nulls, row-group streaming — feeding
 shuffle/join/agg. This module is that end-to-end path:
 
@@ -433,7 +433,7 @@ def load_tables(data_dir: str) -> dict:
 def run_distributed(data_dir: str, devices: int) -> list[dict]:
     """q5/q23/q64 distributed DAGs over an N-device mesh, fed from the
     Parquet files (scan -> shuffle-exchange -> join -> agg): the
-    BASELINE config-4 shape with real data instead of in-memory
+    config-4 shape with real data instead of in-memory
     synthetics."""
     from benchmarks import queries
     from spark_rapids_jni_tpu.parallel.mesh import make_mesh

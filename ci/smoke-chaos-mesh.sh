@@ -29,7 +29,6 @@ trap 'rm -rf "$out"' EXIT
 
 export XLA_FLAGS="${XLA_FLAGS:---xla_force_host_platform_device_count=8}"
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
-export SRT_JAX_PLATFORMS="${SRT_JAX_PLATFORMS:-cpu}"
 export SPARK_RAPIDS_TPU_TRACE=1
 export SPARK_RAPIDS_TPU_METRICS_DUMP="$out/metrics.json"
 export SPARK_RAPIDS_TPU_FLIGHT_DUMP="$out/flight.json"

@@ -24,7 +24,6 @@ out="$(mktemp -d)"
 trap 'rm -rf "$out"' EXIT
 
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
-export SRT_JAX_PLATFORMS="${SRT_JAX_PLATFORMS:-cpu}"
 export SPARK_RAPIDS_TPU_TRACE=1
 export SPARK_RAPIDS_TPU_PROFILE=on
 export SPARK_RAPIDS_TPU_METRICS_DUMP="$out/metrics.json"

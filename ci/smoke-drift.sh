@@ -19,7 +19,6 @@ out="$(mktemp -d)"
 trap 'rm -rf "$out"' EXIT
 
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
-export SRT_JAX_PLATFORMS="${SRT_JAX_PLATFORMS:-cpu}"
 export SPARK_RAPIDS_TPU_PLANSTATS_DIR="$out/planstats"
 
 # Phase 1: the same wire plan twice (distinct data seeds). The stats

@@ -2,9 +2,11 @@
 # Kernel tier smoke gate (ISSUE 20): the Pallas kernel tier
 # (kernels/registry.py) must hold its whole contract end to end —
 #
-#   1. the plan-time static report tags kernel-eligible ops (>= 2 ops
-#      of a sort/groupby/transpose plan carry a kernel tag, rendered
-#      as ~kernel:<name> markers and listed in report["kernel_ops"]);
+#   1. the plan-time static report tags exactly the kernel-eligible
+#      ops (the transposes of a sort/groupby/transpose plan carry a
+#      kernel tag, rendered as ~kernel:<name> markers and listed in
+#      report["kernel_ops"]; sort_by/groupby/join have no registered
+#      kernel and stay untagged);
 #   2. a dispatch stream with SPARK_RAPIDS_TPU_KERNELS=on launches
 #      kernels (nonzero kernel.launches) and stays byte-identical to
 #      the same stream with KERNELS=off;
@@ -26,10 +28,9 @@ out="$(mktemp -d)"
 trap 'rm -rf "$out"' EXIT
 
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
-export SRT_JAX_PLATFORMS="${SRT_JAX_PLATFORMS:-cpu}"
 
-# Phase 1: static kernel tagging — the analyzer must tag >= 2 ops of a
-# kernel-friendly plan and render the markers
+# Phase 1: static kernel tagging — the analyzer must tag the two
+# transposes of the plan, nothing else, and render the markers
 python3 - <<'PY'
 from spark_rapids_jni_tpu import dtype as dt
 from spark_rapids_jni_tpu import plancheck as pc
@@ -39,18 +40,19 @@ PLAN = [
     {"op": "sort_by", "keys": [{"column": 0}]},
     {"op": "groupby", "by": [0], "aggs": [{"column": 1, "agg": "sum"}]},
     {"op": "to_rows"},
+    {"op": "from_rows", "type_ids": [int(I64), int(I64)], "scales": [0, 0]},
 ]
 rep = pc.analyze(
     PLAN, schema=[pc.ColType(I64), pc.ColType(I64)], rows=4096,
 )
 assert rep["ok"], rep
-assert len(rep["kernel_ops"]) >= 2, rep["kernel_ops"]
-tags = {e["kernel"] for e in rep["ops"] if e["kernel"]}
-assert {"packed_sort", "hash_groupby"} <= tags, tags
+assert rep["kernel_ops"] == [2, 3], rep["kernel_ops"]
+tags = [e["kernel"] for e in rep["ops"]]
+assert tags == [None, None, "row_pack", "row_unpack"], tags
 txt = pc.render_report(rep)
-assert "~kernel:packed_sort" in txt, txt
-assert "~kernel:hash_groupby" in txt, txt
-print(f"static kernel tagging OK: ops {rep['kernel_ops']} -> {sorted(tags)}")
+assert "~kernel:row_pack" in txt, txt
+assert "~kernel:row_unpack" in txt, txt
+print(f"static kernel tagging OK: ops {rep['kernel_ops']} -> {tags}")
 PY
 
 # Phases 2-4: dispatch parity + counters, seeded-fault fallback, and
@@ -69,10 +71,9 @@ config.set_flag("METRICS", "1")
 config.set_flag("FLIGHT", "1")
 
 I64 = int(dt.TypeId.INT64)
-OP_SORT = json.dumps({"op": "sort_by", "keys": [{"column": 0}]})
-OP_GROUP = json.dumps(
-    {"op": "groupby", "by": [0], "aggs": [{"column": 1, "agg": "sum"},
-                                          {"column": 1, "agg": "count"}]}
+OP_PACK = json.dumps({"op": "to_rows"})
+OP_UNPACK = json.dumps(
+    {"op": "from_rows", "type_ids": [I64, I64], "scales": [0, 0]}
 )
 N = 4096
 
@@ -84,8 +85,8 @@ wire_in = ([I64, I64], [0, 0], [k.tobytes(), v.tobytes()],
 
 
 def stream():
-    t1 = rb.table_op_wire(OP_SORT, *wire_in)
-    t2 = rb.table_op_wire(OP_GROUP, *wire_in)
+    t1 = rb.table_op_wire(OP_PACK, *wire_in)
+    t2 = rb.table_op_wire(OP_UNPACK, *t1)
     return t1, t2
 
 
@@ -133,8 +134,8 @@ kernel_spans = sorted(
     {e["name"].split("/")[-1] for e in spans
      if e["name"].split("/")[-1].startswith("kernel.")}
 )
-assert "kernel.packed_sort" in kernel_spans, kernel_spans
-assert "kernel.hash_groupby" in kernel_spans, kernel_spans
+assert "kernel.row_pack" in kernel_spans, kernel_spans
+assert "kernel.row_unpack" in kernel_spans, kernel_spans
 assert "kernel" in {e["cat"] for e in spans}, "no kernel category"
 print(f"kernel trace spans OK: {kernel_spans}")
 PY

@@ -24,7 +24,6 @@ out="$(mktemp -d)"
 trap 'rm -rf "$out"' EXIT
 
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
-export SRT_JAX_PLATFORMS="${SRT_JAX_PLATFORMS:-cpu}"
 export SPARK_RAPIDS_TPU_DURABLE=on
 export SPARK_RAPIDS_TPU_CHECKPOINT_DIR="$out/ckpt"
 export SPARK_RAPIDS_TPU_METRICS=on

@@ -19,7 +19,6 @@ out="$(mktemp -d)"
 trap 'rm -rf "$out"' EXIT
 
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
-export SRT_JAX_PLATFORMS="${SRT_JAX_PLATFORMS:-cpu}"
 export XLA_FLAGS="${XLA_FLAGS:-} --xla_force_host_platform_device_count=8"
 export SPARK_RAPIDS_TPU_PLANSTATS_DIR="$out/planstats"
 export SPARK_RAPIDS_TPU_METRICS=1

@@ -27,7 +27,6 @@ trap 'rm -rf "$out"' EXIT
 
 export XLA_FLAGS="${XLA_FLAGS:---xla_force_host_platform_device_count=2}"
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
-export SRT_JAX_PLATFORMS="${SRT_JAX_PLATFORMS:-cpu}"
 export SPARK_RAPIDS_TPU_TRACE=1
 export SPARK_RAPIDS_TPU_METRICS=1
 

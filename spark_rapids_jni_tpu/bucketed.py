@@ -20,7 +20,9 @@
    ops/compaction.py ``_first_of_run_mask(row_valid=...)``),
 4. returns a PADDED result carrying ``Table.logical_rows`` — the wire
    boundary slices host-side (zero extra compiles) and a downstream
-   bucketed op consumes the padding directly.
+   bucketed op consumes the padding directly. A groupby's result is
+   first cut down to the bucket of its group count (``_rebucket``), so
+   what follows an aggregation runs at the aggregate's size.
 
 Semantics contract: for the first ``logical_rows`` rows the result is
 bit-identical to the exact path (``tests/test_buckets.py`` pins this at
@@ -160,6 +162,21 @@ def _key(kind: str, op: dict, *tables: Table, extra: tuple = ()) -> tuple:
     return buckets.cache_key(kind, op, tables, extra)
 
 
+def _rebucket(t: Table) -> Table:
+    """Shrink a padded result to the bucket of its LOGICAL row count.
+
+    A groupby collapses its input by orders of magnitude but its capped
+    form returns ``num_segments`` = the input bucket physical rows, so
+    every op behind it (and the download) would run at the input's
+    bucket: 6,666 groups sorted as 2^23 rows. The count is already on
+    the host when this runs (the runner's one sync), so the slices are
+    static ones."""
+    b = buckets.bucket_for(t.logical_row_count)
+    if b is None or b >= t.row_count:
+        return t
+    return _finish(buckets.head_table(t, b), t.logical_row_count)
+
+
 # ---------------------------------------------------------------------------
 # runners
 # ---------------------------------------------------------------------------
@@ -273,7 +290,7 @@ def _r_groupby(op: dict, table: Table, rest) -> Table:
     )
     out, num_groups = fn(_strip(pt), _n_dev(pt))
     # srt: allow-host-sync(bucketed-runner boundary: the compiled launch is done; one count read sizes the logical rows of the padded result)
-    return _finish(out, int(num_groups))
+    return _rebucket(_finish(out, int(num_groups)))
 
 
 def _r_distinct(op: dict, table: Table, rest) -> Table:

@@ -20,11 +20,11 @@ stays fully vectorized (no per-chunk python loop, no grid unrolling).
 
 Keys are u64 order words (ops/keys.py) split into u32 (hi, lo) halves
 OUTSIDE the kernel — the same "no Mosaic i64 paths" discipline as
-bitonic_sort.py. The build kernel needs gather/scatter by computed
-vectors, which today's Mosaic lowering may refuse; the kernel tier's
-fallback discipline (kernels/registry.py) absorbs that as a metered
-``kernel.fallbacks`` replay on the exact path, while ``interpret=True``
-covers the CPU tier-1 parity fuzz. The probe kernel is gather-only.
+bitonic_sort.py. Both kernels gather (the build one also scatters) by
+computed 1-D index vectors, which Mosaic refuses for a v5e
+("NotImplementedError: Only 2D gather is supported"), so no registry
+entry dispatches them (CHANGES.md PR 23); ``interpret=True`` keeps the
+module's parity tests running on the CPU tier.
 
 Termination is bounded: ``max_probes`` rounds. Rows still live after
 the loop are reported in the ``overflow`` scalar; callers MUST treat a

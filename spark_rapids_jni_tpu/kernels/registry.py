@@ -6,9 +6,9 @@ survey-snapshot example). This registry is that tier for the TPU
 backend: one entry per accelerated inner loop, each declaring
 
 * the dispatch-plane op names it accelerates,
-* an **applicability predicate** — dtypes, key widths, bucket-size and
-  VMEM-footprint bounds — answering a decline *reason* (metered
-  ``kernel.declines``) before any device work, and
+* an **applicability predicate** — dtypes and layouts — answering a
+  decline *reason* (metered ``kernel.declines``) before any device
+  work, and
 * a **runner** that must be byte-identical to the bucketed/exact path
   over the logical rows (the shape-bucket semantics contract,
   bucketed.py): padding-region bytes are free, logical bytes are not.
@@ -17,14 +17,15 @@ Dispatch discipline mirrors ``bucketed.dispatch_bucketed``: the tier is
 consulted first by ``runtime_bridge._dispatch_once`` under the
 ``SPARK_RAPIDS_TPU_KERNELS=on|off|auto`` flag; any runner error — a
 Mosaic lowering the current toolchain refuses, a seeded ``kernel``
-chaos fault, an overflowed probe bound — is caught, metered as
-``kernel.fallbacks``, and answered with ``None`` so the caller replays
-the op on the existing path. The tier can change performance, never
-bytes. Compiled callables live in the shared ``buckets.cached_jit``
-cache with the kernel name folded into the cache-key kind
-(``"kernel.<name>"``), so kernel and non-kernel programs of the same op
-cache independently and the compile-cache hit/miss counters attribute
-them separately.
+chaos fault — is caught, metered as ``kernel.fallbacks``, and answered
+with ``None`` so the caller replays the op on the existing path. The
+tier can change performance, never bytes.
+
+Only kernels the chip's compiler accepts are registered:
+``tests/test_chip_compile.py`` compiles every entry for a described
+v5e. The sort network (``bitonic_sort.py``) and the hash table
+(``hash_table.py``) are not entries — Mosaic refuses their in-kernel
+gathers — so no plan op tries them and falls back.
 
 ``KERNEL_NAMES`` is the SRT012 parity anchor: srt_check statically
 cross-checks it against this module's ``_REGISTRY`` literal, plancheck's
@@ -38,535 +39,19 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Optional, Sequence, Tuple
 
-import jax
-import jax.numpy as jnp
-import numpy as np
-
 from .. import dtype as dt
-from ..column import Column, Table
+from ..column import Table
 from ..utils import buckets, config, faults, log, metrics, profiler
-from . import default_interpret, pallas_capability
+from . import pallas_capability
 
 #: Every registered kernel — the SRT012 static parity anchor. Must
 #: equal the ``_REGISTRY`` keys below and plancheck's ``_KERNEL_RULES``.
-KERNEL_NAMES = frozenset(
-    {"packed_sort", "hash_build_probe", "hash_groupby", "row_pack",
-     "row_unpack"}
-)
-
-_U64_MAX = np.uint64(0xFFFFFFFFFFFFFFFF)
-
-# ---------------------------------------------------------------------------
-# VMEM / shape bounds (applicability predicates)
-# ---------------------------------------------------------------------------
-
-#: packed_sort: (3 fixed words: key hi/lo + iota) + payload words, per
-#: row, times the bucket length, times the 8-row Mosaic block and u32
-#: in+out copies => 2^17 words ~= 8 MB VMEM of a ~16 MB/core budget.
-SORT_MAX_WORDS = 1 << 17
-#: packed_sort bucket-length window (bitonic network depth vs VMEM).
-SORT_MAX_ROWS = 1 << 16
-#: hash_build_probe: build-side bucket bound (table is 2x this).
-JOIN_BUILD_MAX_ROWS = 1 << 16
-#: hash_build_probe: probe-side bucket bound (~6 u32 words/row).
-JOIN_PROBE_MAX_ROWS = 1 << 18
-#: hash_groupby: input bucket bound (C chunks of GROUPBY_CHUNK_ROWS).
-GROUPBY_MAX_ROWS = 1 << 18
-#: hash_groupby chunk length T; per-chunk table is S = 2T slots.
-GROUPBY_CHUNK_ROWS = 4096
-
-_AGG_OPS = frozenset({"sum", "count", "min", "max"})
+KERNEL_NAMES = frozenset({"row_pack", "row_unpack"})
 
 
 class KernelDecline(Exception):
     """Internal: this op/shape opts out of the kernel tier (the
     bucketed/exact path runs). Carries the decline reason."""
-
-
-def _pow2(n: int) -> bool:
-    return n >= 2 and not (n & (n - 1))
-
-
-def _order_word_reason(col: Column) -> Optional[str]:
-    """Why this column cannot be a single-u64-order-word kernel key
-    (ops/keys.py emits exactly one word for it), or None if it can."""
-    d = col.dtype
-    if d.is_string:
-        return "string key (multi-word order key)"
-    if d.id == dt.TypeId.DECIMAL128:
-        return "DECIMAL128 key (two-word order key)"
-    if d.id in (dt.TypeId.LIST, dt.TypeId.STRUCT):
-        return f"{d.id.name} key"
-    if col.validity is not None:
-        return "nullable key (null-placement word)"
-    return None
-
-
-def _padded_rows(table: Table) -> Optional[int]:
-    """The physical bucket length the runner's padded input will have
-    (pre-padded tables keep their size); None = no bucket (decline)."""
-    if table.logical_rows is not None:
-        return table.row_count
-    n = table.logical_row_count
-    if n <= 0:
-        return None
-    return buckets.bucket_for(n)
-
-
-def _resolve_col(table: Table, spec) -> Optional[Column]:
-    try:
-        return table.column(spec)
-    except (KeyError, IndexError, TypeError, ValueError):
-        return None
-
-
-def _split_u64(w: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    return w.astype(jnp.uint32), (w >> jnp.uint64(32)).astype(jnp.uint32)
-
-
-# ---------------------------------------------------------------------------
-# packed_sort — sort_by via the batched VMEM bitonic network
-# ---------------------------------------------------------------------------
-
-
-def _sort_payload_words(table: Table) -> Optional[int]:
-    """u32 words/row the sort network carries beyond key+iota, or None
-    when some buffer cannot ride (narrow float payload)."""
-    w = 0
-    for c in table.columns:
-        if c.data.ndim == 1:
-            size = c.data.dtype.itemsize
-            if size == 8:
-                w += 2
-            elif size < 4 and jnp.issubdtype(c.data.dtype, jnp.floating):
-                return None  # u32 widening would lose bits
-            else:
-                w += 1
-        # matrix buffers (strings, DECIMAL128) gather through the perm
-        if c.validity is not None:
-            w += 1
-        if c.lengths is not None:
-            w += 1
-    return w
-
-
-def _a_packed_sort(op: dict, table: Table, rest) -> Optional[str]:
-    ks = op.get("keys") or []
-    if len(ks) != 1:
-        return "multi-key sort (one packed word per network)"
-    col = _resolve_col(table, ks[0].get("column"))
-    if col is None:
-        return "unresolvable sort key column"
-    r = _order_word_reason(col)
-    if r is not None:
-        return r
-    w = _sort_payload_words(table)
-    if w is None:
-        return "narrow float payload column"
-    b = _padded_rows(table)
-    if b is None:
-        return "no shape bucket"
-    if not _pow2(b):
-        return f"bucket {b} not a power of two"
-    if b > SORT_MAX_ROWS or (3 + w) * b > SORT_MAX_WORDS:
-        return f"VMEM bound: {3 + w} words x {b} rows"
-    return None
-
-
-def _r_packed_sort(op: dict, table: Table, rest) -> Table:
-    from .. import bucketed as bk
-
-    pt = bk._padded_input(table)
-    kspec = op["keys"][0]
-    ci = kspec["column"]
-    asc = bool(kspec.get("ascending", True))
-    interp = default_interpret()
-
-    def build():
-        def fn(t, n):
-            from ..ops import keys as keys_mod
-            from . import bitonic_sort
-
-            w = keys_mod.column_order_keys(t.column(ci))[0]
-            if not asc:
-                w = ~w
-            rv = buckets.tail_valid(t.row_count, n)
-            # padding rows sink to the tail regardless of direction —
-            # the occupancy word of the exact sort, folded into the key
-            w = jnp.where(rv, w, jnp.uint64(_U64_MAX))
-            plan: list = []
-            payloads: list = []
-            for i, c in enumerate(t.columns):
-                if c.data.ndim == 1:
-                    plan.append((i, "data"))
-                    payloads.append(c.data)
-                if c.validity is not None:
-                    plan.append((i, "validity"))
-                    payloads.append(c.validity)
-                if c.lengths is not None:
-                    plan.append((i, "lengths"))
-                    payloads.append(c.lengths)
-            out = bitonic_sort.batched_sort_u64_looped(
-                w[None, :], *[p[None, :] for p in payloads],
-                interpret=interp,
-            )
-            perm = out[1][0]
-            by_col: dict = {}
-            for (i, attr), arr in zip(plan, out[2:]):
-                by_col.setdefault(i, {})[attr] = arr[0]
-            cols = []
-            for i, c in enumerate(t.columns):
-                got = by_col.get(i, {})
-                data = got.get("data")
-                if data is None:  # matrix layout: gather through perm
-                    data = c.data[perm]
-                cols.append(
-                    Column(
-                        data, c.dtype,
-                        got.get("validity")
-                        if c.validity is not None else None,
-                        got.get("lengths")
-                        if c.lengths is not None else None,
-                    )
-                )
-            return Table(cols, t.names)
-
-        return fn
-
-    fn = buckets.cached_jit(
-        bk._key("kernel.packed_sort", op, pt), build, "srt_kernel_sort"
-    )
-    return bk._finish(fn(bk._strip(pt), bk._n_dev(pt)), pt.logical_row_count)
-
-
-# ---------------------------------------------------------------------------
-# hash_build_probe — inner/semi/anti join via the VMEM hash table
-# ---------------------------------------------------------------------------
-
-_KERNEL_JOIN_HOWS = frozenset({"inner", "semi", "anti"})
-
-
-def _a_hash_join(op: dict, table: Table, rest) -> Optional[str]:
-    how = op.get("how", "inner")
-    if how not in _KERNEL_JOIN_HOWS:
-        return f"join how={how!r} (left/outer build on exact machinery)"
-    if not rest:
-        return "missing build-side table"
-    on = op.get("on") or []
-    if len(on) != 1:
-        return "multi-column join key"
-    lcol = _resolve_col(table, on[0])
-    rcol = _resolve_col(rest[0], on[0])
-    if lcol is None or rcol is None:
-        return "unresolvable join key column"
-    for side, col in (("probe", lcol), ("build", rcol)):
-        r = _order_word_reason(col)
-        if r is not None:
-            return f"{side} side: {r}"
-    lb = _padded_rows(table)
-    rb = _padded_rows(rest[0])
-    if lb is None or rb is None:
-        return "no shape bucket"
-    if not (_pow2(lb) and _pow2(rb)):
-        return "bucket not a power of two"
-    if rb > JOIN_BUILD_MAX_ROWS:
-        return f"build side {rb} rows over VMEM table bound"
-    if lb > JOIN_PROBE_MAX_ROWS:
-        return f"probe side {lb} rows over VMEM bound"
-    return None
-
-
-def _join_words(t: Table, spec, rv):
-    from ..ops import keys as keys_mod
-
-    w = keys_mod.column_order_keys(t.column(spec))[0]
-    lo, hi = _split_u64(w)
-    return lo[None, :], hi[None, :], rv[None, :]
-
-
-def _r_hash_join(op: dict, table: Table, rest) -> Table:
-    from .. import bucketed as bk
-    from . import hash_table
-
-    how = op.get("how", "inner")
-    lt = bk._padded_input(table)
-    rt = bk._padded_input(rest[0])
-    on = list(op["on"])
-    slots = 2 * rt.row_count
-    interp = default_interpret()
-
-    if how in ("semi", "anti"):
-        anti = how == "anti"
-
-        def build_sa():
-            def fn(l, r, ln, rn):
-                from ..ops.filter import filter_table_capped
-
-                lv = buckets.tail_valid(l.row_count, ln)
-                rv = buckets.tail_valid(r.row_count, rn)
-                blo, bhi, bval = _join_words(r, on[0], rv)
-                _, tlo, thi, trow, ovf, _ = hash_table.build_table(
-                    blo, bhi, bval, table_slots=slots, interpret=interp
-                )
-                plo, phi, pval = _join_words(l, on[0], lv)
-                found, _, unres = hash_table.probe_table(
-                    plo, phi, pval, tlo, thi, trow, interpret=interp
-                )
-                has = (found[0] != 0) & lv
-                keep = jnp.logical_and(jnp.logical_not(has), lv) \
-                    if anti else has
-                out, count = filter_table_capped(
-                    l, Column(keep, dt.BOOL8, None), capacity=l.row_count
-                )
-                return out, count, ovf, unres
-
-            return fn
-
-        fn = buckets.cached_jit(
-            bk._key("kernel.hash_join." + how, op, lt, rt), build_sa,
-            "srt_kernel_join_" + how,
-        )
-        out, count, ovf, unres = fn(
-            bk._strip(lt), bk._strip(rt), bk._n_dev(lt), bk._n_dev(rt)
-        )
-        # srt: allow-host-sync(kernel-runner boundary: the compiled launch is done; the overflow flags decide decline and the count sizes the logical rows)
-        if int(ovf) or int(unres):
-            raise KernelDecline("hash table probe bound exceeded")
-        return bk._finish(out, int(count))
-
-    # inner: two-phase sizing like the bucketed runner — phase 1 probes
-    # and counts, phase 2 materializes at the OUTPUT bucket capacity.
-    def build_probe():
-        def fn(l, r, ln, rn):
-            lv = buckets.tail_valid(l.row_count, ln)
-            rv = buckets.tail_valid(r.row_count, rn)
-            blo, bhi, bval = _join_words(r, on[0], rv)
-            _, tlo, thi, trow, ovf, dup = hash_table.build_table(
-                blo, bhi, bval, table_slots=slots, interpret=interp
-            )
-            plo, phi, pval = _join_words(l, on[0], lv)
-            found, rrow, unres = hash_table.probe_table(
-                plo, phi, pval, tlo, thi, trow, interpret=interp
-            )
-            keep = (found[0] != 0) & lv
-            return (
-                keep, rrow[0], jnp.sum(keep, dtype=jnp.int64), ovf, dup,
-                unres,
-            )
-
-        return fn
-
-    p1 = buckets.cached_jit(
-        bk._key("kernel.hash_join.probe", {"on": on}, lt, rt),
-        build_probe, "srt_kernel_join_probe",
-    )
-    keep, rrow, total, ovf, dup, unres = p1(
-        bk._strip(lt), bk._strip(rt), bk._n_dev(lt), bk._n_dev(rt)
-    )
-    # srt: allow-host-sync(kernel-runner boundary: the compiled launch is done; the overflow flags decide decline and the count sizes phase 2)
-    if int(ovf) or int(unres):
-        raise KernelDecline("hash table probe bound exceeded")
-    if int(dup):
-        # duplicate build keys fan matches out; the single-slot table
-        # holds one right row per key, so only unique-key builds are
-        # byte-exact here — the range-based exact path owns the rest
-        raise KernelDecline("duplicate build-side keys")
-    total = int(total)
-    cap = buckets.bucket_for(total)
-    if cap is None:
-        raise KernelDecline("no output bucket for join result")
-
-    def build_mat():
-        def fn(l, r, keep, rrow):
-            from ..ops.join import _join_output
-
-            pos = jnp.cumsum(keep.astype(jnp.int32)) - 1
-            to = jnp.where(keep, pos, cap)
-            left_idx = jnp.zeros((cap,), jnp.int32).at[to].set(
-                jnp.arange(l.row_count, dtype=jnp.int32), mode="drop"
-            )
-            right_idx = jnp.zeros((cap,), jnp.int32).at[to].set(
-                rrow, mode="drop"
-            )
-            # no matched/row_valid masks, matching the bucketed/exact
-            # inner output schema; rows past ``total`` are garbage
-            # behind the logical row count
-            return _join_output(l, r, on, left_idx, right_idx, None, None)
-
-        return fn
-
-    p2 = buckets.cached_jit(
-        bk._key("kernel.hash_join.mat", {"on": on}, lt, rt, extra=(cap,)),
-        build_mat, "srt_kernel_join_mat",
-    )
-    out = p2(bk._strip(lt), bk._strip(rt), keep, rrow)
-    return bk._finish(out, total)
-
-
-# ---------------------------------------------------------------------------
-# hash_groupby — chunked hash partials + one small exact merge
-# ---------------------------------------------------------------------------
-
-
-def _a_hash_groupby(op: dict, table: Table, rest) -> Optional[str]:
-    by = op.get("by") or []
-    if len(by) != 1:
-        return "multi-column group key"
-    aggs = op.get("aggs") or []
-    if not aggs:
-        return "no aggregations"
-    bad = [a.get("agg") for a in aggs if a.get("agg") not in _AGG_OPS]
-    if bad:
-        return f"non-decomposable agg {bad[0]!r}"
-    col = _resolve_col(table, by[0])
-    if col is None:
-        return "unresolvable group key column"
-    r = _order_word_reason(col)
-    if r is not None:
-        return r
-    for a in aggs:
-        vc = _resolve_col(table, a.get("column"))
-        if vc is None:
-            return "unresolvable aggregation column"
-        d = vc.dtype
-        if d.is_string or d.is_decimal or d.is_floating or vc.data.ndim != 1:
-            return f"{d.id.name} aggregation value (order-sensitive or multi-word)"
-    b = _padded_rows(table)
-    if b is None:
-        return "no shape bucket"
-    if not _pow2(b):
-        return f"bucket {b} not a power of two"
-    if b > GROUPBY_MAX_ROWS:
-        return f"bucket {b} over chunked-hash bound"
-    return None
-
-
-def _r_hash_groupby(op: dict, table: Table, rest) -> Table:
-    from .. import bucketed as bk
-    from ..ops import compute
-    from ..ops import keys as keys_mod
-    from ..ops.groupby import GroupbyAgg, groupby_aggregate_capped
-    from . import hash_table
-
-    pt = bk._padded_input(table)
-    by0 = op["by"][0]
-    aggs = list(op["aggs"])
-    b = pt.row_count
-    t_chunk = min(b, GROUPBY_CHUNK_ROWS)
-    c_chunks = b // t_chunk
-    slots = 2 * t_chunk
-    ns = c_chunks * slots
-    interp = default_interpret()
-
-    # the exact path's output names, rebuilt on the merged table
-    names = pt.names
-    out_names = [
-        by0 if isinstance(by0, str)
-        else (names[by0] if names else "key0")
-    ]
-    for a in aggs:
-        ac = a["column"]
-        base = ac if isinstance(ac, str) else (
-            names[ac] if names else f"c{ac}"
-        )
-        out_names.append(f"{a['agg']}_{base}")
-
-    merge_op = {"sum": "sum", "count": "sum", "min": "min", "max": "max"}
-    merge_aggs = [
-        GroupbyAgg(i + 1, merge_op[a["agg"]]) for i, a in enumerate(aggs)
-    ]
-
-    def build():
-        def fn(t, n):
-            key_col = t.column(by0)
-            w = keys_mod.column_order_keys(key_col)[0]
-            rv = buckets.tail_valid(t.row_count, n)
-            lo, hi = _split_u64(w)
-            _slot, _, _, trow, ovf, _ = hash_table.build_table(
-                lo.reshape(c_chunks, t_chunk),
-                hi.reshape(c_chunks, t_chunk),
-                rv.reshape(c_chunks, t_chunk),
-                table_slots=slots, interpret=interp,
-            )
-            trow_f = trow.reshape(ns)
-            used = trow_f >= 0
-            # partial id per input row: chunk * S + slot (unplaced rows
-            # scatter to the dropped sentinel NS — only possible when
-            # ovf > 0, which declines below)
-            slot_f = _slot.reshape(b)
-            chunk_of_row = jnp.arange(b, dtype=jnp.int32) // t_chunk
-            pid = jnp.where(
-                slot_f >= 0, chunk_of_row * slots + slot_f, ns
-            )
-            # representative row per slot: the claim winner, which is
-            # the lowest original row id of its key group — the same
-            # representative the exact stable sort elects
-            chunk_of_slot = jnp.arange(ns, dtype=jnp.int32) // slots
-            rep = jnp.where(used, chunk_of_slot * t_chunk + trow_f, 0)
-            part_cols = [Column(key_col.data[rep], key_col.dtype, None)]
-            for a in aggs:
-                acol = t.column(a["column"])
-                vals = compute.values(acol)
-                m = jnp.logical_and(compute.valid_mask(acol), rv)
-                aop = a["agg"]
-                if aop == "count":
-                    part = jax.ops.segment_sum(
-                        m.astype(jnp.int64), pid, num_segments=ns
-                    )
-                    # validity None, like the exact count output
-                    part_cols.append(Column(part, dt.INT64, None))
-                    continue
-                pv = jax.ops.segment_max(
-                    m.astype(jnp.int32), pid, num_segments=ns
-                ) > 0
-                if aop == "sum":
-                    part = jax.ops.segment_sum(
-                        jnp.where(m, vals, 0).astype(jnp.int64), pid,
-                        num_segments=ns,
-                    )
-                    part_cols.append(
-                        compute.from_values(part, dt.INT64, pv)
-                    )
-                    continue
-                # min / max: the exact path's masked-sentinel trick
-                if acol.dtype.is_boolean:
-                    sentinel = aop == "min"
-                    work = jnp.where(m, vals, sentinel).astype(jnp.int32)
-                else:
-                    info = np.iinfo(np.dtype(acol.dtype.storage_dtype))
-                    sentinel = info.max if aop == "min" else info.min
-                    work = jnp.where(
-                        m, vals, jnp.asarray(sentinel, vals.dtype)
-                    )
-                seg = (
-                    jax.ops.segment_min if aop == "min"
-                    else jax.ops.segment_max
-                )
-                part = seg(work, pid, num_segments=ns)
-                if acol.dtype.is_boolean:
-                    part = part.astype(jnp.bool_)
-                part_cols.append(
-                    compute.from_values(part, acol.dtype, pv)
-                )
-            # merge: the EXACT capped groupby over the C*S partials —
-            # same sort, same segment reductions, same output layout
-            merged, num_groups = groupby_aggregate_capped(
-                Table(part_cols), [0], merge_aggs,
-                num_segments=t.row_count, row_valid=used,
-            )
-            return Table(merged.columns, out_names), num_groups, ovf
-
-        return fn
-
-    fn = buckets.cached_jit(
-        bk._key("kernel.hash_groupby", op, pt), build,
-        "srt_kernel_groupby",
-    )
-    out, num_groups, ovf = fn(bk._strip(pt), bk._n_dev(pt))
-    # srt: allow-host-sync(kernel-runner boundary: the compiled launch is done; the overflow flag decides decline and the count sizes the logical rows)
-    if int(ovf):
-        raise KernelDecline("chunk hash table overflow")
-    return bk._finish(out, int(num_groups))
 
 
 # ---------------------------------------------------------------------------
@@ -625,18 +110,6 @@ class KernelSpec:
 
 
 _REGISTRY = {
-    "packed_sort": KernelSpec(
-        "packed_sort", ("sort_by",), _a_packed_sort, _r_packed_sort,
-        "single-key ORDER BY through the batched VMEM bitonic network",
-    ),
-    "hash_build_probe": KernelSpec(
-        "hash_build_probe", ("join",), _a_hash_join, _r_hash_join,
-        "inner/semi/anti join through the VMEM open-addressing table",
-    ),
-    "hash_groupby": KernelSpec(
-        "hash_groupby", ("groupby",), _a_hash_groupby, _r_hash_groupby,
-        "chunked hash partial aggregation + one small exact merge",
-    ),
     "row_pack": KernelSpec(
         "row_pack", ("to_rows",), _a_row_pack, _r_row_pack,
         "columnar -> packed rows via the Pallas transpose tiles",

@@ -519,9 +519,9 @@ def groupby_aggregate_capped(
 
 # above this, SPARK_RAPIDS_TPU_GROUPBY_FORMULATION=packed/chunked can
 # route decomposable aggregations through the two-level designs. The
-# default stays on the single variadic sort: the round-5 chip window
-# measured it 2.9x/7x AHEAD of the packed/chunked bets at 16M rows
-# (BASELINE.md round-5 measured state) — XLA's batched small sorts are
+# default stays on the single variadic sort: a builder's measurement
+# before this round (not reproducible) had it 2.9x/7x AHEAD of the
+# packed/chunked bets at 16M rows — XLA's batched small sorts are
 # not VMEM-resident, so the two-level constant only comes back via the
 # explicit Pallas engines, which are still an A/B in progress.
 CHUNKED_MIN_ROWS = 4_000_000

@@ -15,8 +15,9 @@ Fusable ops (single-table, bucketable, ``row_valid``-maskable):
 (non-negative bounds), and a non-collect ``groupby`` TAIL — a groupby
 may close a fused run but not continue it: its output is a fresh
 keys+aggregates table and the following ops re-enter the compiler on
-the padded result. Everything else (join, concat, explode,
-to_rows/from_rows, ...) is a segment boundary dispatched through the
+the padded result (cut down to the bucket of its group count).
+Everything else (join, concat, explode, to_rows/from_rows, ...) is a
+segment boundary dispatched through the
 existing per-op ``_dispatch`` path — bucketed runner or exact fallback
 — with ``Table.logical_rows`` carried through unchanged so padding
 semantics survive the boundary.
@@ -324,7 +325,12 @@ def _run_fused(
         # to per-op replay with the input intact — nothing was donated
         hbm.note_donation(donated)
     # srt: allow-host-sync(segment boundary: the fused launch is done; the count read is the one sync that sizes the unpadded result)
-    return bucketed._finish(out, int(count))
+    res = bucketed._finish(out, int(count))
+    if seg_ops[-1]["op"] == "groupby":
+        # same shrink as the per-op runner's, so both paths hand the
+        # next op the same physical shape
+        res = bucketed._rebucket(res)
+    return res
 
 
 # ops whose output over a row range depends only on the rows in that

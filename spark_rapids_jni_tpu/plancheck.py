@@ -890,118 +890,8 @@ def _tier(op: dict) -> Tuple[str, str]:
 # (enforced statically by srt_check pass SRT012 and dynamically by
 # tests/test_kernel_tier.py). The tag is ADDITIVE to the support tier:
 # a kernel-tagged op keeps its fusable/per-op/exact-only tier and may
-# still decline at runtime on facts plancheck cannot see (nullability,
-# bucket ladder, duplicate build keys) — the tag means "structurally
-# eligible", never "will launch".
-
-_KERNEL_JOIN_HOWS = frozenset({"inner", "semi", "anti"})
-_KERNEL_AGG_OPS = frozenset({"sum", "count", "min", "max"})
-
-
-def _kernel_key_reason(ct: Optional[ColType]) -> Optional[str]:
-    """Static half of registry._order_word_reason: why this column can
-    never be a single-u64-order-word kernel key (None = maybe; the
-    nullable-key decline is a runtime fact)."""
-    if ct is None:
-        return None
-    if ct.is_string:
-        return "string key (multi-word order key)"
-    if ct.id == dt.TypeId.DECIMAL128:
-        return "DECIMAL128 key (two-word order key)"
-    if ct.id in (dt.TypeId.LIST, dt.TypeId.STRUCT):
-        return f"{ct.id.name} key"
-    return None
-
-
-def _kernel_col(ref, schema, names) -> Tuple[Optional[ColType], bool]:
-    """(coltype, resolvable): resolve a key ref without raising.
-    Unknown schema answers (None, True) — permissive, like the rest of
-    the analyzer."""
-    try:
-        idx = _key_ref(ref, schema, names, what="kernel key")
-    except _Reject:
-        return None, False
-    if idx is None or schema is None:
-        return None, True
-    return schema[idx], True
-
-
-def _peek_rest(op: dict, st) -> Optional[List[Tuple]]:
-    """The (schema, rows) pairs take_rest WOULD hand this op, without
-    consuming them (the kernel tag runs before the rule does)."""
-    idxs = op.get("rest")
-    if idxs is not None:
-        try:
-            return [st.orig_rest[int(i)] for i in idxs]
-        except (IndexError, TypeError, ValueError):
-            return None
-    return [st.queue[0]] if st.queue else []
-
-
-def _k_packed_sort(op: dict, st) -> Optional[str]:
-    ks = op.get("keys")
-    if not isinstance(ks, list) or len(ks) != 1 \
-            or not isinstance(ks[0], dict):
-        return "multi-key sort (one packed word per network)"
-    ct, ok = _kernel_col(ks[0].get("column"), st.schema, st.names)
-    if not ok:
-        return "unresolvable sort key column"
-    return _kernel_key_reason(ct)
-
-
-def _k_hash_join(op: dict, st) -> Optional[str]:
-    how = op.get("how", "inner")
-    if how not in _KERNEL_JOIN_HOWS:
-        return f"join how={how!r} (left/outer build on exact machinery)"
-    on = op.get("on")
-    if not isinstance(on, list) or len(on) != 1:
-        return "multi-column join key"
-    rest = _peek_rest(op, st)
-    if not rest:
-        return "missing build-side table"
-    lct, lok = _kernel_col(on[0], st.schema, st.names)
-    rct, rok = _kernel_col(on[0], rest[0][0], None)
-    if not (lok and rok):
-        return "unresolvable join key column"
-    for side, ct in (("probe", lct), ("build", rct)):
-        r = _kernel_key_reason(ct)
-        if r is not None:
-            return f"{side} side: {r}"
-    return None
-
-
-def _k_hash_groupby(op: dict, st) -> Optional[str]:
-    by = op.get("by")
-    if not isinstance(by, list) or len(by) != 1:
-        return "multi-column group key"
-    aggs = op.get("aggs")
-    if not isinstance(aggs, list) or not aggs:
-        return "no aggregations"
-    for a in aggs:
-        if not isinstance(a, dict):
-            return "malformed aggregation spec"
-        if a.get("agg") not in _KERNEL_AGG_OPS:
-            return f"non-decomposable agg {a.get('agg')!r}"
-    ct, ok = _kernel_col(by[0], st.schema, st.names)
-    if not ok:
-        return "unresolvable group key column"
-    r = _kernel_key_reason(ct)
-    if r is not None:
-        return r
-    for a in aggs:
-        vct, vok = _kernel_col(a.get("column"), st.schema, st.names)
-        if not vok:
-            return "unresolvable aggregation column"
-        if vct is not None and (
-            vct.is_string or vct.is_decimal or vct.is_floating
-            or vct.is_list
-        ):
-            return (
-                f"{vct.id.name} aggregation value (order-sensitive or "
-                "multi-word)"
-            )
-    return None
-
+# still decline at runtime on facts plancheck cannot see — the tag
+# means "structurally eligible", never "will launch".
 
 def _k_row_pack(op: dict, st) -> Optional[str]:
     if st.schema is not None:
@@ -1031,9 +921,6 @@ def _k_row_unpack(op: dict, st) -> Optional[str]:
 # are the SRT012 anchor; the op coverage must mirror the registry's
 # KernelSpec.ops tuples.
 _KERNEL_RULES = {
-    "packed_sort": ("sort_by", _k_packed_sort),
-    "hash_build_probe": ("join", _k_hash_join),
-    "hash_groupby": ("groupby", _k_hash_groupby),
     "row_pack": ("to_rows", _k_row_pack),
     "row_unpack": ("from_rows", _k_row_unpack),
 }

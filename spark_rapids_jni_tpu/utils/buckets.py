@@ -283,26 +283,34 @@ def pad_table(table, target: Optional[int] = None):
     )
 
 
+def head_table(table, rows: int):
+    """The first ``rows`` physical rows of every buffer (device slices);
+    names kept, logical-row metadata dropped."""
+    from ..column import Column, Table
+
+    cols = [
+        Column(
+            c.data[:rows],
+            c.dtype,
+            None if c.validity is None else c.validity[:rows],
+            None if c.lengths is None else c.lengths[:rows],
+        )
+        for c in table.columns
+    ]
+    return Table(cols, table.names)
+
+
 def unpad_table(table):
     """Exact-shape view of a possibly padded table (device slice to the
     logical row count; identity for exact tables)."""
-    from ..column import Column, Table
+    from ..column import Table
 
     lr = table.logical_rows
     if lr is None:
         return table
     if lr == table.row_count:
         return Table(table.columns, table.names)
-    cols = [
-        Column(
-            c.data[:lr],
-            c.dtype,
-            None if c.validity is None else c.validity[:lr],
-            None if c.lengths is None else c.lengths[:lr],
-        )
-        for c in table.columns
-    ]
-    return Table(cols, table.names)
+    return head_table(table, lr)
 
 
 def table_signature(table) -> tuple:

@@ -61,7 +61,7 @@ class FaultError(RuntimeError):
 
 
 class TransientDeviceError(FaultError):
-    """The device/tunnel hiccuped (UNAVAILABLE, reset, unreachable):
+    """The device hiccuped (UNAVAILABLE, reset, unreachable):
     the op is intact and a retry with backoff may succeed."""
 
 
@@ -92,7 +92,7 @@ class Degraded(FaultError):
     device. Answers immediately — a degraded daemon never hangs."""
 
 
-# message markers for transient device/tunnel failures — the superset
+# message markers for transient device failures — the superset
 # of bench.py's historical _UNREACHABLE_MARKERS (gRPC/absl capitalize
 # freely, so matching is casefolded)
 _TRANSIENT_MARKERS = (

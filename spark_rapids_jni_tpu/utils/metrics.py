@@ -245,7 +245,7 @@ NULL_SPAN = _NullSpan()
 
 
 # span-duration histogram edges in MILLISECONDS: ~x3 rungs from 10us to
-# 30s + overflow — wide enough for a tunnel round-trip, fine enough that
+# 30s + overflow — wide enough for a cold compile, fine enough that
 # analyze_bench's p50/p95 estimates are meaningful. Public: subsystem-
 # owned duration histograms (pipeline.stall_ms / pipeline.overlap_ms)
 # share these edges so analyze_bench percentiles line up across planes.

@@ -46,7 +46,7 @@ class TestUnreachableClassifier:
             "device unreachable",
             "UNAVAILABLE: socket closed",
             "DEADLINE_EXCEEDED while fetching",
-            "failed to connect to tunnel",
+            "failed to connect to device",
             "Failed to connect to remote host",  # capitalized gRPC text
             "Socket closed",
         ):
@@ -99,7 +99,7 @@ class TestSigtermTelemetryFlush:
             from spark_rapids_jni_tpu.utils import flight, metrics
             bench._LAST_LINE = '{{"metric": "sigterm-test"}}'
             with metrics.span("cfg.doomed"):
-                flight.record("I", "tunnel.probe_retry")
+                flight.record("I", "probe.device_retry")
                 os.kill(os.getpid(), signal.SIGTERM)
                 time.sleep(30)
                 sys.exit(3)  # handler never fired
@@ -110,7 +110,6 @@ class TestSigtermTelemetryFlush:
             "SPARK_RAPIDS_TPU_METRICS_DUMP": str(mdump),
             "SPARK_RAPIDS_TPU_FLIGHT_DUMP": str(fdump),
             "JAX_PLATFORMS": "cpu",
-            "SRT_JAX_PLATFORMS": "cpu",
         })
         proc = subprocess.run(
             [sys.executable, "-c", script], capture_output=True,
@@ -128,7 +127,7 @@ class TestSigtermTelemetryFlush:
         doc = json.loads(fdump.read_text())
         names = [e["name"] for e in doc["events"]]
         assert "cfg.doomed" in names
-        assert "tunnel.probe_retry" in names
+        assert "probe.device_retry" in names
         assert names[-1] == "bench.sigterm"
         # the span never closed — no E event for it (the crash shape
         # tools/trace2chrome.py renders as an unterminated X)
@@ -149,7 +148,6 @@ class TestBudgetExhaustedRun:
         env.update({
             "SRT_BENCH_BUDGET_S": "0",
             "JAX_PLATFORMS": "cpu",
-            "SRT_JAX_PLATFORMS": "cpu",
         })
         proc = subprocess.run(
             [sys.executable, "bench.py"], capture_output=True,

@@ -1,0 +1,356 @@
+"""Compile the chip path for a described TPU v5e — no chip attached.
+
+The TPU's compiler is installed in the sandbox and compiles for a chip
+that is described, not present (on-chip-measurement guide §2, the third
+rehearsal). Interpret mode cannot see what Mosaic refuses — a 64-bit
+scalar leaking into a kernel body, an in-kernel gather, a program that
+does not fit the device — so every kernel in ``kernels/registry.py``
+``_REGISTRY`` has a case here, at the size ``chip_smoke.py`` runs it,
+next to the capped join (at the smoke's 2^23 bucket), the capped groupby
+and the four-chip exchange. A kernel the chip's compiler refuses does
+not get registered.
+
+This is the only file that describes a chip. The topology is described
+inside a module-scoped fixture (never at import: only one process may
+load libtpu, and every xdist worker imports every test file), the
+compiles run in the test's own process, and the persistent compile
+cache is off around them (an AOT entry cannot be read back without a
+chip, and the next compile would warn).
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs land in /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from spark_rapids_jni_tpu import dtype as dt
+from spark_rapids_jni_tpu import rows as rows_mod
+from spark_rapids_jni_tpu.column import Column, Table
+from spark_rapids_jni_tpu.utils import buckets
+
+#: chip_smoke.py's sizes: the row round trip, and the fact table's bucket
+SMOKE_ROWS = 4_000_000
+SMOKE_BUCKET = 1 << 23
+#: v5e HBM per chip (bytes_limit is a little under this)
+V5E_HBM = 16 << 30
+
+ROW_SCHEMA = (
+    dt.INT64, dt.FLOAT64, dt.INT32, dt.BOOL8, dt.FLOAT32, dt.INT8,
+    dt.DType(dt.TypeId.DECIMAL32, -3), dt.DType(dt.TypeId.DECIMAL64, -8),
+)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        t = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 - any failure: cannot describe
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Steer code that asks the backend at trace time (utils/ieee754.py,
+    kernels.default_interpret) down its TPU branch: under
+    JAX_PLATFORMS=cpu it would otherwise trace the CPU's."""
+    from spark_rapids_jni_tpu import kernels
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+
+
+def _fits(compiled) -> None:
+    m = compiled.memory_analysis()
+    total = (
+        m.argument_size_in_bytes + m.output_size_in_bytes
+        + m.temp_size_in_bytes
+    )
+    assert total < V5E_HBM, f"program needs {total / 2**30:.1f} GiB"
+
+
+def _table(one_chip, schema, n, nullable=False) -> Table:
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    return Table([
+        Column(
+            s((n,), np.dtype(d.storage_dtype)), d,
+            s((n,), jnp.bool_) if nullable else None,
+        )
+        for d in schema
+    ])
+
+
+# ---------------------------------------------------------------------------
+# the Pallas row kernels (kernels/row_transpose.py)
+# ---------------------------------------------------------------------------
+
+
+def test_pack_rows_pallas_compiles(one_chip):
+    from spark_rapids_jni_tpu.kernels import row_transpose as rt
+
+    layout = rows_mod.compute_fixed_width_layout(ROW_SCHEMA)
+    n = 1 << 20
+    cols = tuple(
+        jax.ShapeDtypeStruct((n, w), jnp.uint8, sharding=one_chip)
+        for w in layout.column_widths
+    )
+    valid = jax.ShapeDtypeStruct(
+        (n, len(ROW_SCHEMA)), jnp.uint8, sharding=one_chip
+    )
+    compiled = rt.pack_rows_pallas.lower(
+        cols, valid, layout=layout, interpret=False
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_unpack_rows_pallas_compiles(one_chip):
+    from spark_rapids_jni_tpu.kernels import row_transpose as rt
+
+    layout = rows_mod.compute_fixed_width_layout(ROW_SCHEMA)
+    rows = jax.ShapeDtypeStruct(
+        (1 << 20, layout.row_size), jnp.uint8, sharding=one_chip
+    )
+    compiled = rt.unpack_rows_pallas.lower(
+        rows, layout=layout, interpret=False
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# every registered kernel, as its registry runner launches it
+# ---------------------------------------------------------------------------
+
+
+def _compile_row_pack(one_chip):
+    layout = rows_mod.compute_fixed_width_layout(ROW_SCHEMA)
+    cols = _table(one_chip, ROW_SCHEMA, SMOKE_ROWS, nullable=True).columns
+    return jax.jit(
+        lambda c: rows_mod._pack_batch_pallas(c, layout)
+    ).lower(cols).compile()
+
+
+def _compile_row_unpack(one_chip):
+    layout = rows_mod.compute_fixed_width_layout(ROW_SCHEMA)
+    data = jax.ShapeDtypeStruct(
+        (SMOKE_ROWS, layout.row_size), jnp.uint8, sharding=one_chip
+    )
+    return jax.jit(
+        lambda d: rows_mod._unpack_batch_pallas(d, layout)
+    ).lower(data).compile()
+
+
+#: kernel name -> compile of its device program at the largest shape its
+#: applicability predicate admits (the row kernels have no row bound:
+#: the smoke's 4,000,000 rows stand in). A new registry entry needs a
+#: line here before it can ship.
+REGISTRY_CASES = {
+    "row_pack": _compile_row_pack,
+    "row_unpack": _compile_row_unpack,
+}
+
+
+def test_every_registered_kernel_has_a_compile_case():
+    from spark_rapids_jni_tpu.kernels import registry
+
+    assert set(REGISTRY_CASES) == set(registry._REGISTRY)
+
+
+@pytest.mark.parametrize("name", sorted(REGISTRY_CASES))
+def test_registered_kernel_compiles_at_smoke_size(one_chip, as_tpu, name):
+    compiled = REGISTRY_CASES[name](one_chip)
+    assert "tpu_custom_call" in compiled.as_text(), "no Mosaic kernel inside"
+    _fits(compiled)
+
+
+# ---------------------------------------------------------------------------
+# unregistered engines that do compile (kept honest for the PR that
+# points a kernel at them) and the one the partition op runs on TPU
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("engine", ["u64", "u32"])
+def test_bitonic_roll_engines_compile(one_chip, engine):
+    from spark_rapids_jni_tpu.kernels import bitonic_sort as bs
+
+    c, t = 64, 1024
+    a = jax.ShapeDtypeStruct((c, t), jnp.uint32, sharding=one_chip)
+    if engine == "u64":
+        # hi, lo + one 64-bit payload split in two
+        bs._sort_call(2, t, False).lower(a, a, a, a).compile()
+    else:
+        bs._sort_call_u32(1, t, False).lower(a, a).compile()
+
+
+def test_fused_murmur3_kernel_compiles(one_chip):
+    """ops/hashing.murmur3_table picks this kernel whenever it runs on
+    a TPU — the hash `partition` op's partition ids."""
+    from spark_rapids_jni_tpu.kernels import hashing as khash
+
+    n = SMOKE_BUCKET // 4  # one of four shards of the smoke's fact
+    w = jax.ShapeDtypeStruct((n,), jnp.uint32, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((n,), jnp.uint8, sharding=one_chip)
+    compiled = khash._hash_words_pallas.lower(
+        (w, w), (v,), kinds=("long",), seed=42, interpret=False
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# the capped join and groupby at the smoke's bucket (f64, memory)
+# ---------------------------------------------------------------------------
+
+FACT = (dt.INT64, dt.INT64, dt.INT64, dt.FLOAT64)
+
+
+def test_capped_inner_join_compiles_at_smoke_bucket(one_chip, as_tpu):
+    from spark_rapids_jni_tpu.ops import join as join_mod
+
+    fact = _table(one_chip, FACT, SMOKE_BUCKET)
+    dim = _table(one_chip, (dt.INT64, dt.INT64), 1 << 13)
+    n32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+
+    def fn(l, r, ln, rn):
+        lv = buckets.tail_valid(l.row_count, ln)
+        rv = buckets.tail_valid(r.row_count, rn)
+        return join_mod.inner_join_capped(
+            l, r, [0], SMOKE_BUCKET, left_valid=lv, right_valid=rv
+        )
+
+    _fits(jax.jit(fn).lower(fact, dim, n32, n32).compile())
+
+
+@pytest.mark.parametrize("bucket", [
+    1 << 13, pytest.param(SMOKE_BUCKET, marks=pytest.mark.slow),
+])
+def test_capped_groupby_compiles(one_chip, as_tpu, bucket):
+    """sum / count / float64 sum, as the smoke's resident plan
+    aggregates: the f64 path (utils/ieee754.py's TPU branch) and the
+    64-bit variadic sort, for the chip's compiler. Tier-1 compiles the
+    program at a small bucket; the smoke's own 2^23 bucket, where the
+    question is memory (0.8 GiB), is the nightly tier's — the TPU
+    compiler takes minutes on this program at any size (my AOT runs,
+    PR 23: 33-255 s at 2^14 rows, ~400 s at 2^23; a min/max aggregation
+    adds ~230 s)."""
+    from spark_rapids_jni_tpu.ops.groupby import (
+        GroupbyAgg,
+        groupby_aggregate_capped,
+    )
+
+    fact = _table(one_chip, FACT, bucket)
+    n32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    aggs = [GroupbyAgg(2, "sum"), GroupbyAgg(2, "count"),
+            GroupbyAgg(3, "sum")]
+
+    def fn(t, n):
+        rv = buckets.tail_valid(t.row_count, n)
+        return groupby_aggregate_capped(
+            t, [0], aggs, num_segments=t.row_count, row_valid=rv
+        )
+
+    _fits(jax.jit(fn).lower(fact, n32).compile())
+
+
+# ---------------------------------------------------------------------------
+# four chips: the mesh partition stage (parallel/planmesh.py) as one
+# program over the described 2x2 mesh
+# ---------------------------------------------------------------------------
+
+
+def test_mesh_partition_exchange_compiles_for_four_chips(
+    topo, as_tpu, monkeypatch
+):
+    """filter -> hash partition over 4 chips: the stage's shard_map body
+    must lower to ``ragged-all-to-all`` with 64-bit columns in the table
+    (the TPU compiler has no X64 rewrite for that collective — the
+    exchange carries them as (n, 2) u32 words) and keep the fused murmur3
+    kernel inside."""
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from spark_rapids_jni_tpu import kernels
+    from spark_rapids_jni_tpu.parallel import mesh as mesh_mod
+    from spark_rapids_jni_tpu.parallel import planmesh, shuffle
+
+    axis = mesh_mod.SHUFFLE_AXIS
+    mesh = Mesh(np.array(topo.devices), (axis,))
+    size, per = 4, 1 << 18
+
+    def sds(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, spec)
+        )
+
+    fact = FACT + (dt.BOOL8,)
+    pt = Table([
+        Column(sds((per * size,), np.dtype(d.storage_dtype), P(axis)), d, None)
+        for d in fact
+    ])
+    prepared = {
+        "mesh": mesh, "size": size, "pt": pt,
+        "cnt": sds((size,), jnp.int32, P(axis)),
+        # planned (src, dst) send counts: what the counts pass returns
+        "counts": jnp.full((size, size), per // size, jnp.int32),
+    }
+
+    class Compiled(Exception):
+        pass
+
+    real_shard_map = planmesh.shard_map
+
+    def compile_instead_of_launch(body, **kw):
+        fn = real_shard_map(body, **kw)
+
+        def launch(*args):
+            shapes = [
+                sds(a.shape, a.dtype, P()) if isinstance(a, jax.Array) else a
+                for a in args
+            ]
+            raise Compiled(jax.jit(fn).lower(*shapes).compile())
+
+        return launch
+
+    monkeypatch.setattr(planmesh, "shard_map", compile_instead_of_launch)
+    monkeypatch.setattr(
+        planmesh, "run_collective", lambda label, launch, site=None: launch()
+    )
+    monkeypatch.setattr(shuffle, "_ragged_impl", lambda impl: "ragged")
+    monkeypatch.setattr(kernels, "on_tpu", lambda: True)
+
+    ops = [
+        {"op": "filter", "mask": 4},
+        {"op": "partition", "kind": "hash", "keys": [0], "num": size},
+    ]
+    pre, part, post = planmesh._split_at_exchange(ops)
+    stage = planmesh._partition_stage(
+        pre, part, post, None, per * size, axis, prepared=prepared
+    )
+    with pytest.raises(Compiled) as got:
+        stage(mesh)
+    compiled = got.value.args[0]
+    text = compiled.as_text()
+    assert "ragged-all-to-all" in text
+    assert "tpu_custom_call" in text
+    _fits(compiled)

@@ -296,7 +296,6 @@ class TestDump:
         env.update({
             "SPARK_RAPIDS_TPU_FLIGHT_DUMP": str(dump),
             "JAX_PLATFORMS": "cpu",
-            "SRT_JAX_PLATFORMS": "cpu",
         })
         proc = subprocess.run(
             [sys.executable, "-c", code], capture_output=True,
@@ -576,14 +575,14 @@ class TestBenchFlightTail:
         import bench
 
         config.set_flag("FLIGHT", True)
-        flight.record("I", "tunnel.probe_failed", 1)
-        flight.record("I", "tunnel.probe_retry")
+        flight.record("I", "probe.device_failed", 1)
+        flight.record("I", "probe.device_retry")
         rec = bench._failure_record(
             "join", "device unreachable", exc_type="DeviceUnreachable",
         )
         tail = rec["failure"]["flight_tail"]
         assert [e["name"] for e in tail[-2:]] == [
-            "tunnel.probe_failed", "tunnel.probe_retry",
+            "probe.device_failed", "probe.device_retry",
         ]
         json.dumps(rec)
 

@@ -1,7 +1,7 @@
 """The 64-bit join-fault fence (round-4 VERDICT item 3).
 
-The fused single-shot join graph kills the TPU worker at >= 32M rows
-(tools/xla_join_fault_repro.py), so above ``FUSED_PROBE_MAX_ROWS`` the
+The fused single-shot join graph killed the TPU worker at >= 32M rows
+(ops/join.py, the comment above ``FUSED_PROBE_MAX_ROWS``), so above it the
 eager join APIs must route through chunk-probed graphs automatically —
 the reference never lets callers choose safety (its 2 GB batch split is
 automatic, row_conversion.cu:476-479,505-511). These tests lower the

@@ -71,12 +71,12 @@ def test_alloc_level_overrides_only_alloc_channels(capsys):
 
 def test_level_ordering(capsys):
     config.set_flag("LOG_LEVEL", "WARN")
-    log.log("ERROR", "tunnel", "e")
-    log.log("WARN", "tunnel", "w")
-    log.log("INFO", "tunnel", "i")
+    log.log("ERROR", "probe", "e")
+    log.log("WARN", "probe", "w")
+    log.log("INFO", "probe", "i")
     err = capsys.readouterr().err
-    assert "[srt][tunnel][ERROR] e" in err
-    assert "[srt][tunnel][WARN] w" in err
+    assert "[srt][probe][ERROR] e" in err
+    assert "[srt][probe][WARN] w" in err
     assert " i" not in err
 
 
@@ -89,10 +89,10 @@ def test_alloc_off_silences_even_under_debug(capsys):
     config.set_flag("LOG_LEVEL", "DEBUG")
     config.set_flag("ALLOC_LOG_LEVEL", "OFF")
     log.log("DEBUG", "handles", "handle-line")
-    log.log("DEBUG", "tunnel", "tunnel-line")
+    log.log("DEBUG", "probe", "probe-line")
     err = capsys.readouterr().err
     assert "handle-line" not in err
-    assert "tunnel-line" in err
+    assert "probe-line" in err
 
 
 def test_invalid_alloc_level_falls_back(capsys):

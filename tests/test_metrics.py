@@ -370,7 +370,6 @@ class TestStdoutHygiene:
             "SPARK_RAPIDS_TPU_METRICS": "1",
             "SPARK_RAPIDS_TPU_METRICS_DUMP": str(dump),
             "JAX_PLATFORMS": "cpu",
-            "SRT_JAX_PLATFORMS": "cpu",
         })
         proc = subprocess.run(
             [sys.executable, "-c", code], capture_output=True,
@@ -473,9 +472,12 @@ class TestBenchFailureRecords:
             "SPARK_RAPIDS_TPU_PLANSTATS_DIR", str(tmp_path / "planstats")
         )
         buf = io.StringIO()
-        with contextlib.redirect_stdout(buf):
+        # a run that finds no device fails — after printing the ladder
+        with contextlib.redirect_stdout(buf), pytest.raises(SystemExit) as ex:
             bench.main()
+        assert ex.value.code == 1
         last = json.loads(buf.getvalue().strip().splitlines()[-1])
+        assert last["headline_source"] == "none" and last["value"] is None
         by_name = {e["name"]: e for e in last["configs"]}
         # every ladder arm is present, plus the mesh tail's typed skip
         # records (the arms never vanish into bare progress lines)

@@ -272,6 +272,45 @@ class TestFusedParity:
         rb.table_free(cur)
         assert got == want
 
+    @pytest.mark.parametrize("head", [
+        [],  # one-op run: the per-op bucketed groupby runner
+        [{"op": "filter", "mask": 2}],  # fused filter -> groupby segment
+    ], ids=["per_op", "fused"])
+    def test_groupby_output_shrinks_to_its_own_bucket(self, head):
+        # 7 groups out of a 4096-row bucket: what follows the groupby
+        # (here a sort_by, then the download) runs at the 1024 floor
+        # bucket, not at the input's — same bytes as the exact path
+        n = 3000
+        rng = np.random.default_rng(9)
+        k = rng.integers(0, 7, n, dtype=np.int64)
+        v = rng.integers(-5, 5, n, dtype=np.int64)
+        m = np.ones(n, np.uint8)
+        plan = head + [GROUP]
+        tail = [{"op": "sort_by", "keys": [
+            {"column": 1, "ascending": False}, {"column": 0}]}]
+        ids, datas = [I64, I64], [k.tobytes(), v.tobytes()]
+        if head:
+            ids, datas = ids + [B8], datas + [m.tobytes()]
+
+        def run(ops):
+            tid = rb.table_upload_wire(
+                ids, [0] * len(ids), datas, [None] * len(ids), n
+            )
+            out = rb.table_plan_resident(json.dumps(ops), [tid])
+            t = rb._resident_get(out)
+            shape = (t.row_count, t.logical_rows)
+            got = rb.table_download_wire(out)
+            rb.table_free(tid)
+            rb.table_free(out)
+            return shape, got
+
+        config.set_flag("BUCKETS", "")
+        assert run(plan)[0] == (1024, 7)
+        shape, got = run(plan + tail)
+        assert shape == (1024, 7)
+        config.set_flag("BUCKETS", "off")
+        assert run(plan + tail)[1] == got
+
     def test_fused_failure_replays_per_op(self, monkeypatch):
         # a broken fused builder must not change results — the segment
         # replays per-op and the failure is counted + WARN'd once
