@@ -204,7 +204,9 @@ def _r_cast(op: dict, table: Table, rest) -> Table:
 
         return fn
 
-    fn = buckets.cached_jit(_key("cast", op, pt), build, "srt_bucketed_cast")
+    fn = buckets.cached_jit(
+        _key("cast", op, pt), build, "srt_bucketed_cast", scope="srt.cast"
+    )
     return _finish(fn(_strip(pt)), pt.logical_row_count)
 
 
@@ -232,7 +234,8 @@ def _r_filter(op: dict, table: Table, rest) -> Table:
         return fn
 
     fn = buckets.cached_jit(
-        _key("filter", op, pt), build, "srt_bucketed_filter"
+        _key("filter", op, pt), build, "srt_bucketed_filter",
+        scope="srt.filter",
     )
     out, count = fn(_strip(pt), _n_dev(pt))
     # srt: allow-host-sync(bucketed-runner boundary: the compiled launch is done; one count read sizes the logical rows of the padded result)
@@ -256,7 +259,8 @@ def _r_sort(op: dict, table: Table, rest) -> Table:
         return fn
 
     fn = buckets.cached_jit(
-        _key("sort_by", op, pt), build, "srt_bucketed_sort"
+        _key("sort_by", op, pt), build, "srt_bucketed_sort",
+        scope="srt.sort_by",
     )
     return _finish(fn(_strip(pt), _n_dev(pt)), pt.logical_row_count)
 
@@ -286,7 +290,8 @@ def _r_groupby(op: dict, table: Table, rest) -> Table:
         return fn
 
     fn = buckets.cached_jit(
-        _key("groupby", op, pt), build, "srt_bucketed_groupby"
+        _key("groupby", op, pt), build, "srt_bucketed_groupby",
+        scope="srt.groupby",
     )
     out, num_groups = fn(_strip(pt), _n_dev(pt))
     # srt: allow-host-sync(bucketed-runner boundary: the compiled launch is done; one count read sizes the logical rows of the padded result)
@@ -309,7 +314,8 @@ def _r_distinct(op: dict, table: Table, rest) -> Table:
         return fn
 
     fn = buckets.cached_jit(
-        _key("distinct", op, pt), build, "srt_bucketed_distinct"
+        _key("distinct", op, pt), build, "srt_bucketed_distinct",
+        scope="srt.distinct",
     )
     out, count = fn(_strip(pt), _n_dev(pt))
     # srt: allow-host-sync(bucketed-runner boundary: the compiled launch is done; one count read sizes the logical rows of the padded result)
@@ -338,7 +344,8 @@ def _r_rlike(op: dict, table: Table, rest) -> Table:
         return fn
 
     fn = buckets.cached_jit(
-        _key("rlike", op, pt), build, "srt_bucketed_rlike"
+        _key("rlike", op, pt), build, "srt_bucketed_rlike",
+        scope="srt.rlike",
     )
     out, count = fn(_strip(pt), _n_dev(pt))
     # srt: allow-host-sync(bucketed-runner boundary: the compiled launch is done; one count read sizes the logical rows of the padded result)
@@ -384,7 +391,7 @@ def _r_join(op: dict, table: Table, rest) -> Table:
 
         fn = buckets.cached_jit(
             _key("join." + how, op, lt, rt), build_sa,
-            "srt_bucketed_join_" + how,
+            "srt_bucketed_join_" + how, scope="srt.join",
         )
         out, count = fn(_strip(lt), _strip(rt), _n_dev(lt), _n_dev(rt))
         # srt: allow-host-sync(bucketed-runner boundary: the compiled launch is done; one count read sizes the logical rows of the padded result)
@@ -411,7 +418,7 @@ def _r_join(op: dict, table: Table, rest) -> Table:
 
     p1 = buckets.cached_jit(
         _key("join.ranges", {"on": on}, lt, rt), build_probe,
-        "srt_bucketed_join_probe",
+        "srt_bucketed_join_probe", scope="srt.join",
     )
     perm_r, lo, counts, inner_total, left_total = p1(
         _strip(lt), _strip(rt), _n_dev(lt), _n_dev(rt)
@@ -453,7 +460,7 @@ def _r_join(op: dict, table: Table, rest) -> Table:
 
     p2 = buckets.cached_jit(
         _key("join.mat." + how, {"on": on}, lt, rt, extra=(cap,)),
-        build_mat, "srt_bucketed_join_mat",
+        build_mat, "srt_bucketed_join_mat", scope="srt.join",
     )
     out = p2(_strip(lt), _strip(rt), perm_r, lo, counts, _n_dev(lt))
     return _finish(out, total)

@@ -48,6 +48,7 @@ caller thread — the overlap shows up as ``pipeline.overlap_ms``.
 
 from __future__ import annotations
 
+import threading
 from typing import Optional, Sequence
 
 import jax
@@ -59,6 +60,19 @@ from ..column import Column, Table
 from ..utils import metrics
 from .mesh import SHUFFLE_AXIS, shard_map
 from .tolerant import MeshRunner, run_collective
+
+
+# planned receive rows per device of the calling thread's last exchange
+# stage (the stage reads them to the host to size it): the serving
+# tier's work item moves them onto its session with take_recv()
+_LAST_RECV = threading.local()
+
+
+def take_recv():
+    """The planned receive rows per device of this thread's last
+    exchange stage, once (None when no exchange ran since)."""
+    rows, _LAST_RECV.rows = getattr(_LAST_RECV, "rows", None), None
+    return rows
 
 
 class MeshUnsupported(Exception):
@@ -180,7 +194,8 @@ def _rowlocal_stage(seg_ops, table: Table, n: int, axis: str):
         # re-derived per replay: a smaller surviving mesh re-plans the
         # shard layout + per-shard valid counts from the same lineage
         size = int(mesh.shape[axis])
-        pt, cnt = _pack_sharded(table, mesh, axis, n)
+        with metrics.span("mesh.pack"):
+            pt, cnt = _pack_sharded(table, mesh, axis, n)
 
         def body(local, c):
             t2, n2 = plan_mod._run_segment_traced(seg_ops, local, c[0])
@@ -193,7 +208,10 @@ def _rowlocal_stage(seg_ops, table: Table, n: int, axis: str):
             check_vma=False,
         )
         out_t, out_c = fn(pt, cnt)
-        return _gather_prefix(out_t, out_c, size)
+        # no read between the launch and the gather: the gather's first
+        # device_get waits for the stage's device work
+        with metrics.span("mesh.gather"):
+            return _gather_prefix(out_t, out_c, size)
 
     return stage
 
@@ -245,14 +263,15 @@ def _partition_stage(pre, part, post, table: Table, n: int, axis: str,
 
         def count_body(local, c):
             t2, n2 = plan_mod._run_segment_traced(pre, local, c[0])
-            rv = jnp.arange(t2.row_count, dtype=jnp.int32) < n2
-            pid = pids_of(t2)
-            dd = jnp.where(
-                rv, (pid * size) // num, size
-            ).astype(jnp.int32)
-            return jnp.bincount(dd, length=size + 1)[:size].astype(
-                jnp.int32
-            )[None, :]
+            with jax.named_scope("srt.partition"):
+                rv = jnp.arange(t2.row_count, dtype=jnp.int32) < n2
+                pid = pids_of(t2)
+                dd = jnp.where(
+                    rv, (pid * size) // num, size
+                ).astype(jnp.int32)
+                return jnp.bincount(dd, length=size + 1)[:size].astype(
+                    jnp.int32
+                )[None, :]
 
         fn = shard_map(
             count_body, mesh=mesh,
@@ -273,16 +292,23 @@ def _partition_stage(pre, part, post, table: Table, n: int, axis: str,
             pt, cnt = prepared["pt"], prepared["cnt"]
             counts = prepared["counts"]
         else:
-            pt, cnt = _pack_sharded(table, mesh, axis, n)
-            counts = counts_pass(mesh, pt, cnt, size)
-        cap = total_recv_capacity(counts)
-        # srt: allow-host-sync(two-phase sizing: the planning pass exists to produce this host capacity)
-        pair_cap = _round_capacity(int(jnp.max(counts)))
-        # observe (not split: a pure redistribution has no agg to make
-        # salting lossless) planned recv skew across destinations — the
-        # planstats drift surface for partition-op plans
-        # srt: allow-host-sync(two-phase sizing: the skew observation reads the planned counts)
-        recv = np.asarray(jax.device_get(jnp.sum(counts, axis=0)))
+            with metrics.span("mesh.pack"):
+                pt, cnt = _pack_sharded(table, mesh, axis, n)
+            counts = None
+        # the counts pass through its two read-backs: device-ended
+        with metrics.span("mesh.counts"):
+            if counts is None:
+                counts = counts_pass(mesh, pt, cnt, size)
+            cap = total_recv_capacity(counts)
+            # srt: allow-host-sync(two-phase sizing: the planning pass exists to produce this host capacity)
+            pair_cap = _round_capacity(int(jnp.max(counts)))
+            # observe (not split: a pure redistribution has no agg to
+            # make salting lossless) planned recv skew across
+            # destinations — the planstats drift surface for
+            # partition-op plans, and the serving session's mesh_recv
+            # srt: allow-host-sync(two-phase sizing: the skew observation reads the planned counts)
+            recv = np.asarray(jax.device_get(jnp.sum(counts, axis=0)))
+        _LAST_RECV.rows = recv
         mean = float(recv.mean()) if recv.size else 0.0
         factor = float(config.get_flag("SKEW_SPLIT_FACTOR"))
         if mean > 0 and float(recv.max()) > factor * mean:
@@ -298,24 +324,26 @@ def _partition_stage(pre, part, post, table: Table, n: int, axis: str,
 
         def body(local, c, C):
             t2, n2 = plan_mod._run_segment_traced(pre, local, c[0])
-            rv = jnp.arange(t2.row_count, dtype=jnp.int32) < n2
-            pid = pids_of(t2)
-            dd = ((pid * size) // num).astype(jnp.int32)
-            out, occ, overflow = exchange_ragged(
-                t2, dd, C, cap, axis, impl, row_valid=rv,
-                pair_capacity=pair_cap,
-            )
-            # restore the exact path's order: received rows arrive in
-            # stable (src, in-src) order; a stable sort by recomputed
-            # pid (padding keyed past every real pid) makes this device
-            # hold its contiguous slice of the globally pid-sorted table
-            pid2 = pids_of(out)
-            skey = jnp.where(occ, pid2.astype(jnp.int32), num)
-            perm = jnp.argsort(skey, stable=True).astype(jnp.int32)
-            sorted_t = jax.tree_util.tree_map(
-                lambda x: None if x is None else x[perm], out
-            )
-            n_recv = jnp.sum(occ.astype(jnp.int32))
+            with jax.named_scope("srt.partition"):
+                rv = jnp.arange(t2.row_count, dtype=jnp.int32) < n2
+                pid = pids_of(t2)
+                dd = ((pid * size) // num).astype(jnp.int32)
+                out, occ, overflow = exchange_ragged(
+                    t2, dd, C, cap, axis, impl, row_valid=rv,
+                    pair_capacity=pair_cap,
+                )
+                # restore the exact path's order: received rows arrive
+                # in stable (src, in-src) order; a stable sort by
+                # recomputed pid (padding keyed past every real pid)
+                # makes this device hold its contiguous slice of the
+                # globally pid-sorted table
+                pid2 = pids_of(out)
+                skey = jnp.where(occ, pid2.astype(jnp.int32), num)
+                perm = jnp.argsort(skey, stable=True).astype(jnp.int32)
+                sorted_t = jax.tree_util.tree_map(
+                    lambda x: None if x is None else x[perm], out
+                )
+                n_recv = jnp.sum(occ.astype(jnp.int32))
             t3, n3 = plan_mod._run_segment_traced(post, sorted_t, n_recv)
             return (
                 t3,
@@ -329,18 +357,22 @@ def _partition_stage(pre, part, post, table: Table, n: int, axis: str,
             out_specs=(P(axis), P(axis), P(axis)),
             check_vma=False,
         )
-        out_t, out_c, out_ov = run_collective(
-            "plan.partition_exchange",
-            lambda: fn(pt, cnt, counts),
-            site="shuffle",
-        )
-        # capacity came from the real counts, so overflow means a bug —
-        # surface it loudly rather than gathering a truncated result
-        check_overflow_compact(out_ov, cap, "plan partition")
+        # the exchange launch through the overflow read: device-ended
+        with metrics.span("mesh.exchange"):
+            out_t, out_c, out_ov = run_collective(
+                "plan.partition_exchange",
+                lambda: fn(pt, cnt, counts),
+                site="shuffle",
+            )
+            # capacity came from the real counts, so overflow means a
+            # bug — surface it loudly rather than gathering a truncated
+            # result
+            check_overflow_compact(out_ov, cap, "plan partition")
         if metrics.enabled():
             metrics.counter_add("partition.mesh_segments")
             metrics.counter_add("partition.rows_exchanged", n)
-        return _gather_prefix(out_t, out_c, size)
+        with metrics.span("mesh.gather"):
+            return _gather_prefix(out_t, out_c, size)
 
     return stage
 
@@ -362,6 +394,7 @@ def run_plan_mesh(
     """
     from ..utils import buckets
 
+    _LAST_RECV.rows = None
     pre, part, post = _check_supported(ops, table, rest)
     # a bucket-padded wire upload shrinks to its real rows first: the
     # mesh stage derives its own shard padding, and the caller's padded
@@ -396,7 +429,8 @@ def prepare_exchange(ops: Sequence[dict], table: Table,
     axis = runner.axis
     mesh = runner.mesh
     size = int(mesh.shape[axis])
-    pt, cnt = _pack_sharded(table, mesh, axis, n)
+    with metrics.span("mesh.pack"):
+        pt, cnt = _pack_sharded(table, mesh, axis, n)
     from .. import plan as plan_mod
     from ..ops import partition as partition_mod
 

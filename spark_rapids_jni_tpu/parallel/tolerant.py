@@ -39,7 +39,7 @@ from typing import Callable, Optional
 
 import jax
 
-from ..utils import config, faults, flight, lockcheck, log, metrics, tracing
+from ..utils import config, faults, flight, lockcheck, log, metrics
 from .mesh import SHUFFLE_AXIS, MeshHealth, make_mesh
 
 
@@ -66,19 +66,12 @@ def run_collective(
     keep :func:`~..utils.faults.run_with_retry` semantics — they
     surface unchanged.
     """
-    # the exchange span: trace-tagged on the flight ring, so a merged
-    # trace shows every collective launch (and its retries — same span,
-    # same trace: replay never mints a fresh trace id) under the
-    # request that ran it
-    tok = tracing.span_begin(label)
-    err: Optional[str] = None
-    try:
+    # the exchange span: timed, and trace-tagged on the flight ring, so
+    # a merged trace shows every collective launch (and its retries —
+    # same span, same trace: replay never mints a fresh trace id) under
+    # the request that ran it
+    with metrics.span(label):
         return _run_collective(label, launch, site, donated, max_retries)
-    except BaseException as e:
-        err = type(e).__name__
-        raise
-    finally:
-        tracing.span_end(tok, error=err)
 
 
 def _run_collective(label, launch, site, donated, max_retries):
@@ -171,15 +164,8 @@ class MeshRunner:
         request's trace id (a replay never mints a fresh trace)."""
         with self._lock:
             self.stages += 1
-        tok = tracing.span_begin("mesh.stage")
-        err: Optional[str] = None
-        try:
+        with metrics.span("mesh.stage", stage=label):
             return self._run_stage(label, stage)
-        except BaseException as e:
-            err = type(e).__name__
-            raise
-        finally:
-            tracing.span_end(tok, error=err)
 
     def _run_stage(self, label: str, stage: Callable[[object], object]):
         while True:

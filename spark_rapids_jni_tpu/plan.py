@@ -45,6 +45,7 @@ from __future__ import annotations
 
 from typing import List, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from . import dtype as dt
@@ -278,10 +279,14 @@ def _run_segment_traced(seg_ops: Sequence[dict], t: Table, n):
     """The traced body of one fused segment: thread (table, count)
     through every op at the segment's one physical shape."""
     for op in seg_ops:
-        rv = buckets.tail_valid(t.row_count, n)
-        t, n = _FUSED[op["op"]](op, t, n, rv)
-        if hasattr(n, "astype"):
-            n = n.astype(jnp.int32)
+        # trace-time only: every HLO op of this plan op carries its
+        # name in its op_name metadata, so a device trace can give a
+        # fusion to filter, join, groupby or sort_by
+        with jax.named_scope("srt." + op["op"]):
+            rv = buckets.tail_valid(t.row_count, n)
+            t, n = _FUSED[op["op"]](op, t, n, rv)
+            if hasattr(n, "astype"):
+                n = n.astype(jnp.int32)
     return t, n
 
 
@@ -495,8 +500,6 @@ def run_plan(
     outright), every donation is additionally gated on the flowing
     table's buffers being disjoint from everything the caller can
     still observe (the undonated input and every ``rest`` table)."""
-    from . import bucketed, runtime_bridge
-
     if not isinstance(ops, (list, tuple)):
         raise TypeError("plan must be a JSON list of op objects")
     if not ops:
@@ -504,51 +507,88 @@ def run_plan(
     for op in ops:
         if not isinstance(op, dict) or "op" not in op:
             raise ValueError(f"plan entries must be op objects, got {op!r}")
-    if mesh_runner is not None:
-        from .parallel import planmesh
-        from .utils import hbm
+    # one span for the whole plan, the mesh offer included: the mesh
+    # path is a plan like any other to whoever reads the `plan` timer
+    with metrics.span("plan", ops=len(ops)):
+        if mesh_runner is not None:
+            out = _offer_mesh(ops, table, rest, mesh_runner)
+            if out is not None:
+                return out
+        return _run_segments(ops, table, rest, donate_input)
 
-        # the mesh path runs the whole plan as ONE sharded stage, so it
-        # gets one whole-plan "mesh" segment for attribution — the
-        # plan-stats record of a mesh run carries rows/bytes like the
-        # segment loop below does for the exact path
-        pseg = profiler.segment_begin(
-            0, "mesh", ops, rows_in=int(table.logical_row_count)
-        )
-        try:
-            out = planmesh.run_plan_mesh(ops, table, mesh_runner, rest)
-            metrics.counter_add("plan.mesh_segments")
-            profiler.segment_end(
-                pseg, rows_out=int(out.logical_row_count),
-                out_bytes=hbm.table_bytes(out),
-            )
-            pseg = None
-            return out
-        except planmesh.MeshUnsupported:
-            # not a failure: this plan has no mesh path
+
+def segment_sig(seg_ops: Sequence[dict]) -> str:
+    """A segment's op names joined by ``__`` (``filter__groupby``): the
+    suffix of its ``plan.segment.<sig>`` span. Op names are lowercase
+    identifiers, so the timer name keeps the metric-name grammar."""
+    return "__".join(str(o.get("op", "op")) for o in seg_ops)
+
+
+def _offer_mesh(ops, table: Table, rest, mesh_runner):
+    """Offer the plan to the mesh data-parallel path -> the result, or
+    None where the single-device path below has to run it."""
+    from .parallel import planmesh
+    from .utils import hbm
+
+    # the mesh path runs the whole plan as ONE sharded stage, so it
+    # gets one whole-plan "mesh" segment for attribution — the
+    # plan-stats record of a mesh run carries rows/bytes like the
+    # segment loop does for the exact path
+    pseg = profiler.segment_begin(
+        0, "mesh", ops, rows_in=int(table.logical_row_count)
+    )
+    try:
+        with metrics.span("plan.segment", index=0, kind="mesh",
+                          ops=len(ops)), \
+                metrics.span("plan.segment.mesh"):
+            try:
+                out = planmesh.run_plan_mesh(
+                    ops, table, mesh_runner, rest
+                )
+            except planmesh.MeshUnsupported:
+                # not a failure (and no span error): this plan has no
+                # mesh path
+                out = None
+        if out is None:
             metrics.counter_add("plan.mesh_declined")
             profiler.segment_end(pseg)
             pseg = None
-        except faults.Degraded as e:
-            # collective failures persisted down to the runner's device
-            # floor: the single-device exact path below IS the
-            # degradation target — the mesh path never consumed the
-            # input, so the replay lineage is intact
-            metrics.counter_add("plan.mesh_fallbacks")
-            faults.note_error_class(e, "plan.mesh")
-            if flight.enabled():
-                flight.record("I", "plan.mesh_fallback", str(e)[:160])
-            log.log(
-                "WARN", "plan", "mesh_degraded_to_exact",
-                error=f"{type(e).__name__}: {str(e)[:200]}",
-            )
-            profiler.segment_end(pseg, fallback=True)
-            pseg = None
-        finally:
-            # an unexpected exception propagates: close the segment so
-            # the thread-local binding never leaks past this plan
-            if pseg is not None:
-                profiler.segment_end(pseg)
+            return None
+        metrics.counter_add("plan.mesh_segments")
+        profiler.segment_end(
+            pseg, rows_out=int(out.logical_row_count),
+            out_bytes=hbm.table_bytes(out),
+        )
+        pseg = None
+        return out
+    except faults.Degraded as e:
+        # collective failures persisted down to the runner's device
+        # floor: the single-device exact path IS the degradation
+        # target — the mesh path never consumed the input, so the
+        # replay lineage is intact
+        metrics.counter_add("plan.mesh_fallbacks")
+        faults.note_error_class(e, "plan.mesh")
+        if flight.enabled():
+            flight.record("I", "plan.mesh_fallback", str(e)[:160])
+        log.log(
+            "WARN", "plan", "mesh_degraded_to_exact",
+            error=f"{type(e).__name__}: {str(e)[:200]}",
+        )
+        profiler.segment_end(pseg, fallback=True)
+        pseg = None
+    finally:
+        # an unexpected exception propagates: close the segment so
+        # the thread-local binding never leaks past this plan
+        if pseg is not None:
+            profiler.segment_end(pseg)
+    return None
+
+
+def _run_segments(ops, table: Table, rest, donate_input: bool) -> Table:
+    """The single-device path: the plan's segments in order, each under
+    its ``plan.segment`` span and a ``plan.segment.<sig>`` one."""
+    from . import bucketed, runtime_bridge
+
     orig_rest = tuple(rest)
     queue = list(orig_rest)
     if buckets.enabled():
@@ -571,92 +611,91 @@ def run_plan(
         protected.update(_buffer_ids(table))
     for t in orig_rest:
         protected.update(_buffer_ids(t))
-    with metrics.span("plan", segments=len(segs), ops=len(ops)):
-        for i, (kind, seg_ops) in enumerate(segs):
-            faults.check_cancel()  # between-segment checkpoint
-            with metrics.span(
-                "plan.segment", index=i, kind=kind, ops=len(seg_ops)
-            ):
-                pseg = profiler.segment_begin(
-                    i, kind, seg_ops,
-                    rows_in=int(table.logical_row_count),
-                )
-                fell_back = False
-                try:
-                    replay = seg_ops
-                    if kind == "fused":
-                        donate = owned and protected.isdisjoint(
-                            _buffer_ids(table)
+    for i, (kind, seg_ops) in enumerate(segs):
+        faults.check_cancel()  # between-segment checkpoint
+        with metrics.span(
+            "plan.segment", index=i, kind=kind, ops=len(seg_ops)
+        ), metrics.span("plan.segment." + segment_sig(seg_ops)):
+            pseg = profiler.segment_begin(
+                i, kind, seg_ops,
+                rows_in=int(table.logical_row_count),
+            )
+            fell_back = False
+            try:
+                replay = seg_ops
+                if kind == "fused":
+                    donate = owned and protected.isdisjoint(
+                        _buffer_ids(table)
+                    )
+                    try:
+                        table = _run_fused_tolerant(
+                            seg_ops, table, donate=donate
                         )
-                        try:
-                            table = _run_fused_tolerant(
-                                seg_ops, table, donate=donate
-                            )
-                            metrics.counter_add("plan.fused_segments")
-                            metrics.counter_add(
-                                "plan.fused_ops", len(seg_ops)
-                            )
-                            replay = ()
-                        except bucketed._Decline:
-                            # not a failure: no bucket for this shape —
-                            # the per-op path owns it
-                            metrics.counter_add("plan.declined")
-                        except (
-                            faults.Cancelled, faults.DeadlineExceeded
-                        ):
-                            # cooperative aborts are not segment
-                            # failures: never replayed, never wrapped
+                        metrics.counter_add("plan.fused_segments")
+                        metrics.counter_add(
+                            "plan.fused_ops", len(seg_ops)
+                        )
+                        replay = ()
+                    except bucketed._Decline:
+                        # not a failure: no bucket for this shape —
+                        # the per-op path owns it
+                        metrics.counter_add("plan.declined")
+                    except (
+                        faults.Cancelled, faults.DeadlineExceeded
+                    ):
+                        # cooperative aborts are not segment
+                        # failures: never replayed, never wrapped
+                        raise
+                    except Exception as e:
+                        if _input_consumed(table):
+                            # the donated executable failed AFTER
+                            # consuming its input: a per-op replay
+                            # would dereference deleted buffers —
+                            # surface the real error instead
                             raise
-                        except Exception as e:
-                            if _input_consumed(table):
-                                # the donated executable failed AFTER
-                                # consuming its input: a per-op replay
-                                # would dereference deleted buffers —
-                                # surface the real error instead
-                                raise
-                            # fusion must never change semantics: replay
-                            # per-op; the exact path raises the real
-                            # error if an op itself is at fault
-                            fell_back = True
-                            metrics.counter_add("plan.fallbacks")
-                            names = ",".join(
-                                str(o.get("op", "?")) for o in seg_ops
+                        # fusion must never change semantics: replay
+                        # per-op; the exact path raises the real
+                        # error if an op itself is at fault
+                        fell_back = True
+                        metrics.counter_add("plan.fallbacks")
+                        names = ",".join(
+                            str(o.get("op", "?")) for o in seg_ops
+                        )
+                        if flight.enabled():
+                            flight.record("I", "plan.fallback", names)
+                        if names not in _WARNED_SIGS:
+                            _WARNED_SIGS.add(names)
+                            log.log(
+                                "WARN", "plan",
+                                "fused_segment_failed",
+                                ops=names,
+                                error=(
+                                    f"{type(e).__name__}: "
+                                    f"{str(e)[:200]}"
+                                ),
                             )
-                            if flight.enabled():
-                                flight.record("I", "plan.fallback", names)
-                            if names not in _WARNED_SIGS:
-                                _WARNED_SIGS.add(names)
-                                log.log(
-                                    "WARN", "plan",
-                                    "fused_segment_failed",
-                                    ops=names,
-                                    error=(
-                                        f"{type(e).__name__}: "
-                                        f"{str(e)[:200]}"
-                                    ),
-                                )
-                    for op in replay:
-                        table = runtime_bridge._dispatch(
-                            op, table, _take_rest(op, orig_rest, queue)
-                        )
-                        metrics.counter_add("plan.exact_ops")
-                finally:
-                    if pseg is not None:
-                        from .utils import hbm
+                for op in replay:
+                    table = runtime_bridge._dispatch(
+                        op, table, _take_rest(op, orig_rest, queue)
+                    )
+                    metrics.counter_add("plan.exact_ops")
+            finally:
+                if pseg is not None:
+                    from .utils import hbm
 
-                        try:
-                            ro = int(table.logical_row_count)
-                            ob = int(hbm.table_bytes(table))
-                        # srt: allow-broad-except(donated-and-failed input has no sizeable buffers; profiling must not mask the real error)
-                        except Exception:  # donated-and-failed input
-                            ro, ob = 0, 0
-                        profiler.segment_end(
-                            pseg, rows_out=ro, out_bytes=ob,
-                            fallback=fell_back,
-                        )
-            # every segment output is a fresh plan-owned intermediate:
-            # the NEXT fused segment may donate it
-            owned = True
+                    try:
+                        ro = int(table.logical_row_count)
+                        ob = int(hbm.table_bytes(table))
+                    # srt: allow-broad-except(donated-and-failed input has no sizeable buffers; profiling must not mask the real error)
+                    except Exception:  # donated-and-failed input
+                        ro, ob = 0, 0
+                    profiler.segment_end(
+                        pseg, rows_out=ro, out_bytes=ob,
+                        fallback=fell_back,
+                    )
+        # every segment output is a fresh plan-owned intermediate:
+        # the NEXT fused segment may donate it
+        owned = True
     return table
 
 

@@ -23,6 +23,7 @@ from __future__ import annotations
 import json
 from typing import Optional, Sequence
 
+import jax
 import numpy as np
 
 from . import dtype as dt
@@ -696,12 +697,25 @@ def _table_to_wire_impl(t: Table):
     prof = profiler.session_active()
     t0 = _time.perf_counter() if prof else 0.0
     with metrics.span("wire.serialize"):
-        for c in t.columns:
-            ti, s, d, v = _column_to_wire(c, t.logical_rows, ctx)
-            out_t.append(ti)
-            out_s.append(s)
-            out_d.append(d)
-            out_v.append(v)
+        # the wait for the device apart from the host's copy: the first
+        # np.asarray below would wait all the same, under the copy's
+        # name. Only a live span waits up front; with every plane off
+        # the copies wait column by column, as they always did
+        wait = metrics.span("wire.serialize.wait")
+        if wait is not metrics.NULL_SPAN:
+            with wait:
+                jax.block_until_ready([
+                    b for c in t.columns
+                    for b in (c.data, c.validity, c.lengths)
+                    if b is not None
+                ])
+        with metrics.span("wire.serialize.copy"):
+            for c in t.columns:
+                ti, s, d, v = _column_to_wire(c, t.logical_rows, ctx)
+                out_t.append(ti)
+                out_s.append(s)
+                out_d.append(d)
+                out_v.append(v)
     if prof or flight.enabled():
         nbytes = sum(len(d) for d in out_d if d is not None)
         if flight.enabled():

@@ -15,7 +15,7 @@ import contextlib
 import socket
 from typing import List, Optional, Sequence
 
-from ..utils import tracing
+from ..utils import metrics, tracing
 from . import frames
 
 
@@ -181,8 +181,8 @@ class Client:
             return
         self._sock = None
         with contextlib.suppress(Exception):
-            frames.send_frame(s, {"cmd": "bye"})
-            frames.recv_frame(s)
+            frames.send_frame(s, {"cmd": "bye"}, span="client.send")
+            frames.recv_frame(s, span="client.recv", include_wait=True)
         with contextlib.suppress(OSError):
             s.close()
 
@@ -217,21 +217,20 @@ class Client:
             ctx = tracing.new_context()
         if ctx is not None and "traceparent" not in header:
             header["traceparent"] = ctx.header
-        with tracing.activate(ctx):
-            tok = tracing.span_begin("client.rpc")
-            try:
-                frames.send_frame(self._sock, header, buffers)
-                resp, payload = frames.recv_frame(self._sock)
-            except BaseException as e:
-                tracing.span_end(tok, error=type(e).__name__)
-                raise
-            tracing.span_end(
-                tok,
-                error=None if resp.get("ok")
-                else str((resp.get("error") or {}).get("type", "error")),
+        # client.recv includes the wait for the server's work: the
+        # server's own serving.request says how much of it that is
+        with tracing.activate(ctx), \
+                metrics.span("client.rpc", cmd=header.get("cmd")):
+            frames.send_frame(
+                self._sock, header, buffers, span="client.send"
             )
-        if not resp.get("ok"):
-            _raise_error(resp.get("error") or {})
+            resp, payload = frames.recv_frame(
+                self._sock, span="client.recv", include_wait=True
+            )
+            # inside the span: a typed error ends client.rpc as an error
+            # (the E event's arg, span.client.rpc.errors)
+            if not resp.get("ok"):
+                _raise_error(resp.get("error") or {})
         resp["_payload"] = payload
         return resp
 

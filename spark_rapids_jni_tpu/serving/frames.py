@@ -35,6 +35,13 @@ ambient context for the request, so every span/instant either side
 records into its flight ring carries the same trace id and
 ``tools/tracequery.py`` can merge the per-process dumps into one
 request timeline. A malformed header is ignored, never an error.
+
+``send_frame`` / ``recv_frame`` take the name of the span the caller
+wants the socket time under (``serving.send`` / ``serving.recv`` on the
+daemon's side, ``client.send`` / ``client.recv`` on the client's). The
+daemon's ``recv`` opens once the length prefix has arrived, so a client
+that is thinking between commands is not in it; the client's includes
+the wait for the daemon's reply.
 """
 
 from __future__ import annotations
@@ -42,6 +49,8 @@ from __future__ import annotations
 import json
 import struct
 from typing import List, Optional, Sequence, Tuple
+
+from ..utils import metrics
 
 # hard ceiling on one frame: a corrupt / hostile length prefix must
 # fail loudly instead of allocating the universe
@@ -70,10 +79,11 @@ def _recv_exact(sock, n: int) -> bytes:
     return b"".join(chunks)
 
 
-def send_frame(sock, header: dict, buffers: Sequence[bytes] = ()) -> None:
+def send_frame(sock, header: dict, buffers: Sequence[bytes] = (),
+               span: str = "serving.send") -> None:
     """Serialize and send one frame (single ``sendall`` for the prefix +
     header; buffers follow individually to avoid concatenating large
-    payloads host-side)."""
+    payloads host-side), timed under the caller's ``span``."""
     hdr = json.dumps(header, separators=(",", ":")).encode()
     total = 4 + len(hdr) + sum(len(b) for b in buffers)
     if total > MAX_FRAME_BYTES:
@@ -81,18 +91,21 @@ def send_frame(sock, header: dict, buffers: Sequence[bytes] = ()) -> None:
             f"frame of {total} bytes exceeds MAX_FRAME_BYTES "
             f"({MAX_FRAME_BYTES})"
         )
-    sock.sendall(_U32.pack(total) + _U32.pack(len(hdr)) + hdr)
-    for b in buffers:
-        if b:
-            sock.sendall(b)
+    with metrics.span(span):
+        sock.sendall(_U32.pack(total) + _U32.pack(len(hdr)) + hdr)
+        for b in buffers:
+            if b:
+                sock.sendall(b)
 
 
-def recv_frame(sock) -> Tuple[dict, bytes]:
-    """Receive one frame -> ``(header, payload)`` where ``payload`` is
-    the concatenated buffer bytes after the header."""
+def _recv_prefix(sock) -> int:
     total = _U32.unpack(_recv_exact(sock, 4))[0]
     if total < 4 or total > MAX_FRAME_BYTES:
         raise ProtocolError(f"bad frame length {total}")
+    return total
+
+
+def _recv_body(sock, total: int) -> Tuple[dict, bytes]:
     body = _recv_exact(sock, total)
     hdr_len = _U32.unpack_from(body)[0]
     if hdr_len > total - 4:
@@ -108,6 +121,23 @@ def recv_frame(sock) -> Tuple[dict, bytes]:
             f"frame header must be a JSON object, got {type(header).__name__}"
         )
     return header, body[4 + hdr_len:]
+
+
+def recv_frame(sock, span: str = "serving.recv",
+               include_wait: bool = False) -> Tuple[dict, bytes]:
+    """Receive one frame -> ``(header, payload)`` where ``payload`` is
+    the concatenated buffer bytes after the header. The caller's
+    ``span`` opens once the length prefix is here: it times the frame's
+    bytes coming off the socket (and the copy that splits header from
+    payload), not the wait for a peer to speak. ``include_wait=True``
+    opens it before the prefix instead — the client's side, where the
+    wait for the reply IS the request."""
+    if include_wait:
+        with metrics.span(span):
+            return _recv_body(sock, _recv_prefix(sock))
+    total = _recv_prefix(sock)
+    with metrics.span(span):
+        return _recv_body(sock, total)
 
 
 # ---------------------------------------------------------------------------

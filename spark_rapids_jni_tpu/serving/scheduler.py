@@ -50,8 +50,8 @@ class Ticket:
 
     __slots__ = (
         "session", "fn", "cost", "label", "charge", "prof", "token",
-        "ctx", "submit_t", "start_t", "end_t", "value", "error",
-        "_event",
+        "ctx", "parent", "submit_t", "start_t", "end_t", "value",
+        "error", "_event",
     )
 
     def __init__(self, session: Session, fn: Callable[[], object],
@@ -68,6 +68,10 @@ class Ticket:
         # into the executor pool by themselves, so the worker
         # re-activates this around the work (utils/tracing.py)
         self.ctx = tracing.current()
+        # and the span that submitted it (the connection thread's
+        # serving.request): the worker adopts it, so the work's spans
+        # are its children and its self time excludes the wait for them
+        self.parent = metrics.current_span()
         self.submit_t = time.perf_counter()
         self.start_t: Optional[float] = None
         self.end_t: Optional[float] = None
@@ -276,7 +280,9 @@ class FairScheduler:
                 "serving.queue_wait_ms", wait_s * 1e3,
                 bounds=metrics.SPAN_MS_BOUNDS,
             )
-            with tracing.activate(t.ctx):
+            with tracing.activate(t.ctx), \
+                    metrics.adopt(t.parent) as adopted:
+                adopted.credit(wait_s)
                 if flight.enabled():
                     # the wait is only measurable at dequeue: record
                     # the queue-wait span retroactively with backdated

@@ -451,15 +451,19 @@ class Server:
                     continue
                 t0 = time.perf_counter()
                 err: Optional[BaseException] = None
-                try:
-                    with tracing.activate(ctx):
+                # the server's side of one command, payload received ->
+                # reply sent: the root of the request's server spans
+                # (the scheduler hands it to the worker, metrics.adopt)
+                with tracing.activate(ctx), \
+                        metrics.span("serving.request", cmd=cmd):
+                    try:
                         self._dispatch(sock, sess, cmd, header, payload)
-                except (BrokenPipeError, ConnectionError, OSError):
-                    raise
-                # srt: allow-broad-except(every failure becomes a typed error frame via _error_header; the client always gets an answer, never a hang)
-                except BaseException as e:
-                    err = e
-                    frames.send_frame(sock, _error_header(e))
+                    except (BrokenPipeError, ConnectionError, OSError):
+                        raise
+                    # srt: allow-broad-except(every failure becomes a typed error frame via _error_header; the client always gets an answer, never a hang)
+                    except BaseException as e:
+                        err = e
+                        frames.send_frame(sock, _error_header(e))
                 self._note_request(cmd, sess, ctx, t0, err)
         except (ConnectionError, OSError, frames.ProtocolError):
             # disconnect / crash mid-stream: the finally below detaches
@@ -798,23 +802,26 @@ class Server:
         it running against a dead peer while holding HBM charge."""
         ops = self._plan_ops(header)
         tok = self._request_token(header, sess)
-        batches = frames.batches_from_parts(
-            header.get("batches") or [], payload
-        )
+        # one host copy of the payload into per-column byte strings
+        with metrics.span("serving.request_split"):
+            batches = frames.batches_from_parts(
+                header.get("batches") or [], payload
+            )
         # pre-admission static analysis against the first batch's wire
         # schema: a plan that statically cannot run answers a typed
         # bad_request (tagged report attached) BEFORE any scheduler
         # admission, HBM charge, or upload
-        if batches:
-            schema = plancheck.schema_from_wire(
-                batches[0][0], batches[0][1]
-            )
-            report = plancheck.check_plan(
-                ops, schema=schema, rows=int(batches[0][4]),
-            )
-        else:
-            schema = None
-            report = plancheck.check_plan(ops)
+        with metrics.span("plan.check"):
+            if batches:
+                schema = plancheck.schema_from_wire(
+                    batches[0][0], batches[0][1]
+                )
+                report = plancheck.check_plan(
+                    ops, schema=schema, rows=int(batches[0][4]),
+                )
+            else:
+                schema = None
+                report = plancheck.check_plan(ops)
         n = len(batches)
         sess.stats["bytes_in"] += len(payload)
         scope = profiler.profile_session(
@@ -865,6 +872,12 @@ class Server:
                         ops, tbl, donate_input=True,
                         mesh_runner=runner,
                     )
+                    if runner is not None:
+                        from ..parallel import planmesh
+
+                        recv = planmesh.take_recv()
+                        if recv is not None:
+                            sess.note_mesh_recv(recv)
                     return rb._table_to_wire(out)
 
                 return work
@@ -924,9 +937,10 @@ class Server:
         return None
 
     def _cmd_upload(self, sock, sess, header, payload) -> None:
-        batch = frames.batches_from_parts(
-            [header.get("batch") or {}], payload
-        )[0]
+        with metrics.span("serving.request_split"):
+            batch = frames.batches_from_parts(
+                [header.get("batch") or {}], payload
+            )[0]
         sess.stats["bytes_in"] += len(payload)
         est = estimate_request_bytes(batch)
         sess.admit(est)
@@ -989,13 +1003,14 @@ class Server:
                 (plancheck.schema_of_table(t), int(t.logical_row_count))
                 if resolved else (None, None)
             )
-        plancheck.check_plan(
-            ops,
-            schema=plancheck.schema_of_table(head),
-            rows=int(head.logical_row_count),
-            rest=rest_sigs,
-            names=head.names,
-        )
+        with metrics.span("plan.check"):
+            plancheck.check_plan(
+                ops,
+                schema=plancheck.schema_of_table(head),
+                rows=int(head.logical_row_count),
+                rest=rest_sigs,
+                names=head.names,
+            )
         if (self._manifest is not None and durable.enabled()
                 and len(rest_tabs) == len(rb_ids) - 1):
             # every input resolved: record the compile signature for

@@ -31,7 +31,7 @@ from collections import OrderedDict, deque
 from typing import Dict, Optional, Tuple
 
 from .. import runtime_bridge as rb
-from ..utils import buckets, faults, hbm, lockcheck, metrics, spill, tracing
+from ..utils import buckets, faults, hbm, lockcheck, metrics, spill
 
 # Global reverse map rb_id -> (owning session, charged bytes): the spill
 # tier's residency events carry rb ids, and the owning session credits /
@@ -104,6 +104,9 @@ class Session:
         self._inflight_bytes = 0
         self._spilled_bytes = 0         # charged bytes currently off-device
         self._spilled_rb: set = set()   # rb ids of ours that are spilled
+        # planned receive rows per device of the last served mesh
+        # exchange (parallel/planmesh.py reads them to the host anyway)
+        self._mesh_recv: Optional[list] = None
         self._waits = deque(maxlen=4096)  # queue-wait seconds
         self._lats = deque(maxlen=4096)   # submit->done latency seconds
         self.stats = {
@@ -122,16 +125,10 @@ class Session:
         :class:`OverBudget` when the estimate can never fit (it exceeds
         the budget minus the session's resident tables), and
         :class:`SessionClosed` if torn down while waiting. The whole
-        wait — spill rounds included — shows up in the request's trace
-        as a ``serving.admission`` span."""
-        tok = tracing.span_begin("serving.admission")
-        try:
-            got = self._admit(estimate, wait)
-        except BaseException as e:
-            tracing.span_end(tok, error=type(e).__name__)
-            raise
-        tracing.span_end(tok)
-        return got
+        wait — spill rounds included — is the request's
+        ``serving.admission`` span."""
+        with metrics.span("serving.admission"):
+            return self._admit(estimate, wait)
 
     def _admit(self, estimate: int, wait: bool) -> int:
         est = max(int(estimate), 0)
@@ -358,6 +355,10 @@ class Session:
         with self._lock:
             self.stats["shed"] += 1
 
+    def note_mesh_recv(self, rows) -> None:
+        with self._lock:
+            self._mesh_recv = [int(r) for r in rows]
+
     def note_latency(self, seconds: float) -> None:
         """End-to-end submit->done latency of one scheduled request —
         queue wait PLUS execution, the number the tenant experiences."""
@@ -401,6 +402,13 @@ class Session:
                 "connections": self.connections,
                 "mesh_devices": self.mesh_devices,
                 **dict(self.stats),
+            }
+            recv = self._mesh_recv
+        if recv:
+            mean = sum(recv) / len(recv)
+            doc["mesh_recv"] = {
+                "rows": recv,
+                "imbalance": (max(recv) / mean) if mean > 0 else 0.0,
             }
         doc["queue_wait"] = self.wait_percentiles()
         doc["latency"] = self.latency_percentiles()

@@ -364,12 +364,19 @@ _CACHE: "OrderedDict[tuple, Any]" = OrderedDict()
 
 def cached_jit(
     key: tuple, build: Callable[[], Callable], name: str,
-    donate_args: tuple = (),
+    donate_args: tuple = (), scope: Optional[str] = None,
 ):
     """Jitted callable for ``key``; ``build`` constructs the python fn
     on a miss. ``name`` becomes the callable's __name__ so compile-log
     lines (jax.log_compiles) are attributable to the bucket plane —
     the recompile-regression test greps for it.
+
+    ``scope`` traces the function under ``jax.named_scope(scope)``
+    (``"srt." + op``): trace-time only, every HLO op it emits carries
+    the name in its ``op_name`` metadata (which is not in the
+    compile-cache key), so a device trace can say which plan op a
+    fusion belongs to. The scope has to open INSIDE the jitted
+    function; one around the call does not reach a jit traced beneath.
 
     ``donate_args`` (jax ``donate_argnums``) marks positional arguments
     whose buffers the executable may consume IN PLACE — resident chains
@@ -396,6 +403,13 @@ def cached_jit(
     import jax
 
     raw = build()
+    if scope:
+        inner = raw
+
+        def raw(*args):
+            with jax.named_scope(scope):
+                return inner(*args)
+
     raw.__name__ = name
     raw.__qualname__ = name
     jfn = jax.jit(raw, donate_argnums=tuple(donate_args))

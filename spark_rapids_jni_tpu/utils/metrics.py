@@ -8,11 +8,14 @@ module is both planes for the TPU runtime:
 * a process-wide, thread-safe registry of named **counters**, **byte
   counters**, **wall-clock timers**, bounded **histograms**, and
   high-water **gauges** (the leak-report analog for resident handles);
-* a ``span(name, **attrs)`` context manager that nests (thread-local
-  stack), records its wall-clock duration into the timer registry —
-  including on the exception path — opens the profiler ``trace_range``
-  when ``SPARK_RAPIDS_TPU_TRACE`` is on, and emits one structured
-  stderr line on the ``span`` channel when ``LOG_LEVEL`` admits TRACE.
+* a ``span(name, **attrs)`` context manager — THE one way to open a
+  layer-boundary span — that nests (thread-local stack, carried across
+  a thread hop by :func:`adopt`), records its wall-clock duration into
+  the timer registry — including on the exception path — always opens
+  a ``jax.profiler.TraceAnnotation`` named ``"srt/" + qualname`` (so a
+  profiler capture holds the program's spans on the device's clock),
+  and emits one structured stderr line on the ``span`` channel when
+  ``LOG_LEVEL`` admits TRACE.
 
 Gating follows the ``log.enabled()`` discipline: :func:`enabled` is a
 cheap check (``SPARK_RAPIDS_TPU_METRICS`` truthy, or a
@@ -36,6 +39,8 @@ import sys
 import threading
 import time
 from typing import Dict, List, Optional, Sequence
+
+import jax.profiler
 
 from . import config
 from . import flight
@@ -73,6 +78,8 @@ _SELF: Dict[str, List[float]] = {}
 _DEFAULT_BOUNDS = tuple(4 ** i for i in range(16))
 
 _TLS = threading.local()
+
+_ANNOTATION = jax.profiler.TraceAnnotation  # a name of its own: tests swap it
 
 # Gate cache, invalidated by config.generation(): a disabled
 # instrumentation site costs one int compare + attribute read instead
@@ -279,9 +286,13 @@ class _Span:
             stack[-1].qualname + "/" + self.name if stack else self.name
         )
         stack.append(self)
-        if tracing.tracing_enabled():
-            self._trace_cm = tracing.trace_range(self.qualname)
-            self._trace_cm.__enter__()
+        # the shared clock: a profiler capture shows this span on its
+        # thread's line of the host plane, on the device's clock. With
+        # no capture running the annotation costs ~0.4 us.
+        self._trace_cm = _ANNOTATION(
+            tracing.ANNOTATION_PREFIX + self.qualname
+        )
+        self._trace_cm.__enter__()
         if _GATE_FLIGHT:
             # the ambient trace context rides the B arg (one contextvar
             # read; None outside a traced request, and flight omits
@@ -330,17 +341,19 @@ class _Span:
 
 
 def span(name: str, **attrs):
-    """Context manager: a named, nestable timed region.
+    """Context manager: a named, nestable timed region — the one span
+    API of the served path.
 
     Records duration into the timer registry under ``name`` (exception
     path included) plus self-time and a ``span_ms.*`` duration
-    histogram, emits begin/end events into the flight recorder when
-    ``SPARK_RAPIDS_TPU_FLIGHT`` is on, opens a profiler ``trace_range``
-    when ``SPARK_RAPIDS_TPU_TRACE`` is on, and emits one
-    ``[srt][span][TRACE]`` stderr line when the log level admits it.
-    Returns a shared no-op object when every plane is off — the
-    hot-path cost of a disabled span is one generation compare on the
-    cached gate.
+    histogram, emits begin/end events (the B event carries the ambient
+    traceparent) into the flight recorder when
+    ``SPARK_RAPIDS_TPU_FLIGHT`` is on, opens a
+    ``jax.profiler.TraceAnnotation`` named ``"srt/" + qualname``
+    whenever it is live, and emits one ``[srt][span][TRACE]`` stderr
+    line when the log level admits it. Returns a shared no-op object
+    when every plane is off — the hot-path cost of a disabled span is
+    one generation compare on the cached gate.
     """
     if _GATE_GEN != config.generation():
         _refresh_gate()
@@ -350,8 +363,8 @@ def span(name: str, **attrs):
 
 
 def traced(name: Optional[str] = None):
-    """Decorator form of :func:`span` (tracing.annotate's metrics-aware
-    sibling): wraps the function body in ``span(name or qualname)``."""
+    """Decorator form of :func:`span`: wraps the function body in
+    ``span(name or qualname)``."""
 
     def wrap(fn):
         label = name or fn.__qualname__
@@ -364,6 +377,52 @@ def traced(name: Optional[str] = None):
         return inner
 
     return wrap
+
+
+def current_span():
+    """The innermost span open on THIS thread (None outside any, or
+    with every plane off) — what a thread hop captures at submit so
+    the worker can :func:`adopt` it."""
+    stack = getattr(_TLS, "stack", None)
+    return stack[-1] if stack else None
+
+
+class adopt:
+    """Scope that makes ``parent`` — a span captured on ANOTHER thread
+    with :func:`current_span` — the enclosing span of this thread's
+    work: spans opened inside carry its qualified name as their path,
+    and their time is credited to it as child time, so the parent's
+    self time excludes the work it handed over and waited for.
+    ``credit(seconds)`` adds time spent on the parent's behalf outside
+    any span (the scheduler's queue wait). ``None`` = no-op scope."""
+
+    __slots__ = ("_parent", "qualname", "_child_s")
+
+    def __init__(self, parent):
+        self._parent = parent
+        self.qualname = "" if parent is None else parent.qualname
+        self._child_s = 0.0
+
+    def credit(self, seconds: float) -> None:
+        self._child_s += float(seconds)
+
+    def __enter__(self):
+        if self._parent is not None:
+            stack = getattr(_TLS, "stack", None)
+            if stack is None:
+                stack = _TLS.stack = []
+            stack.append(self)
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._parent is not None:
+            stack = getattr(_TLS, "stack", None)
+            if stack and stack[-1] is self:
+                stack.pop()
+            # several workers may settle into one parent at once
+            with _LOCK:
+                self._parent._child_s += self._child_s
+        return False
 
 
 def span_depth() -> int:

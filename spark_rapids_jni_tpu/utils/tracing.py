@@ -6,9 +6,12 @@ ranges show up in Nsight. The TPU equivalent is
 ``jax.profiler.TraceAnnotation``, which lands named ranges in
 Perfetto/XProf traces captured with ``jax.profiler.trace``.
 
-Enabled via the ``SPARK_RAPIDS_TPU_TRACE`` flag (utils/config.py); when
-off, ``trace_range`` is a no-op with near-zero overhead, matching the
-reference's ship-it-disabled default.
+``trace_range`` is the bare range of code below the span layer (the
+``io/*`` readers): enabled via the ``SPARK_RAPIDS_TPU_TRACE`` flag
+(utils/config.py), a no-op with near-zero overhead when off, matching
+the reference's ship-it-disabled default. Layer boundaries do not use
+it: every one is a ``metrics.span``, which opens its own annotation
+(named ``"srt/" + qualname``) whenever it is live.
 
 On top of the ranges, this module owns the **trace context** (ISSUE 18
 tentpole): a per-request ``trace_id``/``span_id`` pair held in a
@@ -61,6 +64,14 @@ from . import flight
 from . import lockcheck
 
 
+# Every annotation the program emits starts with this prefix. The
+# benchmark's trace reader keeps the bench's own ``client.<step>`` and
+# ``perfbench.window`` annotations by name and shares each idle gap
+# among those that cover it: a program annotation under one of those
+# names would silently change every cell's breakdown.
+ANNOTATION_PREFIX = "srt/"
+
+
 def tracing_enabled() -> bool:
     return bool(config.get_flag("TRACE"))
 
@@ -73,26 +84,8 @@ def trace_range(name: str) -> Iterator[None]:
         return
     import jax.profiler
 
-    with jax.profiler.TraceAnnotation(name):
+    with jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + name):
         yield
-
-
-def annotate(name: Optional[str] = None):
-    """Decorator form: wraps a function body in a trace_range."""
-
-    def wrap(fn):
-        import functools
-
-        label = name or fn.__qualname__
-
-        @functools.wraps(fn)
-        def inner(*args, **kwargs):
-            with trace_range(label):
-                return fn(*args, **kwargs)
-
-        return inner
-
-    return wrap
 
 
 # ---------------------------------------------------------------------------
@@ -250,9 +243,9 @@ def span_begin(name: str):
     token ``span_end`` closes; None when the ring is off — the
     disabled path is one cached gate check, the flight ``record()``
     cost class (asserted within 2x of disabled record() in tests).
-    Callers below metrics in the import graph (profiler) use this
-    pair; everything else gets the same tagging through
-    ``metrics.span``."""
+    Only ``utils/profiler.py`` (below metrics in the import graph)
+    uses this pair: it records nothing with the ring off and feeds no
+    timer. Every other span is a ``metrics.span``."""
     if not flight.enabled():
         return None
     ctx = _CTX.get()

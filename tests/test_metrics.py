@@ -190,20 +190,28 @@ class TestSpans:
         assert fn(1) == 2
         assert metrics.snapshot()["timers"]["deco.fn"]["count"] == 1
 
-    def test_span_opens_trace_range_when_trace_on(self, monkeypatch):
+    def test_span_opens_annotation_without_trace_flag(self, monkeypatch):
+        # a live span always opens its profiler annotation, named
+        # "srt/" + qualname: no TRACE gate (the benchmark sets METRICS
+        # alone, and its device trace must hold the program's spans)
         _on()
-        config.set_flag("TRACE", True)
         opened = []
 
-        @contextlib.contextmanager
-        def fake_range(name):
-            opened.append(name)
-            yield
+        class FakeAnnotation:
+            def __init__(self, name):
+                opened.append(name)
 
-        monkeypatch.setattr(tracing, "trace_range", fake_range)
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(metrics, "_ANNOTATION", FakeAnnotation)
         with metrics.span("ranged"):
-            pass
-        assert opened == ["ranged"]
+            with metrics.span("inner"):
+                pass
+        assert opened == ["srt/ranged", "srt/ranged/inner"]
 
 
 class TestThreadSafety:
