@@ -177,7 +177,7 @@ def phase_resident(srv, rng, n: int):
         # the TPU compile of this program (CHANGES.md PR 23)
         {"op": "groupby", "by": [0], "aggs": AGGS},
         # ORDER BY sum(quantity) DESC, item: runs at the bucket of the
-        # 6,666 groups, not of the 8M-row input (bucketed._rebucket)
+        # 6,666 groups, not of the 8M-row input (bucketed._reduce_groups)
         {"op": "sort_by", "keys": [
             {"column": 1, "ascending": False}, {"column": 0}]},
     ]

@@ -21,8 +21,9 @@
 4. returns a PADDED result carrying ``Table.logical_rows`` — the wire
    boundary slices host-side (zero extra compiles) and a downstream
    bucketed op consumes the padding directly. A groupby's result is
-   first cut down to the bucket of its group count (``_rebucket``), so
-   what follows an aggregation runs at the aggregate's size.
+   born at the bucket of its group count (``_reduce_groups``), so the
+   per-group half of the aggregation, and what follows it, run at the
+   aggregate's size.
 
 Semantics contract: for the first ``logical_rows`` rows the result is
 bit-identical to the exact path (``tests/test_buckets.py`` pins this at
@@ -162,19 +163,59 @@ def _key(kind: str, op: dict, *tables: Table, extra: tuple = ()) -> tuple:
     return buckets.cache_key(kind, op, tables, extra)
 
 
-def _rebucket(t: Table) -> Table:
-    """Shrink a padded result to the bucket of its LOGICAL row count.
+def _groupby_aggs(op: dict) -> list:
+    """The op's aggregations; collect_* decline (a data-dependent list
+    capacity pre-pass the exact path owns)."""
+    from .ops.groupby import _COLLECT_OPS, GroupbyAgg
 
-    A groupby collapses its input by orders of magnitude but its capped
-    form returns ``num_segments`` = the input bucket physical rows, so
-    every op behind it (and the download) would run at the input's
-    bucket: 6,666 groups sorted as 2^23 rows. The count is already on
-    the host when this runs (the runner's one sync), so the slices are
-    static ones."""
-    b = buckets.bucket_for(t.logical_row_count)
-    if b is None or b >= t.row_count:
-        return t
-    return _finish(buckets.head_table(t, b), t.logical_row_count)
+    aggs = [GroupbyAgg(a["column"], a["agg"]) for a in op["aggs"]]
+    if any(a.op in _COLLECT_OPS for a in aggs):
+        raise _Decline
+    return aggs
+
+
+def _reduce_groups(state, num_groups) -> Table:
+    """Second half of a served groupby, launched at the bucket of the
+    group count.
+
+    The first half (``ops.groupby.groupby_sort``, inside the per-op
+    runner's or the fused segment's executable) has sorted the input's
+    N rows and counted the groups; everything per group — the segment
+    searches, the key gather, each aggregate's gathers — runs here at
+    ``K = bucket_for(num_groups)``: 2^13 wide for 6,666 groups of 2^23
+    rows, where one trace has to run it 2^23 wide. The same shape as the
+    inner join's probe -> count read -> materialise. ``K`` is at least
+    the smallest bucket (zero groups still return the exact schema) and
+    at most N (every row its own group: today's work, nothing lost).
+    One executable per (schema, aggs, N, K); the launch returns at
+    enqueue."""
+    from .ops.groupby import groupby_reduce
+
+    n = int(state.perm.shape[0])
+    # srt: allow-host-sync(bucketed-runner boundary: the first half's launch is done; one count read sizes the second half and the logical rows of its result)
+    g = int(num_groups)
+    k = buckets.bucket_for(max(g, 1))
+    if k is None or k > n:
+        k = n
+
+    def build():
+        def fn(st, ng):
+            return groupby_reduce(st, ng, k)
+
+        return fn
+
+    key = (
+        "groupby.reduce", state.slots,
+        buckets.table_signature(state.keys), n, k,
+    )
+    fn = buckets.cached_jit(
+        key, build, "srt_groupby_reduce", scope="srt.groupby"
+    )
+    metrics.counter_add("groupby.input_rows", n)
+    metrics.counter_add("groupby.reduce_rows", k)
+    with metrics.span("groupby.reduce", rows=k):
+        out = fn(state, num_groups)
+    return _finish(out, g)
 
 
 # ---------------------------------------------------------------------------
@@ -266,26 +307,16 @@ def _r_sort(op: dict, table: Table, rest) -> Table:
 
 
 def _r_groupby(op: dict, table: Table, rest) -> Table:
-    from .ops.groupby import (
-        _COLLECT_OPS,
-        GroupbyAgg,
-        groupby_aggregate_capped,
-    )
+    from .ops.groupby import groupby_sort
 
-    aggs = [GroupbyAgg(a["column"], a["agg"]) for a in op["aggs"]]
-    if any(a.op in _COLLECT_OPS for a in aggs):
-        # collect_* needs a data-dependent list capacity pre-pass —
-        # exact path owns that sizing
-        raise _Decline
+    aggs = _groupby_aggs(op)
     pt = _padded_input(table)
     by = list(op["by"])
 
     def build():
         def fn(t, n):
             rv = buckets.tail_valid(t.row_count, n)
-            return groupby_aggregate_capped(
-                t, by, aggs, num_segments=t.row_count, row_valid=rv
-            )
+            return groupby_sort(t, by, aggs, row_valid=rv)
 
         return fn
 
@@ -293,9 +324,8 @@ def _r_groupby(op: dict, table: Table, rest) -> Table:
         _key("groupby", op, pt), build, "srt_bucketed_groupby",
         scope="srt.groupby",
     )
-    out, num_groups = fn(_strip(pt), _n_dev(pt))
-    # srt: allow-host-sync(bucketed-runner boundary: the compiled launch is done; one count read sizes the logical rows of the padded result)
-    return _rebucket(_finish(out, int(num_groups)))
+    state, num_groups = fn(_strip(pt), _n_dev(pt))
+    return _reduce_groups(state, num_groups)
 
 
 def _r_distinct(op: dict, table: Table, rest) -> Table:

@@ -8,9 +8,12 @@ like Spark/cudf.
 
 Two forms (see ops/__init__ docstring): ``groupby_aggregate`` host-syncs
 the group count; ``groupby_aggregate_capped`` is fully jittable with
-``num_segments`` as the static capacity. Large decomposable
-aggregations route through the two-level chunked design
-(ops/groupby_chunked.py).
+``num_segments`` as the static capacity — ``groupby_sort`` (everything
+at the input's row count) then ``groupby_reduce`` (everything per
+group, ``num_segments`` wide) in one trace; the served runners launch
+the two apart and size the second from the group count
+(bucketed.py). Large decomposable aggregations route through the
+two-level chunked design (ops/groupby_chunked.py).
 
 Design note — string keys are NOT auto-dictionary-encoded here (unlike
 joins, ops/join.py): encoding costs a full-width sort of its own, the
@@ -143,9 +146,16 @@ def _segment_ids(
 def _segment_bounds(seg, num_segments: int):
     """Per-segment [start, end) row ranges via binary search over the
     (sorted, nondecreasing) segment-id vector — the TPU replacement for
-    scatter-based segment lookups. XLA lowers ``jax.ops.segment_*`` to
-    device scatters, which are serial-ish on TPU (~1.5 s at 16M rows
-    measured on v5e); two log(n) searchsorted passes cost ~1 ms."""
+    scatter-based segment lookups (XLA lowers ``jax.ops.segment_*`` to
+    device scatters, serial-ish on TPU: ~1.5 s at 16M rows on a v5e).
+
+    Each search is ~log2(n) rounds of ``num_segments``-wide random
+    gathers, and a random gather runs at ~10 M elements/s on a v5e: at
+    ``num_segments`` = n = 2^23 one search took 1.4-1.7 s and at 2^20
+    0.16 s (ledger, PR 25); at ``num_segments`` = 2^13 over n = 2^23 it
+    takes 1.4 ms (chip run, PR 26). The cost is in ``num_segments``, so
+    the served runners pass the bucket of the group count
+    (:func:`groupby_reduce`), not of the input."""
     ids = jnp.arange(num_segments, dtype=seg.dtype)
     starts = jnp.searchsorted(seg, ids, side="left").astype(jnp.int32)
     ends = jnp.searchsorted(seg, ids, side="right").astype(jnp.int32)
@@ -215,15 +225,13 @@ def _nth_valid_gather(vals_sorted, valid_sorted, starts, pad: int):
     return vals_sorted[rows]
 
 
-def _first_occurrence(col, seg, vals_sorted, valid_sorted):
+def _first_occurrence(dtype, seg, vals_sorted, valid_sorted):
     """Value-sort rows within each segment and mark the first occurrence
     of each distinct valid value (the shared core of collect_set and
     nunique). Returns (resorted values, first-occurrence mask)."""
     # vals are arithmetic values (FLOAT64 decoded from bits): re-encode
     # to storage before order-keying, which expects the bit layout
-    tmp = Column(
-        compute.encode_values(vals_sorted, col.dtype), col.dtype, None
-    )
+    tmp = Column(compute.encode_values(vals_sorted, dtype), dtype, None)
     vword = keys_mod.column_order_keys(tmp)[0]
     # valid rows first within the segment (stable), then by value
     inval = jnp.where(valid_sorted, jnp.uint64(0), jnp.uint64(1))
@@ -240,7 +248,7 @@ def _first_occurrence(col, seg, vals_sorted, valid_sorted):
 
 
 def _collect_segment(
-    col: Column,
+    dtype: dt.DType,
     op: str,
     pad: int,
     seg,
@@ -256,14 +264,14 @@ def _collect_segment(
     leaves set order unspecified)."""
     from ..column import _LIST_CHILD_IDS
 
-    if col.dtype.id not in _LIST_CHILD_IDS:
+    if dtype.id not in _LIST_CHILD_IDS:
         raise TypeError(
-            f"{op} not supported for {col.dtype} (LIST children are "
+            f"{op} not supported for {dtype} (LIST children are "
             "int8..64, uint8..64, float32, bool)"
         )
     if op == "collect_set":
         vals_sorted, valid_sorted = _first_occurrence(
-            col, seg, vals_sorted, valid_sorted
+            dtype, seg, vals_sorted, valid_sorted
         )
     counts = _sorted_segment_sum(
         valid_sorted.astype(jnp.int32), starts, ends
@@ -278,36 +286,26 @@ def _collect_segment(
 
 
 def _aggregate_segment(
-    col: Column,
+    dtype: dt.DType,
     op: str,
-    perm,
     seg,
-    num_segments: int,
-    row_valid: Optional[jax.Array] = None,
-    bounds=None,
-    gathered=None,
+    bounds,
+    vals,
+    valid,
     list_capacity: Optional[int] = None,
 ) -> Column:
-    """One aggregation over sorted segments. All paths are scatter-free
+    """One aggregation over sorted segments: ``vals`` / ``valid`` are
+    the value column (a (lo, hi) limb pair for DECIMAL128) and its mask
+    in SORTED row order, ``bounds`` the ``(starts, ends)`` of the
+    ``num_segments`` candidate segments. All paths are scatter-free
     (sorted-segment design): counts/sums are cumsum differences over the
     sorted rows, min/max a segmented associative scan, lookups
     searchsorted — the idiomatic TPU lowering of what cudf does with
-    atomics+hash tables (SURVEY.md §7 hard part 1)."""
-    is_dec128 = col.dtype.id == dt.TypeId.DECIMAL128
-    if gathered is not None:
-        vals, valid = gathered
-    else:
-        if is_dec128:
-            g = col.data[perm]
-            vals = (g[:, 0], g[:, 1])
-        else:
-            vals = compute.values(col)[perm]
-        valid = compute.valid_mask(col)[perm]
-        if row_valid is not None:
-            valid = jnp.logical_and(valid, row_valid[perm])
-    starts, ends = (
-        bounds if bounds is not None else _segment_bounds(seg, num_segments)
-    )
+    atomics+hash tables (SURVEY.md §7 hard part 1). Every scan runs over
+    the rows, every gather at ``num_segments``."""
+    is_dec128 = dtype.id == dt.TypeId.DECIMAL128
+    starts, ends = bounds
+    num_segments = starts.shape[0]
     n_valid = _sorted_segment_sum(valid.astype(jnp.int64), starts, ends)
     has = n_valid > 0
 
@@ -327,14 +325,14 @@ def _aggregate_segment(
         if is_dec128:
             lo, hi_l = vals
             data = jnp.stack([lo[row], hi_l[row]], axis=1)
-            return Column(data, col.dtype, has)
-        return compute.from_values(vals[row], col.dtype, has)
+            return Column(data, dtype, has)
+        return compute.from_values(vals[row], dtype, has)
 
     if op in _COLLECT_OPS or op == "nunique":
-        if is_dec128 or col.dtype.is_string:
-            raise TypeError(f"{op} not supported for {col.dtype}")
+        if is_dec128 or dtype.is_string:
+            raise TypeError(f"{op} not supported for {dtype}")
         if op == "nunique":
-            _, first = _first_occurrence(col, seg, vals, valid)
+            _, first = _first_occurrence(dtype, seg, vals, valid)
             return Column(
                 _sorted_segment_sum(
                     first.astype(jnp.int64), starts, ends
@@ -348,29 +346,29 @@ def _aggregate_segment(
                 "(the static LIST pad width)"
             )
         return _collect_segment(
-            col, op, list_capacity, seg, vals, valid, starts, ends
+            dtype, op, list_capacity, seg, vals, valid, starts, ends
         )
 
     if is_dec128:
         return _aggregate_segment_dec128(
-            col, op, vals, valid, seg, starts, ends, n_valid, has
+            dtype, op, vals, valid, seg, starts, ends, n_valid, has
         )
 
     if op in ("sum", "mean"):
-        acc_dtype = jnp.float64 if col.dtype.is_floating else jnp.int64
+        acc_dtype = jnp.float64 if dtype.is_floating else jnp.int64
         total = _sorted_segment_sum(
             jnp.where(valid, vals, 0).astype(acc_dtype), starts, ends
         )
         if op == "mean":
             mean = total.astype(jnp.float64) / jnp.maximum(n_valid, 1)
-            if col.dtype.is_decimal:
-                mean = mean * (10.0 ** col.dtype.scale)
+            if dtype.is_decimal:
+                mean = mean * (10.0 ** dtype.scale)
             return compute.from_values(mean, dt.FLOAT64, has)
-        if col.dtype.is_floating:
+        if dtype.is_floating:
             return compute.from_values(total, dt.FLOAT64, has)
-        if col.dtype.is_decimal:
+        if dtype.is_decimal:
             return compute.from_values(
-                total, dt.DType(dt.TypeId.DECIMAL64, col.dtype.scale), has
+                total, dt.DType(dt.TypeId.DECIMAL64, dtype.scale), has
             )
         return compute.from_values(total, dt.INT64, has)
 
@@ -381,8 +379,8 @@ def _aggregate_segment(
         # large-magnitude values). Sample variance, ddof=1; groups with
         # fewer than 2 valid rows are null.
         fvals = vals.astype(jnp.float64)
-        if col.dtype.is_decimal:
-            fvals = fvals * (10.0 ** col.dtype.scale)
+        if dtype.is_decimal:
+            fvals = fvals * (10.0 ** dtype.scale)
         nf = n_valid.astype(jnp.float64)
         s1 = _sorted_segment_sum(
             jnp.where(valid, fvals, 0.0), starts, ends
@@ -397,16 +395,168 @@ def _aggregate_segment(
         return compute.from_values(out, dt.FLOAT64, n_valid > 1)
 
     # min / max via masked sentinels + segmented scan
-    if col.dtype.is_floating:
+    if dtype.is_floating:
         sentinel = np.inf if op == "min" else -np.inf
-    elif col.dtype.is_boolean:
+    elif dtype.is_boolean:
         sentinel = op == "min"
     else:
-        info = np.iinfo(np.dtype(col.dtype.storage_dtype))
+        info = np.iinfo(np.dtype(dtype.storage_dtype))
         sentinel = info.max if op == "min" else info.min
     masked = jnp.where(valid, vals, jnp.asarray(sentinel, vals.dtype))
     out = _sorted_segment_extreme(masked, seg, ends, op == "min")
-    return compute.from_values(out, col.dtype, has)
+    return compute.from_values(out, dtype, has)
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass(frozen=True)
+class SortedGroups:
+    """What :func:`groupby_sort` hands :func:`groupby_reduce`: the rows
+    in group order, every array at the INPUT's row count.
+
+    ``keys`` are the key columns in input row order under their output
+    names; ``perm`` the sorted-to-input row map; ``seg`` each sorted
+    row's segment id; ``payload`` the sorted value arrays and masks;
+    ``slots`` (static) one ``(op, list_capacity, output name, value
+    dtype, payload index, value-array count)`` per aggregation."""
+
+    keys: Table
+    perm: jax.Array
+    seg: jax.Array
+    payload: tuple
+    slots: tuple
+
+    def tree_flatten(self):
+        return (self.keys, self.perm, self.seg, self.payload), self.slots
+
+    @classmethod
+    def tree_unflatten(cls, slots, children):
+        return cls(*children, slots)
+
+
+def groupby_sort(
+    table: Table,
+    by: Sequence[Union[int, str]],
+    aggs: Sequence[GroupbyAgg],
+    row_valid: Optional[jax.Array] = None,
+    values_via: str = "sort",
+) -> tuple[SortedGroups, jax.Array]:
+    """First half of the capped groupby, all of it at the input's row
+    count: the variadic stable sort with the value columns as payload,
+    the boundary scan, the group count. -> (sorted state, count).
+
+    Everything per GROUP is :func:`groupby_reduce`'s, which takes its
+    width as an argument — a runner that reads the count between the
+    halves runs the second at the bucket of the group count."""
+    key_cols = [table.column(c) for c in by]
+    key_names = [
+        c if isinstance(c, str)
+        else (table.names[c] if table.names else f"key{i}")
+        for i, c in enumerate(by)
+    ]
+
+    # value columns ride the variadic sort as payload (one fused sort
+    # instead of a 100M-row device gather per agg column)
+    distinct: dict = {}
+    payload: list = []
+    slots = []
+    for agg in aggs:
+        col = table.column(agg.column)
+        if id(col) not in distinct:
+            if col.dtype.id == dt.TypeId.DECIMAL128:
+                # limb columns ride the sort as two 1-D u64 operands
+                v_entries = [col.data[:, 0], col.data[:, 1]]
+            else:
+                v_entries = [compute.values(col)]
+            m = compute.valid_mask(col)
+            if row_valid is not None:
+                m = jnp.logical_and(m, row_valid)
+            distinct[id(col)] = (len(payload), len(v_entries))
+            payload.extend(v_entries + [m])
+        base = (
+            agg.column
+            if isinstance(agg.column, str)
+            else (table.names[agg.column] if table.names else f"c{agg.column}")
+        )
+        slots.append(
+            (agg.op, agg.list_capacity, agg.name or f"{agg.op}_{base}",
+             col.dtype) + distinct[id(col)]
+        )
+    perm, seg, num_groups, sorted_payload = _segment_ids(
+        key_cols, row_valid, payload, values_via=values_via
+    )
+    state = SortedGroups(
+        Table(key_cols, key_names), perm, seg, tuple(sorted_payload),
+        tuple(slots),
+    )
+    return state, num_groups
+
+
+def groupby_reduce(
+    state: SortedGroups,
+    num_groups,
+    num_segments: int,
+    return_collect_overflow: bool = False,
+):
+    """Second half of the capped groupby: segment bounds, the key
+    gather and every aggregation for ``num_segments`` candidate groups
+    -> the padded result of ``num_segments`` rows (with the collect
+    overflow scalar when asked for, see ``groupby_aggregate_capped``).
+
+    ``num_segments`` may be anything from the group count up: rows
+    below ``num_groups`` come out the same bytes whatever it is (the
+    cumsums still run over every sorted row, and ``c[end-1] -
+    c[start-1]`` reads the same elements); only the width of the
+    searches and gathers changes with it."""
+    perm, seg, sorted_payload = state.perm, state.seg, state.payload
+
+    # representative (first) sorted row of each segment -> key values
+    n = perm.shape[0]
+    bounds = _segment_bounds(seg, num_segments)
+    starts, ends = bounds
+    in_range = jnp.arange(num_segments, dtype=jnp.int32) < num_groups
+    first_rows = perm[jnp.clip(starts, 0, max(n - 1, 0))]
+
+    out_cols: list[Column] = []
+    for col in state.keys.columns:
+        k = gather_table(Table([col]), first_rows).columns[0]
+        valid = jnp.logical_and(
+            compute.valid_mask(k), in_range
+        )
+        out_cols.append(Column(k.data, k.dtype, valid, k.lengths))
+    out_names = list(state.keys.names)
+
+    collect_overflow = jnp.zeros((), jnp.int64)
+    for op, list_capacity, name, dtype, j, nv in state.slots:
+        vals_sorted = (
+            tuple(sorted_payload[j : j + nv])
+            if nv > 1
+            else sorted_payload[j]
+        )
+        r = _aggregate_segment(
+            dtype, op, seg, bounds, vals_sorted, sorted_payload[j + nv],
+            list_capacity=list_capacity,
+        )
+        valid = jnp.logical_and(compute.valid_mask(r), in_range)
+        out_cols.append(Column(r.data, r.dtype, valid, r.lengths))
+        out_names.append(name)
+        if return_collect_overflow and op in _COLLECT_OPS:
+            # pre-clamp element count of a group == its valid-row count
+            # (collect drops nulls), which the count machinery already
+            # computes from the same sorted payload. For collect_set
+            # this is an UPPER bound (valid rows, not distinct values):
+            # a conservative overflow signal, never a missed one.
+            n_valid = _sorted_segment_sum(
+                sorted_payload[j + nv].astype(jnp.int64), starts, ends
+            )
+            collect_overflow = jnp.maximum(
+                collect_overflow,
+                jnp.max(jnp.where(in_range, n_valid, 0)),
+            )
+
+    out = Table(out_cols, out_names)
+    if return_collect_overflow:
+        return out, collect_overflow
+    return out
 
 
 def groupby_aggregate_capped(
@@ -423,6 +573,11 @@ def groupby_aggregate_capped(
     Padding rows have null keys/values (validity False past the count).
     ``row_valid`` excludes rows (e.g. shuffle-padding occupancy).
 
+    :func:`groupby_sort` then :func:`groupby_reduce` in ONE trace, for
+    callers that cannot read the group count in between (inside
+    ``shard_map``, under a caller's jit). The served runners launch the
+    halves apart and size the second from the count (``bucketed.py``).
+
     ``return_collect_overflow=True`` appends a device scalar: the
     LARGEST pre-clamp valid-element count of any group across the
     collect_list/collect_set aggregations (0 when there are none).
@@ -431,90 +586,15 @@ def groupby_aggregate_capped(
     two-phase counts let callers detect overflow — so callers that
     need losslessness check ``overflow <= list_capacity`` and resize
     (r3 advisor finding)."""
-    key_cols = [table.column(c) for c in by]
-
-    # value columns ride the variadic sort as payload (one fused sort
-    # instead of a 100M-row device gather per agg column)
-    distinct: dict = {}
-    payload: list = []
-    for agg in aggs:
-        col = table.column(agg.column)
-        if id(col) not in distinct:
-            if col.dtype.id == dt.TypeId.DECIMAL128:
-                # limb columns ride the sort as two 1-D u64 operands
-                v_entries = [col.data[:, 0], col.data[:, 1]]
-            else:
-                v_entries = [compute.values(col)]
-            m = compute.valid_mask(col)
-            if row_valid is not None:
-                m = jnp.logical_and(m, row_valid)
-            distinct[id(col)] = (len(payload), len(v_entries))
-            payload.extend(v_entries + [m])
-    perm, seg, num_groups, sorted_payload = _segment_ids(
-        key_cols, row_valid, payload, values_via=values_via
+    state, num_groups = groupby_sort(
+        table, by, aggs, row_valid=row_valid, values_via=values_via
     )
-
-    # representative (first) sorted row of each segment -> key values
-    n = table.row_count
-    bounds = _segment_bounds(seg, num_segments)
-    starts, _ = bounds
-    in_range = jnp.arange(num_segments, dtype=jnp.int32) < num_groups
-    first_rows = perm[jnp.clip(starts, 0, max(n - 1, 0))]
-
-    out_cols: list[Column] = []
-    out_names: list[str] = []
-    for i, c in enumerate(by):
-        col = table.column(c)
-        k = gather_table(Table([col]), first_rows).columns[0]
-        valid = jnp.logical_and(
-            compute.valid_mask(k), in_range
-        )
-        out_cols.append(Column(k.data, k.dtype, valid, k.lengths))
-        out_names.append(
-            c if isinstance(c, str) else (table.names[c] if table.names else f"key{i}")
-        )
-
-    collect_overflow = jnp.zeros((), jnp.int64)
-    for agg in aggs:
-        col = table.column(agg.column)
-        j, nv = distinct[id(col)]
-        vals_sorted = (
-            tuple(sorted_payload[j : j + nv])
-            if nv > 1
-            else sorted_payload[j]
-        )
-        r = _aggregate_segment(
-            col, agg.op, perm, seg, num_segments, row_valid, bounds,
-            (vals_sorted, sorted_payload[j + nv]),
-            list_capacity=agg.list_capacity,
-        )
-        valid = jnp.logical_and(compute.valid_mask(r), in_range)
-        out_cols.append(Column(r.data, r.dtype, valid, r.lengths))
-        base = (
-            agg.column
-            if isinstance(agg.column, str)
-            else (table.names[agg.column] if table.names else f"c{agg.column}")
-        )
-        out_names.append(agg.name or f"{agg.op}_{base}")
-        if return_collect_overflow and agg.op in _COLLECT_OPS:
-            # pre-clamp element count of a group == its valid-row count
-            # (collect drops nulls), which the count machinery already
-            # computes from the same sorted payload. For collect_set
-            # this is an UPPER bound (valid rows, not distinct values):
-            # a conservative overflow signal, never a missed one.
-            starts, ends = bounds
-            n_valid = _sorted_segment_sum(
-                sorted_payload[j + nv].astype(jnp.int64), starts, ends
-            )
-            collect_overflow = jnp.maximum(
-                collect_overflow,
-                jnp.max(jnp.where(in_range, n_valid, 0)),
-            )
-
-    out = Table(out_cols, out_names)
     if return_collect_overflow:
-        return out, num_groups, collect_overflow
-    return out, num_groups
+        out, overflow = groupby_reduce(
+            state, num_groups, num_segments, return_collect_overflow=True
+        )
+        return out, num_groups, overflow
+    return groupby_reduce(state, num_groups, num_segments), num_groups
 
 
 # above this, SPARK_RAPIDS_TPU_GROUPBY_FORMULATION=packed/chunked can
@@ -632,7 +712,7 @@ def groupby_aggregate(
 
 
 def _aggregate_segment_dec128(
-    col, op, vals, valid, seg, starts, ends, n_valid, has
+    dtype, op, vals, valid, seg, starts, ends, n_valid, has
 ):
     """DECIMAL128 aggregations over sorted segments (ops/int128.py).
 
@@ -644,7 +724,7 @@ def _aggregate_segment_dec128(
     from . import int128
 
     lo, hi = vals
-    scale = col.dtype.scale
+    scale = dtype.scale
 
     if op in ("sum", "mean"):
         m32 = jnp.uint64(0xFFFFFFFF)

@@ -13,9 +13,10 @@ Weld/Photon-style lazy-fusion step layered on PR 2's shape buckets.
 Fusable ops (single-table, bucketable, ``row_valid``-maskable):
 ``cast``, ``filter``, ``rlike``, ``distinct``, ``sort_by``, ``slice``
 (non-negative bounds), and a non-collect ``groupby`` TAIL — a groupby
-may close a fused run but not continue it: its output is a fresh
-keys+aggregates table and the following ops re-enter the compiler on
-the padded result (cut down to the bucket of its group count).
+may close a fused run but not continue it: the segment's executable
+ends with the groupby's sort half, its per-group half is a second
+launch at the bucket of the group count (``bucketed._reduce_groups``),
+and the following ops re-enter the compiler on that result.
 Everything else (join, concat, explode, to_rows/from_rows, ...) is a
 segment boundary dispatched through the
 existing per-op ``_dispatch`` path — bucketed runner or exact fallback
@@ -256,11 +257,13 @@ def _fused_slice(op, t, n, rv):
 
 
 def _fused_groupby(op, t, n, rv):
-    from .ops.groupby import GroupbyAgg, groupby_aggregate_capped
+    # the sort half only -> (sorted state, group count): _run_fused
+    # launches the per-group half once it has read the count
+    from . import bucketed
+    from .ops.groupby import groupby_sort
 
-    aggs = [GroupbyAgg(a["column"], a["agg"]) for a in op["aggs"]]
-    return groupby_aggregate_capped(
-        t, list(op["by"]), aggs, num_segments=t.row_count, row_valid=rv
+    return groupby_sort(
+        t, list(op["by"]), bucketed._groupby_aggs(op), row_valid=rv
     )
 
 
@@ -277,7 +280,8 @@ _FUSED = {
 
 def _run_segment_traced(seg_ops: Sequence[dict], t: Table, n):
     """The traced body of one fused segment: thread (table, count)
-    through every op at the segment's one physical shape."""
+    through every op at the segment's one physical shape. A groupby
+    tail leaves its sorted state in the table's place."""
     for op in seg_ops:
         # trace-time only: every HLO op of this plan op carries its
         # name in its op_name metadata, so a device trace can give a
@@ -293,7 +297,8 @@ def _run_segment_traced(seg_ops: Sequence[dict], t: Table, n):
 def _run_fused(
     seg_ops: Sequence[dict], table: Table, donate: bool = False
 ) -> Table:
-    """One fused segment -> one cached executable -> one launch.
+    """One fused segment -> one cached executable -> one launch (and,
+    behind a groupby tail, the launch of its per-group half).
 
     ``donate=True`` marks the segment's input table as CONSUMED: its
     padded buffers are donated to the executable
@@ -329,13 +334,12 @@ def _run_fused(
         # counted AFTER the launch: a trace/compile failure falls back
         # to per-op replay with the input intact — nothing was donated
         hbm.note_donation(donated)
-    # srt: allow-host-sync(segment boundary: the fused launch is done; the count read is the one sync that sizes the unpadded result)
-    res = bucketed._finish(out, int(count))
     if seg_ops[-1]["op"] == "groupby":
-        # same shrink as the per-op runner's, so both paths hand the
-        # next op the same physical shape
-        res = bucketed._rebucket(res)
-    return res
+        # the per-op runner's second half, so both paths hand the next
+        # op the same physical shape
+        return bucketed._reduce_groups(out, count)
+    # srt: allow-host-sync(segment boundary: the fused launch is done; the count read is the one sync that sizes the unpadded result)
+    return bucketed._finish(out, int(count))
 
 
 # ops whose output over a row range depends only on the rows in that

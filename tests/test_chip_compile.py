@@ -242,35 +242,67 @@ def test_capped_inner_join_compiles_at_smoke_bucket(one_chip, as_tpu):
     _fits(jax.jit(fn).lower(fact, dim, n32, n32).compile())
 
 
-@pytest.mark.parametrize("bucket", [
-    1 << 13, pytest.param(SMOKE_BUCKET, marks=pytest.mark.slow),
+@pytest.mark.parametrize("form,rows,groups", [
+    ("sort_half", 1 << 13, None),
+    ("reduce_half", 1 << 13, 1 << 10),
+    pytest.param("one_trace", 1 << 13, None, marks=pytest.mark.slow),
+    pytest.param("sort_half", SMOKE_BUCKET, None, marks=pytest.mark.slow),
+    pytest.param(
+        "reduce_half", SMOKE_BUCKET, 1 << 13, marks=pytest.mark.slow
+    ),
 ])
-def test_capped_groupby_compiles(one_chip, as_tpu, bucket):
+def test_capped_groupby_compiles(one_chip, as_tpu, form, rows, groups):
     """sum / count / float64 sum, as the smoke's resident plan
     aggregates: the f64 path (utils/ieee754.py's TPU branch) and the
-    64-bit variadic sort, for the chip's compiler. Tier-1 compiles the
-    program at a small bucket; the smoke's own 2^23 bucket, where the
-    question is memory (0.8 GiB), is the nightly tier's — the TPU
-    compiler takes minutes on this program at any size (my AOT runs,
-    PR 23: 33-255 s at 2^14 rows, ~400 s at 2^23; a min/max aggregation
-    adds ~230 s)."""
+    64-bit variadic sort, for the chip's compiler — as the served
+    runners launch them: the sort half at the input's bucket
+    (``groupby_sort``) and the per-group half at the (input bucket,
+    group-count bucket) pair (``groupby_reduce``). Tier-1 compiles the
+    halves at small buckets; the smoke's own 2^23 -> 2^13 pair, where
+    the question is memory, is the nightly tier's, and so is the one
+    trace that ``parallel/distributed.py`` keeps (the two halves in one
+    program: it compiles what they compile). The TPU compiler takes
+    minutes on the reduce half at any size (my AOT runs here, PR 26:
+    sort half 14 s, reduce half 137 s, one trace 127 s at 2^10-2^13
+    rows; PR 23: ~400 s for the one trace at 2^23; a min/max
+    aggregation adds ~230 s)."""
     from spark_rapids_jni_tpu.ops.groupby import (
         GroupbyAgg,
         groupby_aggregate_capped,
+        groupby_reduce,
+        groupby_sort,
     )
 
-    fact = _table(one_chip, FACT, bucket)
+    fact = _table(one_chip, FACT, rows)
     n32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     aggs = [GroupbyAgg(2, "sum"), GroupbyAgg(2, "count"),
             GroupbyAgg(3, "sum")]
 
-    def fn(t, n):
+    def sort_half(t, n):
+        rv = buckets.tail_valid(t.row_count, n)
+        return groupby_sort(t, [0], aggs, row_valid=rv)
+
+    def one_trace(t, n):
         rv = buckets.tail_valid(t.row_count, n)
         return groupby_aggregate_capped(
             t, [0], aggs, num_segments=t.row_count, row_valid=rv
         )
 
-    _fits(jax.jit(fn).lower(fact, n32).compile())
+    if form == "reduce_half":
+        state, _ = jax.eval_shape(sort_half, fact, n32)
+        state = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=one_chip
+            ),
+            state,
+        )
+        _fits(
+            jax.jit(lambda st, g: groupby_reduce(st, g, groups))
+            .lower(state, n32).compile()
+        )
+    else:
+        fn = sort_half if form == "sort_half" else one_trace
+        _fits(jax.jit(fn).lower(fact, n32).compile())
 
 
 # ---------------------------------------------------------------------------

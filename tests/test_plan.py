@@ -483,16 +483,21 @@ class TestPlanRecompile:
         snap = metrics.snapshot()
         misses = snap["counters"]["compile_cache.miss"]
         hits = snap["counters"].get("compile_cache.hit", 0)
-        # ONE segment per plan call -> at most one executable per
-        # bucket across the whole ragged stream; every further call is
-        # a cache hit == one launch of the cached fused executable
-        assert misses <= N_BUCKETS, f"{misses} compiles for {N_BUCKETS}"
-        assert hits == len(SIZES) - misses
+        # ONE segment per plan call -> at most one fused executable per
+        # input bucket across the whole ragged stream, plus the groupby
+        # tail's per-group half: one per (input bucket, group-count
+        # bucket) pair, and 7 groups always land in the 1024 bucket.
+        # Every further call is a cache hit == one launch of each
+        assert misses <= 2 * N_BUCKETS, f"{misses} compiles for {N_BUCKETS}"
+        assert hits == 2 * len(SIZES) - misses
         assert snap["counters"]["plan.fused_ops"] == len(SIZES) * 4
         assert snap["counters"]["plan.segments"] == len(SIZES)
         # cross-check against the ACTUAL XLA compile log
         fused = [m for m in compiles if "srt_fused_plan" in m]
         assert len(fused) <= N_BUCKETS, fused
+        halves = [m for m in compiles if "srt_groupby_reduce" in m]
+        assert len(halves) <= N_BUCKETS, halves
+        assert snap["counters"]["groupby.reduce_rows"] == len(SIZES) * 1024
         # and nothing leaked onto the per-op bucketed path
         assert not [m for m in compiles if "srt_bucketed" in m]
 
@@ -506,8 +511,10 @@ class TestPlanRecompile:
         compiles = _captured_plan_stream()
         snap = metrics.snapshot()
         assert not [m for m in compiles if "srt_fused_plan" in m]
+        assert not [m for m in compiles if "srt_groupby_reduce" in m]
         assert snap["counters"].get("compile_cache.miss", 0) == 0
-        assert snap["counters"]["compile_cache.hit"] == len(SIZES)
+        # the fused executable and the groupby's per-group half
+        assert snap["counters"]["compile_cache.hit"] == 2 * len(SIZES)
 
 
 # ---------------------------------------------------------------------------

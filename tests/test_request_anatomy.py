@@ -155,6 +155,7 @@ EXPECTED = {
         "serving.download": 1, "plan": 1, "plan.segment": 4,
         "plan.segment.filter": 1, "plan.segment.join": 1,
         "plan.segment.groupby": 1, "plan.segment.sort_by": 1,
+        "groupby.reduce": 1,
         "wire.serialize": 1, "wire.serialize.wait": 1,
         "wire.serialize.copy": 1,
     },
@@ -162,7 +163,8 @@ EXPECTED = {
         "serving.request_split": 1,
         "plan.check": 1, "serving.admission": 1, "serving.stream": 1,
         "wire.deserialize": 1, "plan": 1, "plan.segment": 1,
-        "plan.segment.filter__groupby": 1, "wire.serialize": 1,
+        "plan.segment.filter__groupby": 1, "groupby.reduce": 1,
+        "wire.serialize": 1,
         "wire.serialize.wait": 1, "wire.serialize.copy": 1,
         "serving.reply_serialize": 1,
     },
