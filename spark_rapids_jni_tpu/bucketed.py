@@ -34,6 +34,8 @@ performance, never results.
 
 from __future__ import annotations
 
+import contextlib
+import threading
 from typing import Optional, Sequence
 
 import jax.numpy as jnp
@@ -174,6 +176,33 @@ def _groupby_aggs(op: dict) -> list:
     return aggs
 
 
+# A served groupby is two launches with a host read between them, and
+# the device runs launches in the order they arrive. With two tenants
+# the other's sort half, enqueued during that read, ran BETWEEN this
+# groupby's halves: both sorts, both reduces, then both tenants on the
+# host at once and the device idle — or not, as the host's timing fell,
+# so that a request's time depended on when the other tenant's arrived
+# (stream-c2's runs spread by 3% on one tree; PERF.md, PR 27). A turn
+# keeps the halves together: the next tenant's first half is enqueued
+# behind this one's second, and its host work hides behind the device's.
+_GROUPBY_TURN = threading.Lock()
+# a turn orders launches, it never withholds service: a tenant that has
+# waited this long (a cold compile, a 2^23-row sort ahead of it) goes
+# unordered, as every launch did before
+_TURN_WAIT_S = 0.5
+
+
+@contextlib.contextmanager
+def groupby_turn():
+    """Held from a groupby's first launch until its second is enqueued."""
+    held = _GROUPBY_TURN.acquire(timeout=_TURN_WAIT_S)
+    try:
+        yield
+    finally:
+        if held:
+            _GROUPBY_TURN.release()
+
+
 def _reduce_groups(state, num_groups) -> Table:
     """Second half of a served groupby, launched at the bucket of the
     group count.
@@ -251,6 +280,27 @@ def _r_cast(op: dict, table: Table, rest) -> Table:
     return _finish(fn(_strip(pt)), pt.logical_row_count)
 
 
+def _r_project(op: dict, table: Table, rest) -> Table:
+    pt = _padded_input(table)
+    exprs = op["exprs"]
+
+    def build():
+        def fn(t):
+            from .ops.project import project_table
+
+            return project_table(t, exprs)
+
+        return fn
+
+    fn = buckets.cached_jit(
+        _key("project", op, pt), build, "srt_bucketed_project",
+        scope="srt.project",
+    )
+    out = fn(_strip(pt))
+    metrics.counter_add("project.calls")
+    return _finish(out, pt.logical_row_count)
+
+
 def _r_filter(op: dict, table: Table, rest) -> Table:
     pt = _padded_input(table)
     mi = int(op["mask"])
@@ -324,8 +374,9 @@ def _r_groupby(op: dict, table: Table, rest) -> Table:
         _key("groupby", op, pt), build, "srt_bucketed_groupby",
         scope="srt.groupby",
     )
-    state, num_groups = fn(_strip(pt), _n_dev(pt))
-    return _reduce_groups(state, num_groups)
+    with groupby_turn():
+        state, num_groups = fn(_strip(pt), _n_dev(pt))
+        return _reduce_groups(state, num_groups)
 
 
 def _r_distinct(op: dict, table: Table, rest) -> Table:
@@ -498,6 +549,7 @@ def _r_join(op: dict, table: Table, rest) -> Table:
 
 _RUNNERS = {
     "cast": _r_cast,
+    "project": _r_project,
     "filter": _r_filter,
     "sort_by": _r_sort,
     "groupby": _r_groupby,

@@ -5,8 +5,12 @@ Accelerator implements on GPU:
 * any null operand -> null result (plus ``null_safe_eq``, Spark's <=>),
 * integer/decimal division or modulo by zero -> null,
 * float division by zero -> IEEE inf/NaN,
-* decimal add/sub rescale to the finer scale; decimal mul adds scales;
-  decimal div rescales the dividend first (cudf's fixed-point behavior).
+* decimal add/sub are exact at the finer scale and a decimal product at
+  s1 + s2; the result is then brought to the output type's scale, which
+  the caller names as cudf's ``binary_operation`` has it named
+  (``out_dtype``; ``project`` names Spark's) and which defaults to the
+  finer input scale; decimal div rescales the dividend first (cudf's
+  fixed-point behavior).
 
 Everything is jit-traceable; FLOAT64 goes through the compute view
 (ops/compute.py) so storage stays bit-exact.
@@ -45,9 +49,8 @@ _ARITH_OPS = {
 }
 
 
-def _promote(a: Column, b: Column) -> dt.DType:
-    if a.dtype.is_decimal or b.dtype.is_decimal:
-        da, db = a.dtype, b.dtype
+def _promote(da: dt.DType, db: dt.DType) -> dt.DType:
+    if da.is_decimal or db.is_decimal:
         # Spark promotes an integer operand to decimal(scale 0), so
         # qty * price works without an explicit cast; floats still
         # require one (the result type would silently stop being exact)
@@ -72,7 +75,75 @@ def _promote(a: Column, b: Column) -> dt.DType:
         return dt.DType(
             dt.TypeId.DECIMAL64 if wid >= 8 else dt.TypeId.DECIMAL32, scale
         )
-    return dt.common_numeric_dtype(a.dtype, b.dtype)
+    return dt.common_numeric_dtype(da, db)
+
+
+_DECIMAL_ARITH = ("add", "sub", "mul", "div", "true_div")
+
+
+def result_dtype(
+    op: str,
+    da: dt.DType,
+    db: dt.DType,
+    out_dtype: Optional[dt.DType] = None,
+    spark: bool = False,
+) -> dt.DType:
+    """The dtype ``binary_op(op, a, b, out_dtype)`` returns for fixed-width
+    operands, from their dtypes alone — what ``plancheck`` infers a
+    ``project`` expression's type with.
+
+    ``out_dtype`` is the caller's output type, as cudf's
+    ``binary_operation`` takes it: a comparison or a logical op yields
+    BOOL8 and nothing else; a decimal result may be named at any scale
+    (the exact result is rescaled to it, truncating toward zero when it
+    is coarser), a non-decimal result as any non-decimal type. With none
+    named the result is typed as it always was (a decimal at the finer
+    input scale), or, under ``spark=True``, by Spark's rule for the
+    scale: a decimal ``mul`` at s1 + s2. Raises TypeError / ValueError
+    as ``binary_op`` would."""
+    wide = dt.TypeId.DECIMAL128
+    if wide in (da.id, db.id) or (out_dtype is not None and out_dtype.id == wide):
+        if op in _CMP_OPS:
+            natural = dt.BOOL8
+        elif op in ("add", "sub"):
+            for d in (da, db):
+                if not (d.is_decimal or d.is_integer):
+                    raise TypeError(
+                        "decimal128 binary ops require decimal/integer "
+                        f"operands, got {d}"
+                    )
+            natural = dt.DType(wide, min(da.scale, db.scale))
+        else:
+            raise TypeError(f"decimal128 op {op!r} not supported")
+    elif op in _LOGICAL_OPS:
+        if not (da.is_boolean and db.is_boolean):
+            raise TypeError("logical ops require BOOL8 columns")
+        natural = dt.BOOL8
+    elif op in _CMP_OPS:
+        natural = dt.BOOL8
+    elif op in _ARITH_OPS:
+        natural = _promote(da, db)
+        if natural.is_decimal:
+            if op not in _DECIMAL_ARITH:
+                raise TypeError(f"decimal op {op!r} not supported")
+            if spark and op == "mul":
+                natural = dt.DType(natural.id, da.scale + db.scale)
+    else:
+        raise ValueError(f"unknown binary op {op!r}")
+    if out_dtype is None or out_dtype == natural:
+        return natural
+    if (
+        natural.is_boolean
+        or natural.id == wide
+        or out_dtype.id == wide
+        or natural.is_decimal != out_dtype.is_decimal
+        or not out_dtype.is_numeric
+    ):
+        raise TypeError(
+            f"binary op {op!r} over {da} and {db} yields {natural}; "
+            f"it cannot be asked for as {out_dtype}"
+        )
+    return out_dtype
 
 
 def _rescale_decimal(vals: jax.Array, from_scale: int, to_scale: int) -> jax.Array:
@@ -86,16 +157,27 @@ def _rescale_decimal(vals: jax.Array, from_scale: int, to_scale: int) -> jax.Arr
                                          vals.dtype))
 
 
-def binary_op(op: str, a: Column, b: Column) -> Column:
-    """Elementwise ``a <op> b`` with Spark null semantics."""
+def binary_op(
+    op: str, a: Column, b: Column, out_dtype: Optional[dt.DType] = None
+) -> Column:
+    """Elementwise ``a <op> b`` with Spark null semantics.
+
+    ``out_dtype`` names the result's type the way cudf's
+    ``binary_operation`` takes it from its caller (:func:`result_dtype`
+    has the rules); a caller that names none gets the type it always
+    got."""
     if a.dtype.is_string or b.dtype.is_string:
         from . import strings
 
+        if out_dtype is not None:
+            raise TypeError("string binary ops take no output type")
         return strings.binary_op(op, a, b)
 
-    if (
-        a.dtype.id == dt.TypeId.DECIMAL128
-        or b.dtype.id == dt.TypeId.DECIMAL128
+    if out_dtype is not None:
+        out_dtype = result_dtype(op, a.dtype, b.dtype, out_dtype)
+
+    if dt.TypeId.DECIMAL128 in (
+        a.dtype.id, b.dtype.id, out_dtype.id if out_dtype else None
     ):
         return _binary_op_decimal128(op, a, b)
 
@@ -132,21 +214,23 @@ def binary_op(op: str, a: Column, b: Column) -> Column:
     if op not in _ARITH_OPS:
         raise ValueError(f"unknown binary op {op!r}")
 
-    out_dtype = _promote(a, b)
+    natural = _promote(a.dtype, b.dtype)
+    out_dtype = natural if out_dtype is None else out_dtype
 
     if out_dtype.is_decimal:
-        av = _rescale_decimal(av.astype(jnp.int64), a.dtype.scale, out_dtype.scale)
-        bv = _rescale_decimal(bv.astype(jnp.int64), b.dtype.scale, out_dtype.scale)
-        if op == "add":
-            res = av + bv
-        elif op == "sub":
-            res = av - bv
-        elif op == "mul":
-            # product of unscaled values carries scale(a)+scale(b); bring it
-            # back to the output scale (cudf fixed_point multiply).
+        # add/sub are exact at the finer input scale, the product at
+        # s1 + s2; each is then brought to the output scale (cudf
+        # fixed_point: a coarser one truncates toward zero)
+        if op in ("add", "sub"):
+            av = _rescale_decimal(av.astype(jnp.int64), a.dtype.scale, natural.scale)
+            bv = _rescale_decimal(bv.astype(jnp.int64), b.dtype.scale, natural.scale)
             res = _rescale_decimal(
-                compute.values(a).astype(jnp.int64)
-                * compute.values(b).astype(jnp.int64),
+                av + bv if op == "add" else av - bv,
+                natural.scale, out_dtype.scale,
+            )
+        elif op == "mul":
+            res = _rescale_decimal(
+                av.astype(jnp.int64) * bv.astype(jnp.int64),
                 a.dtype.scale + b.dtype.scale,
                 out_dtype.scale,
             )
@@ -171,10 +255,11 @@ def binary_op(op: str, a: Column, b: Column) -> Column:
             raise TypeError(f"decimal op {op!r} not supported")
         return compute.from_values(res, out_dtype, valid)
 
-    want = np.dtype(out_dtype.device_dtype)
+    # computed in the operands' common type, returned as the output type
+    want = np.dtype(natural.device_dtype)
     av = av.astype(want)
     bv = bv.astype(want)
-    is_float = out_dtype.is_floating
+    is_float = natural.is_floating
 
     if op == "add":
         res = av + bv

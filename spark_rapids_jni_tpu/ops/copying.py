@@ -58,7 +58,8 @@ def concatenate(tables: Sequence[Table]) -> Table:
         concatenate_columns([t.columns[i] for t in tables])
         for i in range(first.num_columns)
     ]
-    return Table(out, list(first.names))
+    # an unnamed table (every wire table, every filter's output) stays unnamed
+    return Table(out, first.names)
 
 
 def interleave_columns(table: Table) -> Column:

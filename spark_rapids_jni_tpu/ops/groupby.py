@@ -65,6 +65,51 @@ class GroupbyAgg:
             raise ValueError(f"unknown aggregation {self.op!r}")
 
 
+def _key_words(key_cols: Sequence[Column], row_valid):
+    """The sort's key words, most significant first, and the value from
+    which the first word belongs to a padding row.
+
+    The key is a tuple of bit fields: the occupancy bit (``row_valid``
+    excludes rows entirely: padding sorts behind every real row), then
+    for each key column its validity bit, if it has one (null keys group
+    together, and a null's payload must not split the group), and its
+    order words. Consecutive fields are folded into one word while they
+    fit 64 bits, and a word of at most 32 bits is a u32: two INT8 keys
+    and the occupancy bit make ONE u32 word where they were three u64
+    words, each an operand and a 64-bit compare of every sort pass (the
+    TPU compiler's time on a 2^23-row sort grows faster than the operand
+    count: PERF.md, PR 27). A 64-bit key's own words are what they
+    always were."""
+    fields: list[tuple[jax.Array, int]] = []
+    if row_valid is not None:
+        # invalid rows last: 0 for valid, 1 for padding
+        fields.append((jnp.where(row_valid, jnp.uint64(0), jnp.uint64(1)), 1))
+    for c in key_cols:
+        order = keys_mod.column_order_fields(c)
+        if c.validity is not None:
+            fields.append((c.validity.astype(jnp.uint64), 1))
+            order = [
+                (jnp.where(c.validity, w, jnp.uint64(0)), b) for w, b in order
+            ]
+        fields.extend(order)
+    packed: list[list] = []  # [[fields of one word]]
+    for f in fields:
+        if packed and sum(b for _, b in packed[-1]) + f[1] <= 64:
+            packed[-1].append(f)
+        else:
+            packed.append([f])
+    words = []
+    for group in packed:
+        w = group[0][0]
+        for v, b in group[1:]:  # first field in the high bits
+            w = (w << jnp.uint64(b)) | v
+        narrow = sum(b for _, b in group) <= 32
+        words.append(w.astype(jnp.uint32) if narrow else w)
+    first_bits = sum(b for _, b in packed[0])
+    occupied_from = jnp.asarray(1 << (first_bits - 1), words[0].dtype)
+    return words, occupied_from
+
+
 def _segment_ids(
     key_cols: Sequence[Column],
     row_valid: Optional[jax.Array] = None,
@@ -75,7 +120,7 @@ def _segment_ids(
     boundary scan.
 
     ``row_valid`` excludes rows entirely (shuffle-padding occupancy): the
-    leading occupancy word sorts them behind every real row, where their
+    leading occupancy bit sorts them behind every real row, where their
     garbage keys may split into any number of trailing segments; the group
     count is therefore the highest segment id holding a valid row.
 
@@ -87,21 +132,7 @@ def _segment_ids(
     TPU is a measured A/B (bench ``groupby16m``/``_gather`` rungs) —
     the flat-packed CPU A/B had gather 3.5x ahead.
     """
-    words: list[jax.Array] = []
-    if row_valid is not None:
-        # invalid rows last: word 0 for valid, 1 for padding
-        words.append(jnp.where(row_valid, jnp.uint64(0), jnp.uint64(1)))
-    for c in key_cols:
-        if c.validity is not None:
-            # null key rows group together: validity is a key word and null
-            # payloads must not split the group
-            words.append(c.validity.astype(jnp.uint64))
-            words.extend(
-                jnp.where(c.validity, w, jnp.uint64(0))
-                for w in keys_mod.column_order_keys(c)
-            )
-        else:
-            words.extend(keys_mod.column_order_keys(c))
+    words, occupied_from = _key_words(key_cols, row_valid)
     # one variadic stable sort carries the iota along, yielding the
     # sorted key words AND the permutation together — no post-sort
     # re-gather of each word (jnp.lexsort would return only the perm)
@@ -131,12 +162,13 @@ def _segment_ids(
         )
     seg = jnp.cumsum(boundary.astype(jnp.int32)) - 1
     if row_valid is not None:
-        # Padding rows sort behind every real row (leading occupancy word)
+        # Padding rows sort behind every real row (leading occupancy bit)
         # but can form any number of trailing garbage segments — the real
         # group count is the highest segment id holding a valid row.
-        # Sorted validity is the sorted occupancy word itself (word 0 =
-        # valid), so it neither rides the sort nor pays a gather.
-        rv_sorted = sorted_words[0] == jnp.uint64(0)
+        # Sorted validity is the top bit of the first sorted key word
+        # (the occupancy field), so it neither rides the sort nor pays
+        # a gather.
+        rv_sorted = sorted_words[0] < occupied_from
         num_groups = jnp.max(jnp.where(rv_sorted, seg + 1, 0))
     else:
         num_groups = seg[-1] + 1
@@ -417,7 +449,8 @@ class SortedGroups:
     names; ``perm`` the sorted-to-input row map; ``seg`` each sorted
     row's segment id; ``payload`` the sorted value arrays and masks;
     ``slots`` (static) one ``(op, list_capacity, output name, value
-    dtype, payload index, value-array count)`` per aggregation."""
+    dtype, payload index, value-array count, mask's payload index)`` per
+    aggregation."""
 
     keys: Table
     perm: jax.Array
@@ -459,6 +492,9 @@ def groupby_sort(
     distinct: dict = {}
     payload: list = []
     slots = []
+    # every value column with no validity of its own is valid where the
+    # row is: they share ONE mask operand instead of sorting one each
+    shared_mask = None
     for agg in aggs:
         col = table.column(agg.column)
         if id(col) not in distinct:
@@ -467,11 +503,19 @@ def groupby_sort(
                 v_entries = [col.data[:, 0], col.data[:, 1]]
             else:
                 v_entries = [compute.values(col)]
-            m = compute.valid_mask(col)
-            if row_valid is not None:
-                m = jnp.logical_and(m, row_valid)
-            distinct[id(col)] = (len(payload), len(v_entries))
-            payload.extend(v_entries + [m])
+            first = len(payload)
+            payload.extend(v_entries)
+            if col.validity is None and shared_mask is not None:
+                mask_at = shared_mask
+            else:
+                m = compute.valid_mask(col)
+                if row_valid is not None:
+                    m = jnp.logical_and(m, row_valid)
+                mask_at = len(payload)
+                payload.append(m)
+                if col.validity is None:
+                    shared_mask = mask_at
+            distinct[id(col)] = (first, len(v_entries), mask_at)
         base = (
             agg.column
             if isinstance(agg.column, str)
@@ -526,14 +570,14 @@ def groupby_reduce(
     out_names = list(state.keys.names)
 
     collect_overflow = jnp.zeros((), jnp.int64)
-    for op, list_capacity, name, dtype, j, nv in state.slots:
+    for op, list_capacity, name, dtype, j, nv, mask_at in state.slots:
         vals_sorted = (
             tuple(sorted_payload[j : j + nv])
             if nv > 1
             else sorted_payload[j]
         )
         r = _aggregate_segment(
-            dtype, op, seg, bounds, vals_sorted, sorted_payload[j + nv],
+            dtype, op, seg, bounds, vals_sorted, sorted_payload[mask_at],
             list_capacity=list_capacity,
         )
         valid = jnp.logical_and(compute.valid_mask(r), in_range)
@@ -546,7 +590,7 @@ def groupby_reduce(
             # this is an UPPER bound (valid rows, not distinct values):
             # a conservative overflow signal, never a missed one.
             n_valid = _sorted_segment_sum(
-                sorted_payload[j + nv].astype(jnp.int64), starts, ends
+                sorted_payload[mask_at].astype(jnp.int64), starts, ends
             )
             collect_overflow = jnp.maximum(
                 collect_overflow,

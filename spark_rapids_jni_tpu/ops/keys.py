@@ -73,6 +73,26 @@ def column_order_keys(col: Column) -> list[jax.Array]:
     return [widened ^ _SIGN64]
 
 
+def column_order_fields(col: Column) -> list[tuple[jax.Array, int]]:
+    """:func:`column_order_keys` with each word's width in bits: a
+    column narrower than 64 bits gives a word that fills only its own
+    width (BOOL8 one bit, INT8 eight), so that a sort can fold several
+    narrow keys into one word (:func:`fold_fields`) instead of comparing
+    a 64-bit word a key. Every other column gives its 64-bit words."""
+    d = col.dtype
+    if d.is_boolean:
+        return [(col.data.astype(jnp.uint64), 1)]
+    if d.id == dt.TypeId.FLOAT32:
+        return [(column_order_keys(col)[0], 32)]
+    if d.is_fixed_width and d.id != dt.TypeId.DECIMAL128 and d.itemsize < 8:
+        bits = 8 * d.itemsize
+        wide = col.data.astype(jnp.int64)
+        if np.dtype(d.storage_dtype).kind != "u":
+            wide = wide + (1 << (bits - 1))  # two's complement -> unsigned order
+        return [(wide.astype(jnp.uint64), bits)]
+    return [(w, 64) for w in column_order_keys(col)]
+
+
 def _string_order_keys(col: Column) -> list[jax.Array]:
     mat = col.data  # (n, pad) uint8, zero-padded past length
     n, pad = mat.shape

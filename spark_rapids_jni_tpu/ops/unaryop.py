@@ -53,6 +53,34 @@ _FNS = {
 }
 
 
+def result_dtype(op: str, d: dt.DType) -> dt.DType:
+    """The dtype ``unary_op`` (or a null predicate) returns over a
+    fixed-width column of dtype ``d``, from the dtype alone; raises
+    where the op would, or would read the values wrongly (a decimal's
+    unscaled integers, DECIMAL128's limbs)."""
+    if op in NULL_PREDICATES:
+        if op == "is_nan" and not d.is_floating:
+            raise TypeError("is_nan requires a float column")
+        return dt.BOOL8
+    if op == "not":
+        if not d.is_boolean:
+            raise TypeError("'not' requires BOOL8")
+        return dt.BOOL8
+    if op not in _FNS:
+        raise ValueError(f"unknown unary op {op!r}")
+    if d.id == dt.TypeId.DECIMAL128 or (
+        d.is_decimal and op not in ("abs", "neg")
+    ):
+        raise TypeError(f"unary op {op!r} not supported on {d}")
+    if not d.is_numeric:
+        raise TypeError(f"unary op {op!r} requires a numeric column, got {d}")
+    if op == "bitnot" and not d.is_integer:
+        raise TypeError("'bitnot' requires an integer column")
+    if op in _FLOAT_ONLY and not d.is_floating:
+        return dt.FLOAT64
+    return d
+
+
 def unary_op(op: str, col: Column) -> Column:
     if op == "not":
         if not col.dtype.is_boolean:
@@ -93,3 +121,11 @@ def is_nan(col: Column) -> Column:
     if not col.dtype.is_floating:
         raise TypeError("is_nan requires a float column")
     return Column(jnp.isnan(compute.values(col)), dt.BOOL8, col.validity)
+
+
+# the null predicates by the names a ``project`` expression uses
+NULL_PREDICATES = {
+    "is_null": is_null,
+    "is_not_null": is_not_null,
+    "is_nan": is_nan,
+}

@@ -1,7 +1,7 @@
 """Mesh data-parallel plan execution for row-local segments.
 
 ``run_plan_mesh`` runs a plan whose every op is row-local (``cast``,
-``filter``, ``rlike`` — plan.py's ``_ROW_LOCAL``) as ONE shard_map
+``project``, ``filter``, ``rlike`` — plan.py's ``_ROW_LOCAL``) as ONE shard_map
 stage over a :class:`~.tolerant.MeshRunner`: rows split into contiguous
 blocks (one per device), each shard runs the same fused segment body
 the single-device path compiles (``plan._run_segment_traced``), and the

@@ -11,8 +11,8 @@ Python, and the whole segment costs one executable launch — the
 Weld/Photon-style lazy-fusion step layered on PR 2's shape buckets.
 
 Fusable ops (single-table, bucketable, ``row_valid``-maskable):
-``cast``, ``filter``, ``rlike``, ``distinct``, ``sort_by``, ``slice``
-(non-negative bounds), and a non-collect ``groupby`` TAIL — a groupby
+``cast``, ``project``, ``filter``, ``rlike``, ``distinct``, ``sort_by``,
+``slice`` (non-negative bounds), and a non-collect ``groupby`` TAIL — a groupby
 may close a fused run but not continue it: the segment's executable
 ends with the groupby's sort half, its per-group half is a second
 launch at the bucket of the group count (``bucketed._reduce_groups``),
@@ -44,6 +44,7 @@ lines are attributable).
 
 from __future__ import annotations
 
+import contextlib
 from typing import List, Sequence, Tuple
 
 import jax
@@ -55,7 +56,7 @@ from .utils import buckets, faults, flight, log, metrics, profiler
 
 # single-table ops a fused segment can carry anywhere in its run
 _SIMPLE_FUSABLE = frozenset(
-    {"cast", "filter", "rlike", "distinct", "sort_by", "slice"}
+    {"cast", "filter", "rlike", "distinct", "sort_by", "slice", "project"}
 )
 
 # mesh exchange boundaries: planmesh splits a plan at these ops into a
@@ -182,6 +183,14 @@ def _fused_cast(op, t, n, rv):
     return Table(cols, t.names), n
 
 
+def _fused_project(op, t, n, rv):
+    from .ops.project import project_table
+
+    # elementwise: what it computes over the padding tail stays behind
+    # the flowing count, like a cast's
+    return project_table(t, op["exprs"]), n
+
+
 def _fused_filter(op, t, n, rv):
     from .ops.filter import filter_table_capped
 
@@ -269,6 +278,7 @@ def _fused_groupby(op, t, n, rv):
 
 _FUSED = {
     "cast": _fused_cast,
+    "project": _fused_project,
     "filter": _fused_filter,
     "rlike": _fused_rlike,
     "distinct": _fused_distinct,
@@ -329,17 +339,31 @@ def _run_fused(
         key, build, "srt_fused_plan", donate_args=donate_args
     )
     donated = hbm.table_bytes(pt) if donate else 0
-    out, count = fn(bucketed._strip(pt), bucketed._n_dev(pt))
-    if donated:
-        # counted AFTER the launch: a trace/compile failure falls back
-        # to per-op replay with the input intact — nothing was donated
-        hbm.note_donation(donated)
-    if seg_ops[-1]["op"] == "groupby":
-        # the per-op runner's second half, so both paths hand the next
-        # op the same physical shape
-        return bucketed._reduce_groups(out, count)
+    groupby_tail = seg_ops[-1]["op"] == "groupby"
+    # a groupby tail's two launches stay together on the device
+    with bucketed.groupby_turn() if groupby_tail else contextlib.nullcontext():
+        out, count = fn(bucketed._strip(pt), bucketed._n_dev(pt))
+        _note_project_calls(seg_ops)
+        if donated:
+            # counted AFTER the launch: a trace/compile failure falls back
+            # to per-op replay with the input intact — nothing was donated
+            hbm.note_donation(donated)
+        if groupby_tail:
+            # the per-op runner's second half, so both paths hand the next
+            # op the same physical shape
+            return bucketed._reduce_groups(out, count)
     # srt: allow-host-sync(segment boundary: the fused launch is done; the count read is the one sync that sizes the unpadded result)
     return bucketed._finish(out, int(count))
+
+
+def _note_project_calls(seg_ops: Sequence[dict]) -> None:
+    """``project.calls`` for the ``project`` ops of a segment that has
+    just been launched: counted on the host at launch, not at trace (the
+    executable is cached), so the counter says how many expression lists
+    the daemon evaluated, whether alone or inside a fused segment."""
+    k = sum(1 for o in seg_ops if o.get("op") == "project")
+    if k:
+        metrics.counter_add("project.calls", k)
 
 
 # ops whose output over a row range depends only on the rows in that
@@ -347,7 +371,7 @@ def _run_fused(
 # split: run each half, concatenate, and the result is byte-identical.
 # sort_by/distinct/groupby/slice are global (cross-row) and must not
 # be chunked; they fall back to the exact path instead.
-_ROW_LOCAL = frozenset({"cast", "filter", "rlike"})
+_ROW_LOCAL = frozenset({"cast", "filter", "rlike", "project"})
 
 
 def _run_chunked(seg_ops: Sequence[dict], table: Table) -> Table:
@@ -559,6 +583,7 @@ def _offer_mesh(ops, table: Table, rest, mesh_runner):
             pseg = None
             return None
         metrics.counter_add("plan.mesh_segments")
+        _note_project_calls(ops)
         profiler.segment_end(
             pseg, rows_out=int(out.logical_row_count),
             out_bytes=hbm.table_bytes(out),

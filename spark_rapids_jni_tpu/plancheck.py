@@ -389,6 +389,26 @@ def _r_cast(op, st):
     return out, st.names, st.rows
 
 
+def _r_project(op, st):
+    """Type inference over the expression trees, by the functions the
+    runtime types them with (ops/project.py): decimal scales propagate,
+    a comparison is BOOL8, a literal has the type it names."""
+    from .ops import project
+
+    try:
+        if st.schema is None:
+            project.check_structure(op.get("exprs"))
+            return None, None, st.rows
+        types = project.infer_schema(op.get("exprs"), st.schema)
+    except project.ExprError as e:
+        raise _Reject(str(e)) from None
+    out = [
+        t if isinstance(t, ColType) else ColType(t.id, int(t.scale))
+        for t in types
+    ]
+    return out, None, st.rows  # names dropped, rows unchanged
+
+
 def _r_filter(op, st):
     mi = _col_index(op, "mask", st.schema, what="filter mask")
     if st.schema is None:
@@ -775,6 +795,7 @@ _RULES = {
     "partition": _r_partition,
     "to_rows": _r_to_rows,
     "from_rows": _r_from_rows,
+    "project": _r_project,
 }
 
 
@@ -785,7 +806,10 @@ _RULES = {
 # ops the per-op bucketed runners cover (bucketed._RUNNERS); parity is
 # asserted dynamically by tests/test_plancheck.py
 _BUCKETED_OPS = frozenset(
-    {"cast", "filter", "sort_by", "groupby", "distinct", "rlike", "join"}
+    {
+        "cast", "project", "filter", "sort_by", "groupby", "distinct",
+        "rlike", "join",
+    }
 )
 _BUCKETED_JOIN_HOWS = frozenset({"inner", "left", "semi", "anti"})
 _COLLECT_AGGS = frozenset({"collect_list", "collect_set"})
@@ -797,7 +821,7 @@ def _op_fusable(op: dict) -> bool:
     if not isinstance(op, dict):
         return False
     name = op.get("op")
-    if name in ("cast", "filter", "rlike", "distinct", "sort_by"):
+    if name in ("cast", "project", "filter", "rlike", "distinct", "sort_by"):
         return True
     if name == "slice":
         try:

@@ -524,6 +524,15 @@ def _dispatch_impl(
         else:
             out[op["column"]] = ops.cast(src, target)
         return Table(out, table.names)
+    if name == "project":
+        # Spark's ProjectExec: one output column per expression tree
+        # (ops/project.py has the grammar); counted at launch, here as
+        # in the bucketed runner and the fused segment
+        from .ops.project import project_table
+
+        out = project_table(table, op["exprs"])
+        metrics.counter_add("project.calls")
+        return out
     if name == "explode":
         return ops.explode(table, op["column"])
     if name == "rlike":
@@ -627,6 +636,7 @@ DISPATCH_OPS = frozenset(
         "partition",
         "to_rows",
         "from_rows",
+        "project",
     }
 )
 

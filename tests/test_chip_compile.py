@@ -305,6 +305,38 @@ def test_capped_groupby_compiles(one_chip, as_tpu, form, rows, groups):
         _fits(jax.jit(fn).lower(fact, n32).compile())
 
 
+LINEITEM = (
+    dt.INT64, dt.decimal64(-2), dt.decimal64(-2), dt.decimal64(-2),
+    dt.decimal64(-2), dt.INT32, dt.INT32, dt.INT8, dt.INT8,
+)
+
+
+def test_q1_fused_segment_compiles(one_chip, as_tpu):
+    """TPC-H Q1's ``project -> filter -> project -> groupby`` as the one
+    ``srt_fused_plan`` program the served path launches (perfbench's
+    ``q1-resident`` plan over the ``lineitem`` schema): the comparison,
+    the 64-bit decimal products, the compaction and the sort half (one
+    folded u32 key word, the permutation, five int64 payloads and their
+    one shared mask) for the chip's compiler, at a small bucket; at the
+    cell's 2^23 the question is minutes and memory, and the chip run
+    answers it (PERF.md, PR 27)."""
+    import json
+
+    from spark_rapids_jni_tpu import plan as plan_mod
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perfbench", "traffic", "q1-resident.json")) as f:
+        (step,) = [s for s in json.load(f)["request"] if s["do"] == "plan"]
+    (kind, seg), _ = plan_mod.segment_plan(step["plan"])
+    assert kind == "fused" and len(seg) == 4
+    table = _table(one_chip, LINEITEM, 1 << 13)
+    n32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    _fits(
+        jax.jit(lambda t, n: plan_mod._run_segment_traced(seg, t, n))
+        .lower(table, n32).compile()
+    )
+
+
 # ---------------------------------------------------------------------------
 # four chips: the mesh partition stage (parallel/planmesh.py) as one
 # program over the described 2x2 mesh

@@ -251,3 +251,80 @@ def test_per_op_and_fused_tail_share_the_reduce_executable():
     assert counters["compile_cache.hit"] >= 1
     timers = metrics.snapshot()["timers"]
     assert timers["groupby.reduce"]["count"] == 1
+
+
+# ---------------------------------------------------------------------------
+# the sort's key words: narrow keys fold into one word, 64-bit keys keep
+# theirs, and either way the groups are numpy's
+# ---------------------------------------------------------------------------
+
+KEY_KINDS = {
+    "int8": (dt.INT8, np.int8, lambda r, n: r.integers(-3, 4, n)),
+    "int16": (dt.INT16, np.int16, lambda r, n: r.integers(-300, 300, n) // 100),
+    "int32": (dt.INT32, np.int32, lambda r, n: r.integers(-2, 3, n) * 10**9),
+    "uint8": (dt.UINT8, np.uint8, lambda r, n: r.integers(250, 256, n)),
+    "bool": (dt.BOOL8, np.bool_, lambda r, n: r.integers(0, 2, n)),
+    "float32": (dt.FLOAT32, np.float32, lambda r, n: r.integers(-2, 3, n) * 0.5),
+    "decimal32": (dt.decimal32(-2), np.int32, lambda r, n: r.integers(-2, 3, n)),
+    "int64": (dt.INT64, np.int64, lambda r, n: r.integers(-2, 3, n) * 2**40),
+}
+# key kinds -> (words without an occupancy bit, with one): a word's dtype
+KEY_SETS = {
+    ("int8", "int8"): (["uint32"], ["uint32"]),
+    ("int8", "bool"): (["uint32"], ["uint32"]),
+    ("int16", "int16"): (["uint32"], ["uint64"]),
+    ("int32", "int32"): (["uint64"], ["uint64", "uint32"]),
+    ("float32", "uint8"): (["uint64"], ["uint64"]),
+    ("decimal32", "int8"): (["uint64"], ["uint64"]),
+    ("int8", "int64"): (["uint32", "uint64"], ["uint32", "uint64"]),
+    ("int64", "int8"): (["uint64", "uint32"], ["uint32", "uint64", "uint32"]),
+}
+
+
+@pytest.mark.parametrize("occupancy", [False, True], ids=["whole", "padded"])
+@pytest.mark.parametrize("nulls", [False, True], ids=["dense", "null_keys"])
+@pytest.mark.parametrize("kinds", sorted(KEY_SETS), ids="-".join)
+def test_narrow_keys_fold_into_one_word(kinds, nulls, occupancy):
+    from spark_rapids_jni_tpu.ops.groupby import _key_words
+
+    rng = np.random.default_rng(len(kinds[0]) * 7 + len(kinds[1]))
+    live = N - 17 if occupancy else N
+    cols, host = [], []
+    for kind in kinds:
+        d, npdt, gen = KEY_KINDS[kind]
+        vals = np.asarray(gen(rng, N)).astype(npdt)
+        valid = rng.random(N) > 0.25 if nulls else None
+        cols.append(Column(jnp.asarray(vals), d,
+                           None if valid is None else jnp.asarray(valid)))
+        host.append((vals, valid))
+    values = rng.integers(-50, 50, N).astype(np.int64)
+    table = Table(cols + [Column(jnp.asarray(values), dt.INT64, None)])
+    rv = jnp.arange(N) < live if occupancy else None
+    words, _ = _key_words(table.columns[:2], rv)
+    if not nulls:
+        assert [str(w.dtype) for w in words] == KEY_SETS[kinds][occupancy]
+    out, num_groups = groupby_aggregate_capped(
+        table, [0, 1], [GroupbyAgg(2, "sum"), GroupbyAgg(2, "count")],
+        num_segments=N, row_valid=rv,
+    )
+    want = {}
+    for i in range(live):
+        key = tuple(None if v is not None and not v[i] else vals[i].item()
+                    for vals, v in host)
+        s, c = want.get(key, (0, 0))
+        want[key] = (s + int(values[i]), c + 1)
+    g = len(want)
+    assert int(num_groups) == g
+    got = {}
+    for r in range(g):
+        key = tuple(
+            None if c.validity is not None and not bool(c.validity[r])
+            else np.asarray(c.data)[r].item() for c in out.columns[:2])
+        got[key] = (int(out.columns[2].data[r]), int(out.columns[3].data[r]))
+    assert got == want
+    # dead rows above the group count
+    assert not np.asarray(out.columns[3].validity)[g:].any()
+    # groups come out in key order, nulls first
+    order = [tuple((k is not None, k if k is not None else 0) for k in key)
+             for key in got]
+    assert order == sorted(order)

@@ -200,7 +200,7 @@ METRIC_NAMESPACES = frozenset({
     "shuffle", "distributed", "io", "probe", "bench", "groupby",
     "join", "sort", "profile", "stream", "checkpoint", "restore",
     "mesh", "planstats", "drift", "partition", "client", "compile",
-    "kernel",
+    "kernel", "project",
 })
 METRIC_NAME_RE = re.compile(r"^[a-z0-9_]+(\.[a-z0-9_]+)*$")
 
