@@ -39,6 +39,7 @@ import threading
 from typing import Optional, Sequence
 
 import jax.numpy as jnp
+import numpy as np
 
 from . import dtype as dt
 from .column import Column, Table
@@ -436,6 +437,39 @@ def _r_rlike(op: dict, table: Table, rest) -> Table:
 _BUCKETED_JOIN_HOWS = frozenset({"inner", "left", "semi", "anti"})
 
 
+def _probe_table_size(lt: Table, rt: Table, on: list) -> Optional[int]:
+    """The one choice of a served join's probe, from what the build side
+    shows: the direct probe's table size when the key is one
+    integer-family column whose valid build keys are dense
+    (``ops.join.direct_table_size``), None for the search. A key of
+    another kind is decided from the schema; a dense one costs one tiny
+    program at the build side's width and its read, before the launch."""
+    from .ops import join as join_mod
+
+    if not join_mod.direct_key(
+        [lt.column(c) for c in on], [rt.column(c) for c in on]
+    ):
+        return None
+
+    def build():
+        def fn(r, rn):
+            rv = buckets.tail_valid(r.row_count, rn)
+            return join_mod.build_key_span(r, on, rv)
+
+        return fn
+
+    fn = buckets.cached_jit(
+        _key("join.span", {"on": on}, rt), build,
+        "srt_bucketed_join_span", scope="srt.join",
+    )
+    span = fn(_strip(rt), _n_dev(rt))
+    # srt: allow-host-sync(bucketed-runner boundary: one read of three words from the build side chooses the probe before its launch)
+    kmin, kmax, valid_rows = (int(v) for v in np.asarray(span))
+    return join_mod.direct_table_size(
+        kmin, kmax, valid_rows, rt.row_count, lt.row_count
+    )
+
+
 def _r_join(op: dict, table: Table, rest) -> Table:
     how = op.get("how", "inner")
     if how not in _BUCKETED_JOIN_HOWS or not rest:
@@ -445,6 +479,10 @@ def _r_join(op: dict, table: Table, rest) -> Table:
     lt = _padded_input(table)
     rt = _padded_input(rest[0])
     on = list(op["on"])
+    table_size = _probe_table_size(lt, rt, on)
+    metrics.counter_add(
+        "join.probe.search" if table_size is None else "join.probe.direct"
+    )
 
     if how in ("semi", "anti"):
         anti = how == "anti"
@@ -456,7 +494,9 @@ def _r_join(op: dict, table: Table, rest) -> Table:
 
                 lv = buckets.tail_valid(l.row_count, ln)
                 rv = buckets.tail_valid(r.row_count, rn)
-                _, _, counts, lvalid = _match_ranges(l, r, on, on, lv, rv)
+                _, _, counts, lvalid = _match_ranges(
+                    l, r, on, on, lv, rv, table_size=table_size
+                )
                 has = jnp.logical_and(counts > 0, lvalid)
                 if anti:
                     # null-key rows match nothing -> kept by ANTI;
@@ -471,7 +511,7 @@ def _r_join(op: dict, table: Table, rest) -> Table:
             return fn
 
         fn = buckets.cached_jit(
-            _key("join." + how, op, lt, rt), build_sa,
+            _key("join." + how, op, lt, rt, extra=(table_size,)), build_sa,
             "srt_bucketed_join_" + how, scope="srt.join",
         )
         out, count = fn(_strip(lt), _strip(rt), _n_dev(lt), _n_dev(rt))
@@ -488,7 +528,9 @@ def _r_join(op: dict, table: Table, rest) -> Table:
 
             lv = buckets.tail_valid(l.row_count, ln)
             rv = buckets.tail_valid(r.row_count, rn)
-            perm_r, lo, counts, _ = _match_ranges(l, r, on, on, lv, rv)
+            perm_r, lo, counts, _ = _match_ranges(
+                l, r, on, on, lv, rv, table_size=table_size
+            )
             return (
                 perm_r, lo, counts,
                 jnp.sum(counts),
@@ -498,7 +540,8 @@ def _r_join(op: dict, table: Table, rest) -> Table:
         return fn
 
     p1 = buckets.cached_jit(
-        _key("join.ranges", {"on": on}, lt, rt), build_probe,
+        _key("join.ranges", {"on": on}, lt, rt, extra=(table_size,)),
+        build_probe,
         "srt_bucketed_join_probe", scope="srt.join",
     )
     perm_r, lo, counts, inner_total, left_total = p1(

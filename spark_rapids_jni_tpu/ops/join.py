@@ -28,26 +28,28 @@ from . import compute
 from . import keys as keys_mod
 from .gather import gather_table
 
-# The fused single-shot join graph (key normalization + lexsort +
-# lex-searchsorted in one compiled region) reproducibly kills the TPU
-# worker at >= 32M rows with 64-bit keys (seen by a builder before this
-# round, not re-tested since; every sub-graph passed in isolation at the
-# same sizes — an XLA codegen/runtime fault, not OOM). 16M passed. Above
-# this threshold the
-# eager join APIs route themselves through the chunk-probed path so no
-# public join API can crash the worker at any size — the reference's own
-# discipline of never letting callers choose safety (its 2 GB batch
-# splits are automatic, row_conversion.cu:476-479,505-511).
+# The fence is on the SEARCH probe's fused single-shot graph (key
+# normalization + lexsort + `_lex_searchsorted` in one compiled region):
+# it reproducibly killed the TPU worker at >= 32M rows with 64-bit keys
+# (seen by a builder before this round, not re-tested since; every
+# sub-graph passed in isolation at the same sizes — an XLA
+# codegen/runtime fault, not OOM). 16M passed. Above this threshold the
+# eager join APIs route themselves through the chunk-probed search so
+# no public join API can crash the worker at any size — the reference's
+# own discipline of never letting callers choose safety (its 2 GB batch
+# splits are automatic, row_conversion.cu:476-479,505-511). The direct
+# probe (`_probe_direct`) is reached only from the bucketed runner,
+# whose ladder ends at 2^23 rows, and has never met the fence.
 # Module-level so tests can lower it to pin the routing.
 #
 # MIN_CHUNK_OUT_BYTES floors the batched join's per-chunk output budget
 # (module-level so the skew re-split path is testable at small scale).
 #
-# Scope of the fence: it removes the XLA codegen fault by keeping every
-# compiled probe graph at or below this row count. The OUTER joins'
-# materialization (expand + gathers over the full pair count) still runs
-# single-shot, so a pathological fan-out can exhaust HBM — that sizing
-# concern belongs to the memory planner (utils/hbm.py), not this fence.
+# Scope of the fence: it keeps every compiled search-probe graph at or
+# below this row count. The OUTER joins' materialization (expand +
+# gathers over the full pair count) still runs single-shot, so a
+# pathological fan-out can exhaust HBM — that sizing concern belongs to
+# the memory planner (utils/hbm.py), not this fence.
 FUSED_PROBE_MAX_ROWS = 16_000_000
 MIN_CHUNK_OUT_BYTES = 64 << 20
 
@@ -92,9 +94,12 @@ def _key_words(cols: Sequence[Column]) -> tuple[list[jax.Array], jax.Array]:
 
 
 def _lex_searchsorted(
-    sorted_words: list[jax.Array], query_words: list[jax.Array], side: str
+    sorted_words: list[jax.Array], query_words: list[jax.Array], side: str,
+    unroll: bool = False,
 ) -> jax.Array:
-    """Vectorized multi-word binary search (lower/upper bound)."""
+    """Vectorized multi-word binary search (lower/upper bound).
+    ``unroll`` lays the steps out flat (no loop in the program): for a
+    query side as narrow as the direct probe's table."""
     m = sorted_words[0].shape[0]
     nq = query_words[0].shape[0]
     lo = jnp.zeros((nq,), dtype=jnp.int32)
@@ -118,7 +123,7 @@ def _lex_searchsorted(
         hi = jnp.where(active & ~go_right, mid, hi)
         return lo, hi
 
-    lo, hi = jax.lax.fori_loop(0, steps, body, (lo, hi))
+    lo, hi = jax.lax.fori_loop(0, steps, body, (lo, hi), unroll=unroll)
     return lo
 
 
@@ -228,6 +233,139 @@ def _probe_build(
     return lo, counts, lvalid
 
 
+# The direct probe's table may be this many times the build side's
+# bucket. Provenance (TPU v5e; PERF.md §5 and §6, PRs 26-28): a gather
+# costs 7-8.6 ns an output element whatever it reads from, so the search
+# costs `8 x ceil(log2(m + 1))` of them a probe row (112 for a 2^13
+# build side: 7.29 s over 2^23 rows) and the table as many a table
+# entry, once, plus ONE a probe row (measured, PR 28: the 16,384-entry
+# table 13.1 ms, the one 2^23-wide gather 59.8 ms). Held to 2, the table
+# never costs more than two searches of the build side through itself.
+# 2 is also the one value a cell shows: a surrogate key sampled at 2/3
+# (6,666 of [0, 10,000)) spans 1.5 x its rows, the next bucket. A
+# sparser key would still win while the table is narrower than the probe
+# side; raise this with the cell that has one.
+DIRECT_TABLE_MAX_FACTOR = 2
+
+
+def direct_key(lcols: Sequence[Column], rcols: Sequence[Column]) -> bool:
+    """True when the join key is one the direct probe can address: ONE
+    fixed-width integer-family column of at most 64 bits a side (ints,
+    DECIMAL32/64, timestamps, durations, BOOL8), whose one order word is
+    the value itself, shifted."""
+    from .. import dtype as dt
+
+    def addressable(d) -> bool:
+        return (
+            d.is_integer or d.is_boolean or d.is_timestamp or d.is_duration
+            or d.id in (dt.TypeId.DECIMAL32, dt.TypeId.DECIMAL64)
+        )
+
+    return (
+        len(lcols) == 1 and len(rcols) == 1
+        and addressable(lcols[0].dtype) and addressable(rcols[0].dtype)
+    )
+
+
+def build_key_span(
+    right: Table,
+    right_on: Sequence[Union[int, str]],
+    right_valid: Optional[jax.Array] = None,
+) -> jax.Array:
+    """``u64[3]``: the smallest and the largest order word among the
+    build side's valid keys, and how many there are — what a runner
+    reads (one tiny program at the build side's width) to choose the
+    probe of a `direct_key` join."""
+    (word,), valid = _key_words([right.column(c) for c in right_on])
+    if right_valid is not None:
+        valid = valid & right_valid
+    top = jnp.uint64(np.iinfo(np.uint64).max)
+    return jnp.stack([
+        jnp.min(jnp.where(valid, word, top)),
+        jnp.max(jnp.where(valid, word, jnp.uint64(0))),
+        jnp.sum(valid).astype(jnp.uint64),
+    ])
+
+
+def direct_table_size(
+    kmin: int, kmax: int, valid_rows: int, build_rows: int, probe_rows: int
+) -> Optional[int]:
+    """Table size ``T`` of the direct probe for a build side whose valid
+    keys span ``[kmin, kmax]`` (`build_key_span`'s words, as host
+    integers), or None for the search: ``T`` is the span's bucket, taken when it is at
+    most `DIRECT_TABLE_MAX_FACTOR` times the build side's width and no
+    wider than the probe side's. A build side with no valid key has no
+    span."""
+    from ..utils import buckets
+
+    if valid_rows <= 0:
+        return None
+    size = buckets.bucket_for(kmax - kmin + 1)
+    if size is None:
+        return None
+    if size > DIRECT_TABLE_MAX_FACTOR * build_rows or size > probe_rows:
+        return None
+    return size
+
+
+def _probe_direct(
+    sorted_words,
+    table_size: int,
+    lcols: Sequence[Column],
+    left_valid: Optional[jax.Array] = None,
+):
+    """`_probe_build`'s ``(lo, counts, lvalid)``, bit for bit, by address
+    instead of by search, for a build side whose valid keys span at most
+    ``table_size`` values (`direct_table_size` chose it from the same
+    build side; a wider span is the caller's fault and reads the table's
+    last entry).
+
+    The search's own answer for every key of the span goes into a table
+    at the TABLE's width; a probe row then costs elementwise work on its
+    key and one gather at ``key - kmin``. Whether a key lies in the span
+    is decided on the order words BEFORE the subtraction, so a key at
+    INT64's other end cannot wrap into it; below and above the span the
+    search's ``lo`` is known without it (the first valid row, the end)."""
+    (q,), lvalid = _key_words(lcols)
+    if left_valid is not None:
+        lvalid = lvalid & left_valid
+    valid_w, key_w = sorted_words
+    m = key_w.shape[0]
+    first = m - jnp.sum(valid_w).astype(jnp.int32)  # invalid rows sort first
+    kmin = key_w[jnp.clip(first, 0, m - 1)]
+    kmax = key_w[m - 1]
+
+    # a target past 2^64 wraps below kmin and finds nothing: no probe
+    # key addresses it
+    targets = [
+        jnp.ones((table_size,), jnp.uint64),
+        kmin + jnp.arange(table_size, dtype=jnp.uint64),
+    ]
+    t_lo = _lex_searchsorted(sorted_words, targets, "left", unroll=True)
+    t_hi = _lex_searchsorted(sorted_words, targets, "right", unroll=True)
+    t_cnt = t_hi - t_lo
+
+    below = q < kmin
+    above = q > kmax
+    inside = ~(below | above)
+    # outside the span the difference is clamped (below it, wrapped
+    # first) and what it reads is never used
+    off = jnp.minimum(q - kmin, jnp.uint64(table_size - 1)).astype(jnp.int32)
+    bits = int(m).bit_length()  # lo and cnt lie in [0, m]
+    if 2 * bits <= 32:
+        # one gather carries both
+        packed = (t_lo.astype(jnp.uint32) << bits) | t_cnt.astype(jnp.uint32)
+        got = packed[off]
+        g_lo = (got >> bits).astype(jnp.int32)
+        g_cnt = (got & jnp.uint32((1 << bits) - 1)).astype(jnp.int32)
+    else:
+        g_lo = t_lo[off]
+        g_cnt = t_cnt[off]
+    lo = jnp.where(below, first, jnp.where(above, jnp.int32(m), g_lo))
+    counts = jnp.where(lvalid & inside, g_cnt, 0)
+    return lo, counts, lvalid
+
+
 def _match_ranges(
     left: Table,
     right: Table,
@@ -235,6 +373,7 @@ def _match_ranges(
     right_on: Sequence[Union[int, str]],
     left_valid: Optional[jax.Array] = None,
     right_valid: Optional[jax.Array] = None,
+    table_size: Optional[int] = None,
 ):
     """Per-left-row [lo, hi) match range into the sorted right side.
 
@@ -247,6 +386,11 @@ def _match_ranges(
     String join keys are dictionary-encoded to int32 codes first (one
     shared dictionary, order-preserving) so every sort/search compare
     touches one word instead of pad/8+1.
+
+    ``table_size`` (static; `direct_table_size`, chosen by a caller that
+    could read the build side's key span) resolves the left keys by
+    address (`_probe_direct`); None, what every caller that cannot read
+    passes, searches. The answer is the same.
     """
     lcols = [left.column(c) for c in left_on]
     rcols = [right.column(c) for c in right_on]
@@ -254,9 +398,19 @@ def _match_ranges(
     perm_r, sorted_words = _prepare_build(
         right, right_on, right_valid, rcols=rcols
     )
-    lo, counts, lvalid = _probe_build(
-        sorted_words, left, left_on, left_valid, lcols=lcols
-    )
+    if table_size is None:
+        lo, counts, lvalid = _probe_build(
+            sorted_words, left, left_on, left_valid, lcols=lcols
+        )
+    else:
+        if not direct_key(lcols, rcols):
+            raise TypeError(
+                "a direct-address probe needs one integer-family key "
+                "column of at most 64 bits a side"
+            )
+        lo, counts, lvalid = _probe_direct(
+            sorted_words, table_size, lcols, left_valid
+        )
     return perm_r, lo, counts, lvalid
 
 

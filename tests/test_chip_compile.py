@@ -242,6 +242,35 @@ def test_capped_inner_join_compiles_at_smoke_bucket(one_chip, as_tpu):
     _fits(jax.jit(fn).lower(fact, dim, n32, n32).compile())
 
 
+def test_direct_join_probe_compiles_without_a_loop(one_chip, as_tpu):
+    """The resident query's probe (PR 28): 2^23 fact keys against a
+    2^13-row dimension through a 2^14-entry table. As the chip's
+    compiler leaves it, the program has no loop and gathers ONCE at the
+    fact side's width, where the search gathers eight times in two."""
+    import re
+
+    from spark_rapids_jni_tpu.ops import join as join_mod
+
+    fact = _table(one_chip, FACT, SMOKE_BUCKET)
+    dim = _table(one_chip, (dt.INT64, dt.INT64), 1 << 13)
+    n32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+
+    def fn(l, r, ln, rn):
+        lv = buckets.tail_valid(l.row_count, ln)
+        rv = buckets.tail_valid(r.row_count, rn)
+        perm_r, lo, counts, _ = join_mod._match_ranges(
+            l, r, [0], [0], lv, rv, table_size=1 << 14
+        )
+        return perm_r, lo, counts, jnp.sum(counts)
+
+    compiled = jax.jit(fn).lower(fact, dim, n32, n32).compile()
+    _fits(compiled)
+    hlo = compiled.as_text()
+    assert not re.search(r"\bwhile\(", hlo)
+    wide = re.findall(rf"= \w+\[{SMOKE_BUCKET}\]\S* gather\(", hlo)
+    assert 1 <= len(wide) <= 2, wide
+
+
 @pytest.mark.parametrize("form,rows,groups", [
     ("sort_half", 1 << 13, None),
     ("reduce_half", 1 << 13, 1 << 10),

@@ -1,0 +1,538 @@
+"""The direct-address probe of a dense build key (PR 28).
+
+``ops.join._probe_direct`` must return what the search
+(``_probe_build``) returns, bit for bit: ``perm_r``, ``lo``, ``counts``
+and ``lvalid`` of ``_match_ranges`` are compared on the same inputs
+with and without ``table_size``, over the cases that could tell an
+address from a search apart (duplicates, nulls and padding on either
+side, keys outside the span and at the type's two ends, a span that
+wraps past the type's largest value, a build side with no valid key).
+Above that, the served runner (``bucketed._r_join``) makes ONE choice
+from the build side's observed key type, span and width — pinned here
+by its two counters and by the joined tables of all four bucketed hows —
+and the lowered dense-key probe holds no loop and at most two gathers
+at the probe side's width.
+"""
+
+import functools
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from spark_rapids_jni_tpu import bucketed
+from spark_rapids_jni_tpu import dtype as dt
+from spark_rapids_jni_tpu.column import Column, Table
+from spark_rapids_jni_tpu.ops import join as join_mod
+from spark_rapids_jni_tpu.utils import buckets, config, metrics
+
+N_LEFT, N_RIGHT, T = 300, 64, 128
+
+WIDE = {
+    "int32": dt.INT32, "int64": dt.INT64, "decimal64": dt.decimal64(-2),
+    "timestamp_us": dt.TIMESTAMP_MICROSECONDS,
+}
+FAMILY = {
+    "int8": dt.INT8, "uint8": dt.UINT8, "int16": dt.INT16,
+    "uint32": dt.UINT32, "uint64": dt.UINT64, "bool8": dt.BOOL8,
+    "decimal32": dt.decimal32(-3), "timestamp_days": dt.TIMESTAMP_DAYS,
+    "duration_s": dt.DURATION_SECONDS,
+}
+
+
+@pytest.fixture(autouse=True)
+def _clean_flags():
+    yield
+    config.clear_flag("METRICS")
+
+
+def _np_dtype(d):
+    return np.dtype(d.storage_dtype)
+
+
+def _limits(d):
+    if d.is_boolean:
+        return 0, 1
+    info = np.iinfo(_np_dtype(d))
+    return int(info.min), int(info.max)
+
+
+def _cast(values, d):
+    """Python integers -> the column's storage type (exact: the cases
+    keep every value inside the type's range)."""
+    lo, hi = _limits(d)
+    assert all(lo <= int(v) <= hi for v in values), (lo, hi)
+    return np.array([int(v) for v in values], dtype=object).astype(
+        _np_dtype(d)
+    )
+
+
+def _case(name, d, seed=0):
+    """One scenario as python-int key lists and masks. Every scenario
+    has N_LEFT / N_RIGHT rows and a span of at most T, so one compiled
+    pair of programs a dtype serves them all."""
+    rng = np.random.default_rng(seed)
+    tmin, tmax = _limits(d)
+    base = max(tmin, min(1000, tmax - 60))
+    lnull = np.zeros(N_LEFT, bool)
+    rnull = np.zeros(N_RIGHT, bool)
+    lpad = np.zeros(N_LEFT, bool)
+    rpad = np.zeros(N_RIGHT, bool)
+
+    def draw(lo, hi, n):
+        lo, hi = max(lo, tmin), min(hi, tmax)
+        return [lo + int(x) for x in rng.integers(0, hi - lo + 1, n)]
+
+    rk = draw(base, base + 40, N_RIGHT)         # duplicates: 64 of 41
+    lk = draw(base - 10, base + 50, N_LEFT)     # some below, some above
+    if name == "duplicates":
+        pass
+    elif name == "null_keys":
+        lnull[rng.integers(0, N_LEFT, 40)] = True
+        rnull[rng.integers(0, N_RIGHT, 12)] = True
+    elif name == "padding":
+        # padding rows hold zeros, as a padded upload does: key 0 lies
+        # INSIDE this span and must be neither in the table nor matched
+        base = max(tmin, -20)
+        rk = draw(base, base + 40, N_RIGHT)
+        lk = draw(base - 10, base + 50, N_LEFT)
+        lpad[250:] = True
+        rpad[50:] = True
+        for i in range(250, N_LEFT):
+            lk[i] = 0
+        for i in range(50, N_RIGHT):
+            rk[i] = 0
+        lnull[rng.integers(0, 250, 20)] = True
+        rnull[rng.integers(0, 50, 6)] = True
+    elif name == "negative":
+        base = max(tmin, -100)
+        rk = draw(base, base + 40, N_RIGHT)
+        lk = draw(base - 10, base + 50, N_LEFT)
+    elif name == "outside_and_ends":
+        # below kmin, above kmax, and the type's two ends: a 64-bit key
+        # at the other end must not wrap into the span
+        rk[0], rk[1] = base, base + 40
+        lk[:8] = [tmin, tmax, base - 1, base + 41, base, base + 40,
+                  max(tmin, tmin + 1), tmax - 1]
+    elif name == "span_wraps_at_type_max":
+        # kmin + arange(T) runs past the largest value
+        rk = draw(tmax - 30, tmax, N_RIGHT)
+        rk[0] = tmax
+        lk = draw(tmax - 45, tmax, N_LEFT)
+        lk[:3] = [tmin, tmax, tmax - 31]
+    elif name == "span_starts_at_type_min":
+        # kmin's order word is 0, where a null key's zeroed word lies
+        rk = draw(tmin, tmin + 30, N_RIGHT)
+        rk[0] = tmin
+        lk = draw(tmin, tmin + 45, N_LEFT)
+        lk[:2] = [tmin, tmax]
+        lnull[rng.integers(0, N_LEFT, 30)] = True
+    elif name == "all_null_build":
+        rnull[:] = True
+    elif name == "all_padding_build":
+        rpad[:] = True
+    elif name == "span_exactly_table":
+        rk[0], rk[1] = base, base + T - 1
+        lk = draw(base - 5, base + T + 5, N_LEFT)
+        lk[:4] = [base, base + T - 1, base - 1, base + T]
+    elif name == "one_key":
+        rk = [base + 7] * N_RIGHT
+    else:
+        raise AssertionError(name)
+    if d.is_boolean:
+        rk = [int(k) & 1 for k in rk]
+        lk = [int(k) & 1 for k in lk]
+    return lk, lnull, lpad, rk, rnull, rpad
+
+
+CASES = (
+    "duplicates", "null_keys", "padding", "negative", "outside_and_ends",
+    "span_wraps_at_type_max", "span_starts_at_type_min", "all_null_build",
+    "all_padding_build", "span_exactly_table", "one_key",
+)
+
+
+def _tables(d, lk, lnull, rk, rnull):
+    left = Table([
+        Column.from_numpy(_cast(lk, d), ~lnull, dtype=d),
+        Column.from_numpy(np.arange(len(lk), dtype=np.int64)),
+    ], ["k", "lv"])
+    right = Table([
+        Column.from_numpy(_cast(rk, d), ~rnull, dtype=d),
+        Column.from_numpy(np.arange(len(rk), dtype=np.int64) * 10),
+    ], ["k", "rv"])
+    return left, right
+
+
+@functools.lru_cache(maxsize=None)
+def _ranges_fn(table_size):
+    def fn(left, right, lv, rv):
+        return join_mod._match_ranges(
+            left, right, ["k"], ["k"], lv, rv, table_size=table_size
+        )
+
+    return jax.jit(fn)
+
+
+def _span_fits(rk, rnull, rpad, size):
+    keys = [k for k, a, b in zip(rk, rnull, rpad) if not (a or b)]
+    return not keys or max(keys) - min(keys) + 1 <= size
+
+
+@pytest.mark.parametrize(
+    "dname,case",
+    [(n, c) for n in WIDE for c in CASES]
+    + [(n, "null_keys") for n in FAMILY]
+    + [(n, "outside_and_ends") for n in FAMILY if n != "bool8"],
+)
+def test_direct_probe_equals_the_search(dname, case):
+    d = {**WIDE, **FAMILY}[dname]
+    lk, lnull, lpad, rk, rnull, rpad = _case(case, d, seed=len(case))
+    assert _span_fits(rk, rnull, rpad, T)
+    left, right = _tables(d, lk, lnull, rk, rnull)
+    lv, rv = jnp.asarray(~lpad), jnp.asarray(~rpad)
+    want = _ranges_fn(None)(left, right, lv, rv)
+    got = _ranges_fn(T)(left, right, lv, rv)
+    for name, w, g in zip(("perm_r", "lo", "counts", "lvalid"), want, got):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
+    # the cases are not vacuous: an independent count of the matches
+    live_r = [k for k, a, b in zip(rk, rnull, rpad) if not (a or b)]
+    per_left = [
+        0 if (a or b) else live_r.count(k)
+        for k, a, b in zip(lk, lnull, lpad)
+    ]
+    np.testing.assert_array_equal(np.asarray(got[2]), per_left)
+
+
+def test_direct_probe_refuses_a_key_it_cannot_address():
+    left, right = _tables(dt.INT64, [1, 2], np.zeros(2, bool),
+                          [1, 2], np.zeros(2, bool))
+    with pytest.raises(TypeError, match="direct-address probe"):
+        join_mod._match_ranges(
+            left, right, ["k", "lv"], ["k", "rv"], table_size=1024
+        )
+
+
+# ---------------------------------------------------------------------------
+# the choice: ops.join.direct_table_size and the runner's two counters
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("span,build,probe,want", [
+    (1, 1024, 1024, 1024),
+    (1500, 1024, 4096, 2048),
+    (2048, 1024, 4096, 2048),       # exactly at the threshold
+    (2049, 1024, 4096, None),       # one past: the next bucket is 4 x
+    (1500, 1024, 1024, None),       # wider than the probe side
+    (10000, 8192, 1 << 23, 16384),  # the resident query's dimension
+    ((1 << 23) + 1, 1 << 23, 1 << 23, None),  # no bucket for the span
+    (1 << 64, 1024, 4096, None),    # INT64's whole range
+])
+def test_table_size_is_the_spans_bucket_within_the_threshold(
+    span, build, probe, want
+):
+    kmin = 5
+    assert join_mod.direct_table_size(
+        kmin, kmin + span - 1, 7, build, probe
+    ) == want
+    assert join_mod.direct_table_size(kmin, kmin + span - 1, 0, build,
+                                      probe) is None
+
+
+def _padded(t: Table, logical: int) -> Table:
+    """The first ``logical`` rows, zero-padded to their bucket."""
+    return buckets.pad_table(buckets.head_table(t, logical))
+
+
+def _run_join(how, left, right, on=("k",)):
+    op = {"op": "join", "how": how, "on": list(on)}
+    watched = ["join.probe.direct", "join.probe.search",
+               "bucket.fallback_errors"]
+    before = metrics.counter_values(watched)
+    out = bucketed._r_join(op, left, (right,))
+    after = metrics.counter_values(watched)
+    return out, {k: after[k] - before[k] for k in watched}
+
+
+def _rows(t: Table):
+    """The logical rows of a result, every buffer: data, validity."""
+    n = t.logical_row_count
+    out = []
+    for c in t.columns:
+        out.append(np.asarray(c.data)[:n])
+        out.append(None if c.validity is None
+                   else np.asarray(c.validity)[:n])
+    return n, t.names, out
+
+
+def _assert_same_table(got: Table, want: Table):
+    gn, gnames, gcols = _rows(got)
+    wn, wnames, wcols = _rows(want)
+    assert (gn, gnames) == (wn, wnames)
+    for g, w in zip(gcols, wcols):
+        if w is None:
+            assert g is None
+        else:
+            np.testing.assert_array_equal(g, w)
+
+
+def _dense_pair(d, seed=3, n_left=3000, n_right=700):
+    """A fact side and a dimension whose key is dense: 700 distinct-ish
+    keys of a 1,400-wide span (bucket 2,048 = 2 x the dimension's 1,024),
+    with duplicates, nulls and fact keys outside the span."""
+    rng = np.random.default_rng(seed)
+    tmin, tmax = _limits(d)
+    base = -200 if tmin < 0 else 50
+    rk = [base + int(x) for x in rng.integers(0, 1400, n_right)]
+    rk[0], rk[1] = base, base + 1399
+    lk = [base - 50 + int(x) for x in rng.integers(0, 1500, n_left)]
+    lk[:2] = [tmin, tmax]
+    lnull = rng.random(n_left) < 0.05
+    rnull = rng.random(n_right) < 0.05
+    rnull[:2] = False
+    return _tables(d, lk, lnull, rk, rnull)
+
+
+@pytest.mark.parametrize("how", ["inner", "left", "semi", "anti"])
+@pytest.mark.parametrize("dname", ["int64", "int32", "decimal64",
+                                   "timestamp_us"])
+def test_served_join_is_the_same_table_by_either_probe(
+    how, dname, monkeypatch
+):
+    config.set_flag("METRICS", True)
+    left, right = _dense_pair(WIDE[dname])
+    got, moved = _run_join(how, left, right)
+    assert moved == {"join.probe.direct": 1, "join.probe.search": 0,
+                     "bucket.fallback_errors": 0}
+    monkeypatch.setattr(bucketed, "_probe_table_size", lambda *a: None)
+    want, moved = _run_join(how, left, right)
+    assert moved["join.probe.search"] == 1
+    assert got.logical_row_count > 0
+    _assert_same_table(got, want)
+
+
+@pytest.mark.parametrize("how", ["inner", "left", "semi", "anti"])
+def test_served_join_of_padded_inputs_by_either_probe(how, monkeypatch):
+    """Both sides arrive padded (a filter's result, a padded upload):
+    the padding rows' zero keys lie inside the span."""
+    config.set_flag("METRICS", True)
+    left, right = _dense_pair(dt.INT64, seed=5)
+    left = _padded(left, 2500)
+    right = _padded(right, 600)
+    assert left.row_count == 4096 and right.row_count == 1024
+    got, moved = _run_join(how, left, right)
+    assert moved["join.probe.direct"] == 1
+    monkeypatch.setattr(bucketed, "_probe_table_size", lambda *a: None)
+    want, _ = _run_join(how, left, right)
+    _assert_same_table(got, want)
+
+
+def _pair_with_build_keys(rk, n_left=3000, d=dt.INT64):
+    rng = np.random.default_rng(11)
+    lk = [int(x) for x in rng.choice(np.asarray(rk, dtype=object), n_left)]
+    return _tables(d, lk, np.zeros(n_left, bool), rk,
+                   np.zeros(len(rk), bool))
+
+
+def test_span_at_the_threshold_is_direct_and_one_past_is_searched():
+    config.set_flag("METRICS", True)
+    body = list(range(100, 798))
+    at = _pair_with_build_keys([0] + body + [2047])
+    past = _pair_with_build_keys([0] + body + [2048])
+    out_at, moved = _run_join("inner", *at)
+    assert moved["join.probe.direct"] == 1
+    out_past, moved = _run_join("inner", *past)
+    assert moved["join.probe.search"] == 1
+    assert out_at.logical_row_count == out_past.logical_row_count == 3000
+
+
+def test_table_wider_than_the_probe_side_is_searched():
+    config.set_flag("METRICS", True)
+    left, right = _pair_with_build_keys(
+        [0] + list(range(100, 798)) + [1400], n_left=900
+    )
+    _, moved = _run_join("inner", left, right)
+    assert moved == {"join.probe.direct": 0, "join.probe.search": 1,
+                     "bucket.fallback_errors": 0}
+
+
+def _sparse_int():
+    rk = [int(x) * 1_000_003 for x in range(700)]
+    return _pair_with_build_keys(rk), ("k",)
+
+
+def _two_columns():
+    left, right = _dense_pair(dt.INT64)
+    left = Table([left.columns[0], left.columns[0], left.columns[1]],
+                 ["k", "k2", "lv"])
+    right = Table([right.columns[0], right.columns[0], right.columns[1]],
+                  ["k", "k2", "rv"])
+    return (left, right), ("k", "k2")
+
+
+def _string_key():
+    rng = np.random.default_rng(2)
+    rk = [f"item{i:04d}" for i in range(700)]
+    lk = [rk[int(i)] for i in rng.integers(0, 700, 3000)]
+    left = Table([Column.from_strings(lk),
+                  Column.from_numpy(np.arange(3000, dtype=np.int64))],
+                 ["k", "lv"])
+    right = Table([Column.from_strings(rk),
+                   Column.from_numpy(np.arange(700, dtype=np.int64))],
+                  ["k", "rv"])
+    return (left, right), ("k",)
+
+
+def _float64_key():
+    rng = np.random.default_rng(4)
+    rk = np.arange(700, dtype=np.float64)
+    lk = rng.integers(0, 700, 3000).astype(np.float64)
+    left = Table([Column.from_numpy(lk),
+                  Column.from_numpy(np.arange(3000, dtype=np.int64))],
+                 ["k", "lv"])
+    right = Table([Column.from_numpy(rk),
+                   Column.from_numpy(np.arange(700, dtype=np.int64))],
+                  ["k", "rv"])
+    return (left, right), ("k",)
+
+
+def _all_null_build():
+    left, right = _dense_pair(dt.INT64)
+    k = right.columns[0]
+    right = Table([k.with_validity(jnp.zeros((k.row_count,), jnp.bool_)),
+                   right.columns[1]], right.names)
+    return (left, right), ("k",)
+
+
+@pytest.mark.parametrize("make", [
+    _sparse_int, _two_columns, _string_key, _float64_key, _all_null_build,
+])
+def test_keys_the_table_cannot_address_take_the_search(make):
+    config.set_flag("METRICS", True)
+    (left, right), on = make()
+    out, moved = _run_join("left", left, right, on)
+    assert moved == {"join.probe.direct": 0, "join.probe.search": 1,
+                     "bucket.fallback_errors": 0}
+    assert out.logical_row_count >= 3000
+
+
+def test_choice_is_keyed_into_the_executable_cache():
+    """One executable a choice: the same shapes with a dense and then a
+    sparse key must not serve each other's program."""
+    config.set_flag("METRICS", True)
+    dense = _pair_with_build_keys(list(range(700)))
+    sparse, _ = _sparse_int()
+    a, moved_a = _run_join("inner", *dense)
+    b, moved_b = _run_join("inner", *sparse)
+    c, moved_c = _run_join("inner", *dense)
+    assert (moved_a["join.probe.direct"], moved_b["join.probe.search"],
+            moved_c["join.probe.direct"]) == (1, 1, 1)
+    assert a.logical_row_count == b.logical_row_count == 3000
+    _assert_same_table(a, c)
+
+
+@pytest.mark.parametrize("how", ["inner", "left", "semi", "anti"])
+def test_dispatch_plane_counts_one_direct_probe_a_join(how):
+    """Through the door a served plan's join takes
+    (``runtime_bridge`` -> ``dispatch_bucketed``), against the exact
+    path with the bucket plane off."""
+    import json
+
+    from spark_rapids_jni_tpu import runtime_bridge as rb
+
+    i64 = int(dt.TypeId.INT64)
+    rng = np.random.default_rng(8)
+    n = 1500
+    lk = rng.integers(-20, 120, n, dtype=np.int64)
+    lvalid = (rng.random(n) > 0.1).astype(np.uint8)
+    lv = np.arange(n, dtype=np.int64)
+    rk = rng.integers(0, 100, 60, dtype=np.int64)
+    rv = np.arange(60, dtype=np.int64) * 7
+
+    def run():
+        tidl = rb.table_upload_wire(
+            [i64, i64], [0, 0], [lk.tobytes(), lv.tobytes()],
+            [lvalid.tobytes(), None], n,
+        )
+        tidr = rb.table_upload_wire(
+            [i64, i64], [0, 0], [rk.tobytes(), rv.tobytes()],
+            [None, None], 60,
+        )
+        jid = rb.table_op_resident(
+            json.dumps({"op": "join", "how": how, "on": [0]}), [tidl, tidr]
+        )
+        out = rb.table_download_wire(jid)
+        for t in (tidl, tidr, jid):
+            rb.table_free(t)
+        return out
+
+    config.set_flag("METRICS", True)
+    watched = ["join.probe.direct", "join.probe.search",
+               "bucket.fallback_errors", "bucket.declined"]
+    before = metrics.counter_values(watched)
+    got = run()
+    after = metrics.counter_values(watched)
+    assert {k: after[k] - before[k] for k in watched} == {
+        "join.probe.direct": 1, "join.probe.search": 0,
+        "bucket.fallback_errors": 0, "bucket.declined": 0,
+    }
+    config.set_flag("BUCKETS", "off")
+    try:
+        want = run()
+    finally:
+        config.clear_flag("BUCKETS")
+    assert metrics.counter_values(watched) == after  # the exact path counts neither
+    assert got == want
+
+
+# ---------------------------------------------------------------------------
+# the program: no loop, at most two gathers as wide as the probe side
+# ---------------------------------------------------------------------------
+
+
+def _lowered_probe(table_size, n_left=4096, n_right=1024):
+    left = Table([
+        Column(jax.ShapeDtypeStruct((n_left,), jnp.int64), dt.INT64, None),
+        Column(jax.ShapeDtypeStruct((n_left,), jnp.int64), dt.INT64, None),
+    ])
+    right = Table([
+        Column(jax.ShapeDtypeStruct((n_right,), jnp.int64), dt.INT64, None),
+        Column(jax.ShapeDtypeStruct((n_right,), jnp.int64), dt.INT64, None),
+    ])
+    n32 = jax.ShapeDtypeStruct((), jnp.int32)
+
+    def fn(l, r, ln, rn):
+        lv = buckets.tail_valid(l.row_count, ln)
+        rv = buckets.tail_valid(r.row_count, rn)
+        return join_mod._match_ranges(
+            l, r, [0], [0], lv, rv, table_size=table_size
+        )
+
+    return jax.jit(fn).lower(left, right, n32, n32).as_text()
+
+
+def _gathers_of_width(text, width):
+    """Gather ops whose result has ``width`` elements."""
+    wide = 0
+    for line in text.splitlines():
+        if "stablehlo.gather" not in line:
+            continue
+        result = line.rsplit("->", 1)[-1]
+        if re.search(rf"tensor<{width}x", result):
+            wide += 1
+    return wide
+
+
+def test_lowered_dense_key_probe_has_no_loop_and_two_wide_gathers_at_most():
+    n_left = 4096
+    direct = _lowered_probe(2048, n_left)
+    assert "stablehlo.while" not in direct
+    assert 1 <= _gathers_of_width(direct, n_left) <= 2
+    # the search, through the same reading, is what the table replaced:
+    # two loops whose bodies gather both u64 words at the probe side's
+    # width (the chip splits each into two u32 gathers: eight)
+    search = _lowered_probe(None, n_left)
+    assert search.count("stablehlo.while") == 2
+    assert _gathers_of_width(search, n_left) == 4
