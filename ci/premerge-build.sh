@@ -28,8 +28,7 @@ build/dependency-check
 # call sites, metric-name conventions, un-tiered bench arms. Exits
 # nonzero on any finding not grandfathered in
 # tools/srt_check_baseline.json; the one-line summary is the last line.
-# SRT008 (dispatch-table/plancheck registry parity) and SRT009 (implicit
-# host-sync hazards in hot paths) ride the same gate.
+# SRT009 (implicit host-sync hazards in hot paths) rides the same gate.
 python3 tools/srt_check.py
 
 # Plan-literal gate: every plan literal in the bench arms and smoke

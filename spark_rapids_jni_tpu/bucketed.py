@@ -1,8 +1,11 @@
 """Bucketed op runners: the dispatch plane's pad-to-bucket fast path.
 
-``runtime_bridge._dispatch`` routes every bucketable op through
-:func:`dispatch_bucketed` before falling back to the exact-shape
-``_dispatch_impl``. A runner:
+``planops.dispatch`` routes every op that has a one-op runner through
+:func:`dispatch_bucketed` before falling back to the op's exact-shape
+function. This module is the mechanism only — padding, cache keys, the
+count read, the join's and the groupby's two-launch runners and the
+generic one-op runner — and knows no list of ops: ``planops.OPS`` hands
+it each op's traced body and program name. A runner:
 
 1. pads its input tables to their row-count buckets
    (``utils/buckets.pad_table``; wire uploads arrive pre-padded on the
@@ -54,14 +57,12 @@ _WARNED_OPS = set()
 
 
 def dispatch_bucketed(
-    op: dict, table: Table, rest: Sequence[Table], name: str
+    runner, op: dict, table: Table, rest: Sequence[Table], name: str
 ) -> Optional[Table]:
-    """Run one op through the bucket plane. Returns the (possibly
-    padded) result Table, or None when the op/shape isn't bucketable —
-    the caller then unpads the inputs and runs the exact path."""
-    runner = _RUNNERS.get(name)
-    if runner is None:
-        return None
+    """Run one op through the bucket plane with its one-op ``runner``
+    (``(op, table, rest) -> Table``). Returns the (possibly padded)
+    result Table, or None when the op/shape isn't bucketable — the
+    caller then unpads the inputs and runs the exact path."""
     # the span makes the bucket plane its own flight-recorder/trace
     # track (nested inside dispatch.<op>); declines and fallbacks are
     # handled INSIDE it so they exit the span cleanly instead of
@@ -88,41 +89,6 @@ def dispatch_bucketed(
             return None
     metrics.counter_add("bucket.dispatched")
     return out
-
-
-def dispatch_bucketed_donated(
-    op: dict, table: Table, name: str
-) -> Optional[Table]:
-    """Run ONE op whose input table is CONSUMED (the caller released
-    its resident id) with the padded input donated to the executable —
-    the single-op flavor of plan-segment donation, built on the same
-    fused-applier machinery so the donated executable shares
-    ``plan._run_fused``'s cache keying. Returns None when the op/shape
-    can't take the donated path (the caller then runs the normal
-    dispatch on the still-intact input); raises only when the donated
-    launch failed AFTER consuming its buffers."""
-    from . import plan as plan_mod
-
-    if not buckets.enabled() or not plan_mod.op_fusable(op):
-        return None
-    with metrics.span("bucketed.donated." + name):
-        try:
-            return plan_mod._run_fused([op], table, donate=True)
-        except _Decline:
-            metrics.counter_add("bucket.declined")
-            return None
-        except Exception as e:
-            if plan_mod._input_consumed(table):
-                raise
-            metrics.counter_add("bucket.fallback_errors")
-            profiler.note_fallback("bucketed")
-            if name not in _WARNED_OPS:
-                _WARNED_OPS.add(name)
-                log.log(
-                    "WARN", "buckets", "donated_runner_failed", op=name,
-                    error=f"{type(e).__name__}: {str(e)[:200]}",
-                )
-            return None
 
 
 # ---------------------------------------------------------------------------
@@ -253,108 +219,32 @@ def _reduce_groups(state, num_groups) -> Table:
 # ---------------------------------------------------------------------------
 
 
-def _r_cast(op: dict, table: Table, rest) -> Table:
+def run_one_op(
+    op: dict, table: Table, name: str, traced, program: str, counts: bool
+) -> Table:
+    """The one-op runner of every simple op: pad, compile ``fn(t, n)``
+    from the op's ONE traced body (``planops.OPS[name].traced``, the
+    body a fused segment runs) under ``program`` and the
+    ``(name, op, schema, bucket)`` key, launch. ``counts`` says the body
+    changes the row count: then the program returns the new count and
+    one read of it sizes the logical rows of the padded result."""
     pt = _padded_input(table)
-    ci = int(op["column"])
-    target = dt.DType(dt.TypeId(op["type_id"]), op.get("scale", 0))
-
-    def build():
-        def fn(t):
-            src = t.columns[ci]
-            if src.dtype.is_string or target.is_string:
-                from .ops import strings as strings_mod
-
-                out = strings_mod.cast(src, target)
-            else:
-                from .ops.cast import cast as cast_fn
-
-                out = cast_fn(src, target)
-            cols = list(t.columns)
-            cols[ci] = out
-            return Table(cols, t.names)
-
-        return fn
-
-    fn = buckets.cached_jit(
-        _key("cast", op, pt), build, "srt_bucketed_cast", scope="srt.cast"
-    )
-    return _finish(fn(_strip(pt)), pt.logical_row_count)
-
-
-def _r_project(op: dict, table: Table, rest) -> Table:
-    pt = _padded_input(table)
-    exprs = op["exprs"]
-
-    def build():
-        def fn(t):
-            from .ops.project import project_table
-
-            return project_table(t, exprs)
-
-        return fn
-
-    fn = buckets.cached_jit(
-        _key("project", op, pt), build, "srt_bucketed_project",
-        scope="srt.project",
-    )
-    out = fn(_strip(pt))
-    metrics.counter_add("project.calls")
-    return _finish(out, pt.logical_row_count)
-
-
-def _r_filter(op: dict, table: Table, rest) -> Table:
-    pt = _padded_input(table)
-    mi = int(op["mask"])
 
     def build():
         def fn(t, n):
-            from .ops.filter import filter_table_capped
-
-            mask = t.columns[mi]
-            rv = buckets.tail_valid(t.row_count, n)
-            # padding tails of RE-padded tables can hold arbitrary
-            # garbage (e.g. a prior capped filter clones kept rows), so
-            # the occupancy mask must gate the selection explicitly
-            keep = Column(
-                jnp.logical_and(mask.data, rv), mask.dtype, mask.validity
-            )
-            kept = Table(
-                [c for i, c in enumerate(t.columns) if i != mi]
-            )  # names dropped exactly like the exact-path dispatch
-            return filter_table_capped(kept, keep, capacity=t.row_count)
+            out, n = traced(op, t, n, buckets.tail_valid(t.row_count, n))
+            return (out, n) if counts else out
 
         return fn
 
     fn = buckets.cached_jit(
-        _key("filter", op, pt), build, "srt_bucketed_filter",
-        scope="srt.filter",
+        _key(name, op, pt), build, program, scope="srt." + name
     )
+    if not counts:
+        return _finish(fn(_strip(pt), _n_dev(pt)), pt.logical_row_count)
     out, count = fn(_strip(pt), _n_dev(pt))
     # srt: allow-host-sync(bucketed-runner boundary: the compiled launch is done; one count read sizes the logical rows of the padded result)
     return _finish(out, int(count))
-
-
-def _r_sort(op: dict, table: Table, rest) -> Table:
-    pt = _padded_input(table)
-
-    def build():
-        def fn(t, n):
-            from .ops.sort import SortKey, sort_table
-
-            ks = [
-                SortKey(k["column"], ascending=k.get("ascending", True))
-                for k in op["keys"]
-            ]
-            rv = buckets.tail_valid(t.row_count, n)
-            return sort_table(t, ks, row_valid=rv)
-
-        return fn
-
-    fn = buckets.cached_jit(
-        _key("sort_by", op, pt), build, "srt_bucketed_sort",
-        scope="srt.sort_by",
-    )
-    return _finish(fn(_strip(pt), _n_dev(pt)), pt.logical_row_count)
 
 
 def _r_groupby(op: dict, table: Table, rest) -> Table:
@@ -380,61 +270,9 @@ def _r_groupby(op: dict, table: Table, rest) -> Table:
         return _reduce_groups(state, num_groups)
 
 
-def _r_distinct(op: dict, table: Table, rest) -> Table:
-    pt = _padded_input(table)
-    keyspec = op.get("keys")
-
-    def build():
-        def fn(t, n):
-            from .ops.compaction import distinct_capped
-
-            rv = buckets.tail_valid(t.row_count, n)
-            return distinct_capped(
-                t, keyspec, capacity=t.row_count, row_valid=rv
-            )
-
-        return fn
-
-    fn = buckets.cached_jit(
-        _key("distinct", op, pt), build, "srt_bucketed_distinct",
-        scope="srt.distinct",
-    )
-    out, count = fn(_strip(pt), _n_dev(pt))
-    # srt: allow-host-sync(bucketed-runner boundary: the compiled launch is done; one count read sizes the logical rows of the padded result)
-    return _finish(out, int(count))
-
-
-def _r_rlike(op: dict, table: Table, rest) -> Table:
-    pt = _padded_input(table)
-    ci = int(op["column"])
-    pattern = op["pattern"]
-
-    def build():
-        def fn(t, n):
-            from .ops import regex as regex_mod
-            from .ops.filter import filter_table_capped
-
-            rv = buckets.tail_valid(t.row_count, n)
-            mask = regex_mod.contains_re(t.columns[ci], pattern)
-            # padding rows are zero-length strings: a pattern matching
-            # the empty string would select them without the gate
-            keep = Column(
-                jnp.logical_and(mask.data, rv), mask.dtype, mask.validity
-            )
-            return filter_table_capped(t, keep, capacity=t.row_count)
-
-        return fn
-
-    fn = buckets.cached_jit(
-        _key("rlike", op, pt), build, "srt_bucketed_rlike",
-        scope="srt.rlike",
-    )
-    out, count = fn(_strip(pt), _n_dev(pt))
-    # srt: allow-host-sync(bucketed-runner boundary: the compiled launch is done; one count read sizes the logical rows of the padded result)
-    return _finish(out, int(count))
-
-
-_BUCKETED_JOIN_HOWS = frozenset({"inner", "left", "semi", "anti"})
+# the hows _r_join has code for; planops' join entry reads this for
+# its ``bucketable``
+JOIN_HOWS = frozenset({"inner", "left", "semi", "anti"})
 
 
 def _probe_table_size(lt: Table, rt: Table, on: list) -> Optional[int]:
@@ -472,7 +310,7 @@ def _probe_table_size(lt: Table, rt: Table, on: list) -> Optional[int]:
 
 def _r_join(op: dict, table: Table, rest) -> Table:
     how = op.get("how", "inner")
-    if how not in _BUCKETED_JOIN_HOWS or not rest:
+    if how not in JOIN_HOWS or not rest:
         # right/full build on the exact outer machinery; argument
         # errors surface from the exact path
         raise _Decline
@@ -588,35 +426,3 @@ def _r_join(op: dict, table: Table, rest) -> Table:
     )
     out = p2(_strip(lt), _strip(rt), perm_r, lo, counts, _n_dev(lt))
     return _finish(out, total)
-
-
-_RUNNERS = {
-    "cast": _r_cast,
-    "project": _r_project,
-    "filter": _r_filter,
-    "sort_by": _r_sort,
-    "groupby": _r_groupby,
-    "distinct": _r_distinct,
-    "rlike": _r_rlike,
-    "join": _r_join,
-}
-
-
-def is_bucketable(op: dict) -> bool:
-    """Cheap pre-check: could this op take the bucketed path at all?
-    The wire layer uses it to skip host-side padding (and the extra
-    upload bytes it costs) for ops that would immediately unpad."""
-    name = op.get("op")
-    if name not in _RUNNERS:
-        return False
-    if name == "join":
-        return op.get("how", "inner") in _BUCKETED_JOIN_HOWS
-    if name == "groupby":
-        from .ops.groupby import _COLLECT_OPS
-
-        # collect_* groupbys decline in the runner (data-dependent
-        # list capacity) — don't pay the padded upload for them
-        return not any(
-            a.get("agg") in _COLLECT_OPS for a in op.get("aggs", ())
-        )
-    return True

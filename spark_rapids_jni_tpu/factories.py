@@ -222,7 +222,7 @@ def copy_if_else(lhs: Column, rhs: Column, mask: Column) -> Column:
 # ---------------------------------------------------------------------------
 # shape buckets (utils/buckets.py applied at the Python level)
 #
-# The dispatch plane (runtime_bridge._dispatch) buckets automatically;
+# The dispatch plane (planops.dispatch) buckets automatically;
 # these are the Python-level entry points for callers that drive the op
 # library directly and want the same compiled-shape reuse: pad once,
 # run the *_capped ops with `row_valid`, unpad at the end.

@@ -14,7 +14,7 @@ backend: one entry per accelerated inner loop, each declaring
   bucketed.py): padding-region bytes are free, logical bytes are not.
 
 Dispatch discipline mirrors ``bucketed.dispatch_bucketed``: the tier is
-consulted first by ``runtime_bridge._dispatch_once`` under the
+consulted first by ``planops._dispatch_once`` under the
 ``SPARK_RAPIDS_TPU_KERNELS=on|off|auto`` flag; any runner error — a
 Mosaic lowering the current toolchain refuses, a seeded ``kernel``
 chaos fault — is caught, metered as ``kernel.fallbacks``, and answered

@@ -1,14 +1,15 @@
 """Mesh data-parallel plan execution for row-local segments.
 
 ``run_plan_mesh`` runs a plan whose every op is row-local (``cast``,
-``project``, ``filter``, ``rlike`` — plan.py's ``_ROW_LOCAL``) as ONE shard_map
-stage over a :class:`~.tolerant.MeshRunner`: rows split into contiguous
+``project``, ``filter``, ``rlike`` — the ops ``planops.OPS`` marks
+``row_local``) as ONE shard_map stage over a
+:class:`~.tolerant.MeshRunner`: rows split into contiguous
 blocks (one per device), each shard runs the same fused segment body
 the single-device path compiles (``plan._run_segment_traced``), and the
 host gathers each shard's valid prefix back in mesh order.
 
 Shuffle as a plan op (ISSUE 17): a plan may additionally carry ONE
-``partition`` op (``plan._EXCHANGE_OPS``) anywhere in the chain. It is
+``partition`` op (the spec's ``exchange``) anywhere in the chain. It is
 the mesh segment boundary: the scan-side row-local chain, a two-phase
 counts pass, a ragged all-to-all exchange, a device-local stable sort
 back into partition order, and the merge-side row-local chain all run
@@ -56,6 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .. import planops
 from ..column import Column, Table
 from ..utils import metrics
 from .mesh import SHUFFLE_AXIS, shard_map
@@ -82,12 +84,8 @@ class MeshUnsupported(Exception):
 def _split_at_exchange(ops: Sequence[dict]):
     """``(pre_ops, partition_op | None, post_ops)`` — the plan split at
     its (single) exchange boundary."""
-    from .. import plan as plan_mod
-
-    idx = [
-        i for i, o in enumerate(ops)
-        if o.get("op") in plan_mod._EXCHANGE_OPS
-    ]
+    exchange = {n for n, s in planops.OPS.items() if s.exchange}
+    idx = [i for i, o in enumerate(ops) if o.get("op") in exchange]
     if not idx:
         return list(ops), None, []
     if len(idx) > 1:
@@ -101,8 +99,6 @@ def _split_at_exchange(ops: Sequence[dict]):
 
 def _check_supported(ops: Sequence[dict], table: Table,
                      rest: Sequence[Table]):
-    from .. import plan as plan_mod
-
     if rest:
         raise MeshUnsupported("mesh plan path takes no rest tables")
     if not ops:
@@ -110,12 +106,13 @@ def _check_supported(ops: Sequence[dict], table: Table,
     if not table.columns or table.logical_row_count == 0:
         raise MeshUnsupported("empty table")
     pre, part, post = _split_at_exchange(ops)
+    row_local = sorted(n for n, s in planops.OPS.items() if s.row_local)
     for op in (*pre, *post):
         name = op.get("op")
-        if name not in plan_mod._ROW_LOCAL:
+        if name not in row_local:
             raise MeshUnsupported(
                 f"op {name!r} is not row-local; mesh path handles "
-                f"{sorted(plan_mod._ROW_LOCAL)} chains (around one "
+                f"{row_local} chains (around one "
                 "optional partition boundary) only"
             )
     if part is not None and part.get("kind", "hash") == "range" and pre:

@@ -12,15 +12,15 @@ Weld/Photon-style lazy-fusion step layered on PR 2's shape buckets.
 
 Fusable ops (single-table, bucketable, ``row_valid``-maskable):
 ``cast``, ``project``, ``filter``, ``rlike``, ``distinct``, ``sort_by``,
-``slice`` (non-negative bounds), and a non-collect ``groupby`` TAIL — a groupby
-may close a fused run but not continue it: the segment's executable
+``slice`` (non-negative bounds), and a non-collect ``groupby`` TAIL
+(what ``planops.OPS`` marks ``fusable``) — a groupby may close a fused
+run but not continue it: the segment's executable
 ends with the groupby's sort half, its per-group half is a second
 launch at the bucket of the group count (``bucketed._reduce_groups``),
 and the following ops re-enter the compiler on that result.
 Everything else (join, concat, explode, to_rows/from_rows, ...) is a
-segment boundary dispatched through the
-existing per-op ``_dispatch`` path — bucketed runner or exact fallback
-— with ``Table.logical_rows`` carried through unchanged so padding
+segment boundary dispatched through the one-op ``planops.dispatch``
+path — bucketed runner or exact fallback — with ``Table.logical_rows`` carried through unchanged so padding
 semantics survive the boundary.
 
 Semantics contract: byte-identical to the per-op path (which is itself
@@ -45,28 +45,14 @@ lines are attributable).
 from __future__ import annotations
 
 import contextlib
-from typing import List, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from . import dtype as dt
-from .column import Column, Table
-from .utils import buckets, faults, flight, log, metrics, profiler
-
-# single-table ops a fused segment can carry anywhere in its run
-_SIMPLE_FUSABLE = frozenset(
-    {"cast", "filter", "rlike", "distinct", "sort_by", "slice", "project"}
-)
-
-# mesh exchange boundaries: planmesh splits a plan at these ops into a
-# scan-side chain -> counts-sized all-to-all -> merge-side chain, each
-# chain still fused under shard_map. On the exact path they run through
-# the ordinary per-op dispatch (a stable partition-contiguous reorder).
-# Pure literal — the exchange-plane side of the SRT008 parity check:
-# every member must also be in runtime_bridge.DISPATCH_OPS,
-# _dispatch_impl, and plancheck._RULES.
-_EXCHANGE_OPS = frozenset({"partition"})
+from . import bucketed, plancheck, planops
+from .column import Table
+from .utils import buckets, faults, flight, hbm, log, metrics, profiler
 
 # fused-segment failures are replayed per-op; warn once per op-chain
 # shape (the bucketed._WARNED_OPS discipline), not per call
@@ -97,195 +83,13 @@ def _filter_partial_donation_warning() -> None:
     warnings.filterwarnings("ignore", message=_DONATE_WARNING_MSG)
 
 
-def op_fusable(op: dict) -> bool:
-    """Could this op ride inside a fused segment? (groupby: tail-only,
-    see segment_plan). Mirrors ``bucketed.is_bucketable`` plus ``slice``,
-    minus multi-table ops."""
-    if not isinstance(op, dict):
-        return False  # malformed entries fail loudly in run_plan
-    name = op.get("op")
-    if name in _SIMPLE_FUSABLE:
-        if name == "slice":
-            # negative bounds raise in the exact path; keep that error
-            # surfacing there, not from inside a traced segment
-            try:
-                start = int(op.get("start", 0))
-                stop = op.get("stop")
-                return start >= 0 and (stop is None or int(stop) >= 0)
-            except (TypeError, ValueError):
-                return False
-        return True
-    if name == "groupby":
-        from .ops.groupby import _COLLECT_OPS
-
-        # collect_* needs a data-dependent list-capacity pre-pass the
-        # exact path owns (the bucketed-runner decline, applied early)
-        return not any(
-            a.get("agg") in _COLLECT_OPS for a in op.get("aggs", ())
-        )
-    return False
-
-
 def segment_plan(ops: Sequence[dict]) -> List[Tuple[str, list]]:
-    """Split a plan into ``[(kind, ops)]`` segments: ``"fused"`` (a run
-    of >= 2 fusable ops compiled as one executable) or ``"exact"`` (a
-    single op through the per-op dispatch — non-fusable ops, and
-    1-op runs, which the per-op bucketed runners already cache under
-    their own keys). A groupby is tail-only: it closes the run it ends."""
-    segs: List[Tuple[str, list]] = []
-    cur: list = []
-
-    def flush():
-        nonlocal cur
-        if not cur:
-            return
-        if len(cur) >= 2:
-            segs.append(("fused", cur))
-        else:
-            segs.extend(("exact", [o]) for o in cur)
-        cur = []
-
-    for op in ops:
-        if op_fusable(op):
-            cur.append(op)
-            if op.get("op") == "groupby":
-                flush()
-        else:
-            flush()
-            segs.append(("exact", [op]))
-    flush()
-    return segs
-
-
-# ---------------------------------------------------------------------------
-# fused per-op appliers — each runs INSIDE the traced segment, taking
-# (op, padded table, device logical count, row_valid occupancy) and
-# returning (table at the same physical shape, new device count). The
-# occupancy mask is recomputed per step from the flowing count, so a
-# filter's clone-padded tail is dead for everything downstream.
-# ---------------------------------------------------------------------------
-
-
-def _fused_cast(op, t, n, rv):
-    ci = int(op["column"])
-    target = dt.DType(dt.TypeId(op["type_id"]), op.get("scale", 0))
-    src = t.columns[ci]
-    if src.dtype.is_string or target.is_string:
-        from .ops import strings as strings_mod
-
-        out = strings_mod.cast(src, target)
-    else:
-        from .ops.cast import cast as cast_fn
-
-        out = cast_fn(src, target)
-    cols = list(t.columns)
-    cols[ci] = out
-    return Table(cols, t.names), n
-
-
-def _fused_project(op, t, n, rv):
-    from .ops.project import project_table
-
-    # elementwise: what it computes over the padding tail stays behind
-    # the flowing count, like a cast's
-    return project_table(t, op["exprs"]), n
-
-
-def _fused_filter(op, t, n, rv):
-    from .ops.filter import filter_table_capped
-
-    mi = int(op["mask"])
-    mask = t.columns[mi]
-    # the occupancy gate: padding tails can hold arbitrary garbage
-    # (e.g. an upstream capped filter clones kept rows)
-    keep = Column(
-        jnp.logical_and(mask.data, rv), mask.dtype, mask.validity
-    )
-    kept = Table(
-        [c for i, c in enumerate(t.columns) if i != mi]
-    )  # names dropped exactly like the exact-path dispatch
-    return filter_table_capped(kept, keep, capacity=t.row_count)
-
-
-def _fused_rlike(op, t, n, rv):
-    from .ops import regex as regex_mod
-    from .ops.filter import filter_table_capped
-
-    mask = regex_mod.contains_re(
-        t.columns[int(op["column"])], op["pattern"]
-    )
-    # padding rows are zero-length strings: a pattern matching the
-    # empty string would select them without the gate
-    keep = Column(
-        jnp.logical_and(mask.data, rv), mask.dtype, mask.validity
-    )
-    return filter_table_capped(t, keep, capacity=t.row_count)
-
-
-def _fused_distinct(op, t, n, rv):
-    from .ops.compaction import distinct_capped
-
-    return distinct_capped(
-        t, op.get("keys"), capacity=t.row_count, row_valid=rv
-    )
-
-
-def _fused_sort(op, t, n, rv):
-    from .ops.sort import SortKey, sort_table
-
-    ks = [
-        SortKey(k["column"], ascending=k.get("ascending", True))
-        for k in op["keys"]
+    """Split a plan into ``[(kind, ops)]`` segments, ``"fused"`` or
+    ``"exact"``: ``plancheck.predict_segments`` over the ops themselves."""
+    return [
+        (kind, [ops[i] for i in idxs])
+        for kind, idxs in plancheck.predict_segments(ops)
     ]
-    return sort_table(t, ks, row_valid=rv), n
-
-
-def _fused_slice(op, t, n, rv):
-    from .ops.filter import filter_table_capped
-
-    # exact-path semantics (start/stop clamped to the LOGICAL count)
-    # expressed against the device scalar: keep rows [s, e) of the
-    # first n, compacted to the front at the same physical shape.
-    # Host-side clamp to the physical row count first: n <= row_count,
-    # so the clamp is semantics-free and keeps a giant (>= 2^31) but
-    # valid bound from overflowing the int32 conversion
-    cap = t.row_count
-    s = jnp.minimum(jnp.int32(min(int(op.get("start", 0)), cap)), n)
-    stop = op.get("stop")
-    e = (
-        n
-        if stop is None
-        else jnp.minimum(jnp.int32(min(int(stop), cap)), n)
-    )
-    e = jnp.maximum(s, e)
-    iota = jnp.arange(t.row_count, dtype=jnp.int32)
-    keep = jnp.logical_and(iota >= s, iota < e)
-    return filter_table_capped(
-        t, Column(keep, dt.BOOL8, None), capacity=t.row_count
-    )
-
-
-def _fused_groupby(op, t, n, rv):
-    # the sort half only -> (sorted state, group count): _run_fused
-    # launches the per-group half once it has read the count
-    from . import bucketed
-    from .ops.groupby import groupby_sort
-
-    return groupby_sort(
-        t, list(op["by"]), bucketed._groupby_aggs(op), row_valid=rv
-    )
-
-
-_FUSED = {
-    "cast": _fused_cast,
-    "project": _fused_project,
-    "filter": _fused_filter,
-    "rlike": _fused_rlike,
-    "distinct": _fused_distinct,
-    "sort_by": _fused_sort,
-    "slice": _fused_slice,
-    "groupby": _fused_groupby,
-}
 
 
 def _run_segment_traced(seg_ops: Sequence[dict], t: Table, n):
@@ -298,7 +102,7 @@ def _run_segment_traced(seg_ops: Sequence[dict], t: Table, n):
         # fusion to filter, join, groupby or sort_by
         with jax.named_scope("srt." + op["op"]):
             rv = buckets.tail_valid(t.row_count, n)
-            t, n = _FUSED[op["op"]](op, t, n, rv)
+            t, n = planops.OPS[op["op"]].traced(op, t, n, rv)
             if hasattr(n, "astype"):
                 n = n.astype(jnp.int32)
     return t, n
@@ -320,9 +124,6 @@ def _run_fused(
     tables). After the call the input arrays are deleted; the
     ``run_plan`` fallback checks for that before attempting a per-op
     replay."""
-    from . import bucketed
-    from .utils import hbm
-
     pt = bucketed._padded_input(table)  # _Decline when unbucketable
     key = buckets.cache_key("plan", list(seg_ops), (pt,))
 
@@ -343,7 +144,7 @@ def _run_fused(
     # a groupby tail's two launches stay together on the device
     with bucketed.groupby_turn() if groupby_tail else contextlib.nullcontext():
         out, count = fn(bucketed._strip(pt), bucketed._n_dev(pt))
-        _note_project_calls(seg_ops)
+        planops.note_launched(seg_ops)
         if donated:
             # counted AFTER the launch: a trace/compile failure falls back
             # to per-op replay with the input intact — nothing was donated
@@ -356,22 +157,35 @@ def _run_fused(
     return bucketed._finish(out, int(count))
 
 
-def _note_project_calls(seg_ops: Sequence[dict]) -> None:
-    """``project.calls`` for the ``project`` ops of a segment that has
-    just been launched: counted on the host at launch, not at trace (the
-    executable is cached), so the counter says how many expression lists
-    the daemon evaluated, whether alone or inside a fused segment."""
-    k = sum(1 for o in seg_ops if o.get("op") == "project")
-    if k:
-        metrics.counter_add("project.calls", k)
-
-
-# ops whose output over a row range depends only on the rows in that
-# range — the segments the OOM half-batch degradation may legally
-# split: run each half, concatenate, and the result is byte-identical.
-# sort_by/distinct/groupby/slice are global (cross-row) and must not
-# be chunked; they fall back to the exact path instead.
-_ROW_LOCAL = frozenset({"cast", "filter", "rlike", "project"})
+def run_donated(op: dict, table: Table, name: str) -> Optional[Table]:
+    """Run ONE op whose input table is CONSUMED (the caller released
+    its resident id) with the padded input donated to the executable —
+    the single-op flavor of plan-segment donation: a one-op segment
+    through :func:`_run_fused`, so the donated executable shares its
+    cache keying. Returns None when the op/shape can't take the donated
+    path (the caller then runs the normal dispatch on the still-intact
+    input); raises only when the donated launch failed AFTER consuming
+    its buffers."""
+    if not buckets.enabled() or not planops.op_fusable(op):
+        return None
+    with metrics.span("bucketed.donated." + name):
+        try:
+            return _run_fused([op], table, donate=True)
+        except bucketed._Decline:
+            metrics.counter_add("bucket.declined")
+            return None
+        except Exception as e:
+            if _input_consumed(table):
+                raise
+            metrics.counter_add("bucket.fallback_errors")
+            profiler.note_fallback("bucketed")
+            if name not in bucketed._WARNED_OPS:
+                bucketed._WARNED_OPS.add(name)
+                log.log(
+                    "WARN", "buckets", "donated_runner_failed", op=name,
+                    error=f"{type(e).__name__}: {str(e)[:200]}",
+                )
+            return None
 
 
 def _run_chunked(seg_ops: Sequence[dict], table: Table) -> Table:
@@ -379,9 +193,10 @@ def _run_chunked(seg_ops: Sequence[dict], table: Table) -> Table:
     segment: split the input at half the rows, run each half through
     the same fused machinery (smaller bucket -> smaller working set),
     and concatenate — parity-safe because every op in the segment is
-    row-local (caller-gated on :data:`_ROW_LOCAL`). Returns the exact
-    (unpadded) result table; raises faults.ResourceExhausted when the
-    input is too small to split."""
+    row-local (caller-gated on the specs' ``row_local``: sort_by,
+    distinct, groupby and slice are global and fall back to the exact
+    path instead). Returns the exact (unpadded) result table; raises
+    faults.ResourceExhausted when the input is too small to split."""
     from .ops.copying import concatenate, slice_rows
 
     t = buckets.unpad_table(table)
@@ -424,8 +239,6 @@ def _run_fused_tolerant(
       consumes anything, so an injected retry is always safe);
     * anything else propagates to run_plan's per-op replay fallback.
     """
-    from . import bucketed
-
     attempt = 0
     spill_tried = False
     while True:
@@ -449,7 +262,7 @@ def _run_fused_tolerant(
                 # resident tables, then retry the SAME shape. 2x the
                 # input sizes the launch's input + output residency.
                 spill_tried = True
-                from .utils import hbm, spill
+                from .utils import spill
 
                 freed = spill.request_headroom(
                     2 * hbm.table_bytes(table), reason="oom"
@@ -460,7 +273,7 @@ def _run_fused_tolerant(
                         flight.record("I", "plan.oom_spill_retry", freed)
                     continue
             if cls is faults.ResourceExhausted and all(
-                o.get("op") in _ROW_LOCAL for o in seg_ops
+                planops.OPS[o["op"]].row_local for o in seg_ops
             ):
                 try:
                     return _run_chunked(seg_ops, table)
@@ -556,7 +369,6 @@ def _offer_mesh(ops, table: Table, rest, mesh_runner):
     """Offer the plan to the mesh data-parallel path -> the result, or
     None where the single-device path below has to run it."""
     from .parallel import planmesh
-    from .utils import hbm
 
     # the mesh path runs the whole plan as ONE sharded stage, so it
     # gets one whole-plan "mesh" segment for attribution — the
@@ -583,7 +395,7 @@ def _offer_mesh(ops, table: Table, rest, mesh_runner):
             pseg = None
             return None
         metrics.counter_add("plan.mesh_segments")
-        _note_project_calls(ops)
+        planops.note_launched(ops)
         profiler.segment_end(
             pseg, rows_out=int(out.logical_row_count),
             out_bytes=hbm.table_bytes(out),
@@ -616,8 +428,6 @@ def _offer_mesh(ops, table: Table, rest, mesh_runner):
 def _run_segments(ops, table: Table, rest, donate_input: bool) -> Table:
     """The single-device path: the plan's segments in order, each under
     its ``plan.segment`` span and a ``plan.segment.<sig>`` one."""
-    from . import bucketed, runtime_bridge
-
     orig_rest = tuple(rest)
     queue = list(orig_rest)
     if buckets.enabled():
@@ -704,14 +514,12 @@ def _run_segments(ops, table: Table, rest, donate_input: bool) -> Table:
                                 ),
                             )
                 for op in replay:
-                    table = runtime_bridge._dispatch(
+                    table = planops.dispatch(
                         op, table, _take_rest(op, orig_rest, queue)
                     )
                     metrics.counter_add("plan.exact_ops")
             finally:
                 if pseg is not None:
-                    from .utils import hbm
-
                     try:
                         ro = int(table.logical_row_count)
                         ob = int(hbm.table_bytes(table))

@@ -27,7 +27,8 @@ import jax
 import numpy as np
 
 from . import dtype as dt
-from . import pipeline
+from . import pipeline, plancheck, planops
+from . import plan as plan_mod
 from .column import Column, Table
 from .utils import buckets, faults, flight, lockcheck, log, metrics, profiler, spill
 
@@ -393,254 +394,6 @@ def _column_to_wire_impl(
     )
 
 
-def _dispatch(op: dict, table: Table, rest: Sequence[Table] = ()) -> Table:
-    """Run one op on device; returns the result Table.
-
-    ``rest`` carries additional input tables for multi-table ops
-    (``join`` takes the probe side as ``table`` and the build side as
-    ``rest[0]``; ``concat`` appends every table in ``rest``).
-
-    With shape bucketing on (the default; ``SPARK_RAPIDS_TPU_BUCKETS``),
-    bucketable ops run through ``bucketed.dispatch_bucketed``: inputs
-    padded to row-count buckets, one compiled executable per
-    ``(op, schema, bucket)`` from the central cache, results padded with
-    ``Table.logical_rows`` carrying the real count. Non-bucketable ops
-    (and the ``=off`` debug mode) take the exact-shape path — padded
-    inputs are unpadded first so exact ops never see garbage tails.
-
-    Every op runs inside a ``metrics.span`` and feeds the per-op
-    call/row counters — the ``GpuMetric`` plane of the dispatch layer.
-    The disabled path costs one string concat and the span's cheap
-    gate checks. Row counters count LOGICAL rows (padding is an
-    implementation detail; its cost shows up in ``bucket.*`` instead).
-
-    This is also a fault boundary (utils/faults.py): the ``dispatch``
-    injection site is armed here, transient-classified failures retry
-    with backoff (safe: nothing on this path donates its inputs — the
-    consumed single-op flavor is ``dispatch_bucketed_donated``, gated
-    by its caller), and permanent-classified errors surface unchanged.
-    """
-    name = op["op"]
-
-    def attempt():
-        faults.inject("dispatch")
-        return _dispatch_once(op, table, rest, name)
-
-    return faults.run_with_retry(attempt, "dispatch." + name)
-
-
-def _dispatch_once(
-    op: dict, table: Table, rest: Sequence[Table], name: str
-) -> Table:
-    # a tracked lock held across a device launch serializes every other
-    # dispatcher behind the chip — the lockcheck shim reports it
-    lockcheck.note_blocking("device_dispatch")
-    with metrics.span("dispatch." + name):
-        # the kernel tier (kernels/registry.py) is consulted FIRST:
-        # hand-written Pallas runners under SPARK_RAPIDS_TPU_KERNELS,
-        # byte-identical over the logical rows, declining/falling back
-        # to the bucketed/exact chain below. The flag-off path is one
-        # generation check (<5 µs contract, test_kernel_tier.py).
-        from .kernels import registry as kernel_registry
-
-        out = kernel_registry.dispatch_kernel(op, table, rest, name)
-        if out is None and buckets.enabled():
-            from . import bucketed
-
-            out = bucketed.dispatch_bucketed(op, table, rest, name)
-        if out is None:
-            out = _dispatch_impl(
-                op,
-                buckets.unpad_table(table),
-                [buckets.unpad_table(t) for t in rest],
-                name,
-            )
-    if metrics.enabled():
-        rows_in = int(table.logical_row_count) + sum(
-            int(t.logical_row_count) for t in rest
-        )
-        metrics.counter_add("op." + name + ".calls")
-        metrics.counter_add("op." + name + ".rows_in", rows_in)
-        metrics.counter_add(
-            "op." + name + ".rows_out", int(out.logical_row_count)
-        )
-        metrics.hist_observe("dispatch.rows_in", rows_in)
-    return out
-
-
-def _dispatch_impl(
-    op: dict, table: Table, rest: Sequence[Table], name: str
-) -> Table:
-    import jax.numpy as jnp
-
-    from . import ops
-    from . import rows as rows_mod
-
-    if name == "join":
-        how = op.get("how", "inner")
-        fn = {
-            "inner": ops.inner_join,
-            "left": ops.left_join,
-            "right": ops.right_join,
-            "full": ops.full_join,
-            "semi": ops.semi_join,
-            "anti": ops.anti_join,
-        }.get(how)
-        if fn is None:
-            raise ValueError(f"unknown join how={how!r}")
-        if not rest:
-            raise ValueError("join needs two input tables")
-        return fn(table, rest[0], op["on"])
-    if name == "concat":
-        return ops.concatenate([table, *rest])
-    if name == "groupby":
-        from .ops.groupby import GroupbyAgg
-
-        aggs = [GroupbyAgg(a["column"], a["agg"]) for a in op["aggs"]]
-        return ops.groupby_aggregate(table, op["by"], aggs)
-    if name == "sort_by":
-        keys = [
-            ops.SortKey(k["column"], ascending=k.get("ascending", True))
-            for k in op["keys"]
-        ]
-        return ops.sort_table(table, keys)
-    if name == "filter":
-        mask_idx = op["mask"]
-        mask = table.columns[mask_idx]
-        keep = [
-            c for i, c in enumerate(table.columns) if i != mask_idx
-        ]
-        return ops.filter_table(Table(keep), mask)
-    if name == "distinct":
-        return ops.distinct(table, op.get("keys"))
-    if name == "cast":
-        target = dt.DType(dt.TypeId(op["type_id"]), op.get("scale", 0))
-        out = list(table.columns)
-        src = table.columns[op["column"]]
-        if src.dtype.is_string or target.is_string:
-            from .ops import strings as strings_mod
-
-            out[op["column"]] = strings_mod.cast(src, target)
-        else:
-            out[op["column"]] = ops.cast(src, target)
-        return Table(out, table.names)
-    if name == "project":
-        # Spark's ProjectExec: one output column per expression tree
-        # (ops/project.py has the grammar); counted at launch, here as
-        # in the bucketed runner and the fused segment
-        from .ops.project import project_table
-
-        out = project_table(table, op["exprs"])
-        metrics.counter_add("project.calls")
-        return out
-    if name == "explode":
-        return ops.explode(table, op["column"])
-    if name == "rlike":
-        # filter rows whose string column matches the pattern (the
-        # Spark `WHERE col RLIKE pat` scan shape)
-        from .ops import regex as regex_mod
-
-        mask = regex_mod.contains_re(
-            table.columns[op["column"]], op["pattern"]
-        )
-        return ops.filter_table(table, mask)
-    if name == "cross_join":
-        if not rest:
-            raise ValueError("cross_join needs two input tables")
-        return ops.cross_join(table, rest[0])
-    if name == "slice":
-        n = table.row_count
-        start = int(op.get("start", 0))
-        stop = int(op.get("stop", n))
-        if start < 0 or stop < 0:
-            raise ValueError(
-                f"slice: negative bounds not supported (start={start}, "
-                f"stop={stop})"
-            )
-        start = min(start, n)
-        stop = max(start, min(stop, n))
-        return ops.slice_rows(table, start, stop)
-    if name == "repeat":
-        return ops.repeat(table, int(op["count"]))
-    if name == "sample":
-        return ops.sample(
-            table, int(op["n"]), seed=int(op.get("seed", 0)),
-            replacement=bool(op.get("replacement", False)),
-        )
-    if name == "partition":
-        # Spark's ShuffleExchangeExec partitioning step as a table op:
-        # rows reordered partition-contiguously by Pmod(Murmur3, num)
-        # (hash) or sampled key-range splitters (range). The exchange
-        # itself is the mesh path's job (planmesh); on the exact path
-        # the stable reorder IS the observable result, which is what
-        # the mesh path must match byte-for-byte after its all-to-all.
-        from .ops import partition as partition_mod
-
-        kind = op.get("kind", "hash")
-        num = int(op["num"])
-        if num < 1:
-            raise ValueError(f"partition: num must be >= 1, got {num}")
-        keys = list(op.get("keys", []))
-        if kind == "hash":
-            out, _ = partition_mod.hash_partition(table, keys or None, num)
-        elif kind == "range":
-            if not keys:
-                raise ValueError("partition: range kind needs keys")
-            out, _ = partition_mod.range_partition(table, keys, num)
-        else:
-            raise ValueError(f"unknown partition kind {kind!r}")
-        if metrics.enabled():
-            metrics.counter_add("partition.exact")
-        return out
-    if name == "to_rows":
-        # device row transpose; result = a true LIST<UINT8> column (the
-        # reference's output type, row_conversion.cu:389-406)
-        return Table([rows_mod.to_rows_list(table)])
-    if name == "from_rows":
-        schema = [
-            dt.DType(dt.TypeId(t), s)
-            for t, s in zip(op["type_ids"], op["scales"])
-        ]
-        src = table.columns[0]
-        if src.dtype.id == dt.TypeId.LIST:
-            return rows_mod.from_rows_list(src, schema)
-        # legacy flat-UINT8 input: one column of num_rows*row_size bytes
-        layout = rows_mod.compute_fixed_width_layout(schema)
-        n = int(op["num_rows"])
-        raw = np.asarray(src.data).reshape(n, layout.row_size)
-        pr = rows_mod.PackedRows(jnp.asarray(raw), layout)
-        return rows_mod.from_rows(pr, schema)
-    raise ValueError(f"unknown table op {name!r}")
-
-
-# Every op key the dispatch chain above accepts. This literal is the
-# dispatch-plane side of the SRT008 registry-parity pair: srt_check
-# verifies (statically) that it matches both the ``name == "..."`` arms
-# of _dispatch_impl and plancheck's inference-rule table, so an op added
-# to one registry without the others fails CI before it can ship.
-DISPATCH_OPS = frozenset(
-    {
-        "join",
-        "concat",
-        "groupby",
-        "sort_by",
-        "filter",
-        "distinct",
-        "cast",
-        "explode",
-        "rlike",
-        "cross_join",
-        "slice",
-        "repeat",
-        "sample",
-        "partition",
-        "to_rows",
-        "from_rows",
-        "project",
-    }
-)
-
-
 def _table_from_wire(
     type_ids: Sequence[int],
     scales: Sequence[int],
@@ -751,18 +504,15 @@ def table_op_wire(
     """
     op = json.loads(op_json)
     pad_to = None
-    if buckets.enabled():
-        from . import bucketed
-
-        # pad only when the op can actually take the bucketed path —
-        # a non-bucketable op would pay the padded upload AND a device
-        # unpad slice for nothing
-        if bucketed.is_bucketable(op):
-            pad_to = buckets.bucket_for(num_rows)
+    # pad only when the op can actually take the bucketed path — a
+    # non-bucketable op would pay the padded upload AND a device unpad
+    # slice for nothing
+    if buckets.enabled() and planops.op_bucketable(op):
+        pad_to = buckets.bucket_for(num_rows)
     tbl = _table_from_wire(
         type_ids, scales, datas, valids, num_rows, pad_to
     )
-    result = _dispatch(op, tbl)
+    result = planops.dispatch(op, tbl)
     return _table_to_wire(result)
 
 
@@ -773,13 +523,11 @@ def _plan_pad_to(ops, num_rows: int) -> Optional[int]:
     segment granularity, so a plan opening with e.g. a lone slice
     doesn't pay a padded upload just to unpad on the exact path;
     malformed entries fall through to run_plan's loud validation."""
-    from . import bucketed, plan as plan_mod
-
     if not (buckets.enabled() and ops and isinstance(ops[0], dict)):
         return None
     segs = plan_mod.segment_plan(ops)
     if segs and (
-        segs[0][0] == "fused" or bucketed.is_bucketable(segs[0][1][0])
+        segs[0][0] == "fused" or planops.op_bucketable(segs[0][1][0])
     ):
         return buckets.bucket_for(num_rows)
     return None
@@ -800,16 +548,12 @@ def table_plan_wire(
     by construction (nothing else holds a wire table), so the first
     fused segment donates its buffers — the chain updates HBM in place
     instead of doubling peak (``hbm.donated_bytes``)."""
-    from . import plan as plan_mod
-
     ops = json.loads(plan_json)
     if not isinstance(ops, list):
         raise TypeError("table_plan_wire: plan must be a JSON list of ops")
     # static analysis BEFORE the upload: a plan that cannot run costs
     # zero wire bytes, zero compiles (plancheck.PlanCheckError names the
     # op index + reason and subclasses ValueError)
-    from . import plancheck
-
     schema = plancheck.schema_from_wire(type_ids, scales)
     report = plancheck.check_plan(ops, schema=schema, rows=int(num_rows))
     pad_to = _plan_pad_to(ops, num_rows)
@@ -839,8 +583,6 @@ def table_stream_wire(plan_json: str, batches: Sequence) -> list:
     results and error surfacing either way. Each batch's decoded table
     is consumed by its plan run, so fused chains donate
     (``hbm.donated_bytes``)."""
-    from . import plan as plan_mod
-
     ops = json.loads(plan_json)
     if not isinstance(ops, list):
         raise TypeError(
@@ -849,8 +591,6 @@ def table_stream_wire(plan_json: str, batches: Sequence) -> list:
     # static analysis against the first batch's wire schema before any
     # batch decodes or the pipeline spins up; an empty stream still gets
     # the structural walk
-    from . import plancheck
-
     batches = list(batches)
     schema = None
     bucket = None
@@ -1141,13 +881,11 @@ def _run_resident_op(
     tables = pipeline.materialize_inputs(inputs)
     out = None
     if donate:
-        from . import bucketed
-
         for p in barrier:
             p.settle_terminally()
-        out = bucketed.dispatch_bucketed_donated(op, tables[0], name)
+        out = plan_mod.run_donated(op, tables[0], name)
     if out is None:
-        out = _dispatch(op, tables[0], tables[1:])
+        out = planops.dispatch(op, tables[0], tables[1:])
     return out
 
 
@@ -1212,7 +950,6 @@ def _static_check_resident_plan(ops, table_ids: Sequence[int]):
     enqueue). Raises plancheck.PlanCheckError before any input capture,
     pin, or pipeline enqueue. Returns ``(report, head_schema)`` so the
     caller can key the profile session's plan-stats record."""
-    from . import plancheck
 
     def settled(tid):
         t = _resident_peek(int(tid))
@@ -1257,8 +994,6 @@ def table_plan_resident(
     immediately when the pipeline is on (see ``table_op_resident``)."""
     if not table_ids:
         raise ValueError("table_plan_resident needs at least one input")
-    from . import plan as plan_mod
-
     ops = json.loads(plan_json)
     if not isinstance(ops, list):
         raise TypeError(

@@ -154,7 +154,7 @@ def retryable_class(cls: type) -> bool:
 
 # the injection-site registry: every name a FAULTS plan may target.
 # Each site is armed at exactly one choke point:
-#   dispatch     runtime_bridge._dispatch + plan._run_fused (per-op and
+#   dispatch     planops.dispatch + plan._run_fused (per-op and
 #                fused-segment device launches)
 #   compile      buckets.cached_jit (executable build, miss path)
 #   serde        runtime_bridge._table_from_wire / _table_to_wire

@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 from spark_rapids_jni_tpu import dtype as dt
+from spark_rapids_jni_tpu import planops
 from spark_rapids_jni_tpu import runtime_bridge as rb
 from spark_rapids_jni_tpu.column import Column, Table
 from spark_rapids_jni_tpu.utils import buckets, config, metrics
@@ -156,19 +157,17 @@ class TestPadUnpad:
         assert again.row_count == 16 and again.logical_rows == 10
 
     def test_is_bucketable_gate(self):
-        from spark_rapids_jni_tpu import bucketed
-
-        assert bucketed.is_bucketable({"op": "sort_by", "keys": []})
-        assert bucketed.is_bucketable({"op": "join", "how": "semi"})
-        assert bucketed.is_bucketable({"op": "join"})  # default inner
-        assert not bucketed.is_bucketable({"op": "join", "how": "full"})
-        assert not bucketed.is_bucketable({"op": "explode"})
-        assert not bucketed.is_bucketable({"op": "concat"})
-        assert bucketed.is_bucketable(
+        assert planops.op_bucketable({"op": "sort_by", "keys": []})
+        assert planops.op_bucketable({"op": "join", "how": "semi"})
+        assert planops.op_bucketable({"op": "join"})  # default inner
+        assert not planops.op_bucketable({"op": "join", "how": "full"})
+        assert not planops.op_bucketable({"op": "explode"})
+        assert not planops.op_bucketable({"op": "concat"})
+        assert planops.op_bucketable(
             {"op": "groupby", "by": [0],
              "aggs": [{"column": 1, "agg": "sum"}]}
         )
-        assert not bucketed.is_bucketable(
+        assert not planops.op_bucketable(
             {"op": "groupby", "by": [0],
              "aggs": [{"column": 1, "agg": "collect_list"}]}
         )
@@ -385,7 +384,7 @@ class TestBucketEdgeSemantics:
 
         def run():
             col = Column.from_strings(strs)
-            out = rb._dispatch(
+            out = planops.dispatch(
                 {"op": "rlike", "column": 0, "pattern": ".*"},
                 Table([col], ["s"]),
             )

@@ -14,6 +14,7 @@ import pytest
 
 from spark_rapids_jni_tpu import dtype as dt
 from spark_rapids_jni_tpu import plancheck as pc
+from spark_rapids_jni_tpu import planops
 from spark_rapids_jni_tpu import runtime_bridge as rb
 from spark_rapids_jni_tpu.column import Column, Table
 from spark_rapids_jni_tpu.kernels import registry
@@ -64,10 +65,10 @@ def _ab(op, table, rest=()):
     and return the ON-side wire tuple + the kernel counters."""
     config.set_flag("METRICS", "1")
     config.set_flag("KERNELS", "off")
-    off = _wire(rb._dispatch(op, table, rest))
+    off = _wire(planops.dispatch(op, table, rest))
     metrics.reset()
     config.set_flag("KERNELS", "on")
-    on = _wire(rb._dispatch(op, table, rest))
+    on = _wire(planops.dispatch(op, table, rest))
     ctr = dict(metrics.snapshot().get("counters", {}))
     assert on == off, f"kernel tier changed bytes for {op}"
     return on, ctr
@@ -111,7 +112,7 @@ class TestPredicates:
         st = Table([Column.from_strings(["a", "b"])])
         assert "no fixed-width" in registry._a_row_pack(
             {"op": "to_rows"}, st, ())
-        packed = rb._dispatch({"op": "to_rows"}, t, ())
+        packed = planops.dispatch({"op": "to_rows"}, t, ())
         unp = {"op": "from_rows",
                "type_ids": [int(dt.TypeId.INT64)] * 2, "scales": [0, 0]}
         assert registry._a_row_unpack(unp, packed, ()) is None
@@ -185,7 +186,7 @@ class TestParity:
         _, ctr = _ab({"op": "to_rows"}, t)
         assert _launched(ctr) == 1
         config.set_flag("KERNELS", "off")
-        packed = rb._dispatch({"op": "to_rows"}, t, ())
+        packed = planops.dispatch({"op": "to_rows"}, t, ())
         op = {"op": "from_rows",
               "type_ids": [int(dt.TypeId.INT64)] * 2, "scales": [0, 0]}
         _, ctr = _ab(op, packed)
@@ -204,7 +205,7 @@ class TestParity:
                 _, ctr = _ab({"op": "to_rows"}, t)
                 assert _launched(ctr) == 1
                 config.set_flag("KERNELS", "off")
-                packed = rb._dispatch({"op": "to_rows"}, t, ())
+                packed = planops.dispatch({"op": "to_rows"}, t, ())
                 _, ctr = _ab(unp, packed)
                 assert _launched(ctr) == 1
         finally:
@@ -216,7 +217,7 @@ class TestParity:
         config.set_flag("METRICS", "1")
         config.set_flag("KERNELS", "on")
         metrics.reset()
-        rb._dispatch({"op": "filter", "mask": 1}, Table(
+        planops.dispatch({"op": "filter", "mask": 1}, Table(
             [t.columns[0],
              Column.from_numpy(np.ones(64, dtype=np.bool_))]), ())
         ctr = metrics.snapshot().get("counters", {})
@@ -233,13 +234,13 @@ class TestFallback:
         t = _table(1024, seed=2)
         op = {"op": "to_rows"}
         config.set_flag("KERNELS", "off")
-        want = _wire(rb._dispatch(op, t, ()))
+        want = _wire(planops.dispatch(op, t, ()))
         config.set_flag("METRICS", "1")
         config.set_flag("KERNELS", "on")
         config.set_flag("FAULTS", "seed=3,kernel:permanent:1:1")
         live_before = len(rb._RESIDENT)
         metrics.reset()
-        got = _wire(rb._dispatch(op, t, ()))
+        got = _wire(planops.dispatch(op, t, ()))
         ctr = metrics.snapshot().get("counters", {})
         assert got == want
         assert int(ctr.get("kernel.fallbacks", 0)) == 1
@@ -247,7 +248,7 @@ class TestFallback:
         # no leaked resident tables from the failed launch
         assert len(rb._RESIDENT) == live_before
         # the one-shot rule is spent: the next dispatch launches
-        got2 = _wire(rb._dispatch(op, t, ()))
+        got2 = _wire(planops.dispatch(op, t, ()))
         assert got2 == want
         assert int(metrics.snapshot()["counters"].get(
             "kernel.launches", 0)) == 1

@@ -20,7 +20,7 @@ import numpy as np
 import pytest
 
 from spark_rapids_jni_tpu import dtype as dt
-from spark_rapids_jni_tpu import pipeline
+from spark_rapids_jni_tpu import pipeline, planops
 from spark_rapids_jni_tpu import runtime_bridge as rb
 from spark_rapids_jni_tpu.utils import config, metrics
 
@@ -210,14 +210,14 @@ class TestWorkerFailureReplay:
         # resolving thread then succeeds — pipelining healed a flake
         # without changing results
         b, want = _sync_want(1024)
-        real = rb._dispatch
+        real = planops.dispatch
 
         def flaky(op, table, rest=()):
             if threading.current_thread().name.startswith("srt-pipeline"):
                 raise RuntimeError("injected worker failure")
             return real(op, table, rest)
 
-        monkeypatch.setattr(rb, "_dispatch", flaky)
+        monkeypatch.setattr(planops, "dispatch", flaky)
         config.set_flag("METRICS", True)
         config.set_flag("PIPELINE", "2")
         metrics.reset()
@@ -345,7 +345,7 @@ class TestDonationSafety:
         for t in (w1, w2):
             rb.table_free(t)
 
-        real = rb._dispatch
+        real = planops.dispatch
 
         def slow(op, table, rest=()):
             if (
@@ -355,7 +355,7 @@ class TestDonationSafety:
                 _time.sleep(0.3)
             return real(op, table, rest)
 
-        monkeypatch.setattr(rb, "_dispatch", slow)
+        monkeypatch.setattr(planops, "dispatch", slow)
         config.set_flag("PIPELINE", "2")
         A = rb.table_upload_wire(*b)
         r1 = rb.table_op_resident(json.dumps(sort_op), [A])

@@ -13,6 +13,7 @@ executables, double-sourced from the cache counters and from
 test_buckets_recompile.py discipline).
 """
 
+import dataclasses
 import json
 import logging
 
@@ -22,6 +23,7 @@ import pytest
 
 from spark_rapids_jni_tpu import dtype as dt
 from spark_rapids_jni_tpu import plan as plan_mod
+from spark_rapids_jni_tpu import planops
 from spark_rapids_jni_tpu import runtime_bridge as rb
 from spark_rapids_jni_tpu.utils import buckets, config, metrics
 
@@ -84,7 +86,7 @@ class TestSegmentation:
             "op": "groupby", "by": [0],
             "aggs": [{"column": 1, "agg": "collect_list"}],
         }
-        assert not plan_mod.op_fusable(collect)
+        assert not planops.op_fusable(collect)
         assert plan_mod.segment_plan([CAST, SORT, collect]) == [
             ("fused", [CAST, SORT]),
             ("exact", [collect]),
@@ -92,8 +94,8 @@ class TestSegmentation:
 
     def test_negative_slice_not_fusable(self):
         # negative bounds must raise from the exact path
-        assert not plan_mod.op_fusable({"op": "slice", "start": -1})
-        assert plan_mod.op_fusable({"op": "slice", "start": 1, "stop": 9})
+        assert not planops.op_fusable({"op": "slice", "start": -1})
+        assert planops.op_fusable({"op": "slice", "start": 1, "stop": 9})
 
 
 # ---------------------------------------------------------------------------
@@ -323,8 +325,10 @@ class TestFusedParity:
         cols = _cols(n)
         ops = CHAINS["filter_cast_sort_groupby"]
         want = _run_per_op_wire(ops, cols, n)
-        monkeypatch.setattr(plan_mod, "_FUSED",
-                            dict(plan_mod._FUSED, cast=boom))
+        monkeypatch.setitem(
+            planops.OPS, "cast",
+            dataclasses.replace(planops.OPS["cast"], traced=boom),
+        )
         # a warm cache would launch the previously compiled segment
         # without ever reaching the patched builder
         buckets.cache_clear()

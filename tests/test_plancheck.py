@@ -7,10 +7,9 @@ schema/dtypes, a support tier with a human-readable reason, predicted
 fusion segmentation, and a static HBM footprint bound. Three invariants
 pin it to the runtime so the two can never drift:
 
-* registry parity — ``plancheck._RULES`` keys == the dispatch plane's
-  ``runtime_bridge.DISPATCH_OPS`` (also enforced statically by srt-check
-  SRT008), and the tier tables mirror ``bucketed._RUNNERS`` /
-  ``plan.op_fusable``;
+* one registry — rules, tiers and the runtime read ``planops.OPS``
+  (``tests/test_planops.py`` holds the tier to the path the runtime
+  takes, op by op);
 * segmentation parity — ``predict_segments`` agrees exactly with
   ``plan.segment_plan`` over a fuzzed corpus, bucket edges included;
 * inference parity — an analyzer-clean plan EXECUTES, and its executed
@@ -30,10 +29,10 @@ import json
 import numpy as np
 import pytest
 
-from spark_rapids_jni_tpu import bucketed
 from spark_rapids_jni_tpu import dtype as dt
 from spark_rapids_jni_tpu import plan as plan_mod
 from spark_rapids_jni_tpu import plancheck as pc
+from spark_rapids_jni_tpu import planops
 from spark_rapids_jni_tpu import runtime_bridge as rb
 from spark_rapids_jni_tpu.utils import config, metrics
 
@@ -373,7 +372,7 @@ class TestInferenceRules:
 
 
 # ---------------------------------------------------------------------------
-# registry + tier parity with the runtime (the SRT008 pair, dynamically)
+# the tiers of the one op table
 # ---------------------------------------------------------------------------
 
 
@@ -408,19 +407,8 @@ OPS_CORPUS = [
 
 
 class TestRegistryParity:
-    def test_rule_table_matches_dispatch_ops(self):
-        assert set(pc._RULES) == rb.DISPATCH_OPS
-
-    def test_bucketed_tier_tables_match_runtime(self):
-        assert pc._BUCKETED_OPS == frozenset(bucketed._RUNNERS)
-        assert pc._BUCKETED_JOIN_HOWS == bucketed._BUCKETED_JOIN_HOWS
-
-    def test_op_fusable_mirror_matches_plan(self):
-        for op in OPS_CORPUS:
-            assert pc._op_fusable(op) == plan_mod.op_fusable(op), op
-
     def test_every_dispatch_op_gets_a_tier_and_reason(self):
-        for name in sorted(rb.DISPATCH_OPS):
+        for name in sorted(planops.OPS):
             tier, reason = pc._tier({"op": name})
             assert tier in ("fusable", "per-op", "exact-only"), name
             assert reason

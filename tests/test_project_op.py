@@ -24,7 +24,7 @@ from perfbench import compare, datagen, reference
 from perfbench.plugins import refop_project
 from perfbench.wirefmt import NP_DTYPES, TYPE_IDS, Col, unwire, wire
 from spark_rapids_jni_tpu import dtype as dt
-from spark_rapids_jni_tpu import parallel, plancheck, serving
+from spark_rapids_jni_tpu import parallel, plancheck, planops, serving
 from spark_rapids_jni_tpu import plan as plan_mod
 from spark_rapids_jni_tpu import runtime_bridge as rb
 from spark_rapids_jni_tpu.ops import binaryop
@@ -296,7 +296,7 @@ def test_refused_statically_and_at_dispatch(case):
     assert static.value.index == 0 and static.value.op_name == "project"
     device = rb._table_from_wire(*wire(table), None)
     with pytest.raises(project_mod.ExprError) as runtime:
-        rb._dispatch_impl(op, device, [], "project")
+        planops.OPS["project"].exact(op, device, [])
     assert str(runtime.value) == static.value.reason
 
 
@@ -334,15 +334,14 @@ def test_structure_is_checked_without_a_schema():
 
 
 def test_project_lives_in_every_registry():
-    from spark_rapids_jni_tpu import bucketed
-
-    assert "project" in rb.DISPATCH_OPS and "project" in plancheck._RULES
-    assert "project" in bucketed._RUNNERS
-    assert "project" in plan_mod._SIMPLE_FUSABLE
-    assert "project" in plan_mod._ROW_LOCAL and "project" in plan_mod._FUSED
+    """There is one registry: the entry and what it says of the op."""
+    spec = planops.OPS["project"]
+    assert spec.fusable is True and spec.bucketable is True
+    assert spec.row_local and not spec.exchange and not spec.counts
+    assert spec.program == "srt_bucketed_project" and spec.runner is None
     op = {"op": "project", "exprs": [col(0)]}
-    assert plan_mod.op_fusable(op) and plancheck._op_fusable(op)
-    assert bucketed.is_bucketable(op)
+    assert planops.op_fusable(op) and planops.op_bucketable(op)
+    assert plancheck.analyze([op, op])["segments"][0]["kind"] == "fused"
 
 
 def q1_plan():

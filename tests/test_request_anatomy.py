@@ -538,16 +538,46 @@ def test_cached_jit_traces_under_its_scope(scope):
     assert fn.__name__ == "srt_anatomy_scope"
 
 
-@pytest.mark.parametrize("op", ["filter", "groupby", "sort_by", "join"])
-def test_bucketed_runner_ops_carry_their_scope(op):
+_SCOPED_OPS = {
+    "filter": {"op": "filter", "mask": 1},
+    "groupby": {"op": "groupby", "by": [0],
+                "aggs": [{"column": 0, "agg": "count"}]},
+    "sort_by": {"op": "sort_by", "keys": [{"column": 0}]},
+    "join": {"op": "join", "on": [0]},
+}
+
+
+@pytest.mark.parametrize("op", sorted(_SCOPED_OPS))
+def test_bucketed_runner_ops_carry_their_scope(op, monkeypatch):
     """Each plan op the resident cell runs through a bucketed runner
-    asks ``cached_jit`` for its own ``srt.<op>`` scope."""
-    import inspect
+    asks ``cached_jit`` for its own ``srt.<op>`` scope, for every
+    program it launches."""
+    import jax.numpy as jnp
 
-    from spark_rapids_jni_tpu import bucketed
+    from spark_rapids_jni_tpu import planops
+    from spark_rapids_jni_tpu.column import Column, Table
+    from spark_rapids_jni_tpu.utils import buckets
 
-    runner = getattr(bucketed, {"sort_by": "_r_sort"}.get(op, "_r_" + op))
-    assert f'scope="srt.{op}"' in inspect.getsource(runner)
+    scopes = []
+    real = buckets.cached_jit
+
+    def spy(key, build, name, donate_args=(), scope=None):
+        scopes.append((name, scope))
+        return real(key, build, name, donate_args=donate_args, scope=scope)
+
+    monkeypatch.setattr(buckets, "cached_jit", spy)
+    keys = Column(jnp.arange(40, dtype=jnp.int64) % 7, dt.INT64)
+    mask = Column(jnp.arange(40) % 2 == 0, dt.BOOL8)
+    rest = [Table([Column(jnp.arange(7, dtype=jnp.int64), dt.INT64)])]
+    config.set_flag("METRICS", True)
+    try:
+        metrics.reset()
+        planops.dispatch(_SCOPED_OPS[op], Table([keys, mask]),
+                         rest if op == "join" else ())
+        assert metrics.snapshot()["counters"]["bucket.dispatched"] == 1
+    finally:
+        config.clear_flag("METRICS")
+    assert scopes and {s for _, s in scopes} == {"srt." + op}
 
 
 def test_mesh_recv_in_the_session_doc(anatomy):

@@ -591,132 +591,6 @@ class TestHostSync:
         assert got == []
 
 
-class TestDispatchParity:
-    PLANCHECK_OK = """
-        _RULES = {
-            "cast": None,
-            "filter": None,
-        }
-    """
-    DISPATCH_OK = """
-        DISPATCH_OPS = frozenset({"cast", "filter"})
-
-        def _dispatch_impl(name, op):
-            if name == "cast":
-                return 1
-            if name == "filter":
-                return 2
-            raise ValueError(f"unknown table op {name!r}")
-    """
-
-    def _plancheck(self, tmp_path, src=None):
-        full = tmp_path / PKG / "plancheck.py"
-        full.parent.mkdir(parents=True, exist_ok=True)
-        full.write_text(textwrap.dedent(src or self.PLANCHECK_OK))
-
-    def test_three_way_parity_clean(self, tmp_path):
-        self._plancheck(tmp_path)
-        got = scan(tmp_path, f"{PKG}/runtime_bridge.py", self.DISPATCH_OK)
-        assert got == []
-
-    def test_arm_missing_from_dispatch_ops(self, tmp_path):
-        self._plancheck(tmp_path)
-        got = scan(tmp_path, f"{PKG}/runtime_bridge.py", """
-            DISPATCH_OPS = frozenset({"cast", "filter"})
-
-            def _dispatch_impl(name, op):
-                if name == "cast":
-                    return 1
-                if name == "filter":
-                    return 2
-                if name == "explode":
-                    return 3
-                raise ValueError(f"unknown table op {name!r}")
-        """)
-        msgs = [f.message for f in got]
-        assert passes_of(got) == ["SRT008"]
-        assert "dispatch arm 'explode' missing from DISPATCH_OPS" in msgs[0]
-
-    def test_stale_dispatch_ops_entry(self, tmp_path):
-        self._plancheck(tmp_path, """
-            _RULES = {"cast": None, "filter": None, "repeat": None}
-        """)
-        got = scan(tmp_path, f"{PKG}/runtime_bridge.py", """
-            DISPATCH_OPS = frozenset({"cast", "filter", "repeat"})
-
-            def _dispatch_impl(name, op):
-                if name == "cast":
-                    return 1
-                if name == "filter":
-                    return 2
-                raise ValueError(f"unknown table op {name!r}")
-        """)
-        assert passes_of(got) == ["SRT008"]
-        assert "stale" in got[0].message
-
-    def test_dispatch_op_without_plancheck_rule(self, tmp_path):
-        self._plancheck(tmp_path, """
-            _RULES = {"cast": None}
-        """)
-        got = scan(tmp_path, f"{PKG}/runtime_bridge.py", self.DISPATCH_OK)
-        assert passes_of(got) == ["SRT008"]
-        assert "no plancheck inference rule" in got[0].message
-
-    def test_plancheck_rule_without_dispatch_arm(self, tmp_path):
-        self._plancheck(tmp_path, """
-            _RULES = {"cast": None, "filter": None, "ghost": None}
-        """)
-        got = scan(tmp_path, f"{PKG}/runtime_bridge.py", self.DISPATCH_OK)
-        assert passes_of(got) == ["SRT008"]
-        assert "plancheck rule 'ghost' has no dispatch arm" \
-            in got[0].message
-
-    def test_missing_plancheck_module(self, tmp_path):
-        got = scan(tmp_path, f"{PKG}/runtime_bridge.py", self.DISPATCH_OK)
-        assert passes_of(got) == ["SRT008"]
-        assert "no sibling plancheck.py" in got[0].message
-
-    def test_non_literal_dispatch_ops(self, tmp_path):
-        self._plancheck(tmp_path)
-        got = scan(tmp_path, f"{PKG}/runtime_bridge.py", """
-            _OPS = ["cast"]
-            DISPATCH_OPS = frozenset(_OPS)
-
-            def _dispatch_impl(name, op):
-                if name == "cast":
-                    return 1
-                raise ValueError(f"unknown table op {name!r}")
-        """)
-        assert passes_of(got) == ["SRT008"]
-        assert "pure string-literal" in got[0].message
-
-    def test_pragma_suppresses(self, tmp_path):
-        got = scan(tmp_path, f"{PKG}/runtime_bridge.py", """
-            # srt: allow-dispatch-parity(migration window: rules land next)
-            DISPATCH_OPS = frozenset({"cast"})
-
-            def _dispatch_impl(name, op):
-                if name == "cast":
-                    return 1
-                raise ValueError(f"unknown table op {name!r}")
-        """)
-        assert got == []
-
-    def test_non_dispatch_modules_exempt(self, tmp_path):
-        # a module with only one of the two anchors is not the dispatch
-        # plane; the pass stays quiet
-        got = scan(tmp_path, f"{PKG}/other.py", """
-            DISPATCH_OPS = frozenset({"cast"})
-        """)
-        assert got == []
-
-    def test_real_repo_three_way_parity_holds(self):
-        findings = srt.scan_file(
-            os.path.join(REPO_ROOT, PKG, "runtime_bridge.py"), REPO_ROOT
-        )
-        assert [f for f in findings if f.pass_id == "SRT008"] == []
-
-
 class TestPruneBaseline:
     def test_prune_drops_only_stale_entries(self, tmp_path, capsys):
         full = tmp_path / PKG / "foo.py"

@@ -40,15 +40,6 @@ Passes (each emits ``file:line:col`` findings):
   manual) in ``_ARM_TIERS``: un-tiered arms are how bench rounds
   r04/r05 silently blew the ``SRT_BENCH_BUDGET_S`` wall budget
   (rc=124, headline parsed=null).
-* **SRT008 dispatch-parity** — the op registries of the dispatch
-  plane (``runtime_bridge.DISPATCH_OPS``, the ``name == "..."`` arms
-  of ``_dispatch_impl``, and ``plancheck._RULES``) must hold exactly
-  the same op keys: an op added to the dispatcher without a plancheck
-  inference rule would make the plan-time analyzer reject (or
-  mis-infer) a runnable plan — the GpuOverrides-tag/exec drift bug
-  class, caught statically. The exchange plane rides the same pass:
-  every ``plan._EXCHANGE_OPS`` entry (the ops planmesh splits mesh
-  plans at) must appear in all three registries above.
 * **SRT009 host-sync** — implicit device->host synchronizations in the
   hot dispatch modules (``plan.py``, ``bucketed.py``): ``bool()``/
   ``int()``/``float()`` over device values (``.data``/``.validity``/
@@ -74,8 +65,8 @@ Passes (each emits ``file:line:col`` findings):
   mint, which is what keeps ids W3C-shaped and the ambient context the
   single source of truth. Justified sites carry
   ``# srt: allow-trace-context(<reason>)``.
-* **SRT012 kernel-parity** — the kernel-tier registries (the SRT008
-  discipline applied to ``kernels/registry.py``): the ``KERNEL_NAMES``
+* **SRT012 kernel-parity** — the kernel-tier registries
+  (``kernels/registry.py``): the ``KERNEL_NAMES``
   literal, the ``_REGISTRY`` dict keys, and plancheck's
   ``_KERNEL_RULES`` table must hold exactly the same kernel names, the
   ``kernel`` metric namespace must be registered here, and every
@@ -174,7 +165,7 @@ HOST_CALLS = frozenset({
     "abs", "get", "isinstance", "getattr", "hasattr", "repr", "format",
     "join", "split", "append", "pop", "keys", "values", "items",
     "perf_counter", "monotonic", "bucket_for", "enabled", "get_flag",
-    "generation", "segment_plan", "op_fusable", "is_bucketable",
+    "generation", "segment_plan", "op_fusable", "op_bucketable",
     "table_bytes", "dumps", "loads",
 })
 
@@ -236,7 +227,6 @@ PASS_PRAGMAS = {
     "SRT005": "retry-donated",
     "SRT006": "metric-name",
     "SRT007": "untiered-arm",
-    "SRT008": "dispatch-parity",
     "SRT009": "host-sync",
     "SRT010": "stats-append",
     "SRT011": "trace-context",
@@ -906,7 +896,7 @@ def check_bench_tiers(relpath: str, tree: ast.Module,
 
 
 # ---------------------------------------------------------------------------
-# SRT008: dispatch-plane / plancheck registry parity
+# SRT012: kernel-tier registry parity
 # ---------------------------------------------------------------------------
 
 
@@ -928,177 +918,12 @@ def _str_set_literal(node: ast.AST) -> Optional[set]:
     return None
 
 
-def check_dispatch_parity(relpath: str, tree: ast.Module,
-                          pragmas: _Pragmas,
-                          src_dir: str) -> List[Finding]:
-    """Runs when the scanned module IS the dispatch plane (it defines
-    both ``DISPATCH_OPS`` and ``_dispatch_impl``): the three op
-    registries — the DISPATCH_OPS literal, the ``name == "..."`` arms
-    inside _dispatch_impl, and the sibling ``plancheck.py``'s _RULES
-    table — must hold exactly the same keys. Adding an op to one
-    without the others fails CI here, before the analyzer can reject
-    (or mis-tag) a runnable plan."""
-    ops_assign: Optional[ast.Assign] = None
-    declared: Optional[set] = None
-    impl: Optional[ast.FunctionDef] = None
-    for node in tree.body:
-        if isinstance(node, ast.Assign) and len(node.targets) == 1 \
-                and isinstance(node.targets[0], ast.Name) \
-                and node.targets[0].id == "DISPATCH_OPS":
-            ops_assign = node
-            declared = _str_set_literal(node.value)
-        elif isinstance(node, ast.FunctionDef) \
-                and node.name == "_dispatch_impl":
-            impl = node
-    if ops_assign is None or impl is None:
-        return []  # not the dispatch-plane module
-    findings: List[Finding] = []
-
-    def emit(node, msg):
-        line = getattr(node, "lineno", 1)
-        if not pragmas.suppresses("SRT008", line):
-            findings.append(Finding(
-                "SRT008", relpath, line,
-                getattr(node, "col_offset", 0), msg,
-            ))
-
-    if declared is None:
-        emit(
-            ops_assign,
-            "DISPATCH_OPS must be a pure string-literal frozenset — "
-            "the registry-parity pass reads it statically",
-        )
-        return findings
-
-    # the dispatch arms: `if name == "<op>":` comparisons in the chain
-    arms: set = set()
-    for sub in ast.walk(impl):
-        if (
-            isinstance(sub, ast.Compare)
-            and isinstance(sub.left, ast.Name)
-            and sub.left.id == "name"
-            and len(sub.ops) == 1
-            and isinstance(sub.ops[0], ast.Eq)
-            and isinstance(sub.comparators[0], ast.Constant)
-            and isinstance(sub.comparators[0].value, str)
-        ):
-            arms.add(sub.comparators[0].value)
-
-    for op in sorted(arms - declared):
-        emit(ops_assign,
-             f"dispatch arm {op!r} missing from DISPATCH_OPS")
-    for op in sorted(declared - arms):
-        emit(ops_assign,
-             f"DISPATCH_OPS entry {op!r} has no `name == ...` arm in "
-             "_dispatch_impl — stale entry?")
-
-    # the analyzer side: plancheck._RULES in the sibling module
-    pc_path = os.path.join(src_dir, "plancheck.py")
-    if not os.path.exists(pc_path):
-        emit(
-            ops_assign,
-            "no sibling plancheck.py next to the dispatch plane — "
-            "every dispatch op needs a plan-time inference rule",
-        )
-        return findings
-    try:
-        with open(pc_path, "r", encoding="utf-8") as f:
-            pc_tree = ast.parse(f.read(), filename=pc_path)
-    except SyntaxError:
-        return findings  # plancheck.py's own scan reports the error
-    rules: Optional[set] = None
-    rules_line = 1
-    for node in pc_tree.body:
-        if isinstance(node, ast.Assign) and len(node.targets) == 1 \
-                and isinstance(node.targets[0], ast.Name) \
-                and node.targets[0].id == "_RULES" \
-                and isinstance(node.value, ast.Dict):
-            rules_line = node.lineno
-            rules = set()
-            for k in node.value.keys:
-                if isinstance(k, ast.Constant) and isinstance(
-                    k.value, str
-                ):
-                    rules.add(k.value)
-    if rules is None:
-        emit(
-            ops_assign,
-            "plancheck.py has no literal _RULES table — the parity "
-            "pass (and the analyzer) need one rule per dispatch op",
-        )
-        return findings
-    for op in sorted(declared - rules):
-        emit(
-            ops_assign,
-            f"dispatch op {op!r} has no plancheck inference rule "
-            f"(plancheck.py _RULES, line {rules_line}) — teach the "
-            "analyzer before (or with) the dispatcher",
-        )
-    for op in sorted(rules - declared):
-        emit(
-            ops_assign,
-            f"plancheck rule {op!r} has no dispatch arm — the analyzer "
-            "would tag an op the runtime cannot execute",
-        )
-
-    # the exchange plane (4th registry): plan.py's _EXCHANGE_OPS names
-    # the ops planmesh treats as mesh segment boundaries; each must be
-    # a full dispatch citizen (DISPATCH_OPS + arm + plancheck rule), or
-    # the mesh path would split plans at an op the exact path cannot
-    # run and the analyzer cannot tag
-    plan_path = os.path.join(src_dir, "plan.py")
-    if os.path.exists(plan_path):
-        try:
-            with open(plan_path, "r", encoding="utf-8") as f:
-                plan_tree = ast.parse(f.read(), filename=plan_path)
-        except SyntaxError:
-            return findings  # plan.py's own scan reports the error
-        exchange: Optional[set] = None
-        exch_line = 1
-        for node in plan_tree.body:
-            if isinstance(node, ast.Assign) and len(node.targets) == 1 \
-                    and isinstance(node.targets[0], ast.Name) \
-                    and node.targets[0].id == "_EXCHANGE_OPS":
-                exch_line = node.lineno
-                exchange = _str_set_literal(node.value)
-        if exchange is None:
-            emit(
-                ops_assign,
-                "plan.py has no literal _EXCHANGE_OPS frozenset — the "
-                "exchange-plane side of the registry-parity pass reads "
-                "it statically",
-            )
-            return findings
-        for op in sorted(exchange - declared):
-            emit(
-                ops_assign,
-                f"exchange op {op!r} (plan.py _EXCHANGE_OPS, line "
-                f"{exch_line}) is not in DISPATCH_OPS — the mesh path "
-                "would split plans at an op the exact path cannot run",
-            )
-        for op in sorted(exchange - arms):
-            emit(
-                ops_assign,
-                f"exchange op {op!r} (plan.py _EXCHANGE_OPS, line "
-                f"{exch_line}) has no `name == ...` arm in "
-                "_dispatch_impl — no exact fallback for the boundary",
-            )
-        for op in sorted(exchange - rules):
-            emit(
-                ops_assign,
-                f"exchange op {op!r} (plan.py _EXCHANGE_OPS, line "
-                f"{exch_line}) has no plancheck inference rule "
-                f"(plancheck.py _RULES, line {rules_line})",
-            )
-    return findings
-
-
 def check_kernel_parity(relpath: str, tree: ast.Module,
                         pragmas: _Pragmas,
                         src_dir: str) -> List[Finding]:
     """Runs when the scanned module IS the kernel registry (it defines
     both ``KERNEL_NAMES`` and ``_REGISTRY``): the kernel-tier parity
-    pass, mirroring SRT008 for the kernel plane. The KERNEL_NAMES
+    pass. The KERNEL_NAMES
     literal, the _REGISTRY dict keys, and the sibling plancheck.py's
     _KERNEL_RULES table must hold exactly the same names; every
     _REGISTRY entry must be a ``KernelSpec(...)`` whose name argument
@@ -1355,10 +1180,6 @@ def scan_file(path: str, repo_root: str = REPO_ROOT) -> List[Finding]:
     findings = checker.findings
     findings.extend(check_bench_tiers(relpath, tree, pragmas))
     findings.extend(check_stats_append(relpath, tree, pragmas))
-    findings.extend(check_dispatch_parity(
-        relpath, tree, pragmas,
-        os.path.dirname(os.path.abspath(path)),
-    ))
     findings.extend(check_kernel_parity(
         relpath, tree, pragmas,
         os.path.dirname(os.path.abspath(path)),
