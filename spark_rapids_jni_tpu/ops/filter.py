@@ -22,7 +22,7 @@ from . import compute
 from .gather import gather_table
 
 
-def _selection_mask(mask: Column) -> jax.Array:
+def selection_mask(mask: Column) -> jax.Array:
     """Spark WHERE keeps rows where the predicate is TRUE (not null)."""
     if not mask.dtype.is_boolean:
         raise TypeError("filter mask must be BOOL8")
@@ -53,14 +53,14 @@ def filter_table_capped(
     Rows past the count are clones of kept rows (garbage but in-bounds);
     consumers must respect the count.
     """
-    keep = _selection_mask(mask)
+    keep = selection_mask(mask)
     idx, count = _compaction_indices(keep, capacity)
     return gather_table(table, idx), count
 
 
 def filter_table(table: Table, mask: Column) -> Table:
     """Eager filter with exact output size (one host sync for the count)."""
-    keep = _selection_mask(mask)
+    keep = selection_mask(mask)
     count = int(jnp.sum(keep))
     if count == table.row_count:
         return table

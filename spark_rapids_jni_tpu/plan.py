@@ -17,7 +17,10 @@ Fusable ops (single-table, bucketable, ``row_valid``-maskable):
 run but not continue it: the segment's executable
 ends with the groupby's sort half, its per-group half is a second
 launch at the bucket of the group count (``bucketed._reduce_groups``),
-and the following ops re-enter the compiler on that result.
+and the following ops re-enter the compiler on that result. A filter
+from which only row-local ops lead to that groupby moves no row: its
+selection joins the occupancy mask the groupby's sort already carries
+(``_run_segment_traced``).
 Everything else (join, concat, explode, to_rows/from_rows, ...) is a
 segment boundary dispatched through the one-op ``planops.dispatch``
 path — bucketed runner or exact fallback — with ``Table.logical_rows`` carried through unchanged so padding
@@ -93,16 +96,31 @@ def segment_plan(ops: Sequence[dict]) -> List[Tuple[str, list]]:
 
 
 def _run_segment_traced(seg_ops: Sequence[dict], t: Table, n):
-    """The traced body of one fused segment: thread (table, count)
+    """The traced body of one fused segment: thread (table, occupancy)
     through every op at the segment's one physical shape. A groupby
-    tail leaves its sorted state in the table's place."""
-    for op in seg_ops:
+    tail leaves its sorted state in the table's place.
+
+    Occupancy flows as the count ``n`` (a prefix, turned into a mask
+    for each op) until a selecting op at or behind
+    ``planops.deferred_from``: that one keeps its rows where they are
+    and ANDs its selection into the mask, which then flows on as it is
+    — the row-local ops behind it never read the count, and the groupby
+    tail's sort puts the rows of any mask last. Everywhere else a
+    selecting op compacts, because what follows (an op that reads the
+    count, the segment's caller) needs the prefix."""
+    masked_from = planops.deferred_from(seg_ops)
+    mask = None
+    for i, op in enumerate(seg_ops):
+        spec = planops.OPS[op["op"]]
         # trace-time only: every HLO op of this plan op carries its
         # name in its op_name metadata, so a device trace can give a
         # fusion to filter, join, groupby or sort_by
         with jax.named_scope("srt." + op["op"]):
-            rv = buckets.tail_valid(t.row_count, n)
-            t, n = planops.OPS[op["op"]].traced(op, t, n, rv)
+            rv = buckets.tail_valid(t.row_count, n) if mask is None else mask
+            if spec.select is not None and i >= masked_from:
+                t, mask = spec.select(op, t, rv)
+                continue
+            t, n = spec.traced(op, t, n, rv)
             if hasattr(n, "astype"):
                 n = n.astype(jnp.int32)
     return t, n

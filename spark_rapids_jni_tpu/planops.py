@@ -12,6 +12,11 @@
   (``parallel/planmesh``) and the generic one-op runner
   (``bucketed.run_one_op``) all run it. ``t`` is the padded table,
   ``n`` the device logical count, ``rv`` the occupancy mask;
+* ``select(op, t, rv) -> (t, rv)`` — a selecting op's body up to its
+  choice of rows: the table it hands on, rows where they are, and the
+  occupancy ANDed with its selection. Its ``traced`` compacts by that
+  (:func:`_compacting`); a fused segment whose occupancy may stay a
+  mask (:func:`deferred_from`) hands it to the next op as it is;
 * ``fusable`` / ``bucketable`` — may the op ride a fused segment, has
   it a one-op bucketed program (a constant, or a predicate over the op
   for the cases its JSON decides);
@@ -47,7 +52,7 @@ from .ops import partition as partition_mod
 from .ops import regex as regex_mod
 from .ops import strings as strings_mod
 from .ops.compaction import distinct_capped
-from .ops.filter import filter_table_capped
+from .ops.filter import filter_table_capped, selection_mask
 from .ops.groupby import _COLLECT_OPS, GroupbyAgg, groupby_sort
 from .ops.project import project_table
 from .utils import buckets, faults, lockcheck, metrics
@@ -58,6 +63,7 @@ class OpSpec:
     infer: Callable
     exact: Callable
     traced: Optional[Callable] = None
+    select: Optional[Callable] = None
     fusable: Union[bool, Callable[[dict], bool]] = False
     bucketable: Union[bool, Callable[[dict], bool]] = False
     row_local: bool = False
@@ -245,9 +251,10 @@ def _x_from_rows(op, table, rest):
 # ---------------------------------------------------------------------------
 # traced: (op, padded table, device logical count, row_valid occupancy)
 # -> (table at the same physical shape, new device count), INSIDE a
-# traced program. The occupancy mask is recomputed per step from the
-# flowing count, so a filter's clone-padded tail is dead for everything
-# downstream.
+# traced program. Where the count flows, the occupancy mask is
+# recomputed per step from it, so a filter's clone-padded tail is dead
+# for everything downstream; where a fused segment lets the occupancy
+# flow as a mask (deferred_from), rv is that mask and n is not read.
 # ---------------------------------------------------------------------------
 
 
@@ -272,19 +279,30 @@ def _t_project(op, t, n, rv):
     return project_table(t, op["exprs"]), n
 
 
-def _t_filter(op, t, n, rv):
+def _s_filter(op, t, rv):
     mi = int(op["mask"])
     kept = Table(
         [c for i, c in enumerate(t.columns) if i != mi]
     )  # names dropped exactly like the exact path
-    return filter_table_capped(
-        kept, _gated(t.columns[mi], rv), capacity=t.row_count
-    )
+    return kept, selection_mask(_gated(t.columns[mi], rv))
 
 
-def _t_rlike(op, t, n, rv):
+def _s_rlike(op, t, rv):
     mask = regex_mod.contains_re(t.columns[int(op["column"])], op["pattern"])
-    return filter_table_capped(t, _gated(mask, rv), capacity=t.row_count)
+    return t, selection_mask(_gated(mask, rv))
+
+
+def _compacting(select):
+    """The traced body of a selecting op: its selection, then the
+    stable compaction that turns the occupancy back into a prefix."""
+
+    def traced(op, t, n, rv):
+        kept, keep = select(op, t, rv)
+        return filter_table_capped(
+            kept, Column(keep, dt.BOOL8, None), capacity=t.row_count
+        )
+
+    return traced
 
 
 def _t_distinct(op, t, n, rv):
@@ -375,13 +393,14 @@ OPS: Dict[str, OpSpec] = {
         bucketable=True, row_local=True, program="srt_bucketed_project",
     ),
     "filter": OpSpec(
-        rules._r_filter, _x_filter, _t_filter, fusable=True,
-        bucketable=True, row_local=True, counts=True,
+        rules._r_filter, _x_filter, _compacting(_s_filter), _s_filter,
+        fusable=True, bucketable=True, row_local=True, counts=True,
         program="srt_bucketed_filter",
     ),
     "rlike": OpSpec(
-        rules._r_rlike, _x_rlike, _t_rlike, fusable=True, bucketable=True,
-        row_local=True, counts=True, program="srt_bucketed_rlike",
+        rules._r_rlike, _x_rlike, _compacting(_s_rlike), _s_rlike,
+        fusable=True, bucketable=True, row_local=True, counts=True,
+        program="srt_bucketed_rlike",
     ),
     "distinct": OpSpec(
         rules._r_distinct, _x_distinct, _t_distinct, fusable=True,
@@ -434,15 +453,40 @@ def op_bucketable(op) -> bool:
     return _spec_says(op, "bucketable")
 
 
+def deferred_from(seg_ops: Sequence[dict]) -> int:
+    """The index from which a traced segment's occupancy may flow as a
+    MASK instead of a prefix: a selecting op at or behind it keeps its
+    rows where they are and ANDs its selection into the occupancy
+    (``plan._run_segment_traced``). That holds when every op from there
+    to the tail is row-local (none reads the count or looks across
+    rows) and the tail is a groupby, whose sort puts ANY mask's rows
+    last (``ops.groupby._key_words``). ``len(seg_ops)`` where nothing
+    may defer: the segment's result then needs the prefix."""
+    last = len(seg_ops) - 1
+    if last < 0 or seg_ops[last]["op"] != "groupby":
+        return len(seg_ops)
+    i = last
+    while i > 0 and OPS[seg_ops[i - 1]["op"]].row_local:
+        i -= 1
+    return i
+
+
 def note_launched(seg_ops: Sequence[dict]) -> None:
-    """``project.calls`` for the ``project`` ops of a traced program
-    that has just been launched — one op alone, a fused segment, a mesh
-    stage: counted on the host at launch, not at trace (the executable
-    is cached), so the counter says how many expression lists the
-    daemon evaluated."""
+    """The counters of a traced program that has just been launched —
+    one op alone, a fused segment, a mesh stage: ``project.calls`` for
+    its ``project`` ops, and for each selecting op ``filter.deferred``
+    or ``filter.compacted``, the way its occupancy went. Counted on the
+    host at launch, not at trace (the executable is cached), so the
+    counters say what the daemon evaluated."""
     k = sum(1 for o in seg_ops if o.get("op") == "project")
     if k:
         metrics.counter_add("project.calls", k)
+    start = deferred_from(seg_ops)
+    for i, o in enumerate(seg_ops):
+        if OPS[o["op"]].select is not None:
+            metrics.counter_add(
+                "filter.deferred" if i >= start else "filter.compacted"
+            )
 
 
 # ---------------------------------------------------------------------------

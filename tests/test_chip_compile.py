@@ -344,11 +344,13 @@ def test_q1_fused_segment_compiles(one_chip, as_tpu):
     """TPC-H Q1's ``project -> filter -> project -> groupby`` as the one
     ``srt_fused_plan`` program the served path launches (perfbench's
     ``q1-resident`` plan over the ``lineitem`` schema): the comparison,
-    the 64-bit decimal products, the compaction and the sort half (one
-    folded u32 key word, the permutation, five int64 payloads and their
-    one shared mask) for the chip's compiler, at a small bucket; at the
-    cell's 2^23 the question is minutes and memory, and the chip run
-    answers it (PERF.md, PR 27)."""
+    the 64-bit decimal products and the sort half (one folded u32 key
+    word whose leading bit is occupancy AND the predicate, the
+    permutation, five int64 payloads and their one shared mask) for the
+    chip's compiler, at a small bucket; the filter keeps its rows, so
+    the chip's own HLO holds no gather (PR 30). At the cell's 2^23 the
+    question is minutes and memory, and the chip run answers it
+    (PERF.md, PR 27)."""
     import json
 
     from spark_rapids_jni_tpu import plan as plan_mod
@@ -360,10 +362,12 @@ def test_q1_fused_segment_compiles(one_chip, as_tpu):
     assert kind == "fused" and len(seg) == 4
     table = _table(one_chip, LINEITEM, 1 << 13)
     n32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    _fits(
+    compiled = (
         jax.jit(lambda t, n: plan_mod._run_segment_traced(seg, t, n))
         .lower(table, n32).compile()
     )
+    _fits(compiled)
+    assert " gather(" not in compiled.as_text()
 
 
 # ---------------------------------------------------------------------------

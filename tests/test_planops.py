@@ -86,6 +86,10 @@ def test_spec_is_whole(name):
         assert spec.runner is None and spec.program is None
     if spec.row_local:
         assert spec.traced is not None  # only a traced chain is chunked
+    if spec.select is not None:
+        # a selection may stay a mask only among row-local ops, and the
+        # compacting body built over it returns the new count
+        assert spec.row_local and spec.counts
     for flag in (spec.fusable, spec.bucketable):
         assert isinstance(flag, bool) or callable(flag)
 
@@ -95,6 +99,8 @@ def test_the_table_has_the_seventeen_ops():
     assert {n for n, s in planops.OPS.items() if s.exchange} == {"partition"}
     assert sorted(n for n, s in planops.OPS.items() if s.row_local) == [
         "cast", "filter", "project", "rlike"]
+    assert sorted(n for n, s in planops.OPS.items() if s.select) == [
+        "filter", "rlike"]
 
 
 # ---------------------------------------------------------------------------
