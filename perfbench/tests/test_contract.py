@@ -4,6 +4,7 @@ and against the data files the harness finds by name."""
 import json
 import os
 import re
+import sys
 
 import pytest
 
@@ -11,12 +12,19 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+STATISTICS = ("setup_s", "rows_per_s", "p50_s", "p95_s")  # run.py tells them by a name's ending
+STREAM = "ss-star-8m.stream-c2"
+
+with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+    BENCH = json.load(_f)
+CELLS = [w["name"] for w in BENCH["workloads"]]
+SPLIT = [m["name"] for m in BENCH["per_layer"]
+         if m["name"].split(".")[-1] in ("stream", "convert", "exchange")]
 
 
 @pytest.fixture(scope="module")
 def bench():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        return json.load(f)
+    return BENCH
 
 
 def line(s):
@@ -62,7 +70,7 @@ def test_metrics(bench):
         assert m["source"] in ("host_clock", "device_trace")
         assert 0.01 <= m["bound"] <= 0.25
     for name in e2e:  # run.py tells the statistic by the name's ending
-        assert sum(name.endswith(k) for k in ("setup_s", "rows_per_s", "p50_s", "p95_s")) == 1
+        assert sum(name.endswith(k) for k in STATISTICS) == 1
     reported = {n: set(m.get("workloads", cells)) for n, m in e2e.items()}
     for cell in cells:
         assert cell in reported["setup_s"]
@@ -93,3 +101,56 @@ def test_peaks_are_keyed_by_device_kind():
         peaks = json.load(f)
     assert peaks["TPU v5 lite"]["hbm_gbps"] == 819
     assert all("source" in v for v in peaks.values())
+
+
+# -- the split of a quantity by the end-to-end metric it moves (PR 34) --------
+
+def harness():
+    sys.path.insert(0, ROOT)
+    from perfbench import run
+
+    return run
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_reports_one_rate_one_latency_and_setup(cell):
+    """What ``run.load_cell`` hands a run of the cell: exactly one
+    rate-like metric, one latency-like metric and ``setup_s``, each name
+    ending in exactly one of the statistics ``run.main`` computes."""
+    names = [m["name"] for m in harness().load_cell(cell)[3]]
+    for name in names:
+        assert sum(name.endswith(k) for k in STATISTICS) == 1, name
+    rates = [n for n in names if n.endswith("rows_per_s")]
+    waits = [n for n in names if n.endswith(("p50_s", "p95_s"))]
+    assert len(rates) == 1 and len(waits) == 1, names
+    assert sorted(names) == sorted(rates + waits + ["setup_s"])
+
+
+def test_no_tight_bound_serves_the_two_tenant_stream(bench):
+    """stream-c2 sits on a balance point of host and device (PERF.md §2):
+    a metric bounded under 5% may not list it, or every PR is `unresolved`."""
+    cells = [w["name"] for w in bench["workloads"]]
+    for m in bench["end_to_end"]:
+        if m["name"] != "setup_s" and STREAM in m.get("workloads", cells):
+            assert m["bound"] >= 0.05, m["name"]
+    assert STREAM in cells
+
+
+@pytest.mark.parametrize("name", SPLIT)
+def test_split_metric_shares_its_reader_and_no_cell_with_its_twin(bench, name):
+    """``x.stream`` / ``x.convert`` / ``x.exchange`` is read by a reader
+    file ``run.reader_file`` finds, lists its cells itself, and where a
+    plain ``x`` stands beside it the two share no cell and no `moves`."""
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    run = harness()
+    stem = name.rsplit(".", 1)[0]
+    assert run.reader_file(name) in (name + ".json", stem + ".json")
+    mine = by_name[name]
+    assert mine.get("workloads"), "a split metric lists its cells"
+    twin = by_name.get(stem)
+    if twin is not None:
+        assert run.reader_file(stem) == run.reader_file(name)
+        assert not set(twin["workloads"]) & set(mine["workloads"])
+        assert twin["moves"] != mine["moves"]
+    for cell in mine["workloads"]:
+        assert name in [s["name"] for s in run.load_cell(cell)[4]]
