@@ -100,15 +100,17 @@ def _padded_input(t: Table) -> Table:
     """The bucketed view of an input table: pre-padded tables pass
     through (their physical size keys the cache), exact tables pad to
     their bucket; shapes with no bucket decline."""
+    b = padded_rows(t)
+    return t if t.logical_rows is not None else buckets.pad_table(t, b)
+
+
+def padded_rows(t: Table) -> int:
+    """`_padded_input`'s physical row count, before anything is padded."""
     n = t.logical_row_count
-    if n <= 0:
+    b = t.row_count if t.logical_rows is not None else buckets.bucket_for(n)
+    if n <= 0 or b is None:
         raise _Decline
-    if t.logical_rows is not None:
-        return t
-    b = buckets.bucket_for(n)
-    if b is None:
-        raise _Decline
-    return buckets.pad_table(t, b)
+    return b
 
 
 def _strip(t: Table) -> Table:
@@ -275,19 +277,12 @@ def _r_groupby(op: dict, table: Table, rest) -> Table:
 JOIN_HOWS = frozenset({"inner", "left", "semi", "anti"})
 
 
-def _probe_table_size(lt: Table, rt: Table, on: list) -> Optional[int]:
-    """The one choice of a served join's probe, from what the build side
-    shows: the direct probe's table size when the key is one
-    integer-family column whose valid build keys are dense
-    (``ops.join.direct_table_size``), None for the search. A key of
-    another kind is decided from the schema; a dense one costs one tiny
-    program at the build side's width and its read, before the launch."""
+def _build_key_facts(rt: Table, on: list) -> tuple:
+    """What a `direct_key` join's build side shows, as host integers:
+    ``(kmin, kmax, valid_rows, repeats)`` (`ops.join.build_key_span`).
+    One tiny program at the build side's width and its read, before the
+    launch it chooses."""
     from .ops import join as join_mod
-
-    if not join_mod.direct_key(
-        [lt.column(c) for c in on], [rt.column(c) for c in on]
-    ):
-        return None
 
     def build():
         def fn(r, rn):
@@ -301,10 +296,55 @@ def _probe_table_size(lt: Table, rt: Table, on: list) -> Optional[int]:
         "srt_bucketed_join_span", scope="srt.join",
     )
     span = fn(_strip(rt), _n_dev(rt))
-    # srt: allow-host-sync(bucketed-runner boundary: one read of three words from the build side chooses the probe before its launch)
-    kmin, kmax, valid_rows = (int(v) for v in np.asarray(span))
+    # srt: allow-host-sync(bucketed-runner boundary: one read of four words from the build side chooses the probe before its launch)
+    return tuple(int(v) for v in np.asarray(span))
+
+
+def _probe_table_size(lt: Table, rt: Table, on: list) -> Optional[int]:
+    """The one choice of a served join's probe, from what the build side
+    shows: the direct probe's table size when the key is one
+    integer-family column whose valid build keys are dense
+    (``ops.join.direct_table_size``), None for the search. A key of
+    another kind is decided from the schema; a dense one costs
+    `_build_key_facts`."""
+    from .ops import join as join_mod
+
+    if not join_mod.direct_key(
+        [lt.column(c) for c in on], [rt.column(c) for c in on]
+    ):
+        return None
+    kmin, kmax, valid_rows, _ = _build_key_facts(rt, on)
     return join_mod.direct_table_size(
         kmin, kmax, valid_rows, rt.row_count, lt.row_count
+    )
+
+
+def selecting_table_size(
+    op: dict, rt: Table, probe_rows: int
+) -> Optional[int]:
+    """The direct probe's table size when this join only SELECTS among
+    its probe rows, so that a fused segment can run it without moving
+    one (``ops.join.lookup_unique``): an ``inner`` join on one
+    integer-family column whose valid build keys are dense and repeat
+    no value. None for every other join, which stays `_r_join`'s. Read
+    from the (padded) build side ``rt`` alone, before the plan is
+    segmented; the probe side's key is held to `direct_key` where the
+    segment is traced."""
+    from .ops import join as join_mod
+
+    on = op.get("on")
+    if op.get("how", "inner") != "inner" or not isinstance(on, list):
+        return None
+    try:
+        if not join_mod.addressable_key([rt.column(c) for c in on]):
+            return None
+    except (IndexError, KeyError, TypeError, ValueError):
+        return None  # no such column: the per-op path says so
+    kmin, kmax, valid_rows, repeats = _build_key_facts(rt, on)
+    if repeats:
+        return None
+    return join_mod.direct_table_size(
+        kmin, kmax, valid_rows, rt.row_count, probe_rows
     )
 
 

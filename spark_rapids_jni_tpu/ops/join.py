@@ -248,23 +248,25 @@ def _probe_build(
 DIRECT_TABLE_MAX_FACTOR = 2
 
 
-def direct_key(lcols: Sequence[Column], rcols: Sequence[Column]) -> bool:
-    """True when the join key is one the direct probe can address: ONE
-    fixed-width integer-family column of at most 64 bits a side (ints,
-    DECIMAL32/64, timestamps, durations, BOOL8), whose one order word is
-    the value itself, shifted."""
+def addressable_key(cols: Sequence[Column]) -> bool:
+    """True for ONE fixed-width integer-family column of at most 64 bits
+    (ints, DECIMAL32/64, timestamps, durations, BOOL8), whose one order
+    word is the value itself, shifted."""
     from .. import dtype as dt
 
-    def addressable(d) -> bool:
-        return (
-            d.is_integer or d.is_boolean or d.is_timestamp or d.is_duration
-            or d.id in (dt.TypeId.DECIMAL32, dt.TypeId.DECIMAL64)
-        )
-
+    if len(cols) != 1:
+        return False
+    d = cols[0].dtype
     return (
-        len(lcols) == 1 and len(rcols) == 1
-        and addressable(lcols[0].dtype) and addressable(rcols[0].dtype)
+        d.is_integer or d.is_boolean or d.is_timestamp or d.is_duration
+        or d.id in (dt.TypeId.DECIMAL32, dt.TypeId.DECIMAL64)
     )
+
+
+def direct_key(lcols: Sequence[Column], rcols: Sequence[Column]) -> bool:
+    """True when the join key is one the direct probe can address: an
+    `addressable_key` on each side."""
+    return addressable_key(lcols) and addressable_key(rcols)
 
 
 def build_key_span(
@@ -272,18 +274,26 @@ def build_key_span(
     right_on: Sequence[Union[int, str]],
     right_valid: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """``u64[3]``: the smallest and the largest order word among the
-    build side's valid keys, and how many there are — what a runner
-    reads (one tiny program at the build side's width) to choose the
-    probe of a `direct_key` join."""
+    """``u64[4]``: the smallest and the largest order word among the
+    build side's valid keys, how many there are, and whether any of
+    them repeats — what a runner reads (one tiny program at the build
+    side's width) to choose the probe of a `direct_key` join, and
+    whether an inner join on it only selects (`lookup_unique`)."""
     (word,), valid = _key_words([right.column(c) for c in right_on])
     if right_valid is not None:
         valid = valid & right_valid
     top = jnp.uint64(np.iinfo(np.uint64).max)
+    count = jnp.sum(valid)
+    # invalid rows sort last as `top`; a valid key that IS `top` sorts
+    # among them, and the first `count` words still hold every valid key
+    ordered = jnp.sort(jnp.where(valid, word, top))
+    pair = jnp.arange(1, ordered.shape[0]) < count
+    repeats = jnp.any(pair & (ordered[1:] == ordered[:-1]))
     return jnp.stack([
         jnp.min(jnp.where(valid, word, top)),
         jnp.max(jnp.where(valid, word, jnp.uint64(0))),
-        jnp.sum(valid).astype(jnp.uint64),
+        count.astype(jnp.uint64),
+        repeats.astype(jnp.uint64),
     ])
 
 
@@ -308,27 +318,10 @@ def direct_table_size(
     return size
 
 
-def _probe_direct(
-    sorted_words,
-    table_size: int,
-    lcols: Sequence[Column],
-    left_valid: Optional[jax.Array] = None,
-):
-    """`_probe_build`'s ``(lo, counts, lvalid)``, bit for bit, by address
-    instead of by search, for a build side whose valid keys span at most
-    ``table_size`` values (`direct_table_size` chose it from the same
-    build side; a wider span is the caller's fault and reads the table's
-    last entry).
-
-    The search's own answer for every key of the span goes into a table
-    at the TABLE's width; a probe row then costs elementwise work on its
-    key and one gather at ``key - kmin``. Whether a key lies in the span
-    is decided on the order words BEFORE the subtraction, so a key at
-    INT64's other end cannot wrap into it; below and above the span the
-    search's ``lo`` is known without it (the first valid row, the end)."""
-    (q,), lvalid = _key_words(lcols)
-    if left_valid is not None:
-        lvalid = lvalid & left_valid
+def _direct_table(sorted_words, table_size: int):
+    """The direct probe's table, at the TABLE's width: the search's own
+    ``(lo, count)`` for every key of the build side's span, with the
+    span's ends and the first valid row of the sorted build side."""
     valid_w, key_w = sorted_words
     m = key_w.shape[0]
     first = m - jnp.sum(valid_w).astype(jnp.int32)  # invalid rows sort first
@@ -343,14 +336,43 @@ def _probe_direct(
     ]
     t_lo = _lex_searchsorted(sorted_words, targets, "left", unroll=True)
     t_hi = _lex_searchsorted(sorted_words, targets, "right", unroll=True)
-    t_cnt = t_hi - t_lo
+    return first, kmin, kmax, t_lo, t_hi - t_lo
 
-    below = q < kmin
-    above = q > kmax
-    inside = ~(below | above)
-    # outside the span the difference is clamped (below it, wrapped
-    # first) and what it reads is never used
+
+def _direct_address(q, kmin, kmax, table_size: int):
+    """Where a probe key's order word ``q`` lies: ``(below, above,
+    off)``. Whether it lies in the span is decided on the order words
+    BEFORE the subtraction, so a key at INT64's other end cannot wrap
+    into it; outside the span the difference is clamped (below it,
+    wrapped first) and what ``off`` reads is never used."""
     off = jnp.minimum(q - kmin, jnp.uint64(table_size - 1)).astype(jnp.int32)
+    return q < kmin, q > kmax, off
+
+
+def _probe_direct(
+    sorted_words,
+    table_size: int,
+    lcols: Sequence[Column],
+    left_valid: Optional[jax.Array] = None,
+):
+    """`_probe_build`'s ``(lo, counts, lvalid)``, bit for bit, by address
+    instead of by search, for a build side whose valid keys span at most
+    ``table_size`` values (`direct_table_size` chose it from the same
+    build side; a wider span is the caller's fault and reads the table's
+    last entry).
+
+    The search's own answer for every key of the span goes into a table
+    at the TABLE's width (`_direct_table`); a probe row then costs
+    elementwise work on its key and one gather at ``key - kmin``
+    (`_direct_address`). Below and above the span the search's ``lo`` is
+    known without it (the first valid row, the end)."""
+    (q,), lvalid = _key_words(lcols)
+    if left_valid is not None:
+        lvalid = lvalid & left_valid
+    m = sorted_words[1].shape[0]
+    first, kmin, kmax, t_lo, t_cnt = _direct_table(sorted_words, table_size)
+    below, above, off = _direct_address(q, kmin, kmax, table_size)
+    inside = ~(below | above)
     bits = int(m).bit_length()  # lo and cnt lie in [0, m]
     if 2 * bits <= 32:
         # one gather carries both
@@ -364,6 +386,50 @@ def _probe_direct(
     lo = jnp.where(below, first, jnp.where(above, jnp.int32(m), g_lo))
     counts = jnp.where(lvalid & inside, g_cnt, 0)
     return lo, counts, lvalid
+
+
+def lookup_unique(
+    left: Table,
+    right: Table,
+    left_on: Sequence[Union[int, str]],
+    right_on: Sequence[Union[int, str]],
+    left_valid: Optional[jax.Array],
+    right_valid: Optional[jax.Array],
+    table_size: int,
+):
+    """An inner join whose build key repeats no valid value, as what it
+    is: a selection of the probe rows plus a row-local lookup. Returns
+    ``(matched, out)``: the probe rows that have their ONE build row,
+    and `_join_output`'s table with every probe row where it was (an
+    unmatched row's build columns are garbage behind ``matched``). The
+    probe rows that match, in probe order, are the exact inner join.
+
+    The caller read the build side (`build_key_span`): no valid key
+    repeats and the valid keys span at most ``table_size`` values; and
+    it holds both keys to `direct_key`. Null keys on either side and
+    rows outside ``left_valid`` / ``right_valid`` match nothing, as in
+    `_match_ranges`.
+
+    One gather at the probe's width finds each row's build row through
+    the direct probe's address (the table holds the build ROW of every
+    key of the span, -1 where the span has a hole); a build column then
+    costs one more a 32-bit word, and none when nothing reads it."""
+    perm_r, sorted_words = _prepare_build(right, right_on, right_valid)
+    (q,), lvalid = _key_words([left.column(c) for c in left_on])
+    if left_valid is not None:
+        lvalid = lvalid & left_valid
+    m = perm_r.shape[0]
+    _, kmin, kmax, t_lo, t_cnt = _direct_table(sorted_words, table_size)
+    # one 32-bit word an entry (lexsort's permutation is int64 here)
+    t_row = jnp.where(
+        t_cnt > 0, perm_r[jnp.clip(t_lo, 0, m - 1)].astype(jnp.int32), -1
+    )
+    below, above, off = _direct_address(q, kmin, kmax, table_size)
+    right_idx = jnp.where(lvalid & ~(below | above), t_row[off], -1)
+    out = _join_output(
+        left, right, right_on, None, jnp.maximum(right_idx, 0), None, None
+    )
+    return right_idx >= 0, out
 
 
 def _match_ranges(
@@ -531,8 +597,11 @@ def _join_output(
                 drop.add(right.names.index(c))
         else:
             drop.add(c)
-    lcols = gather_table(left, left_idx, None).columns
-    out_cols = list(lcols)
+    # left_idx None: every left row where it is (`lookup_unique`)
+    out_cols = list(
+        left.columns if left_idx is None
+        else gather_table(left, left_idx, None).columns
+    )
     out_names = list(left.names) if left.names else [f"l{i}" for i in range(left.num_columns)]
     for j, c in enumerate(right.columns):
         if j in drop:

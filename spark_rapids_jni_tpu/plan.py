@@ -20,8 +20,12 @@ launch at the bucket of the group count (``bucketed._reduce_groups``),
 and the following ops re-enter the compiler on that result. A filter
 from which only row-local ops lead to that groupby moves no row: its
 selection joins the occupancy mask the groupby's sort already carries
-(``_run_segment_traced``).
-Everything else (join, concat, explode, to_rows/from_rows, ...) is a
+(``_run_segment_traced``). So does an ``inner`` join in that place
+whose build side shows a unique, directly addressable key (read from
+the data before the plan is segmented, ``_selecting_joins``): its match
+bit joins the mask, its build columns are a row-local lookup, and the
+build table is one more argument of the segment's executable.
+Everything else (any other join, concat, explode, to_rows/from_rows, ...) is a
 segment boundary dispatched through the one-op ``planops.dispatch``
 path — bucketed runner or exact fallback — with ``Table.logical_rows`` carried through unchanged so padding
 semantics survive the boundary.
@@ -95,10 +99,12 @@ def segment_plan(ops: Sequence[dict]) -> List[Tuple[str, list]]:
     ]
 
 
-def _run_segment_traced(seg_ops: Sequence[dict], t: Table, n):
+def _run_segment_traced(seg_ops: Sequence[dict], t: Table, n, builds=()):
     """The traced body of one fused segment: thread (table, occupancy)
     through every op at the segment's one physical shape. A groupby
-    tail leaves its sorted state in the table's place.
+    tail leaves its sorted state in the table's place. ``builds`` holds
+    ``(build table, its device count, table size)`` for each join of
+    the segment, in order.
 
     Occupancy flows as the count ``n`` (a prefix, turned into a mask
     for each op) until a selecting op at or behind
@@ -109,6 +115,7 @@ def _run_segment_traced(seg_ops: Sequence[dict], t: Table, n):
     selecting op compacts, because what follows (an op that reads the
     count, the segment's caller) needs the prefix."""
     masked_from = planops.deferred_from(seg_ops)
+    builds = iter(builds)
     mask = None
     for i, op in enumerate(seg_ops):
         spec = planops.OPS[op["op"]]
@@ -118,7 +125,8 @@ def _run_segment_traced(seg_ops: Sequence[dict], t: Table, n):
         with jax.named_scope("srt." + op["op"]):
             rv = buckets.tail_valid(t.row_count, n) if mask is None else mask
             if spec.select is not None and i >= masked_from:
-                t, mask = spec.select(op, t, rv)
+                build = (next(builds),) if op["op"] == "join" else ()
+                t, mask = spec.select(op, t, rv, *build)
                 continue
             t, n = spec.traced(op, t, n, rv)
             if hasattr(n, "astype"):
@@ -127,10 +135,16 @@ def _run_segment_traced(seg_ops: Sequence[dict], t: Table, n):
 
 
 def _run_fused(
-    seg_ops: Sequence[dict], table: Table, donate: bool = False
+    seg_ops: Sequence[dict], table: Table, donate: bool = False,
+    builds: Sequence[Tuple[Table, int]] = (),
 ) -> Table:
     """One fused segment -> one cached executable -> one launch (and,
     behind a groupby tail, the launch of its per-group half).
+
+    ``builds`` is ``(build table, table size)`` for each join of the
+    segment, in order (``_selecting_joins``): further arguments of the
+    executable, whose key holds their schema, bucket and table size.
+    They are the caller's and are never donated.
 
     ``donate=True`` marks the segment's input table as CONSUMED: its
     padded buffers are donated to the executable
@@ -143,11 +157,15 @@ def _run_fused(
     ``run_plan`` fallback checks for that before attempting a per-op
     replay."""
     pt = bucketed._padded_input(table)  # _Decline when unbucketable
-    key = buckets.cache_key("plan", list(seg_ops), (pt,))
+    rts = [bucketed._padded_input(b) for b, _ in builds]
+    sizes = tuple(size for _, size in builds)
+    key = buckets.cache_key("plan", list(seg_ops), (pt, *rts), sizes)
 
     def build():
-        def fn(t, n):
-            return _run_segment_traced(seg_ops, t, n)
+        def fn(t, n, *rest):
+            return _run_segment_traced(
+                seg_ops, t, n, [(*rn, size) for rn, size in zip(rest, sizes)]
+            )
 
         return fn
 
@@ -161,7 +179,10 @@ def _run_fused(
     groupby_tail = seg_ops[-1]["op"] == "groupby"
     # a groupby tail's two launches stay together on the device
     with bucketed.groupby_turn() if groupby_tail else contextlib.nullcontext():
-        out, count = fn(bucketed._strip(pt), bucketed._n_dev(pt))
+        out, count = fn(
+            bucketed._strip(pt), bucketed._n_dev(pt),
+            *((bucketed._strip(r), bucketed._n_dev(r)) for r in rts),
+        )
         planops.note_launched(seg_ops)
         if donated:
             # counted AFTER the launch: a trace/compile failure falls back
@@ -239,7 +260,8 @@ def _run_chunked(seg_ops: Sequence[dict], table: Table) -> Table:
 
 
 def _run_fused_tolerant(
-    seg_ops: Sequence[dict], table: Table, donate: bool
+    seg_ops: Sequence[dict], table: Table, donate: bool,
+    builds: Sequence[Tuple[Table, int]] = (),
 ) -> Table:
     """One fused segment with the fault-tolerance contract applied at
     segment granularity:
@@ -263,7 +285,7 @@ def _run_fused_tolerant(
         faults.check_cancel()
         try:
             faults.inject("dispatch")
-            return _run_fused(seg_ops, table, donate=donate)
+            return _run_fused(seg_ops, table, donate=donate, builds=builds)
         except bucketed._Decline:
             raise
         except (faults.Cancelled, faults.DeadlineExceeded):
@@ -443,16 +465,60 @@ def _offer_mesh(ops, table: Table, rest, mesh_runner):
     return None
 
 
+def _selecting_joins(ops, table: Table, orig_rest: tuple):
+    """What the segmenter may know of the plan's joins, read from the
+    data once, before the plan is segmented: ``(join_selects, builds)``.
+    ``join_selects(i, op)`` (``plancheck.predict_segments`` asks it of
+    the joins that could ride a run) reads the build side of join ``i``
+    (``bucketed.selecting_table_size``) and, when the join only
+    selects, leaves ``(padded build table, table size)`` in
+    ``builds[i]``. The probe side is taken to be as wide as the plan's
+    input: its width bounds the table, and is a matter of cost alone.
+    ``(None, {})`` for a plan without a join."""
+    build_of: dict = {}
+    queue = list(orig_rest)
+    try:
+        for i, op in enumerate(ops):
+            got = _take_rest(op, orig_rest, queue)
+            if op["op"] == "join" and got:
+                build_of[i] = got[0]
+    except (IndexError, TypeError, ValueError):
+        pass  # a bad ``rest`` field: the op's own dispatch says so
+    if not build_of:
+        return None, {}
+    builds: dict = {}
+
+    def join_selects(i: int, op: dict) -> bool:
+        try:
+            rt = bucketed._padded_input(build_of[i])
+            size = bucketed.selecting_table_size(
+                op, rt, bucketed.padded_rows(table)
+            )
+        except (faults.Cancelled, faults.DeadlineExceeded):
+            raise
+        # srt: allow-broad-except(no build table, no bucket, a read that failed: the join stays the boundary it was and its own runner surfaces the real error)
+        except Exception:
+            return False
+        if size is None:
+            return False
+        builds[i] = (rt, size)
+        return True
+
+    return join_selects, builds
+
+
 def _run_segments(ops, table: Table, rest, donate_input: bool) -> Table:
     """The single-device path: the plan's segments in order, each under
     its ``plan.segment`` span and a ``plan.segment.<sig>`` one."""
     orig_rest = tuple(rest)
     queue = list(orig_rest)
+    builds: dict = {}
     if buckets.enabled():
-        segs = segment_plan(ops)
+        join_selects, builds = _selecting_joins(ops, table, orig_rest)
+        segs = plancheck.predict_segments(ops, join_selects)
     else:
         # debugging mode: the whole plan runs per-op on the exact path
-        segs = [("exact", [op]) for op in ops]
+        segs = [("exact", [i]) for i in range(len(ops))]
     metrics.counter_add("plan.calls")
     metrics.counter_add("plan.segments", len(segs))
     owned = bool(donate_input)
@@ -468,7 +534,8 @@ def _run_segments(ops, table: Table, rest, donate_input: bool) -> Table:
         protected.update(_buffer_ids(table))
     for t in orig_rest:
         protected.update(_buffer_ids(t))
-    for i, (kind, seg_ops) in enumerate(segs):
+    for i, (kind, idxs) in enumerate(segs):
+        seg_ops = [ops[j] for j in idxs]
         faults.check_cancel()  # between-segment checkpoint
         with metrics.span(
             "plan.segment", index=i, kind=kind, ops=len(seg_ops)
@@ -484,10 +551,18 @@ def _run_segments(ops, table: Table, rest, donate_input: bool) -> Table:
                     donate = owned and protected.isdisjoint(
                         _buffer_ids(table)
                     )
+                    riding = [j for j in idxs if j in builds]
                     try:
                         table = _run_fused_tolerant(
-                            seg_ops, table, donate=donate
+                            seg_ops, table, donate=donate,
+                            builds=[builds[j] for j in riding],
                         )
+                        # a riding join takes its build table from the
+                        # queue as the per-op replay would, once the
+                        # launch is through: a fallback replay below
+                        # finds the queue as it was
+                        for j in riding:
+                            _take_rest(ops[j], orig_rest, queue)
                         metrics.counter_add("plan.fused_segments")
                         metrics.counter_add(
                             "plan.fused_ops", len(seg_ops)

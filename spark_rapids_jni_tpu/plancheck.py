@@ -34,7 +34,7 @@ dispatch.
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from . import dtype as dt
 from . import planops
@@ -238,13 +238,27 @@ def _kernel_tag(op: dict, st) -> Optional[str]:
     return None
 
 
-def predict_segments(ops: Sequence[dict]) -> List[Tuple[str, List[int]]]:
+def predict_segments(
+    ops: Sequence[dict],
+    join_selects: Optional[Callable[[int, dict], bool]] = None,
+) -> List[Tuple[str, List[int]]]:
     """The fusion segmentation as ``[(kind, [op indices])]``:
     ``"fused"`` (a run of >= 2 fusable ops compiled as one executable)
     or ``"exact"`` (a single op through the per-op dispatch —
     non-fusable ops, and 1-op runs, which the one-op runners already
     cache under their own keys). A groupby is tail-only: it closes the
-    run it ends. ``plan.segment_plan`` runs what this returns."""
+    run it ends. ``plan.segment_plan`` runs what this returns.
+
+    A join is a boundary unless ``join_selects(index, op)`` says its
+    build side makes it a selection (``plan._run_segments``, which
+    holds the build tables, reads that from the data) AND only ops
+    through which occupancy flows as a mask lie between it and a
+    groupby tail (``planops.keeps_rows``): then it rides that run and
+    moves no row. A join that would have to hand on a prefix gains
+    nothing from riding and is never asked. With nothing known of the
+    build side (``join_selects`` None: every static caller) a join
+    stays the boundary it is."""
+    riding = _riding_joins(ops, join_selects) if join_selects else ()
     segs: List[Tuple[str, List[int]]] = []
     cur: List[int] = []
 
@@ -259,7 +273,7 @@ def predict_segments(ops: Sequence[dict]) -> List[Tuple[str, List[int]]]:
         cur = []
 
     for i, op in enumerate(ops):
-        if planops.op_fusable(op):
+        if planops.op_fusable(op) or i in riding:
             cur.append(i)
             if op.get("op") == "groupby":
                 flush()
@@ -268,6 +282,26 @@ def predict_segments(ops: Sequence[dict]) -> List[Tuple[str, List[int]]]:
             segs.append(("exact", [i]))
     flush()
     return segs
+
+
+def _riding_joins(ops: Sequence[dict], join_selects) -> set:
+    """Indices of the joins that ride a fused run: walking back from
+    each fusable groupby over the ops that keep their rows, every join
+    met is asked; the first that does not select ends the walk."""
+    riding: set = set()
+    reaches_groupby = False
+    for i in range(len(ops) - 1, -1, -1):
+        op = ops[i]
+        name = op.get("op")
+        if name == "groupby":
+            reaches_groupby = planops.op_fusable(op)
+        elif name == "join":
+            reaches_groupby = reaches_groupby and bool(join_selects(i, op))
+            if reaches_groupby:
+                riding.add(i)
+        elif not (planops.op_fusable(op) and planops.keeps_rows(op)):
+            reaches_groupby = False
+    return riding
 
 
 # ---------------------------------------------------------------------------

@@ -16,7 +16,10 @@
   choice of rows: the table it hands on, rows where they are, and the
   occupancy ANDed with its selection. Its ``traced`` compacts by that
   (:func:`_compacting`); a fused segment whose occupancy may stay a
-  mask (:func:`deferred_from`) hands it to the next op as it is;
+  mask (:func:`deferred_from`) hands it to the next op as it is. A
+  ``join`` has this body alone (``select(op, t, rv, build)``): inside a
+  segment it can only select, which an inner join on a unique build key
+  is, and ``plancheck.predict_segments`` lets no other join in;
 * ``fusable`` / ``bucketable`` — may the op ride a fused segment, has
   it a one-op bucketed program (a constant, or a predicate over the op
   for the cases its JSON decides);
@@ -48,6 +51,7 @@ import numpy as np
 from . import bucketed, dtype as dt, ops, planrules as rules, rows as rows_mod
 from .column import Column, Table
 from .kernels import registry as kernel_registry
+from .ops import join as join_mod
 from .ops import partition as partition_mod
 from .ops import regex as regex_mod
 from .ops import strings as strings_mod
@@ -292,6 +296,25 @@ def _s_rlike(op, t, rv):
     return t, selection_mask(_gated(mask, rv))
 
 
+def _s_join(op, t, rv, build):
+    # an inner join whose build key repeats no value and is addressed
+    # directly (bucketed.selecting_table_size read it from ``build``'s
+    # table): each probe row has at most one build row, so the join is
+    # a selection plus a row-local lookup and moves no row
+    r, rn, table_size = build
+    on = list(op["on"])
+    if not join_mod.direct_key(
+        [t.column(c) for c in on], [r.column(c) for c in on]
+    ):
+        # the build side's key was read; the probe side's is known only
+        # here, where the segment is traced: the per-op path owns it
+        raise bucketed._Decline
+    matched, out = join_mod.lookup_unique(
+        t, r, on, on, rv, buckets.tail_valid(r.row_count, rn), table_size
+    )
+    return out, jnp.logical_and(rv, matched)
+
+
 def _compacting(select):
     """The traced body of a selecting op: its selection, then the
     stable compaction that turns the occupancy back into a prefix."""
@@ -419,7 +442,7 @@ OPS: Dict[str, OpSpec] = {
         bucketable=_no_collect, runner=bucketed._r_groupby,
     ),
     "join": OpSpec(
-        rules._r_join, _x_join, bucketable=_bucketed_how,
+        rules._r_join, _x_join, select=_s_join, bucketable=_bucketed_how,
         runner=bucketed._r_join,
     ),
     "partition": OpSpec(rules._r_partition, _x_partition, exchange=True),
@@ -459,31 +482,47 @@ def deferred_from(seg_ops: Sequence[dict]) -> int:
     rows where they are and ANDs its selection into the occupancy
     (``plan._run_segment_traced``). That holds when every op from there
     to the tail is row-local (none reads the count or looks across
-    rows) and the tail is a groupby, whose sort puts ANY mask's rows
-    last (``ops.groupby._key_words``). ``len(seg_ops)`` where nothing
-    may defer: the segment's result then needs the prefix."""
+    rows) or a join — inside a segment a join only selects among its
+    probe rows (:func:`_s_join`), though it is not ``row_local``: it
+    reads a second table, which no chunked or sharded chain has — and
+    the tail is a groupby, whose sort puts ANY mask's rows last
+    (``ops.groupby._key_words``). ``len(seg_ops)`` where nothing may
+    defer: the segment's result then needs the prefix."""
     last = len(seg_ops) - 1
     if last < 0 or seg_ops[last]["op"] != "groupby":
         return len(seg_ops)
     i = last
-    while i > 0 and OPS[seg_ops[i - 1]["op"]].row_local:
+    while i > 0 and keeps_rows(seg_ops[i - 1]):
         i -= 1
     return i
+
+
+def keeps_rows(op: dict) -> bool:
+    """May a segment's occupancy flow through this op as a mask?"""
+    return OPS[op["op"]].row_local or op["op"] == "join"
 
 
 def note_launched(seg_ops: Sequence[dict]) -> None:
     """The counters of a traced program that has just been launched —
     one op alone, a fused segment, a mesh stage: ``project.calls`` for
-    its ``project`` ops, and for each selecting op ``filter.deferred``
-    or ``filter.compacted``, the way its occupancy went. Counted on the
-    host at launch, not at trace (the executable is cached), so the
-    counters say what the daemon evaluated."""
+    its ``project`` ops, for each selecting op ``filter.deferred`` or
+    ``filter.compacted``, the way its occupancy went, and for each join
+    ``join.deferred`` (inside a segment: it selected, by the direct
+    probe) or ``join.materialised`` (its own runner moved the rows).
+    Counted on the host at launch, not at trace (the executable is
+    cached), so the counters say what the daemon evaluated."""
     k = sum(1 for o in seg_ops if o.get("op") == "project")
     if k:
         metrics.counter_add("project.calls", k)
     start = deferred_from(seg_ops)
     for i, o in enumerate(seg_ops):
-        if OPS[o["op"]].select is not None:
+        if o["op"] == "join":
+            if len(seg_ops) > 1:
+                metrics.counter_add("join.deferred")
+                metrics.counter_add("join.probe.direct")
+            else:
+                metrics.counter_add("join.materialised")
+        elif OPS[o["op"]].select is not None:
             metrics.counter_add(
                 "filter.deferred" if i >= start else "filter.compacted"
             )

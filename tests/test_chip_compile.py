@@ -370,6 +370,45 @@ def test_q1_fused_segment_compiles(one_chip, as_tpu):
     assert " gather(" not in compiled.as_text()
 
 
+def test_resident_query_fused_segment_compiles(one_chip, as_tpu):
+    """The resident query's ``filter -> join -> groupby`` as the one
+    ``srt_fused_plan`` program the served path launches when the
+    dimension's key is unique and dense (perfbench's ``resident-query``
+    plan over the ``ss-star-8m`` schemas, PR 38), for the chip's
+    compiler at small buckets: 2^13 fact rows against a 2^10-row
+    dimension through a 2^11-entry table. Neither the filter nor the
+    join moves a row, so at the fact side's width the chip's own HLO
+    gathers three times: each row's build row through the direct
+    probe's address, and the two ``bits_to_f64`` table reads of the
+    FLOAT64 sum (ROADMAP A9 (b)). ``cat``, which nothing reads, costs
+    nothing."""
+    import json
+    import re
+
+    from spark_rapids_jni_tpu import plan as plan_mod, plancheck
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perfbench", "traffic", "resident-query.json")) as f:
+        (step,) = [s for s in json.load(f)["request"] if s["do"] == "plan"]
+    ops = step["plan"]
+    (kind, idxs), (tail, _) = plancheck.predict_segments(
+        ops, lambda i, op: True
+    )
+    assert (kind, idxs, tail) == ("fused", [0, 1, 2], "exact")
+    width = 1 << 13
+    fact = _table(one_chip, FACT + (dt.BOOL8,), width)
+    dim = _table(one_chip, (dt.INT64, dt.INT64), 1 << 10)
+    n32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = (
+        jax.jit(lambda t, n, r, rn: plan_mod._run_segment_traced(
+            ops[:3], t, n, [(r, rn, 1 << 11)]))
+        .lower(fact, n32, dim, n32).compile()
+    )
+    _fits(compiled)
+    wide = re.findall(rf"= (\w+)\[{width}\]\S* gather\(", compiled.as_text())
+    assert sorted(wide) == ["f32", "f32", "s32"], wide
+
+
 # ---------------------------------------------------------------------------
 # four chips: the mesh partition stage (parallel/planmesh.py) as one
 # program over the described 2x2 mesh

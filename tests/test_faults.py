@@ -366,7 +366,7 @@ def test_consumed_segment_is_never_retried(monkeypatch):
     config.set_flag("METRICS", "1")
     calls = {"n": 0}
 
-    def launch_then_die(seg_ops, table, donate=False):
+    def launch_then_die(seg_ops, table, donate=False, builds=()):
         calls["n"] += 1
         raise RuntimeError("UNAVAILABLE: device lost after launch")
 

@@ -529,6 +529,37 @@ class TestSegmentationFuzz:
         ]
 
 
+_GROUP = {"op": "groupby", "by": [0], "aggs": [{"column": 1, "agg": "sum"}]}
+_MASK = {"op": "filter", "mask": 2}
+_JOIN = {"op": "join", "on": [0]}
+
+
+@pytest.mark.parametrize("ops,static,riding", [
+    ([_MASK, _JOIN, _GROUP],
+     [("exact", [0]), ("exact", [1]), ("exact", [2])],
+     [("fused", [0, 1, 2])]),
+    ([_JOIN, _GROUP],
+     [("exact", [0]), ("exact", [1])], [("fused", [0, 1])]),
+    ([_MASK, _JOIN, {"op": "sort_by", "keys": [{"column": 0}]}],
+     [("exact", [0]), ("exact", [1]), ("exact", [2])], None),
+    ([_MASK, dict(_JOIN, how="full"), _GROUP],
+     [("exact", [0]), ("exact", [1]), ("exact", [2])], None),
+], ids=["filter_join_groupby", "join_groupby", "sort_tail", "full_join"])
+def test_a_join_is_a_boundary_unless_its_build_side_is_known(
+        ops, static, riding):
+    """Without a build side (every static caller) the prediction is what
+    it was; told that an inner join selects (``plan._run_segments`` reads
+    that from the data, PR 38), the one segmenter lets it ride the run
+    that a groupby closes, and no other."""
+    assert pc.predict_segments(ops) == static
+    _assert_seg_parity(ops)
+    rep = pc.analyze(ops, schema=BASE_SCHEMA, rows=10,
+                     rest=[([C(T.INT64)], 5)])
+    assert [(s["kind"], s["ops"]) for s in rep["segments"]] == static
+    inner = lambda i, op: op.get("how", "inner") == "inner"  # noqa: E731
+    assert pc.predict_segments(ops, inner) == (riding or static)
+
+
 # ---------------------------------------------------------------------------
 # inference-vs-execution fuzz: analyzer-clean plans run, and the wire
 # result's (type_ids, scales) match the inferred schema byte-for-byte

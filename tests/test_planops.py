@@ -86,10 +86,15 @@ def test_spec_is_whole(name):
         assert spec.runner is None and spec.program is None
     if spec.row_local:
         assert spec.traced is not None  # only a traced chain is chunked
-    if spec.select is not None:
+    if spec.select is not None and spec.traced is not None:
         # a selection may stay a mask only among row-local ops, and the
         # compacting body built over it returns the new count
         assert spec.row_local and spec.counts
+    if spec.select is not None and spec.traced is None:
+        # the join: inside a segment it can only select (PR 38); alone
+        # it is its runner's, and no chain may chunk or shard it
+        assert name == "join" and spec.runner is not None
+        assert not spec.row_local and spec.fusable is False
     for flag in (spec.fusable, spec.bucketable):
         assert isinstance(flag, bool) or callable(flag)
 
@@ -100,7 +105,7 @@ def test_the_table_has_the_seventeen_ops():
     assert sorted(n for n, s in planops.OPS.items() if s.row_local) == [
         "cast", "filter", "project", "rlike"]
     assert sorted(n for n, s in planops.OPS.items() if s.select) == [
-        "filter", "rlike"]
+        "filter", "join", "rlike"]
 
 
 # ---------------------------------------------------------------------------

@@ -50,7 +50,7 @@ STATIC = {
 
 
 def _run_once(rows_out=50, out_bytes=400, label="t", bucket=None,
-              plan=None, static=STATIC, kind="fused"):
+              plan=None, static=STATIC, kind="fused", seg_ops=("filter",)):
     """One profile session with one segment — the shape every dispatch
     entry produces."""
     with profiler.profile_session(
@@ -58,7 +58,7 @@ def _run_once(rows_out=50, out_bytes=400, label="t", bucket=None,
         bucket=bucket, static=static,
     ):
         tok = profiler.segment_begin(
-            0, kind, [{"op": "filter"}], rows_in=100
+            0, kind, [{"op": name} for name in seg_ops], rows_in=100
         )
         profiler.segment_end(tok, rows_out=rows_out, out_bytes=out_bytes)
 
@@ -255,6 +255,20 @@ class TestDrift:
             f["type"] == "segmentation"
             for f in last.get("drift") or []
         )
+
+    def test_a_join_that_rode_its_segment_is_not_segmentation_drift(self):
+        # the static prediction knows nothing of the build side: three
+        # boundaries where the run, having read it, fused (PR 38)
+        static = {
+            "segments": [
+                {"kind": "exact", "ops": [i], "rows_bound": 10,
+                 "est_hbm_bytes": 40} for i in range(3)],
+            "rows_out_bound": 10,
+            "est_hbm_peak_bytes": 40,
+        }
+        _run_once(static=static, seg_ops=("filter", "join", "groupby"))
+        last = planstats.load()[-1]
+        assert not (last.get("drift") or [])
 
     def test_history_seeds_from_disk_across_reset(self):
         config.set_flag("DRIFT_ROWS_FACTOR", 2.0)
