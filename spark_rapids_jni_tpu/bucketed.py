@@ -300,22 +300,29 @@ def _build_key_facts(rt: Table, on: list) -> tuple:
     return tuple(int(v) for v in np.asarray(span))
 
 
-def _probe_table_size(lt: Table, rt: Table, on: list) -> Optional[int]:
+def _probe_choice(lt: Table, rt: Table, on: list) -> tuple:
     """The one choice of a served join's probe, from what the build side
-    shows: the direct probe's table size when the key is one
-    integer-family column whose valid build keys are dense
-    (``ops.join.direct_table_size``), None for the search. A key of
-    another kind is decided from the schema; a dense one costs
+    shows: ``(table_size, narrow)``. ``table_size`` is the direct
+    probe's when the key is one integer-family column whose valid build
+    keys are dense (``ops.join.direct_table_size``), None for the
+    search; ``narrow`` says that the search can run over one u32 word a
+    side, because the valid build keys span under 2^32 values
+    (``ops.join.offsets_fit``: a sparse INT64 key of any one table is
+    that). A key of another kind is decided from the schema and
+    searched over all its words; an integer one costs
     `_build_key_facts`."""
     from .ops import join as join_mod
 
     if not join_mod.direct_key(
         [lt.column(c) for c in on], [rt.column(c) for c in on]
     ):
-        return None
+        return None, False
     kmin, kmax, valid_rows, _ = _build_key_facts(rt, on)
-    return join_mod.direct_table_size(
+    table_size = join_mod.direct_table_size(
         kmin, kmax, valid_rows, rt.row_count, lt.row_count
+    )
+    return table_size, (
+        table_size is None and join_mod.offsets_fit(kmin, kmax, valid_rows)
     )
 
 
@@ -348,6 +355,53 @@ def selecting_table_size(
     )
 
 
+def join_probe_program(on: list, table_size: Optional[int], narrow: bool):
+    """Phase 1 of a served inner / left join, as `_r_join` compiles it
+    (and `tests/test_chip_compile.py`, at a cell's buckets): the match
+    ranges of every probe row and both totals."""
+    def fn(l, r, ln, rn):
+        from .ops.join import _left_emit, _match_ranges
+
+        lv = buckets.tail_valid(l.row_count, ln)
+        rv = buckets.tail_valid(r.row_count, rn)
+        perm_r, lo, counts, _ = _match_ranges(
+            l, r, on, on, lv, rv, table_size=table_size, narrow=narrow
+        )
+        return (
+            perm_r, lo, counts,
+            jnp.sum(counts),
+            jnp.sum(_left_emit(counts, lv)),
+        )
+
+    return fn
+
+
+def join_mat_program(on: list, cap: int, left_outer: bool):
+    """Phase 2: the joined rows at the output's bucket ``cap``, from
+    phase 1's ranges."""
+    def fn(l, r, perm_r, lo, counts, ln):
+        from .ops.join import _expand, _join_output, _left_emit
+
+        if left_outer:
+            lv = buckets.tail_valid(l.row_count, ln)
+            emit = _left_emit(counts, lv)
+            left_idx, right_idx, matched, _ = _expand(
+                perm_r, lo, counts, cap, left_outer=True, emit=emit
+            )
+            return _join_output(
+                l, r, on, left_idx, right_idx, matched, None
+            )
+        left_idx, right_idx, _, _ = _expand(
+            perm_r, lo, counts, cap, left_outer=False
+        )
+        # no matched/row_valid masks, matching the exact-path
+        # inner_join output schema; rows past ``total`` are garbage
+        # behind the logical row count
+        return _join_output(l, r, on, left_idx, right_idx, None, None)
+
+    return fn
+
+
 def _r_join(op: dict, table: Table, rest) -> Table:
     how = op.get("how", "inner")
     if how not in JOIN_HOWS or not rest:
@@ -357,10 +411,17 @@ def _r_join(op: dict, table: Table, rest) -> Table:
     lt = _padded_input(table)
     rt = _padded_input(rest[0])
     on = list(op["on"])
-    table_size = _probe_table_size(lt, rt, on)
+    table_size, narrow = _probe_choice(lt, rt, on)
     metrics.counter_add(
         "join.probe.search" if table_size is None else "join.probe.direct"
     )
+    if narrow:
+        metrics.counter_add("join.probe.narrow")
+    # logical rows of the two sides and (below) of the result, a served
+    # join: beside groupby.input_rows / groupby.reduce_rows
+    metrics.counter_add("join.probe_rows", lt.logical_row_count)
+    metrics.counter_add("join.build_rows", rt.logical_row_count)
+    probe = (table_size, narrow)
 
     if how in ("semi", "anti"):
         anti = how == "anti"
@@ -373,7 +434,8 @@ def _r_join(op: dict, table: Table, rest) -> Table:
                 lv = buckets.tail_valid(l.row_count, ln)
                 rv = buckets.tail_valid(r.row_count, rn)
                 _, _, counts, lvalid = _match_ranges(
-                    l, r, on, on, lv, rv, table_size=table_size
+                    l, r, on, on, lv, rv,
+                    table_size=table_size, narrow=narrow,
                 )
                 has = jnp.logical_and(counts > 0, lvalid)
                 if anti:
@@ -389,37 +451,22 @@ def _r_join(op: dict, table: Table, rest) -> Table:
             return fn
 
         fn = buckets.cached_jit(
-            _key("join." + how, op, lt, rt, extra=(table_size,)), build_sa,
+            _key("join." + how, op, lt, rt, extra=probe), build_sa,
             "srt_bucketed_join_" + how, scope="srt.join",
         )
         out, count = fn(_strip(lt), _strip(rt), _n_dev(lt), _n_dev(rt))
         # srt: allow-host-sync(bucketed-runner boundary: the compiled launch is done; one count read sizes the logical rows of the padded result)
-        return _finish(out, int(count))
+        total = int(count)
+        metrics.counter_add("join.output_rows", total)
+        return _finish(out, total)
 
     # inner/left: two-phase sizing. Phase 1 (probe) compiles per input
     # bucket pair; phase 2 (materialize) per OUTPUT capacity bucket —
     # the output size is bucketed too, so both phases cost O(#buckets)
     # executables across a ragged stream.
-    def build_probe():
-        def fn(l, r, ln, rn):
-            from .ops.join import _left_emit, _match_ranges
-
-            lv = buckets.tail_valid(l.row_count, ln)
-            rv = buckets.tail_valid(r.row_count, rn)
-            perm_r, lo, counts, _ = _match_ranges(
-                l, r, on, on, lv, rv, table_size=table_size
-            )
-            return (
-                perm_r, lo, counts,
-                jnp.sum(counts),
-                jnp.sum(_left_emit(counts, lv)),
-            )
-
-        return fn
-
     p1 = buckets.cached_jit(
-        _key("join.ranges", {"on": on}, lt, rt, extra=(table_size,)),
-        build_probe,
+        _key("join.ranges", {"on": on}, lt, rt, extra=probe),
+        lambda: join_probe_program(on, table_size, narrow),
         "srt_bucketed_join_probe", scope="srt.join",
     )
     perm_r, lo, counts, inner_total, left_total = p1(
@@ -427,6 +474,7 @@ def _r_join(op: dict, table: Table, rest) -> Table:
     )
     # srt: allow-host-sync(bucketed-runner boundary: the compiled launch is done; one count read sizes the logical rows of the padded result)
     total = int(left_total if how == "left" else inner_total)
+    metrics.counter_add("join.output_rows", total)
     cap = buckets.bucket_for(total)
     if cap is None:
         # no output bucket (empty result, or a fan-out past the ladder
@@ -435,34 +483,10 @@ def _r_join(op: dict, table: Table, rest) -> Table:
         # graphs the cap exists to avoid — the exact path (with its
         # fenced batched-probe routing) owns those shapes
         raise _Decline
-    left_outer = how == "left"
-
-    def build_mat():
-        def fn(l, r, perm_r, lo, counts, ln):
-            from .ops.join import _expand, _join_output, _left_emit
-
-            if left_outer:
-                lv = buckets.tail_valid(l.row_count, ln)
-                emit = _left_emit(counts, lv)
-                left_idx, right_idx, matched, _ = _expand(
-                    perm_r, lo, counts, cap, left_outer=True, emit=emit
-                )
-                return _join_output(
-                    l, r, on, left_idx, right_idx, matched, None
-                )
-            left_idx, right_idx, _, _ = _expand(
-                perm_r, lo, counts, cap, left_outer=False
-            )
-            # no matched/row_valid masks, matching the exact-path
-            # inner_join output schema; rows past ``total`` are garbage
-            # behind the logical row count
-            return _join_output(l, r, on, left_idx, right_idx, None, None)
-
-        return fn
-
     p2 = buckets.cached_jit(
         _key("join.mat." + how, {"on": on}, lt, rt, extra=(cap,)),
-        build_mat, "srt_bucketed_join_mat", scope="srt.join",
+        lambda: join_mat_program(on, cap, how == "left"),
+        "srt_bucketed_join_mat", scope="srt.join",
     )
     out = p2(_strip(lt), _strip(rt), perm_r, lo, counts, _n_dev(lt))
     return _finish(out, total)

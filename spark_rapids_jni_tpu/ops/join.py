@@ -95,14 +95,16 @@ def _key_words(cols: Sequence[Column]) -> tuple[list[jax.Array], jax.Array]:
 
 def _lex_searchsorted(
     sorted_words: list[jax.Array], query_words: list[jax.Array], side: str,
-    unroll: bool = False,
+    unroll: bool = False, first=0,
 ) -> jax.Array:
     """Vectorized multi-word binary search (lower/upper bound).
     ``unroll`` lays the steps out flat (no loop in the program): for a
-    query side as narrow as the direct probe's table."""
+    query side as narrow as the direct probe's table. ``first`` (a
+    scalar, may be traced) starts the search there: the rows in front
+    of it are never read."""
     m = sorted_words[0].shape[0]
     nq = query_words[0].shape[0]
-    lo = jnp.zeros((nq,), dtype=jnp.int32)
+    lo = jnp.full((nq,), first, dtype=jnp.int32)
     hi = jnp.full((nq,), m, dtype=jnp.int32)
     steps = max(1, int(np.ceil(np.log2(m + 1)))) if m > 0 else 1
 
@@ -234,10 +236,11 @@ def _probe_build(
 
 
 # The direct probe's table may be this many times the build side's
-# bucket. Provenance (TPU v5e; PERF.md §5 and §6, PRs 26-28): a gather
-# costs 7-8.6 ns an output element whatever it reads from, so the search
-# costs `8 x ceil(log2(m + 1))` of them a probe row (112 for a 2^13
-# build side: 7.29 s over 2^23 rows) and the table as many a table
+# bucket. Provenance (TPU v5e; PERF.md §5 and §6, PRs 26-28, 40): a
+# gather costs 7-8.6 ns an output element, so the search over every
+# order word costs `8 x ceil(log2(m + 1))` of them a probe row (112 for
+# a 2^13 build side: 7.29 s over 2^23 rows; a quarter of that over one
+# u32 word, `_probe_offsets`) and the table a quarter as many a table
 # entry, once, plus ONE a probe row (measured, PR 28: the 16,384-entry
 # table 13.1 ms, the one 2^23-wide gather 59.8 ms). Held to 2, the table
 # never costs more than two searches of the build side through itself.
@@ -318,24 +321,73 @@ def direct_table_size(
     return size
 
 
+def offsets_fit(kmin: int, kmax: int, valid_rows: int) -> bool:
+    """True when a build side whose valid keys span ``[kmin, kmax]``
+    (`build_key_span`'s words, as host integers) can be searched as ONE
+    u32 word a row (`_probe_offsets`): the span is under 2^32 wide."""
+    return valid_rows > 0 and kmax - kmin < 1 << 32
+
+
+def _build_offsets(sorted_words):
+    """A `direct_key` build side whose valid keys span under 2^32
+    values, as ONE u32 word a row: ``(first, kmin, kmax, offsets)``, the
+    first valid row of the sorted build side (invalid rows sort in front
+    of it), the span's ends, and every sorted key's distance from
+    ``kmin``. In front of ``first`` the distances are garbage, which a
+    search that starts there never reads.
+
+    Why one word (TPU v5e, PERF.md §6, PR 40): the search gathers from
+    each word's table once a step, and of the four u32 tables a side
+    that the validity word and a 64-bit key word make, the compiler
+    keeps one in the fast memory space; gathers from the other three
+    cost 2-3.7x as much, by the data."""
+    valid_w, key_w = sorted_words
+    m = key_w.shape[0]
+    first = m - jnp.sum(valid_w).astype(jnp.int32)
+    kmin = key_w[jnp.clip(first, 0, m - 1)]
+    kmax = key_w[m - 1]
+    return first, kmin, kmax, (key_w - kmin).astype(jnp.uint32)
+
+
+def _probe_offsets(
+    sorted_words,
+    lcols: Sequence[Column],
+    left_valid: Optional[jax.Array] = None,
+):
+    """`_probe_build`'s ``(lo, counts, lvalid)``, bit for bit, by the
+    same two searches over ONE u32 word a side (`_build_offsets`), for
+    a `direct_key` join whose build side the caller read: `offsets_fit`.
+    A probe key outside the span is decided on the order words before
+    the subtraction, as in `_direct_address`: below it the search's
+    ``lo`` is the first valid row, above it the end."""
+    (q,), lvalid = _key_words(lcols)
+    if left_valid is not None:
+        lvalid = lvalid & left_valid
+    m = sorted_words[1].shape[0]
+    first, kmin, kmax, offsets = _build_offsets(sorted_words)
+    below, above = q < kmin, q > kmax
+    query = [(q - kmin).astype(jnp.uint32)]  # wrapped outside the span
+    s_lo = _lex_searchsorted([offsets], query, "left", first=first)
+    s_hi = _lex_searchsorted([offsets], query, "right", first=first)
+    lo = jnp.where(below, first, jnp.where(above, jnp.int32(m), s_lo))
+    counts = jnp.where(lvalid & ~(below | above), s_hi - s_lo, 0)
+    return lo, counts, lvalid
+
+
 def _direct_table(sorted_words, table_size: int):
     """The direct probe's table, at the TABLE's width: the search's own
     ``(lo, count)`` for every key of the build side's span, with the
-    span's ends and the first valid row of the sorted build side."""
-    valid_w, key_w = sorted_words
-    m = key_w.shape[0]
-    first = m - jnp.sum(valid_w).astype(jnp.int32)  # invalid rows sort first
-    kmin = key_w[jnp.clip(first, 0, m - 1)]
-    kmax = key_w[m - 1]
-
-    # a target past 2^64 wraps below kmin and finds nothing: no probe
-    # key addresses it
-    targets = [
-        jnp.ones((table_size,), jnp.uint64),
-        kmin + jnp.arange(table_size, dtype=jnp.uint64),
-    ]
-    t_lo = _lex_searchsorted(sorted_words, targets, "left", unroll=True)
-    t_hi = _lex_searchsorted(sorted_words, targets, "right", unroll=True)
+    span's ends and the first valid row of the sorted build side. An
+    entry past the span's end finds nothing, and no probe key addresses
+    it."""
+    first, kmin, kmax, offsets = _build_offsets(sorted_words)
+    targets = [jnp.arange(table_size, dtype=jnp.uint32)]
+    t_lo = _lex_searchsorted(
+        [offsets], targets, "left", unroll=True, first=first
+    )
+    t_hi = _lex_searchsorted(
+        [offsets], targets, "right", unroll=True, first=first
+    )
     return first, kmin, kmax, t_lo, t_hi - t_lo
 
 
@@ -440,6 +492,7 @@ def _match_ranges(
     left_valid: Optional[jax.Array] = None,
     right_valid: Optional[jax.Array] = None,
     table_size: Optional[int] = None,
+    narrow: bool = False,
 ):
     """Per-left-row [lo, hi) match range into the sorted right side.
 
@@ -456,7 +509,9 @@ def _match_ranges(
     ``table_size`` (static; `direct_table_size`, chosen by a caller that
     could read the build side's key span) resolves the left keys by
     address (`_probe_direct`); None, what every caller that cannot read
-    passes, searches. The answer is the same.
+    passes, searches: over ONE u32 word a side where the same caller
+    found that `offsets_fit` (``narrow``, static; `_probe_offsets`),
+    else over every order word (`_probe_build`). The answer is the same.
     """
     lcols = [left.column(c) for c in left_on]
     rcols = [right.column(c) for c in right_on]
@@ -464,16 +519,19 @@ def _match_ranges(
     perm_r, sorted_words = _prepare_build(
         right, right_on, right_valid, rcols=rcols
     )
-    if table_size is None:
+    if table_size is None and not narrow:
         lo, counts, lvalid = _probe_build(
             sorted_words, left, left_on, left_valid, lcols=lcols
         )
+        return perm_r, lo, counts, lvalid
+    if not direct_key(lcols, rcols):
+        raise TypeError(
+            "a direct-address probe and a one-word search need one "
+            "integer-family key column of at most 64 bits a side"
+        )
+    if table_size is None:
+        lo, counts, lvalid = _probe_offsets(sorted_words, lcols, left_valid)
     else:
-        if not direct_key(lcols, rcols):
-            raise TypeError(
-                "a direct-address probe needs one integer-family key "
-                "column of at most 64 bits a side"
-            )
         lo, counts, lvalid = _probe_direct(
             sorted_words, table_size, lcols, left_valid
         )

@@ -409,6 +409,74 @@ def test_resident_query_fused_segment_compiles(one_chip, as_tpu):
     assert sorted(wide) == ["f32", "f32", "s32"], wide
 
 
+# perfbench's ``tpch-q3-part.q3-resident`` cell: the lineitem join's two
+# programs as ``bucketed._r_join`` builds them, at the cell's buckets
+Q3_PROBE = (dt.INT64, dt.decimal64(-2), dt.decimal64(-2))  # 2^23: kept lines
+Q3_BUILD = (dt.INT64, dt.INT32, dt.INT32)                  # 2^18: open orders
+Q3_BUILD_BUCKET = 1 << 18
+Q3_OUT_BUCKET = 1 << 16
+
+
+def _q3_probe():
+    """The probe program `bucketed._r_join` compiles for that join: its
+    own builder, with the choice the runner makes for a sparse INT64
+    key whose span fits 32 bits (no table; the one-word search)."""
+    from spark_rapids_jni_tpu import bucketed
+
+    return bucketed.join_probe_program([0], None, True)
+
+
+def _wide_gathers(compiled, width: int) -> list:
+    import re
+
+    return re.findall(rf"= \w+\[{width}\]\S* gather\(", compiled.as_text())
+
+
+def test_q3_search_probe_compiles_at_the_cell_s_buckets(one_chip, as_tpu):
+    """The fact-to-fact join of TPC-H Q3 (2^23 kept ``lineitem`` rows
+    against the 2^18-row bucket of the open orders, a sparse INT64 key:
+    `direct_table_size` answers None, `offsets_fit` True) takes the
+    one-word SEARCH: the build side's sort and `_lex_searchsorted`
+    twice, each a loop of ``ceil(log2(2^18 + 1))`` = 19 steps of ONE
+    u32 gather at the probe side's width, from a table the compiler
+    keeps in the fast memory space (`S(1)`: what makes a step cost its
+    8.6 ns an element whatever the data, PERF.md §6)."""
+    import re
+
+    probe = _table(one_chip, Q3_PROBE, SMOKE_BUCKET)
+    build = _table(one_chip, Q3_BUILD, Q3_BUILD_BUCKET)
+    n32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(_q3_probe()).lower(probe, build, n32, n32).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    assert len(re.findall(r"\bwhile\(", text)) == 2
+    assert len(_wide_gathers(compiled, SMOKE_BUCKET)) == 2
+
+
+def test_q3_join_materialise_compiles_at_the_cell_s_buckets(one_chip, as_tpu):
+    """...and its materialise: ~40,000 matched rows (bucket 2^16) of
+    the 2^23-row probe side, each with the build side's date and
+    priority, which the next op reads as group keys. Nothing in it is
+    as wide as the probe side but the cumsum that places the rows: every
+    gather is at the OUTPUT's bucket."""
+    from spark_rapids_jni_tpu import bucketed
+
+    probe = _table(one_chip, Q3_PROBE, SMOKE_BUCKET)
+    build = _table(one_chip, Q3_BUILD, Q3_BUILD_BUCKET)
+    n32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    perm_r, lo, counts = (
+        jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+        for x in jax.eval_shape(_q3_probe(), probe, build, n32, n32)[:3]
+    )
+    fn = bucketed.join_mat_program([0], Q3_OUT_BUCKET, False)
+    compiled = (
+        jax.jit(fn).lower(probe, build, perm_r, lo, counts, n32).compile()
+    )
+    _fits(compiled)
+    assert not _wide_gathers(compiled, SMOKE_BUCKET)
+    assert _wide_gathers(compiled, Q3_OUT_BUCKET)
+
+
 # ---------------------------------------------------------------------------
 # four chips: the mesh partition stage (parallel/planmesh.py) as one
 # program over the described 2x2 mesh

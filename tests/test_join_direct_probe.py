@@ -167,10 +167,11 @@ def _tables(d, lk, lnull, rk, rnull):
 
 
 @functools.lru_cache(maxsize=None)
-def _ranges_fn(table_size):
+def _ranges_fn(table_size, narrow=False):
     def fn(left, right, lv, rv):
         return join_mod._match_ranges(
-            left, right, ["k"], ["k"], lv, rv, table_size=table_size
+            left, right, ["k"], ["k"], lv, rv, table_size=table_size,
+            narrow=narrow,
         )
 
     return jax.jit(fn)
@@ -181,12 +182,14 @@ def _span_fits(rk, rnull, rpad, size):
     return not keys or max(keys) - min(keys) + 1 <= size
 
 
-@pytest.mark.parametrize(
-    "dname,case",
+PROBE_CASES = (
     [(n, c) for n in WIDE for c in CASES]
     + [(n, "null_keys") for n in FAMILY]
-    + [(n, "outside_and_ends") for n in FAMILY if n != "bool8"],
+    + [(n, "outside_and_ends") for n in FAMILY if n != "bool8"]
 )
+
+
+@pytest.mark.parametrize("dname,case", PROBE_CASES)
 def test_direct_probe_equals_the_search(dname, case):
     d = {**WIDE, **FAMILY}[dname]
     lk, lnull, lpad, rk, rnull, rpad = _case(case, d, seed=len(case))
@@ -206,12 +209,59 @@ def test_direct_probe_equals_the_search(dname, case):
     np.testing.assert_array_equal(np.asarray(got[2]), per_left)
 
 
+@pytest.mark.parametrize("dname,case", PROBE_CASES)
+def test_one_word_search_equals_the_search(dname, case):
+    """`_probe_offsets` (PR 40: one u32 word a side, from the build
+    side's first valid row) against `_probe_build` over every order
+    word, on the direct probe's cases with the keys pulled 30,000,000
+    apart where the type has room (a 64-bit key's span then just fits
+    32 bits)."""
+    d = {**WIDE, **FAMILY}[dname]
+    lk, lnull, lpad, rk, rnull, rpad = _case(case, d, seed=len(case))
+    tmin, tmax = _limits(d)
+    # one affine map for both sides keeps which keys match (a probe key
+    # pushed past the type's end stays outside the span)
+    lo = min(rk)
+    step = max(1, min(30_000_000, (tmax - lo) // 200))
+    rk = [lo + (k - lo) * step for k in rk]
+    lk = [min(tmax, max(tmin, lo + (k - lo) * step)) for k in lk]
+    live = [k for k, a, b in zip(rk, rnull, rpad) if not (a or b)]
+    assert join_mod.offsets_fit(min(live, default=0), max(live, default=0),
+                                len(live)) == bool(live)
+    left, right = _tables(d, lk, lnull, rk, rnull)
+    lv, rv = jnp.asarray(~lpad), jnp.asarray(~rpad)
+    want = _ranges_fn(None)(left, right, lv, rv)
+    got = _ranges_fn(None, True)(left, right, lv, rv)
+    for name, w, g in zip(("perm_r", "lo", "counts", "lvalid"), want, got):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
+    per_left = [
+        0 if (a or b) else live.count(k)
+        for k, a, b in zip(lk, lnull, lpad)
+    ]
+    np.testing.assert_array_equal(np.asarray(got[2]), per_left)
+
+
+@pytest.mark.parametrize("kmin,kmax,rows,want", [
+    (5, 5, 1, True),
+    (0, (1 << 32) - 1, 2, True),    # the widest span one u32 holds
+    (7, (1 << 32) + 7, 2, False),   # one past
+    (0, (1 << 64) - 1, 9, False),   # INT64's whole range
+    (5, 5, 0, False),               # no valid key: no span
+])
+def test_offsets_fit_under_32_bits_of_span(kmin, kmax, rows, want):
+    assert join_mod.offsets_fit(kmin, kmax, rows) is want
+
+
 def test_direct_probe_refuses_a_key_it_cannot_address():
     left, right = _tables(dt.INT64, [1, 2], np.zeros(2, bool),
                           [1, 2], np.zeros(2, bool))
     with pytest.raises(TypeError, match="direct-address probe"):
         join_mod._match_ranges(
             left, right, ["k", "lv"], ["k", "rv"], table_size=1024
+        )
+    with pytest.raises(TypeError, match="one-word search"):
+        join_mod._match_ranges(
+            left, right, ["k", "lv"], ["k", "rv"], narrow=True
         )
 
 
@@ -249,7 +299,7 @@ def _padded(t: Table, logical: int) -> Table:
 def _run_join(how, left, right, on=("k",)):
     op = {"op": "join", "how": how, "on": list(on)}
     watched = ["join.probe.direct", "join.probe.search",
-               "bucket.fallback_errors"]
+               "join.probe.narrow", "bucket.fallback_errors"]
     before = metrics.counter_values(watched)
     out = bucketed._r_join(op, left, (right,))
     after = metrics.counter_values(watched)
@@ -295,26 +345,37 @@ def _dense_pair(d, seed=3, n_left=3000, n_right=700):
     return _tables(d, lk, lnull, rk, rnull)
 
 
+# the search a test forces in place of the runner's choice: over every
+# order word, or over one u32 word a side (`_probe_offsets`)
+SEARCHES = {"all_words": (None, False), "one_word": (None, True)}
+
+
+@pytest.mark.parametrize("search", SEARCHES)
 @pytest.mark.parametrize("how", ["inner", "left", "semi", "anti"])
 @pytest.mark.parametrize("dname", ["int64", "int32", "decimal64",
                                    "timestamp_us"])
 def test_served_join_is_the_same_table_by_either_probe(
-    how, dname, monkeypatch
+    how, dname, search, monkeypatch
 ):
     config.set_flag("METRICS", True)
     left, right = _dense_pair(WIDE[dname])
     got, moved = _run_join(how, left, right)
     assert moved == {"join.probe.direct": 1, "join.probe.search": 0,
-                     "bucket.fallback_errors": 0}
-    monkeypatch.setattr(bucketed, "_probe_table_size", lambda *a: None)
+                     "join.probe.narrow": 0, "bucket.fallback_errors": 0}
+    monkeypatch.setattr(bucketed, "_probe_choice",
+                        lambda *a: SEARCHES[search])
     want, moved = _run_join(how, left, right)
     assert moved["join.probe.search"] == 1
+    assert moved["join.probe.narrow"] == (search == "one_word")
     assert got.logical_row_count > 0
     _assert_same_table(got, want)
 
 
+@pytest.mark.parametrize("search", SEARCHES)
 @pytest.mark.parametrize("how", ["inner", "left", "semi", "anti"])
-def test_served_join_of_padded_inputs_by_either_probe(how, monkeypatch):
+def test_served_join_of_padded_inputs_by_either_probe(
+    how, search, monkeypatch
+):
     """Both sides arrive padded (a filter's result, a padded upload):
     the padding rows' zero keys lie inside the span."""
     config.set_flag("METRICS", True)
@@ -324,7 +385,8 @@ def test_served_join_of_padded_inputs_by_either_probe(how, monkeypatch):
     assert left.row_count == 4096 and right.row_count == 1024
     got, moved = _run_join(how, left, right)
     assert moved["join.probe.direct"] == 1
-    monkeypatch.setattr(bucketed, "_probe_table_size", lambda *a: None)
+    monkeypatch.setattr(bucketed, "_probe_choice",
+                        lambda *a: SEARCHES[search])
     want, _ = _run_join(how, left, right)
     _assert_same_table(got, want)
 
@@ -355,7 +417,7 @@ def test_table_wider_than_the_probe_side_is_searched():
     )
     _, moved = _run_join("inner", left, right)
     assert moved == {"join.probe.direct": 0, "join.probe.search": 1,
-                     "bucket.fallback_errors": 0}
+                     "join.probe.narrow": 1, "bucket.fallback_errors": 0}
 
 
 def _sparse_int():
@@ -406,14 +468,24 @@ def _all_null_build():
     return (left, right), ("k",)
 
 
-@pytest.mark.parametrize("make", [
-    _sparse_int, _two_columns, _string_key, _float64_key, _all_null_build,
+def _sparse_past_32_bits():
+    rk = [int(x) * 7_000_003 for x in range(700)]  # spans 4.9e9
+    return _pair_with_build_keys(rk), ("k",)
+
+
+@pytest.mark.parametrize("make,narrow", [
+    (_sparse_int, 1), (_sparse_past_32_bits, 0), (_two_columns, 0),
+    (_string_key, 0), (_float64_key, 0), (_all_null_build, 0),
 ])
-def test_keys_the_table_cannot_address_take_the_search(make):
+def test_keys_the_table_cannot_address_take_the_search(make, narrow):
+    """...over one u32 word a side where the key is one integer column
+    whose valid build keys span under 2^32 values (PR 40), over every
+    order word otherwise."""
     config.set_flag("METRICS", True)
     (left, right), on = make()
     out, moved = _run_join("left", left, right, on)
     assert moved == {"join.probe.direct": 0, "join.probe.search": 1,
+                     "join.probe.narrow": narrow,
                      "bucket.fallback_errors": 0}
     assert out.logical_row_count >= 3000
 
