@@ -302,28 +302,28 @@ def _build_key_facts(rt: Table, on: list) -> tuple:
 
 def _probe_choice(lt: Table, rt: Table, on: list) -> tuple:
     """The one choice of a served join's probe, from what the build side
-    shows: ``(table_size, narrow)``. ``table_size`` is the direct
-    probe's when the key is one integer-family column whose valid build
-    keys are dense (``ops.join.direct_table_size``), None for the
-    search; ``narrow`` says that the search can run over one u32 word a
-    side, because the valid build keys span under 2^32 values
-    (``ops.join.offsets_fit``: a sparse INT64 key of any one table is
-    that). A key of another kind is decided from the schema and
-    searched over all its words; an integer one costs
+    shows: ``(table_size, narrow, unique)``. ``table_size`` is the
+    direct probe's when the key is one integer-family column and a
+    table as wide as its valid build keys' span, however sparse, fits
+    the device's share (``ops.join.direct_table_size``), None for the
+    search; ``unique`` says that no valid build key repeats, so that
+    the table holds a row and no count; ``narrow`` says that the search
+    can run over one u32 word a side: the valid build keys span under
+    2^32 values (``ops.join.offsets_fit``), and only the table's bytes
+    were in the way. A key of another kind is decided from the schema
+    and searched over all its words; an integer one costs
     `_build_key_facts`."""
     from .ops import join as join_mod
 
     if not join_mod.direct_key(
         [lt.column(c) for c in on], [rt.column(c) for c in on]
     ):
-        return None, False
-    kmin, kmax, valid_rows, _ = _build_key_facts(rt, on)
-    table_size = join_mod.direct_table_size(
-        kmin, kmax, valid_rows, rt.row_count, lt.row_count
-    )
-    return table_size, (
-        table_size is None and join_mod.offsets_fit(kmin, kmax, valid_rows)
-    )
+        return None, False, False
+    kmin, kmax, valid_rows, repeats = _build_key_facts(rt, on)
+    table_size = join_mod.direct_table_size(kmin, kmax, valid_rows)
+    if table_size is not None:
+        return table_size, False, not repeats
+    return None, join_mod.offsets_fit(kmin, kmax, valid_rows), False
 
 
 def selecting_table_size(
@@ -336,7 +336,15 @@ def selecting_table_size(
     no value. None for every other join, which stays `_r_join`'s. Read
     from the (padded) build side ``rt`` alone, before the plan is
     segmented; the probe side's key is held to `direct_key` where the
-    segment is traced."""
+    segment is traced.
+
+    Dense, here, is the table no wider than twice the build side nor
+    than the probe side: narrower than `_r_join`'s own probe asks
+    (``ops.join.direct_table_size``), because riding the segment is
+    priced differently: one more probe-wide gather a 32-bit word of
+    every build column read, and the groupby's sort at the probe's
+    width. A sparse unique key may well win there too; it has not been
+    measured (PERF.md §7)."""
     from .ops import join as join_mod
 
     on = op.get("on")
@@ -350,12 +358,15 @@ def selecting_table_size(
     kmin, kmax, valid_rows, repeats = _build_key_facts(rt, on)
     if repeats:
         return None
-    return join_mod.direct_table_size(
-        kmin, kmax, valid_rows, rt.row_count, probe_rows
-    )
+    size = join_mod.direct_table_size(kmin, kmax, valid_rows)
+    if size is None or size > 2 * rt.row_count or size > probe_rows:
+        return None
+    return size
 
 
-def join_probe_program(on: list, table_size: Optional[int], narrow: bool):
+def join_probe_program(
+    on: list, table_size: Optional[int], narrow: bool, unique: bool
+):
     """Phase 1 of a served inner / left join, as `_r_join` compiles it
     (and `tests/test_chip_compile.py`, at a cell's buckets): the match
     ranges of every probe row and both totals."""
@@ -365,7 +376,8 @@ def join_probe_program(on: list, table_size: Optional[int], narrow: bool):
         lv = buckets.tail_valid(l.row_count, ln)
         rv = buckets.tail_valid(r.row_count, rn)
         perm_r, lo, counts, _ = _match_ranges(
-            l, r, on, on, lv, rv, table_size=table_size, narrow=narrow
+            l, r, on, on, lv, rv, table_size=table_size, narrow=narrow,
+            unique=unique,
         )
         return (
             perm_r, lo, counts,
@@ -411,17 +423,18 @@ def _r_join(op: dict, table: Table, rest) -> Table:
     lt = _padded_input(table)
     rt = _padded_input(rest[0])
     on = list(op["on"])
-    table_size, narrow = _probe_choice(lt, rt, on)
+    probe = table_size, narrow, unique = _probe_choice(lt, rt, on)
     metrics.counter_add(
         "join.probe.search" if table_size is None else "join.probe.direct"
     )
     if narrow:
         metrics.counter_add("join.probe.narrow")
     # logical rows of the two sides and (below) of the result, a served
-    # join: beside groupby.input_rows / groupby.reduce_rows
+    # join, and the entries of the table it probed (none: a search):
+    # beside groupby.input_rows / groupby.reduce_rows
     metrics.counter_add("join.probe_rows", lt.logical_row_count)
     metrics.counter_add("join.build_rows", rt.logical_row_count)
-    probe = (table_size, narrow)
+    metrics.counter_add("join.table_entries", table_size or 0)
 
     if how in ("semi", "anti"):
         anti = how == "anti"
@@ -435,7 +448,7 @@ def _r_join(op: dict, table: Table, rest) -> Table:
                 rv = buckets.tail_valid(r.row_count, rn)
                 _, _, counts, lvalid = _match_ranges(
                     l, r, on, on, lv, rv,
-                    table_size=table_size, narrow=narrow,
+                    table_size=table_size, narrow=narrow, unique=unique,
                 )
                 has = jnp.logical_and(counts > 0, lvalid)
                 if anti:
@@ -466,7 +479,7 @@ def _r_join(op: dict, table: Table, rest) -> Table:
     # executables across a ragged stream.
     p1 = buckets.cached_jit(
         _key("join.ranges", {"on": on}, lt, rt, extra=probe),
-        lambda: join_probe_program(on, table_size, narrow),
+        lambda: join_probe_program(on, table_size, narrow, unique),
         "srt_bucketed_join_probe", scope="srt.join",
     )
     perm_r, lo, counts, inner_total, left_total = p1(

@@ -95,13 +95,11 @@ def _key_words(cols: Sequence[Column]) -> tuple[list[jax.Array], jax.Array]:
 
 def _lex_searchsorted(
     sorted_words: list[jax.Array], query_words: list[jax.Array], side: str,
-    unroll: bool = False, first=0,
+    first=0,
 ) -> jax.Array:
     """Vectorized multi-word binary search (lower/upper bound).
-    ``unroll`` lays the steps out flat (no loop in the program): for a
-    query side as narrow as the direct probe's table. ``first`` (a
-    scalar, may be traced) starts the search there: the rows in front
-    of it are never read."""
+    ``first`` (a scalar, may be traced) starts the search there: the
+    rows in front of it are never read."""
     m = sorted_words[0].shape[0]
     nq = query_words[0].shape[0]
     lo = jnp.full((nq,), first, dtype=jnp.int32)
@@ -125,7 +123,7 @@ def _lex_searchsorted(
         hi = jnp.where(active & ~go_right, mid, hi)
         return lo, hi
 
-    lo, hi = jax.lax.fori_loop(0, steps, body, (lo, hi), unroll=unroll)
+    lo, hi = jax.lax.fori_loop(0, steps, body, (lo, hi))
     return lo
 
 
@@ -222,7 +220,9 @@ def _probe_build(
     left_valid: Optional[jax.Array] = None,
     lcols: Optional[Sequence[Column]] = None,
 ):
-    """Binary-search the prepared build side: (lo, counts, lvalid)."""
+    """Binary-search the prepared build side: (lo, counts, lvalid).
+    ``lo`` is a row's first match in the sorted build side and, where
+    it has none, the build side's first valid row (`_no_match_lo`)."""
     if lcols is None:
         lcols = [left.column(c) for c in left_on]
     lwords, lvalid = _key_words(lcols)
@@ -232,23 +232,53 @@ def _probe_build(
     lo = _lex_searchsorted(sorted_words, qwords, "left")
     hi = _lex_searchsorted(sorted_words, qwords, "right")
     counts = jnp.where(lvalid, hi - lo, 0)
-    return lo, counts, lvalid
+    first = _first_valid(sorted_words)
+    return _no_match_lo(lo, counts, first), counts, lvalid
 
 
-# The direct probe's table may be this many times the build side's
-# bucket. Provenance (TPU v5e; PERF.md §5 and §6, PRs 26-28, 40): a
-# gather costs 7-8.6 ns an output element, so the search over every
-# order word costs `8 x ceil(log2(m + 1))` of them a probe row (112 for
-# a 2^13 build side: 7.29 s over 2^23 rows; a quarter of that over one
-# u32 word, `_probe_offsets`) and the table a quarter as many a table
-# entry, once, plus ONE a probe row (measured, PR 28: the 16,384-entry
-# table 13.1 ms, the one 2^23-wide gather 59.8 ms). Held to 2, the table
-# never costs more than two searches of the build side through itself.
-# 2 is also the one value a cell shows: a surrogate key sampled at 2/3
-# (6,666 of [0, 10,000)) spans 1.5 x its rows, the next bucket. A
-# sparser key would still win while the table is narrower than the probe
-# side; raise this with the cell that has one.
-DIRECT_TABLE_MAX_FACTOR = 2
+def _first_valid(sorted_words) -> jax.Array:
+    """The first valid row of the sorted build side (its width when no
+    row is valid): the invalid rows sort in front of it."""
+    valid_w = sorted_words[0]
+    return valid_w.shape[0] - jnp.sum(valid_w).astype(jnp.int32)
+
+
+def _no_match_lo(lo, counts, first):
+    """``lo`` as every probe returns it: a row's first match and, where
+    it has none, ``first``. Nothing reads it there (`_expand`,
+    `_left_emit`, the semi / anti keep) but the build columns of a left
+    join's null rows, garbage behind their mask; ONE value, which every
+    probe knows (a hole of `_probe_direct`'s table holds no insertion
+    point) and no padding moves (the same build row whether the side
+    arrived padded or not), keeps that garbage the same bytes by any
+    probe, on the bucketed and on the exact path."""
+    return jnp.where(counts > 0, lo, first)
+
+
+# The direct probe's table (four bytes an entry, as wide as the build
+# keys' span) may take one part in this many of the device's budget
+# (`utils.hbm.budget_bytes`): on a 16 GB TPU v5e 2^27 entries, 512 MiB,
+# and not 2^28. Memory is what ends it, not time. Provenance (TPU v5e;
+# PERF.md §6, PR 41's stand-alone sweep, two seeds): filling a table
+# and scattering the build rows into it costs 2.5 ms for 2^18 rows and
+# 13.2 ms for 2^21 at 2^26 entries (3.0 / 13.5 at 2^27: ~5 ns an update
+# and the fill's bytes, whatever the span; 1.75 ms in Q3's request), and
+# the ONE gather a probe side of 2^23 rows then makes costs 0.073 s
+# from a table of up to 2^24 entries, which the compiler keeps in the
+# fast memory space, and 0.12-0.24 s by its addresses from 2^25, 2^26
+# or 2^27 entries in HBM, no more at 2^27 than at 2^25 (0.150 s in
+# Q3's request, at 2^26). The search it replaces gathers
+# `2 x ceil(log2(m + 1))` times at that width over one u32 word
+# (`_probe_offsets`: 38 x 0.072 s against 2^18 build rows), so the
+# table wins by 10x at every width measured, and a share of the chip is
+# the honest limit: with P shuffle partitions a TPC-H order key spans
+# about P times a partition's `lineitem` rows (60 M at P = 7 over
+# batches of 8 M: 2^26 entries), so 2^27 holds P = 15 at that batch
+# and the search takes over from there, as it does for a span past
+# 2^30. 2^28 entries were not measured.
+DIRECT_TABLE_BUDGET_SHARE = 16
+# no table is narrower: spans under this share one executable
+DIRECT_TABLE_MIN_ENTRIES = 1024
 
 
 def addressable_key(cols: Sequence[Column]) -> bool:
@@ -300,23 +330,21 @@ def build_key_span(
     ])
 
 
-def direct_table_size(
-    kmin: int, kmax: int, valid_rows: int, build_rows: int, probe_rows: int
-) -> Optional[int]:
+def direct_table_size(kmin: int, kmax: int, valid_rows: int) -> Optional[int]:
     """Table size ``T`` of the direct probe for a build side whose valid
     keys span ``[kmin, kmax]`` (`build_key_span`'s words, as host
-    integers), or None for the search: ``T`` is the span's bucket, taken when it is at
-    most `DIRECT_TABLE_MAX_FACTOR` times the build side's width and no
-    wider than the probe side's. A build side with no valid key has no
-    span."""
-    from ..utils import buckets
+    integers), or None for the search: ``T`` is the span's next power
+    of two (not a row bucket: the ladder's cap bounds batches, not
+    tables), taken while its four-byte entries stay within
+    `DIRECT_TABLE_BUDGET_SHARE` of the device's budget. A build side
+    with no valid key has no span, and a span past 2^30 no int32
+    address (4 GiB of entries: no chip's share)."""
+    from ..utils import hbm
 
-    if valid_rows <= 0:
+    if valid_rows <= 0 or kmax - kmin >= 1 << 30:
         return None
-    size = buckets.bucket_for(kmax - kmin + 1)
-    if size is None:
-        return None
-    if size > DIRECT_TABLE_MAX_FACTOR * build_rows or size > probe_rows:
+    size = max(DIRECT_TABLE_MIN_ENTRIES, 1 << (kmax - kmin).bit_length())
+    if 4 * size * DIRECT_TABLE_BUDGET_SHARE > hbm.budget_bytes():
         return None
     return size
 
@@ -341,9 +369,9 @@ def _build_offsets(sorted_words):
     that the validity word and a 64-bit key word make, the compiler
     keeps one in the fast memory space; gathers from the other three
     cost 2-3.7x as much, by the data."""
-    valid_w, key_w = sorted_words
+    key_w = sorted_words[1]
     m = key_w.shape[0]
-    first = m - jnp.sum(valid_w).astype(jnp.int32)
+    first = _first_valid(sorted_words)
     kmin = key_w[jnp.clip(first, 0, m - 1)]
     kmax = key_w[m - 1]
     return first, kmin, kmax, (key_w - kmin).astype(jnp.uint32)
@@ -358,37 +386,56 @@ def _probe_offsets(
     same two searches over ONE u32 word a side (`_build_offsets`), for
     a `direct_key` join whose build side the caller read: `offsets_fit`.
     A probe key outside the span is decided on the order words before
-    the subtraction, as in `_direct_address`: below it the search's
-    ``lo`` is the first valid row, above it the end."""
+    the subtraction, as in `_direct_address`, and matches nothing."""
     (q,), lvalid = _key_words(lcols)
     if left_valid is not None:
         lvalid = lvalid & left_valid
-    m = sorted_words[1].shape[0]
     first, kmin, kmax, offsets = _build_offsets(sorted_words)
-    below, above = q < kmin, q > kmax
     query = [(q - kmin).astype(jnp.uint32)]  # wrapped outside the span
     s_lo = _lex_searchsorted([offsets], query, "left", first=first)
     s_hi = _lex_searchsorted([offsets], query, "right", first=first)
-    lo = jnp.where(below, first, jnp.where(above, jnp.int32(m), s_lo))
-    counts = jnp.where(lvalid & ~(below | above), s_hi - s_lo, 0)
-    return lo, counts, lvalid
+    counts = jnp.where(lvalid & (q >= kmin) & (q <= kmax), s_hi - s_lo, 0)
+    return _no_match_lo(s_lo, counts, first), counts, lvalid
 
 
-def _direct_table(sorted_words, table_size: int):
-    """The direct probe's table, at the TABLE's width: the search's own
-    ``(lo, count)`` for every key of the build side's span, with the
-    span's ends and the first valid row of the sorted build side. An
-    entry past the span's end finds nothing, and no probe key addresses
-    it."""
+def _table_heads(sorted_words, table_size: int):
+    """Where the direct probe's table is written: ``(first, kmin, kmax,
+    addr, run)``, `_build_offsets`' first three and two arrays at the
+    build side's width. ``addr[i]`` is the table entry of sorted build
+    row ``i``, its key's distance from ``kmin``, where ``i`` is the
+    HEAD of a run of equal valid keys, and ``table_size`` (past the
+    end: dropped) everywhere else: the null and padded rows, which sort
+    in front of the first valid one, the rest of a run, and a key whose
+    distance does not fit the table (a span wider than the caller
+    said). ``run[i]`` is the length of the run a head starts (garbage
+    elsewhere)."""
     first, kmin, kmax, offsets = _build_offsets(sorted_words)
-    targets = [jnp.arange(table_size, dtype=jnp.uint32)]
-    t_lo = _lex_searchsorted(
-        [offsets], targets, "left", unroll=True, first=first
+    m = offsets.shape[0]
+    i = jnp.arange(m, dtype=jnp.int32)
+    head = (i >= first) & ((i == first) | (offsets != jnp.roll(offsets, 1)))
+    # the next head behind each row (m behind the last), by a running
+    # minimum from the end: a head's run ends where the next one starts
+    nxt = jax.lax.cummin(jnp.where(head, i, jnp.int32(m)), reverse=True)
+    run = jnp.concatenate([nxt[1:], jnp.full((1,), m, jnp.int32)]) - i
+    addr = jnp.where(
+        head, jnp.minimum(offsets, jnp.uint32(table_size)),
+        jnp.uint32(table_size),
+    ).astype(jnp.int32)
+    return first, kmin, kmax, addr, run
+
+
+def _direct_table(addr, values, table_size: int, hole):
+    """The direct probe's table, filled by ONE scatter of the build
+    rows: ``values`` at `_table_heads`' addresses, ``hole`` at every
+    key of the span that no valid build row holds.
+
+    What it costs (TPU v5e, PERF.md §6, PR 41): one word an entry of
+    fill and one update a build row, whatever the span; the search's
+    own answer for every entry, which this replaced, cost
+    ``2 x ceil(log2(m + 1))`` gathers at the TABLE's width."""
+    return jnp.full((table_size,), hole, values.dtype).at[addr].set(
+        values, mode="drop"
     )
-    t_hi = _lex_searchsorted(
-        [offsets], targets, "right", unroll=True, first=first
-    )
-    return first, kmin, kmax, t_lo, t_hi - t_lo
 
 
 def _direct_address(q, kmin, kmax, table_size: int):
@@ -406,38 +453,42 @@ def _probe_direct(
     table_size: int,
     lcols: Sequence[Column],
     left_valid: Optional[jax.Array] = None,
+    unique: bool = False,
 ):
     """`_probe_build`'s ``(lo, counts, lvalid)``, bit for bit, by address
     instead of by search, for a build side whose valid keys span at most
     ``table_size`` values (`direct_table_size` chose it from the same
-    build side; a wider span is the caller's fault and reads the table's
-    last entry).
+    build side).
 
-    The search's own answer for every key of the span goes into a table
-    at the TABLE's width (`_direct_table`); a probe row then costs
-    elementwise work on its key and one gather at ``key - kmin``
-    (`_direct_address`). Below and above the span the search's ``lo`` is
-    known without it (the first valid row, the end)."""
+    A probe row costs elementwise work on its key and one gather at
+    ``key - kmin`` (`_direct_address`) from the table `_direct_table`
+    scatters: each key's first row in the sorted build side. ``unique``
+    (static: the caller read that no valid build key repeats) makes a
+    hit a count of one; otherwise the run's length rides the same word
+    where both fit 32 bits, or is one more gather, from the build
+    side's width."""
     (q,), lvalid = _key_words(lcols)
     if left_valid is not None:
         lvalid = lvalid & left_valid
     m = sorted_words[1].shape[0]
-    first, kmin, kmax, t_lo, t_cnt = _direct_table(sorted_words, table_size)
+    first, kmin, kmax, addr, run = _table_heads(sorted_words, table_size)
     below, above, off = _direct_address(q, kmin, kmax, table_size)
-    inside = ~(below | above)
-    bits = int(m).bit_length()  # lo and cnt lie in [0, m]
-    if 2 * bits <= 32:
-        # one gather carries both
-        packed = (t_lo.astype(jnp.uint32) << bits) | t_cnt.astype(jnp.uint32)
-        got = packed[off]
+    inside = lvalid & ~(below | above)
+    i = jnp.arange(m, dtype=jnp.int32)
+    bits = int(m).bit_length()  # lo and a run's length lie in [0, m]
+    if not unique and 2 * bits <= 32:
+        # one word carries both: a hole's is 0, a count of none
+        packed = (i.astype(jnp.uint32) << bits) | run.astype(jnp.uint32)
+        got = _direct_table(addr, packed, table_size, 0)[off]
         g_lo = (got >> bits).astype(jnp.int32)
         g_cnt = (got & jnp.uint32((1 << bits) - 1)).astype(jnp.int32)
+        hit = inside & (g_cnt > 0)
     else:
-        g_lo = t_lo[off]
-        g_cnt = t_cnt[off]
-    lo = jnp.where(below, first, jnp.where(above, jnp.int32(m), g_lo))
-    counts = jnp.where(lvalid & inside, g_cnt, 0)
-    return lo, counts, lvalid
+        g_lo = _direct_table(addr, i, table_size, -1)[off]
+        hit = inside & (g_lo >= 0)
+        g_cnt = jnp.int32(1) if unique else run[jnp.maximum(g_lo, 0)]
+    counts = jnp.where(hit, g_cnt, 0)
+    return _no_match_lo(g_lo, counts, first), counts, lvalid
 
 
 def lookup_unique(
@@ -463,19 +514,17 @@ def lookup_unique(
     `_match_ranges`.
 
     One gather at the probe's width finds each row's build row through
-    the direct probe's address (the table holds the build ROW of every
-    key of the span, -1 where the span has a hole); a build column then
-    costs one more a 32-bit word, and none when nothing reads it."""
+    the direct probe's address (`_direct_table` scatters the build ROW
+    of every key of the span, -1 where the span has a hole); a build
+    column then costs one more a 32-bit word, and none when nothing
+    reads it."""
     perm_r, sorted_words = _prepare_build(right, right_on, right_valid)
     (q,), lvalid = _key_words([left.column(c) for c in left_on])
     if left_valid is not None:
         lvalid = lvalid & left_valid
-    m = perm_r.shape[0]
-    _, kmin, kmax, t_lo, t_cnt = _direct_table(sorted_words, table_size)
     # one 32-bit word an entry (lexsort's permutation is int64 here)
-    t_row = jnp.where(
-        t_cnt > 0, perm_r[jnp.clip(t_lo, 0, m - 1)].astype(jnp.int32), -1
-    )
+    _, kmin, kmax, addr, _ = _table_heads(sorted_words, table_size)
+    t_row = _direct_table(addr, perm_r.astype(jnp.int32), table_size, -1)
     below, above, off = _direct_address(q, kmin, kmax, table_size)
     right_idx = jnp.where(lvalid & ~(below | above), t_row[off], -1)
     out = _join_output(
@@ -493,6 +542,7 @@ def _match_ranges(
     right_valid: Optional[jax.Array] = None,
     table_size: Optional[int] = None,
     narrow: bool = False,
+    unique: bool = False,
 ):
     """Per-left-row [lo, hi) match range into the sorted right side.
 
@@ -508,10 +558,12 @@ def _match_ranges(
 
     ``table_size`` (static; `direct_table_size`, chosen by a caller that
     could read the build side's key span) resolves the left keys by
-    address (`_probe_direct`); None, what every caller that cannot read
-    passes, searches: over ONE u32 word a side where the same caller
-    found that `offsets_fit` (``narrow``, static; `_probe_offsets`),
-    else over every order word (`_probe_build`). The answer is the same.
+    address (`_probe_direct`; ``unique``, static: the same caller read
+    that no valid build key repeats); None, what every caller that
+    cannot read passes, searches: over ONE u32 word a side where the
+    same caller found that `offsets_fit` (``narrow``, static;
+    `_probe_offsets`), else over every order word (`_probe_build`). The
+    answer is the same.
     """
     lcols = [left.column(c) for c in left_on]
     rcols = [right.column(c) for c in right_on]
@@ -533,7 +585,7 @@ def _match_ranges(
         lo, counts, lvalid = _probe_offsets(sorted_words, lcols, left_valid)
     else:
         lo, counts, lvalid = _probe_direct(
-            sorted_words, table_size, lcols, left_valid
+            sorted_words, table_size, lcols, left_valid, unique
         )
     return perm_r, lo, counts, lvalid
 

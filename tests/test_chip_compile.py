@@ -244,9 +244,10 @@ def test_capped_inner_join_compiles_at_smoke_bucket(one_chip, as_tpu):
 
 def test_direct_join_probe_compiles_without_a_loop(one_chip, as_tpu):
     """The resident query's probe (PR 28): 2^23 fact keys against a
-    2^13-row dimension through a 2^14-entry table. As the chip's
-    compiler leaves it, the program has no loop and gathers ONCE at the
-    fact side's width, where the search gathers eight times in two."""
+    2^13-row dimension through a 2^14-entry table, which one scatter of
+    the dimension's rows fills (PR 41). As the chip's compiler leaves
+    it, the program has no loop and gathers ONCE at the fact side's
+    width, where the search gathers eight times in two."""
     import re
 
     from spark_rapids_jni_tpu.ops import join as join_mod
@@ -269,6 +270,8 @@ def test_direct_join_probe_compiles_without_a_loop(one_chip, as_tpu):
     assert not re.search(r"\bwhile\(", hlo)
     wide = re.findall(rf"= \w+\[{SMOKE_BUCKET}\]\S* gather\(", hlo)
     assert 1 <= len(wide) <= 2, wide
+    assert len(re.findall(rf"= \w+\[{1 << 14}\]\S* scatter\(", hlo)) == 1
+    assert not re.findall(rf"= \w+\[{1 << 14}\]\S* gather\(", hlo)
 
 
 @pytest.mark.parametrize("form,rows,groups", [
@@ -415,15 +418,22 @@ Q3_PROBE = (dt.INT64, dt.decimal64(-2), dt.decimal64(-2))  # 2^23: kept lines
 Q3_BUILD = (dt.INT64, dt.INT32, dt.INT32)                  # 2^18: open orders
 Q3_BUILD_BUCKET = 1 << 18
 Q3_OUT_BUCKET = 1 << 16
+#: dbgen's sparse order key, one shuffle partition of seven: the open
+#: orders' keys span ~58 M values, 28 x the partition's orders
+Q3_TABLE = 1 << 26
 
 
-def _q3_probe():
+def _q3_probe(table_size=Q3_TABLE):
     """The probe program `bucketed._r_join` compiles for that join: its
-    own builder, with the choice the runner makes for a sparse INT64
-    key whose span fits 32 bits (no table; the one-word search)."""
+    own builder, with the choice the runner makes for a unique INT64
+    key whose span fits the device's share (a table as wide as the
+    span, a row a key), or, with None, for one whose span is past it
+    and under 2^32 (no table; the one-word search)."""
     from spark_rapids_jni_tpu import bucketed
 
-    return bucketed.join_probe_program([0], None, True)
+    return bucketed.join_probe_program(
+        [0], table_size, table_size is None, table_size is not None
+    )
 
 
 def _wide_gathers(compiled, width: int) -> list:
@@ -432,24 +442,48 @@ def _wide_gathers(compiled, width: int) -> list:
     return re.findall(rf"= \w+\[{width}\]\S* gather\(", compiled.as_text())
 
 
-def test_q3_search_probe_compiles_at_the_cell_s_buckets(one_chip, as_tpu):
-    """The fact-to-fact join of TPC-H Q3 (2^23 kept ``lineitem`` rows
-    against the 2^18-row bucket of the open orders, a sparse INT64 key:
-    `direct_table_size` answers None, `offsets_fit` True) takes the
-    one-word SEARCH: the build side's sort and `_lex_searchsorted`
-    twice, each a loop of ``ceil(log2(2^18 + 1))`` = 19 steps of ONE
-    u32 gather at the probe side's width, from a table the compiler
-    keeps in the fast memory space (`S(1)`: what makes a step cost its
-    8.6 ns an element whatever the data, PERF.md §6)."""
-    import re
-
+def _compile_q3_probe(one_chip, table_size):
     probe = _table(one_chip, Q3_PROBE, SMOKE_BUCKET)
     build = _table(one_chip, Q3_BUILD, Q3_BUILD_BUCKET)
     n32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    compiled = jax.jit(_q3_probe()).lower(probe, build, n32, n32).compile()
+    compiled = (
+        jax.jit(_q3_probe(table_size)).lower(probe, build, n32, n32).compile()
+    )
     _fits(compiled)
+    return compiled
+
+
+def test_q3_table_probe_compiles_at_the_cell_s_buckets(one_chip, as_tpu):
+    """The fact-to-fact join of TPC-H Q3 (2^23 kept ``lineitem`` rows
+    against the 2^18-row bucket of the open orders, a unique INT64 key
+    that spans 2^26 values: `direct_table_size` answers 2^26) takes the
+    TABLE (PR 41): the build side's sort, 268 MB of fill, ONE scatter
+    of the 2^18 build rows and ONE gather at the probe side's width; no
+    loop, no search. The table is too large for the fast memory space,
+    so that gather reads HBM (0.15-0.24 s where an `S(1)` one costs
+    0.073, PERF.md §6), once."""
+    import re
+
+    compiled = _compile_q3_probe(one_chip, Q3_TABLE)
     text = compiled.as_text()
-    assert len(re.findall(r"\bwhile\(", text)) == 2
+    assert not re.search(r"\bwhile\(", text)
+    assert len(_wide_gathers(compiled, SMOKE_BUCKET)) == 1
+    assert not _wide_gathers(compiled, Q3_TABLE)
+    assert len(re.findall(rf"= \w+\[{Q3_TABLE}\]\S* scatter\(", text)) == 1
+
+
+def test_q3_search_probe_compiles_past_the_table_s_bound(one_chip, as_tpu):
+    """...and the path that stays for a span past the device's share
+    (more shuffle partitions, a smaller chip) and under 2^32: the
+    one-word SEARCH at the same buckets, `_lex_searchsorted` twice,
+    each a loop of ``ceil(log2(2^18 + 1))`` = 19 steps of ONE u32 gather
+    at the probe side's width, from a table the compiler keeps in the
+    fast memory space (`S(1)`: what makes a step cost its 8.6 ns an
+    element whatever the data, PERF.md §6)."""
+    import re
+
+    compiled = _compile_q3_probe(one_chip, None)
+    assert len(re.findall(r"\bwhile\(", compiled.as_text())) == 2
     assert len(_wide_gathers(compiled, SMOKE_BUCKET)) == 2
 
 

@@ -38,6 +38,7 @@ from spark_rapids_jni_tpu import planops
 from spark_rapids_jni_tpu import runtime_bridge as rb
 from spark_rapids_jni_tpu.utils import buckets, config, metrics
 
+from test_join_direct_probe import _scatters
 from test_plan import _string_wire
 
 I32, I64 = int(dt.TypeId.INT32), int(dt.TypeId.INT64)
@@ -284,6 +285,33 @@ def test_a_join_that_does_not_qualify_stays_a_boundary(case):
     assert got == exact
 
 
+@pytest.mark.parametrize("keys,probe_rows,rides,served", [
+    # the resident query's shape: 6,666 of [0, 10,000) in a 2^13-row
+    # dimension under 2^23 fact rows
+    ("dense", 1 << 23, 1 << 14, (1 << 14, False, True)),
+    # the same key 50 apart: the table is 64 x the dimension's bucket,
+    # still narrower than the fact side. `_r_join` addresses it (PR
+    # 41: a scatter fills it whatever the span), a segment does not
+    # carry it (riding is priced by the build columns' gathers and the
+    # groupby's sort at the probe's width, not by the table)
+    ("sparse", 1 << 23, None, (1 << 19, False, True)),
+    # dense, under a probe side narrower than the table
+    ("dense", 1 << 13, None, (1 << 14, False, True)),
+])
+def test_a_unique_key_rides_only_within_the_two_limits(
+    keys, probe_rows, rides, served
+):
+    rng = np.random.default_rng(6)
+    k = np.sort(rng.choice(10_000, 6_666, replace=False)).astype(np.int64)
+    if keys == "sparse":
+        k = k * 50
+    rt = bucketed._padded_input(
+        _device([(I64, 0, k.tobytes(), None)], len(k)))
+    assert rt.row_count == 1 << 13
+    assert bucketed.selecting_table_size(_join(), rt, probe_rows) == rides
+    assert bucketed._probe_choice(rt, rt, [0]) == served
+
+
 @pytest.mark.parametrize("ops", [
     [FILTER, _join(), GROUP],
     [_join(), GROUP],
@@ -432,8 +460,8 @@ def _wide_gathers(ops, n=1500):
         bucketed._strip(rt), bucketed._n_dev(rt),
     ).as_text()
     assert "stablehlo.sort" in text
-    assert not re.search(
-        r"stablehlo\.scatter.*indices_are_sorted = false", text)
+    # no compaction: the one scatter fills the probe's table (PR 41)
+    assert _scatters(text) == [(size, rt.row_count)]
     return len(re.findall(
         rf"stablehlo\.gather.*-> tensor<{pt.row_count}x", text))
 

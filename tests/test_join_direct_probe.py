@@ -1,18 +1,28 @@
-"""The direct-address probe of a dense build key (PR 28).
+"""The direct-address probe of an integer build key (PR 28; its table
+filled by one scatter and as wide as the key's span since PR 41).
 
 ``ops.join._probe_direct`` must return what the search
 (``_probe_build``) returns, bit for bit: ``perm_r``, ``lo``, ``counts``
 and ``lvalid`` of ``_match_ranges`` are compared on the same inputs
-with and without ``table_size``, over the cases that could tell an
-address from a search apart (duplicates, nulls and padding on either
-side, keys outside the span and at the type's two ends, a span that
-wraps past the type's largest value, a build side with no valid key).
-Above that, the served runner (``bucketed._r_join``) makes ONE choice
-from the build side's observed key type, span and width — pinned here
-by its two counters and by the joined tables of all four bucketed hows —
-and the lowered dense-key probe holds no loop and at most two gathers
-at the probe side's width.
+with and without ``table_size`` (``lo`` is a row's first match and,
+where it has none, the first valid build row by every probe: a hole of
+the span holds no insertion point, and nothing reads one), over the
+cases that
+could tell an address from a search apart (duplicates, nulls and
+padding on either side, keys outside the span and at the type's two
+ends, a span that wraps past the type's largest value, a span wider
+than the probe side, holes and runs of repeats, a build side with no
+valid key), in each of the table's three forms (a row a key where the
+caller read that no key repeats; row and count in one word; the count
+by a second gather from a build side of 2^16 rows or more). Above that,
+the served runner (``bucketed._r_join``) makes ONE choice from the build
+side's observed key type, span and repeats and the device's budget —
+pinned here by its counters and by the joined tables of all four
+bucketed hows — and the lowered probe holds no loop, one scatter, no
+gather at the table's width and at most two at the probe side's.
 """
+
+import collections
 
 import functools
 import re
@@ -46,6 +56,7 @@ FAMILY = {
 def _clean_flags():
     yield
     config.clear_flag("METRICS")
+    config.clear_flag("HBM_BUDGET_GB")
 
 
 def _np_dtype(d):
@@ -139,6 +150,50 @@ def _case(name, d, seed=0):
         lk[:4] = [base, base + T - 1, base - 1, base + T]
     elif name == "one_key":
         rk = [base + 7] * N_RIGHT
+    elif name == "span_wider_than_probe":
+        # 64 keys over a span of 1,000: three times the probe side
+        rk = draw(base, base + 999, N_RIGHT)
+        rk[0], rk[1] = base, base + 999
+        lk = draw(base - 20, base + 1020, N_LEFT)
+        lk[:40] = rk[:40]
+        lnull[rng.integers(0, N_LEFT, 10)] = True
+    elif name == "holes_and_runs":
+        # holes behind kmin and in front of kmax, runs of repeats right
+        # beside holes; the probe asks for every key of the span, the
+        # two ends, and one outside each end
+        body = [base + 9] * 5 + [base + 10] * 7 + [base + 12] * 3
+        rk = ([base] * 2 + body + [base + 57] * 4 + [base + 60]
+              + [base + 30 + (i % 3) for i in range(N_RIGHT)])[:N_RIGHT]
+        lk = [base - 1 + (i % 63) for i in range(N_LEFT)]
+        rnull[40:44] = True
+        rpad[60:] = True
+    elif name in ("unique_sparse", "unique_nulls_and_padding"):
+        # no valid build key repeats: what `build_key_span` reads as
+        # ``repeats == 0``
+        rk = [base + 2 * int(x) for x in rng.permutation(N_RIGHT)]
+        lk = draw(base - 5, base + 2 * N_RIGHT + 5, N_LEFT)
+        lk[:4] = [base, base + 2 * (N_RIGHT - 1), base - 1,
+                  base + 2 * N_RIGHT - 1]
+        if name == "unique_nulls_and_padding":
+            # the nulled and the padded rows repeat a LIVE key: never
+            # scattered, so the key's one live row stays the answer
+            rk[5], rk[6], rk[50], rk[51] = rk[0], rk[0], rk[1], rk[2]
+            rnull[5:7] = True
+            rpad[50:52] = True
+            lnull[rng.integers(0, N_LEFT, 20)] = True
+            lpad[280:] = True
+    elif name == "wide_build_repeats":
+        # 2^16 build rows: row and count no longer fit one 32-bit word,
+        # so a run's length is the second gather
+        n = 1 << 16
+        rk = draw(base, base + 5000, n)
+        rk[0], rk[1] = base, base + 5000
+        lk = draw(base - 10, base + 5010, N_LEFT)
+        lk[:4] = [base, base + 5000, base - 1, base + 5001]
+        rnull = rng.random(n) < 0.1
+        rpad = np.zeros(n, bool)
+        rpad[n - 3000:] = True
+        rnull[:2] = False
     else:
         raise AssertionError(name)
     if d.is_boolean:
@@ -151,6 +206,14 @@ CASES = (
     "duplicates", "null_keys", "padding", "negative", "outside_and_ends",
     "span_wraps_at_type_max", "span_starts_at_type_min", "all_null_build",
     "all_padding_build", "span_exactly_table", "one_key",
+)
+# PR 41's: the table of a case that needs more than T entries, and the
+# cases whose valid build keys repeat no value (run in the one-table
+# form too)
+TABLE_OF = {"span_wider_than_probe": 1024, "wide_build_repeats": 8192}
+UNIQUE_CASES = ("unique_sparse", "unique_nulls_and_padding")
+SCATTER_CASES = (
+    ("span_wider_than_probe", "holes_and_runs") + UNIQUE_CASES
 )
 
 
@@ -167,14 +230,25 @@ def _tables(d, lk, lnull, rk, rnull):
 
 
 @functools.lru_cache(maxsize=None)
-def _ranges_fn(table_size, narrow=False):
+def _ranges_fn(table_size, narrow=False, unique=False):
     def fn(left, right, lv, rv):
         return join_mod._match_ranges(
             left, right, ["k"], ["k"], lv, rv, table_size=table_size,
-            narrow=narrow,
+            narrow=narrow, unique=unique,
         )
 
     return jax.jit(fn)
+
+
+def _assert_same_ranges(got, want, live_build_rows):
+    """Bit for bit; and ``lo``, which a table's hole cannot give where
+    nothing matches, is the first valid build row there by every probe
+    (the invalid rows sort in front of it)."""
+    for name, w, g in zip(("perm_r", "lo", "counts", "lvalid"), want, got):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
+    lo, counts = np.asarray(got[1]), np.asarray(got[2])
+    first = len(np.asarray(got[0])) - live_build_rows
+    assert (lo[counts == 0] == first).all()
 
 
 def _span_fits(rk, rnull, rpad, size):
@@ -187,26 +261,46 @@ PROBE_CASES = (
     + [(n, "null_keys") for n in FAMILY]
     + [(n, "outside_and_ends") for n in FAMILY if n != "bool8"]
 )
+# (dtype, case, the caller read ``repeats == 0``): every case through
+# the forms that keep a count, the unique ones through the one-table
+# form as well
+DIRECT_CASES = (
+    [(n, c, False) for n, c in PROBE_CASES]
+    + [(n, c, False) for n in WIDE for c in SCATTER_CASES]
+    + [(n, c, True) for n in WIDE for c in UNIQUE_CASES]
+    + [("int64", "wide_build_repeats", False),
+       ("int16", "holes_and_runs", False),
+       ("uint64", "unique_sparse", True)]
+)
 
 
-@pytest.mark.parametrize("dname,case", PROBE_CASES)
-def test_direct_probe_equals_the_search(dname, case):
+def _live_counts(lk, lnull, lpad, rk, rnull, rpad):
+    """An independent count of every probe row's matches."""
+    live = collections.Counter(
+        k for k, a, b in zip(rk, rnull, rpad) if not (a or b)
+    )
+    return [
+        0 if (a or b) else live[k] for k, a, b in zip(lk, lnull, lpad)
+    ]
+
+
+@pytest.mark.parametrize("dname,case,unique", DIRECT_CASES)
+def test_direct_probe_equals_the_search(dname, case, unique):
     d = {**WIDE, **FAMILY}[dname]
     lk, lnull, lpad, rk, rnull, rpad = _case(case, d, seed=len(case))
-    assert _span_fits(rk, rnull, rpad, T)
+    size = TABLE_OF.get(case, T)
+    assert _span_fits(rk, rnull, rpad, size)
     left, right = _tables(d, lk, lnull, rk, rnull)
     lv, rv = jnp.asarray(~lpad), jnp.asarray(~rpad)
     want = _ranges_fn(None)(left, right, lv, rv)
-    got = _ranges_fn(T)(left, right, lv, rv)
-    for name, w, g in zip(("perm_r", "lo", "counts", "lvalid"), want, got):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
+    got = _ranges_fn(size, unique=unique)(left, right, lv, rv)
+    _assert_same_ranges(got, want, int((~(rnull | rpad)).sum()))
     # the cases are not vacuous: an independent count of the matches
-    live_r = [k for k, a, b in zip(rk, rnull, rpad) if not (a or b)]
-    per_left = [
-        0 if (a or b) else live_r.count(k)
-        for k, a, b in zip(lk, lnull, lpad)
-    ]
+    per_left = _live_counts(lk, lnull, lpad, rk, rnull, rpad)
     np.testing.assert_array_equal(np.asarray(got[2]), per_left)
+    assert case.startswith("all_") or sum(per_left) > 0
+    if unique:
+        assert max(per_left) == 1
 
 
 @pytest.mark.parametrize("dname,case", PROBE_CASES)
@@ -232,12 +326,8 @@ def test_one_word_search_equals_the_search(dname, case):
     lv, rv = jnp.asarray(~lpad), jnp.asarray(~rpad)
     want = _ranges_fn(None)(left, right, lv, rv)
     got = _ranges_fn(None, True)(left, right, lv, rv)
-    for name, w, g in zip(("perm_r", "lo", "counts", "lvalid"), want, got):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
-    per_left = [
-        0 if (a or b) else live.count(k)
-        for k, a, b in zip(lk, lnull, lpad)
-    ]
+    _assert_same_ranges(got, want, len(live))
+    per_left = _live_counts(lk, lnull, lpad, rk, rnull, rpad)
     np.testing.assert_array_equal(np.asarray(got[2]), per_left)
 
 
@@ -270,25 +360,34 @@ def test_direct_probe_refuses_a_key_it_cannot_address():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("span,build,probe,want", [
-    (1, 1024, 1024, 1024),
-    (1500, 1024, 4096, 2048),
-    (2048, 1024, 4096, 2048),       # exactly at the threshold
-    (2049, 1024, 4096, None),       # one past: the next bucket is 4 x
-    (1500, 1024, 1024, None),       # wider than the probe side
-    (10000, 8192, 1 << 23, 16384),  # the resident query's dimension
-    ((1 << 23) + 1, 1 << 23, 1 << 23, None),  # no bucket for the span
-    (1 << 64, 1024, 4096, None),    # INT64's whole range
+# the pretend v5e of a CPU run: 16 GiB, 65% of it the budget, a
+# sixteenth of that the table's: 2^27 four-byte entries, not 2^28
+@pytest.mark.parametrize("span,budget_gb,want", [
+    (1, None, 1024),                # no table is narrower
+    (1500, None, 2048),
+    (2048, None, 2048),
+    (2049, None, 4096),             # the span's next power of two
+    (10000, None, 16384),           # the resident query's dimension
+    ((1 << 23) + 1, None, 1 << 24),  # the ladder's cap bounds batches
+    (58_000_000, None, 1 << 26),    # Q3's order key, 1 partition of 7
+    ((1 << 27) - 1, None, 1 << 27),  # one under the bound
+    (1 << 27, None, 1 << 27),       # at it
+    ((1 << 27) + 1, None, None),    # one past: 2^28 entries are 1 GiB
+    (1 << 64, None, None),          # INT64's whole range
+    (1 << 23, 1, 1 << 23),          # a sixteenth of 0.65 GiB: 2^23 ...
+    ((1 << 23) + 1, 1, None),       # ... and not 2^24
+    (1 << 28, 32, 1 << 28),         # a chip twice the size
+    (1 << 30, 1 << 10, 1 << 30),    # whatever the chip, an address ...
+    ((1 << 30) + 1, 1 << 10, None),  # ... is an int32
 ])
-def test_table_size_is_the_spans_bucket_within_the_threshold(
-    span, build, probe, want
+def test_table_size_is_the_spans_power_of_two_within_the_budget(
+    span, budget_gb, want
 ):
+    if budget_gb is not None:
+        config.set_flag("HBM_BUDGET_GB", budget_gb)
     kmin = 5
-    assert join_mod.direct_table_size(
-        kmin, kmin + span - 1, 7, build, probe
-    ) == want
-    assert join_mod.direct_table_size(kmin, kmin + span - 1, 0, build,
-                                      probe) is None
+    assert join_mod.direct_table_size(kmin, kmin + span - 1, 7) == want
+    assert join_mod.direct_table_size(kmin, kmin + span - 1, 0) is None
 
 
 def _padded(t: Table, logical: int) -> Table:
@@ -299,7 +398,8 @@ def _padded(t: Table, logical: int) -> Table:
 def _run_join(how, left, right, on=("k",)):
     op = {"op": "join", "how": how, "on": list(on)}
     watched = ["join.probe.direct", "join.probe.search",
-               "join.probe.narrow", "bucket.fallback_errors"]
+               "join.probe.narrow", "join.table_entries",
+               "bucket.fallback_errors"]
     before = metrics.counter_values(watched)
     out = bucketed._r_join(op, left, (right,))
     after = metrics.counter_values(watched)
@@ -345,28 +445,67 @@ def _dense_pair(d, seed=3, n_left=3000, n_right=700):
     return _tables(d, lk, lnull, rk, rnull)
 
 
+def _sparse_pair(d, repeats, seed=7, n_left=3000, n_right=700):
+    """A fact side and a build side whose key is SPARSE: ~700 keys 1,400
+    apart, a span of 978,601 (a table of 2^20 entries: 256 times the
+    probe side's bucket), unique or in runs of up to three; nulls on
+    both sides, fact keys in the holes and outside the span."""
+    rng = np.random.default_rng(seed)
+    tmin, tmax = _limits(d)
+    base = -200_000
+    ks = [base + 1400 * int(x) for x in rng.permutation(n_right)]
+    if repeats:
+        ks = [ks[i - (i % 3)] for i in range(n_right)]
+    lk = [int(x) for x in rng.choice(np.asarray(ks, dtype=object), n_left)]
+    for i in range(0, n_left, 7):
+        lk[i] += 1 + int(rng.integers(0, 1398))  # a hole of the span
+    lk[:4] = [tmin, tmax, base - 1, max(ks) + 1]
+    lnull = rng.random(n_left) < 0.05
+    rnull = rng.random(n_right) < 0.05
+    rnull[[ks.index(min(ks)), ks.index(max(ks))]] = False  # the span's ends
+    return _tables(d, lk, lnull, ks, rnull)
+
+
+# (the pair, its table's entries, whether the runner reads the build
+# key as unique); the dense pair's nulls and duplicates repeat values
+PAIRS = {
+    "dense": (_dense_pair, 2048, False),
+    "sparse_unique": (lambda d: _sparse_pair(d, False), 1 << 20, True),
+    "sparse_repeats": (lambda d: _sparse_pair(d, True), 1 << 20, False),
+}
+
 # the search a test forces in place of the runner's choice: over every
 # order word, or over one u32 word a side (`_probe_offsets`)
-SEARCHES = {"all_words": (None, False), "one_word": (None, True)}
+SEARCHES = {"all_words": (None, False, False),
+            "one_word": (None, True, False)}
 
 
 @pytest.mark.parametrize("search", SEARCHES)
 @pytest.mark.parametrize("how", ["inner", "left", "semi", "anti"])
-@pytest.mark.parametrize("dname", ["int64", "int32", "decimal64",
-                                   "timestamp_us"])
+@pytest.mark.parametrize("dname,pair", [
+    ("int64", "dense"), ("int32", "dense"), ("decimal64", "dense"),
+    ("timestamp_us", "dense"), ("int64", "sparse_unique"),
+    ("int64", "sparse_repeats"),
+])
 def test_served_join_is_the_same_table_by_either_probe(
-    how, dname, search, monkeypatch
+    how, dname, pair, search, monkeypatch
 ):
     config.set_flag("METRICS", True)
-    left, right = _dense_pair(WIDE[dname])
+    make, entries, unique = PAIRS[pair]
+    left, right = make(WIDE[dname])
+    assert bucketed._probe_choice(left, right, ["k"]) == (
+        entries, False, unique
+    )
     got, moved = _run_join(how, left, right)
     assert moved == {"join.probe.direct": 1, "join.probe.search": 0,
-                     "join.probe.narrow": 0, "bucket.fallback_errors": 0}
+                     "join.probe.narrow": 0, "join.table_entries": entries,
+                     "bucket.fallback_errors": 0}
     monkeypatch.setattr(bucketed, "_probe_choice",
                         lambda *a: SEARCHES[search])
     want, moved = _run_join(how, left, right)
     assert moved["join.probe.search"] == 1
     assert moved["join.probe.narrow"] == (search == "one_word")
+    assert moved["join.table_entries"] == 0
     assert got.logical_row_count > 0
     _assert_same_table(got, want)
 
@@ -398,26 +537,44 @@ def _pair_with_build_keys(rk, n_left=3000, d=dt.INT64):
                    np.zeros(len(rk), bool))
 
 
+# a budget whose sixteenth holds 2,048 four-byte entries and not 4,096
+# (139,586 bytes: the rule at small size, `direct_table_size`)
+BUDGET_OF_2048_ENTRIES_GB = 0.0002
+
+
 def test_span_at_the_threshold_is_direct_and_one_past_is_searched():
     config.set_flag("METRICS", True)
+    config.set_flag("HBM_BUDGET_GB", BUDGET_OF_2048_ENTRIES_GB)
     body = list(range(100, 798))
     at = _pair_with_build_keys([0] + body + [2047])
     past = _pair_with_build_keys([0] + body + [2048])
     out_at, moved = _run_join("inner", *at)
-    assert moved["join.probe.direct"] == 1
+    assert (moved["join.probe.direct"], moved["join.table_entries"]) == (
+        1, 2048
+    )
     out_past, moved = _run_join("inner", *past)
-    assert moved["join.probe.search"] == 1
+    assert (moved["join.probe.search"], moved["join.table_entries"]) == (
+        1, 0
+    )
     assert out_at.logical_row_count == out_past.logical_row_count == 3000
 
 
-def test_table_wider_than_the_probe_side_is_searched():
+def test_a_table_past_the_bound_is_searched():
+    """...and one that is only wider than the probe side (2,048 entries
+    against 1,024 probe rows: searched until PR 41) is not."""
     config.set_flag("METRICS", True)
     left, right = _pair_with_build_keys(
         [0] + list(range(100, 798)) + [1400], n_left=900
     )
     _, moved = _run_join("inner", left, right)
+    assert moved == {"join.probe.direct": 1, "join.probe.search": 0,
+                     "join.probe.narrow": 0, "join.table_entries": 2048,
+                     "bucket.fallback_errors": 0}
+    config.set_flag("HBM_BUDGET_GB", BUDGET_OF_2048_ENTRIES_GB / 2)
+    _, moved = _run_join("inner", left, right)
     assert moved == {"join.probe.direct": 0, "join.probe.search": 1,
-                     "join.probe.narrow": 1, "bucket.fallback_errors": 0}
+                     "join.probe.narrow": 1, "join.table_entries": 0,
+                     "bucket.fallback_errors": 0}
 
 
 def _sparse_int():
@@ -485,23 +642,31 @@ def test_keys_the_table_cannot_address_take_the_search(make, narrow):
     (left, right), on = make()
     out, moved = _run_join("left", left, right, on)
     assert moved == {"join.probe.direct": 0, "join.probe.search": 1,
-                     "join.probe.narrow": narrow,
+                     "join.probe.narrow": narrow, "join.table_entries": 0,
                      "bucket.fallback_errors": 0}
     assert out.logical_row_count >= 3000
 
 
 def test_choice_is_keyed_into_the_executable_cache():
-    """One executable a choice: the same shapes with a dense and then a
-    sparse key must not serve each other's program."""
+    """One executable a choice: the same shapes with a dense unique key,
+    a dense key that repeats (the table keeps a count) and a key too
+    sparse for the device's share must not serve each other's program."""
     config.set_flag("METRICS", True)
     dense = _pair_with_build_keys(list(range(700)))
+    repeats = _pair_with_build_keys([k // 2 for k in range(700)])
     sparse, _ = _sparse_int()
+    assert [bucketed._probe_choice(l, r, ["k"]) for l, r in
+            (dense, repeats, sparse)] == [
+        (1024, False, True), (1024, False, False), (None, True, False)]
     a, moved_a = _run_join("inner", *dense)
     b, moved_b = _run_join("inner", *sparse)
+    r, moved_r = _run_join("inner", *repeats)
     c, moved_c = _run_join("inner", *dense)
     assert (moved_a["join.probe.direct"], moved_b["join.probe.search"],
-            moved_c["join.probe.direct"]) == (1, 1, 1)
+            moved_r["join.probe.direct"], moved_c["join.probe.direct"]) == (
+        1, 1, 1, 1)
     assert a.logical_row_count == b.logical_row_count == 3000
+    assert r.logical_row_count == 6000  # every key twice
     _assert_same_table(a, c)
 
 
@@ -564,7 +729,7 @@ def test_dispatch_plane_counts_one_direct_probe_a_join(how):
 # ---------------------------------------------------------------------------
 
 
-def _lowered_probe(table_size, n_left=4096, n_right=1024):
+def _lowered_probe(table_size, n_left=4096, n_right=1024, unique=False):
     left = Table([
         Column(jax.ShapeDtypeStruct((n_left,), jnp.int64), dt.INT64, None),
         Column(jax.ShapeDtypeStruct((n_left,), jnp.int64), dt.INT64, None),
@@ -579,7 +744,7 @@ def _lowered_probe(table_size, n_left=4096, n_right=1024):
         lv = buckets.tail_valid(l.row_count, ln)
         rv = buckets.tail_valid(r.row_count, rn)
         return join_mod._match_ranges(
-            l, r, [0], [0], lv, rv, table_size=table_size
+            l, r, [0], [0], lv, rv, table_size=table_size, unique=unique
         )
 
     return jax.jit(fn).lower(left, right, n32, n32).as_text()
@@ -597,14 +762,38 @@ def _gathers_of_width(text, width):
     return wide
 
 
-def test_lowered_dense_key_probe_has_no_loop_and_two_wide_gathers_at_most():
-    n_left = 4096
-    direct = _lowered_probe(2048, n_left)
+def _scatters(text):
+    """(table entries, updates) of every scatter op: its types close
+    the op's region, lines below its name."""
+    return [(int(t), int(u)) for t, u in re.findall(
+        r"\}\) : \(tensor<(\d+)x\w+>, tensor<(\d+)x1xi32>, "
+        r"tensor<\d+x\w+>\) -> tensor<\d+x", text)]
+
+
+# (build rows, the caller read ``repeats == 0``) -> gathers at the
+# probe side's width: a row a key; row and count in one word; the count
+# by a second gather where 2 x 17 bits do not fit the word
+@pytest.mark.parametrize("n_right,unique,gathers", [
+    (1024, True, 1), (1024, False, 1), (1 << 16, False, 2),
+    (1 << 16, True, 1),
+])
+def test_lowered_probe_has_no_loop_one_scatter_and_two_wide_gathers_at_most(
+    n_right, unique, gathers
+):
+    """The table is wider than both sides (a sparse key's span), so a
+    tensor's width says what it serves: the fill and ONE scatter at the
+    table's, no gather and no search there."""
+    n_left, size = 4096, 1 << 18
+    direct = _lowered_probe(size, n_left, n_right, unique)
     assert "stablehlo.while" not in direct
-    assert 1 <= _gathers_of_width(direct, n_left) <= 2
+    assert direct.count('"stablehlo.scatter"') == 1
+    assert _scatters(direct) == [(size, n_right)]
+    assert _gathers_of_width(direct, size) == 0
+    assert _gathers_of_width(direct, n_left) == gathers
     # the search, through the same reading, is what the table replaced:
     # two loops whose bodies gather both u64 words at the probe side's
     # width (the chip splits each into two u32 gathers: eight)
-    search = _lowered_probe(None, n_left)
+    search = _lowered_probe(None, n_left, n_right)
     assert search.count("stablehlo.while") == 2
     assert _gathers_of_width(search, n_left) == 4
+    assert '"stablehlo.scatter"' not in search

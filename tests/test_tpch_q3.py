@@ -52,7 +52,8 @@ SEGMENT_TIMERS = {
 COUNTERS = [
     "project.calls", "join.probe.search", "join.probe.direct",
     "join.probe.narrow", "join.materialised", "join.deferred", "join.probe_rows",
-    "join.build_rows", "join.output_rows", "filter.compacted",
+    "join.build_rows", "join.output_rows", "join.table_entries",
+    "filter.compacted",
     "filter.deferred", "plan.calls", "plan.segments", "plan.fallbacks",
     "bucket.fallback_errors", "bucket.declined", "kernel.fallbacks",
 ]
@@ -105,12 +106,11 @@ def test_q3_request_equals_the_reference(seed):
     assert moved["project.calls"] == TRAFFIC["expect_counters"]["project.calls"] == 5
     assert moved["plan.calls"] == 3 and moved["plan.segments"] == 8
     assert moved["join.materialised"] == 2 and moved["join.deferred"] == 0
-    # the sparse order key is searched, as one u32 word a side; the
-    # customer key's span (every customer) fits a table no wider than
-    # the probe side and twice the build side's BUCKET, whatever its
-    # logical rows (ROADMAP A1): addressed
+    # both keys are addressed (PR 41): the customer key's span (every
+    # customer) and the sparse order key's, many times its rows, each
+    # through a table one scatter of the build rows fills
     assert (moved["join.probe.search"], moved["join.probe.narrow"],
-            moved["join.probe.direct"]) == (1, 1, 1)
+            moved["join.probe.direct"]) == (0, 0, 2)
     assert moved["filter.compacted"] == 3 and moved["filter.deferred"] == 0
     for k in ("plan.fallbacks", "bucket.fallback_errors", "bucket.declined",
               "kernel.fallbacks"):
@@ -126,13 +126,18 @@ def test_q3_request_equals_the_reference(seed):
     assert moved["join.build_rows"] == table_rows(building) + table_rows(open_orders)
     assert moved["join.output_rows"] == table_rows(open_orders) + table_rows(joined)
     assert table_rows(joined) > 0
+    assert moved["join.table_entries"] == sum(
+        max(1024, 1 << int(k.max() - k.min()).bit_length())
+        for k in (building[0].values, open_orders[0].values))
 
 
-def test_the_order_key_is_too_sparse_for_the_direct_probe():
+def test_the_sparse_order_key_is_addressed_and_stays_a_boundary():
     """The lineitem join's build side, as the served path sees it: the
-    span of its keys is past every bucket and under 2^32, so the one
-    choice of the probe answers the one-word search and the segmenter
-    leaves the join a boundary (`bucketed.selecting_table_size` None)."""
+    span of its keys is many times their count, wider than both sides,
+    and unique. The one choice of the probe answers a table as wide as
+    the span with a row a key (PR 41), and the segmenter still leaves
+    the join a boundary (`bucketed.selecting_table_size` None: riding a
+    fused segment is priced differently, `PERF.md` §7)."""
     data = script.Data(CONFIG, TRAFFIC, 7, rehearse=True)
     env = data.env(0)
     building = reference.run_plan(PLAN_A, [env["customer"]])
@@ -142,7 +147,9 @@ def test_the_order_key_is_too_sparse_for_the_direct_probe():
     build = rb._table_from_wire(*wire(open_orders), None)
     probe = rb._table_from_wire(*wire(reference.run_plan(PLAN_C[:2], [env["lineitem"]])), None)
     assert bucketed.selecting_table_size(PLAN_C[2], build, probe.row_count) is None
-    assert bucketed._probe_choice(probe, build, [0]) == (None, True)
+    size = 1 << int(keys.max() - keys.min()).bit_length()
+    assert size > max(probe.row_count, 2 * build.row_count)
+    assert bucketed._probe_choice(probe, build, [0]) == (size, False, True)
 
 
 def _schema(table):
