@@ -86,13 +86,17 @@ trace = json.load(open(sys.argv[2]))
 events = trace["traceEvents"]
 assert events, "empty plan trace"
 spans = [e for e in events if e["ph"] == "X"]
-seg = [e for e in spans if e["name"].split("/")[-1] == "plan.segment"]
+seg = [e for e in spans
+       if e["name"].split("/")[-1].startswith("plan.segment.")]
 assert seg, sorted({e["name"] for e in spans})
 assert "plan" in {e["cat"] for e in spans}
+# the completion clock's lane: one device interval a launch
+assert [e for e in spans if e["name"].startswith("device.srt_")], sorted(
+    {e["name"] for e in spans})
 print(
     "plan fusion smoke OK:",
     {k: v for k, v in sorted(c.items()) if k.startswith("plan.")},
-    f"+ {len(seg)} plan.segment spans in trace",
+    f"+ {len(seg)} plan.segment.<sig> spans in trace",
 )
 PY
 
@@ -135,7 +139,8 @@ stage = [
 assert stage, sorted({e["name"] for e in spans})
 stage_tids = {e["tid"] for e in stage}
 compute_tids = {
-    e["tid"] for e in spans if e["name"].split("/")[-1] == "plan.segment"
+    e["tid"] for e in spans
+    if e["name"].split("/")[-1].startswith("plan.segment.")
 }
 worker_tids = stage_tids - compute_tids
 assert worker_tids, (

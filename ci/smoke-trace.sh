@@ -129,7 +129,7 @@ B8 = int(dt.TypeId.BOOL8)
 # two plans under ONE trace: the row-local chain runs sharded over the
 # mesh (mesh.stage / plan.mesh exchange spans); the sort chain declines
 # the mesh and runs exact, paying a fresh cached_jit compile
-# (compile.jit) with per-segment execute spans (plan.segment)
+# (compile.jit) with per-segment execute spans (plan.segment.<sig>)
 MESH_CHAIN = [
     {"op": "filter", "mask": 1},
     {"op": "cast", "column": 0, "type_id": F64},
@@ -209,7 +209,8 @@ for want in ("client.rpc", "serving.admission", "serving.queue_wait",
     assert want in names, f"{want!r} missing from merged trace: {sorted(names)}"
 # compile + per-segment execute spans ride the same trace
 assert any(n.startswith("compile.") for n in names), sorted(names)
-assert "plan.segment" in names or "plan" in names, sorted(names)
+assert any(n.startswith("plan.segment.") for n in names) or "plan" in names, \
+    sorted(names)
 
 chrome = json.load(open(sys.argv[3]))
 spans = [e for e in chrome["traceEvents"] if e.get("ph") == "X"]

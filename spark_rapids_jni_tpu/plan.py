@@ -43,7 +43,9 @@ Telemetry (``plan.*``, through the metrics registry + flight recorder):
 ``plan.declined`` counters (plus ``plan.mesh_segments``/
 ``plan.mesh_declined``/``plan.mesh_fallbacks`` when a mesh runner is
 offered — see ``parallel/planmesh.py``), a ``plan`` span wrapping each run with one
-``plan.segment`` span per segment, ``plan.fallback`` flight instants,
+``plan.segment.<sig>`` span per segment (its device-ended time is the
+completion clock's ``device.plan.segment.<sig>``, utils/devclock.py),
+``plan.fallback`` flight instants,
 and the ``compile_cache.miss`` instants ``cached_jit`` already emits
 (fused executables are named ``srt_fused_plan`` so ``jax.log_compiles``
 lines are attributable).
@@ -326,7 +328,8 @@ def _run_fused_tolerant(
             ):
                 attempt += 1
                 faults.sleep_backoff(
-                    attempt, "plan.segment", error=e
+                    attempt, "plan.segment." + segment_sig(seg_ops),
+                    error=e,
                 )
                 continue
             raise
@@ -418,9 +421,8 @@ def _offer_mesh(ops, table: Table, rest, mesh_runner):
         0, "mesh", ops, rows_in=int(table.logical_row_count)
     )
     try:
-        with metrics.span("plan.segment", index=0, kind="mesh",
-                          ops=len(ops)), \
-                metrics.span("plan.segment.mesh"):
+        with metrics.span("plan.segment.mesh", device=True, index=0,
+                          kind="mesh", ops=len(ops)):
             try:
                 out = planmesh.run_plan_mesh(
                     ops, table, mesh_runner, rest
@@ -509,7 +511,7 @@ def _selecting_joins(ops, table: Table, orig_rest: tuple):
 
 def _run_segments(ops, table: Table, rest, donate_input: bool) -> Table:
     """The single-device path: the plan's segments in order, each under
-    its ``plan.segment`` span and a ``plan.segment.<sig>`` one."""
+    its ``plan.segment.<sig>`` span."""
     orig_rest = tuple(rest)
     queue = list(orig_rest)
     builds: dict = {}
@@ -538,8 +540,9 @@ def _run_segments(ops, table: Table, rest, donate_input: bool) -> Table:
         seg_ops = [ops[j] for j in idxs]
         faults.check_cancel()  # between-segment checkpoint
         with metrics.span(
-            "plan.segment", index=i, kind=kind, ops=len(seg_ops)
-        ), metrics.span("plan.segment." + segment_sig(seg_ops)):
+            "plan.segment." + segment_sig(seg_ops), device=True,
+            index=i, kind=kind, ops=len(seg_ops),
+        ):
             pseg = profiler.segment_begin(
                 i, kind, seg_ops,
                 rows_in=int(table.logical_row_count),

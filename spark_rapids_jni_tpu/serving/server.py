@@ -53,6 +53,7 @@ from typing import Optional
 from .. import pipeline, plan as plan_mod, plancheck, runtime_bridge as rb
 from ..utils import (
     config,
+    devclock,
     faults,
     flight,
     hbm,
@@ -1107,6 +1108,7 @@ class Server:
             "spill": spill.stats_doc(),
             "breaker": self.breaker.to_doc(),
             "planstats": planstats.stats_doc(),
+            "device": devclock.stats_doc(),
             "mesh": [r.to_doc() for r in runners],
             "durability": {
                 **durable.stats_doc(),

@@ -40,6 +40,7 @@ from collections import OrderedDict
 from typing import Any, Callable, Optional, Tuple
 
 from . import config
+from . import devclock
 from . import flight
 from . import lockcheck
 from . import log
@@ -386,7 +387,12 @@ def cached_jit(
     output to input buffers), so it is folded into the cache key: a
     donated and a non-donated call of the same op compile separately
     and never serve each other. Callers must never reuse a donated
-    argument's buffers after the call."""
+    argument's buffers after the call.
+
+    What comes back is a ``devclock.Launch`` around the jitted callable:
+    under ``METRICS`` each call leaves its device interval in
+    ``device.<name>`` (utils/devclock.py); ``lower`` and every other
+    attribute are the jitted callable's."""
     if donate_args:
         key = key + (("donate", tuple(donate_args)),)
     with _CACHE_LOCK:
@@ -412,7 +418,11 @@ def cached_jit(
 
     raw.__name__ = name
     raw.__qualname__ = name
-    jfn = jax.jit(raw, donate_argnums=tuple(donate_args))
+    # every launch of it reports to the completion clock: here, at the
+    # one choke point, so that no launch site can forget to
+    jfn = devclock.Launch(
+        jax.jit(raw, donate_argnums=tuple(donate_args)), name
+    )
     with _CACHE_LOCK:
         cur = _CACHE.setdefault(key, jfn)
         won = cur is jfn

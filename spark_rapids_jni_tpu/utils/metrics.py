@@ -40,6 +40,7 @@ import threading
 import time
 from typing import Dict, List, Optional, Sequence
 
+import jax.monitoring
 import jax.profiler
 
 from . import config
@@ -94,6 +95,30 @@ _GATE_SPAN = False
 _GATE_FLIGHT = False
 
 
+# jax's own account of building a program, as one timer: every duration
+# event of trace, lowering and backend compile (the persistent cache's
+# retrieval is inside the last) goes to `jax.build`. The listener fires
+# on trace and compile events only, so a warm path never reaches it;
+# programs built outside `cached_jit` (the mesh stage's) are seen here
+# and nowhere else.
+_JAX_BUILD_PREFIX = "/jax/core/compile/"
+_JAX_LISTENING = False
+
+
+def _on_jax_duration(event: str, duration_secs: float, **_kw) -> None:
+    if event.startswith(_JAX_BUILD_PREFIX):
+        timer_record("jax.build", duration_secs)
+
+
+def _listen_to_jax() -> None:
+    """Registered once, when the plane first turns on; with the plane
+    off again the mutator no-ops."""
+    global _JAX_LISTENING
+    if not _JAX_LISTENING:
+        _JAX_LISTENING = True
+        jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
 def _refresh_gate() -> None:
     global _GATE_GEN, _GATE_ENABLED, _GATE_SPAN, _GATE_FLIGHT
     _GATE_ENABLED = (
@@ -114,6 +139,8 @@ def _refresh_gate() -> None:
         or log.enabled("TRACE", "span")
     )
     _GATE_GEN = config.generation()
+    if _GATE_ENABLED:
+        _listen_to_jax()
 
 
 def enabled() -> bool:
@@ -263,14 +290,15 @@ SPAN_MS_BOUNDS = (
 
 
 class _Span:
-    __slots__ = ("name", "attrs", "qualname", "_t0", "_trace_cm",
-                 "_child_s")
+    __slots__ = ("name", "attrs", "qualname", "t0", "device",
+                 "_trace_cm", "_child_s")
 
-    def __init__(self, name: str, attrs: dict):
+    def __init__(self, name: str, attrs: dict, device: bool = False):
         self.name = name
         self.attrs = attrs
         self.qualname = name
-        self._t0 = 0.0
+        self.t0 = 0.0  # perf_counter at __enter__
+        self.device = device
         self._trace_cm = None
         self._child_s = 0.0
 
@@ -299,13 +327,13 @@ class _Span:
             # None args) — the join key tracequery/assign_trace_ids
             # merge per-process dumps on
             flight.record("B", self.qualname, tracing.current_traceparent())
-        self._t0 = time.perf_counter()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         # duration is recorded on the exception path too: a span that
         # dies mid-op is exactly the one the telemetry must explain
-        dur = time.perf_counter() - self._t0
+        dur = time.perf_counter() - self.t0
         if _GATE_FLIGHT:
             flight.record(
                 "E", self.qualname,
@@ -340,7 +368,7 @@ class _Span:
         return False
 
 
-def span(name: str, **attrs):
+def span(name: str, device: bool = False, **attrs):
     """Context manager: a named, nestable timed region — the one span
     API of the served path.
 
@@ -354,12 +382,18 @@ def span(name: str, **attrs):
     line when the log level admits it. Returns a shared no-op object
     when every plane is off — the hot-path cost of a disabled span is
     one generation compare on the cached gate.
+
+    ``device=True`` asks the completion clock (utils/devclock.py) for
+    the span's device-ended time as well: every program launched on
+    this thread while the span is the innermost such one leaves its
+    device interval in the timer ``device.<name>``, though the span
+    itself may have ended long before the program completes.
     """
     if _GATE_GEN != config.generation():
         _refresh_gate()
     if not _GATE_SPAN:
         return NULL_SPAN
-    return _Span(name, attrs)
+    return _Span(name, attrs, device)
 
 
 def traced(name: Optional[str] = None):
@@ -431,6 +465,20 @@ def span_depth() -> int:
     return len(stack) if stack else 0
 
 
+def open_spans() -> tuple:
+    """The spans open on THIS thread, outermost first; where the work
+    crossed a thread hop, the span it was adopted from stands for the
+    ``adopt`` scope. What the completion clock keeps of a launch
+    (utils/devclock.py): it reads ``name``, ``qualname``, ``t0`` and
+    ``device`` of each."""
+    stack = getattr(_TLS, "stack", None)
+    if not stack:
+        return ()
+    return tuple(
+        s._parent if type(s) is adopt else s for s in stack
+    )
+
+
 def span_stack() -> tuple:
     """Qualified names of the spans open on THIS thread, outermost
     first — the allocation provenance the resident-table leak report
@@ -444,8 +492,26 @@ def span_stack() -> tuple:
 # ---------------------------------------------------------------------------
 
 
-def snapshot() -> dict:
-    """One JSON-able dict of everything measured so far."""
+# What records into the registry from a thread of its own (the completion
+# clock, utils/devclock.py) registers here how to settle — record what it
+# still holds, within a bound — and how to start over, so that this
+# module imports nothing above it.
+_SETTLERS: List[tuple] = []
+
+
+def register_settler(settle, start_over) -> None:
+    _SETTLERS.append((settle, start_over))
+
+
+def snapshot(settle: bool = True) -> dict:
+    """One JSON-able dict of everything measured so far. With ``settle``
+    the completion clock is drained first (a bounded wait for the
+    launches in flight, outside the lock its thread records under), so
+    a window's delta holds every launch of the window; a scrape passes
+    False and never waits for the device."""
+    if settle:
+        for fn, _ in _SETTLERS:
+            fn()
     with _LOCK:
         return {
             "counters": dict(_COUNTERS),
@@ -494,9 +560,10 @@ def prometheus_text(snap: Optional[dict] = None) -> str:
     registry. Counters/bytes render as ``counter``, gauges as ``gauge``
     (plus a ``_high_water`` series), timers as a summary-shaped
     ``_count``/``_total_seconds`` pair, histograms as a classic
-    cumulative ``_bucket{le=...}`` family."""
+    cumulative ``_bucket{le=...}`` family. A scrape never waits for
+    the device: a launch still in flight is in the next one."""
     if snap is None:
-        snap = snapshot()
+        snap = snapshot(settle=False)
     lines: List[str] = []
 
     def emit(name: str, kind: str, series) -> None:
@@ -543,7 +610,10 @@ def prometheus_text(snap: Optional[dict] = None) -> str:
 
 
 def reset() -> None:
-    """Clear the registry (test isolation; bench per-config blocks)."""
+    """Clear the registry (test isolation; bench per-config blocks).
+    The completion clock starts a timeline of its own."""
+    for _, fn in _SETTLERS:
+        fn()
     with _LOCK:
         _COUNTERS.clear()
         _BYTES.clear()

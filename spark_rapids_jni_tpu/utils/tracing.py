@@ -356,11 +356,23 @@ def assign_trace_ids(events) -> List[dict]:
                     trace = stack.pop(i)[1]
                     break
         else:
-            trace = stack[-1][1] if stack else None
+            # a device interval ("X", utils/devclock.py) is recorded on
+            # the clock's thread: its trace rides its arg
+            ctx = parse_traceparent(_x_arg(e).get("tp")) if ph == "X" else None
+            trace = ctx.trace_id if ctx is not None else (
+                stack[-1][1] if stack else None
+            )
         if trace:
             e["trace_id"] = trace
         out.append(e)
     return out
+
+
+def _x_arg(e: dict) -> dict:
+    """The arg of an ``"X"`` (complete) flight event: ``end_ns``, the
+    launching ``span``, the traceparent ``tp``."""
+    arg = e.get("arg")
+    return arg if isinstance(arg, dict) else {}
 
 
 def trace_span_records(events, trace_id: str) -> List[dict]:
@@ -395,6 +407,14 @@ def trace_span_records(events, trace_id: str) -> List[dict]:
             if e.get("arg") is not None:
                 rec["error"] = e["arg"]
             spans.append(rec)
+        elif ph == "X":
+            t_ns = e.get("t_ns", 0)
+            spans.append({
+                "name": e.get("name", "?"), "tid": tid, "t_ns": t_ns,
+                "dur_ms": round(
+                    (_x_arg(e).get("end_ns", t_ns) - t_ns) / 1e6, 3
+                ),
+            })
         elif ph in ("I", "C"):
             rec = {"name": e.get("name", "?"), "tid": tid,
                    "t_ns": e.get("t_ns", 0), "instant": True}
@@ -458,7 +478,10 @@ def to_chrome_trace(
       ``args.unterminated``.
 
     ``I`` events become instants (``ph:"i"``), ``C`` events become
-    counter tracks (``ph:"C"``, one series per name). Thread-name
+    counter tracks (``ph:"C"``, one series per name), ``X`` events (the
+    completion clock's device intervals, one record each) complete
+    events on their recording thread's track, which is labelled
+    ``device``: a device lane beside the threads'. Thread-name
     metadata rows give each tid a stable label; ``process_name`` /
     ``process_sort_index`` label the process track (a multi-process
     merge passes "host:pid" per dump so timelines stop colliding on tid
@@ -474,13 +497,16 @@ def to_chrome_trace(
     if not evs:
         return {"traceEvents": [], "displayTimeUnit": "ms"}
     t0 = min(e.get("t_ns", 0) for e in evs) if t0_ns is None else t0_ns
-    t_end = max(e.get("t_ns", 0) for e in evs)
+    t_end = max(
+        max(e.get("t_ns", 0), _x_arg(e).get("end_ns", 0)) for e in evs
+    )
 
     def us(t_ns: int) -> float:
         return round((t_ns - t0) / 1e3, 3)
 
     out = []
     tids: list = []
+    lanes = set()  # tids that recorded device intervals
     open_spans: dict = {}  # tid -> stack of B events
     for e in evs:
         tid = e.get("tid", 0)
@@ -522,6 +548,25 @@ def to_chrome_trace(
             else:
                 x["ts"] = us(begin["t_ns"])
                 x["dur"] = round((e["t_ns"] - begin["t_ns"]) / 1e3, 3)
+            if args:
+                x["args"] = args
+            out.append(x)
+        elif ph == "X":
+            arg = _x_arg(e)
+            lanes.add(tid)
+            x = {
+                "name": name,
+                "cat": _chrome_cat(name),
+                "ph": "X",
+                "pid": pid,
+                "tid": tid,
+                "ts": us(e["t_ns"]),
+                "dur": round(
+                    (arg.get("end_ns", e["t_ns"]) - e["t_ns"]) / 1e3, 3
+                ),
+            }
+            args = {k: v for k, v in (("span", arg.get("span")),
+                                      ("traceparent", arg.get("tp"))) if v}
             if args:
                 x["args"] = args
             out.append(x)
@@ -601,7 +646,9 @@ def to_chrome_trace(
             "ph": "M",
             "pid": pid,
             "tid": tid,
-            "args": {"name": f"thread-{i} ({tid})"},
+            "args": {"name": (
+                f"device ({tid})" if tid in lanes else f"thread-{i} ({tid})"
+            )},
         })
         meta.append({
             "name": "thread_sort_index",

@@ -154,7 +154,7 @@ EXPECTED = {
         "plan.check": 1, "serving.admission": 1, "serving.plan": 1,
         # the dimension's key is unique and dense: the filter and the
         # join ride the groupby's segment as masks (PR 38)
-        "serving.download": 1, "plan": 1, "plan.segment": 2,
+        "serving.download": 1, "plan": 1,
         "plan.segment.filter__join__groupby": 1, "plan.segment.sort_by": 1,
         "groupby.reduce": 1,
         "wire.serialize": 1, "wire.serialize.wait": 1,
@@ -163,7 +163,7 @@ EXPECTED = {
     "stream": {
         "serving.request_split": 1,
         "plan.check": 1, "serving.admission": 1, "serving.stream": 1,
-        "wire.deserialize": 1, "plan": 1, "plan.segment": 1,
+        "wire.deserialize": 1, "plan": 1,
         "plan.segment.filter__groupby": 1, "groupby.reduce": 1,
         "wire.serialize": 1,
         "wire.serialize.wait": 1, "wire.serialize.copy": 1,
@@ -178,7 +178,7 @@ EXPECTED = {
     "mesh": {
         "serving.request_split": 1,
         "plan.check": 1, "serving.admission": 1, "serving.stream": 1,
-        "wire.deserialize": 1, "plan": 1, "plan.segment": 1,
+        "wire.deserialize": 1, "plan": 1,
         "plan.segment.mesh": 1, "mesh.stage": 1, "mesh.pack": 1,
         "mesh.counts": 1, "mesh.exchange": 1, "mesh.gather": 1,
         "plan.partition_counts": 1, "plan.partition_exchange": 1,
@@ -267,10 +267,14 @@ def anatomy():
                         _wait_for_requests(facts["commands"])
                     finally:
                         metrics._ANNOTATION = real
+                    snap = metrics.snapshot()  # drains the clock
                     seen[name] = dict(
                         facts, trace_id=ctx.trace_id,
                         spans=_spans(flight.tail_records()),
-                        snap=metrics.snapshot(),
+                        device=[e for e in flight.tail_records()
+                                if e["ph"] == "X"],
+                        stats_device=c.stats()["device"],
+                        snap=snap,
                         annotations=list(_Recorder.names),
                         session=next(s for s in c.stats()["sessions"]
                                      if s["name"] == name),
@@ -383,18 +387,119 @@ def test_request_self_time_is_small(anatomy, req):
     assert 0.0 <= self_s < 0.10 * total, (self_s, total)
 
 
-@pytest.mark.parametrize("req", ["resident", "stream", "mesh"])
-def test_segment_timers_sum_to_plan_segment(anatomy, req):
-    timers = anatomy[req]["snap"]["timers"]
-    whole = timers["plan.segment"]
-    parts = {k: t for k, t in timers.items()
-             if k.startswith("plan.segment.")}
-    assert sum(t["count"] for t in parts.values()) == whole["count"]
-    inner = sum(t["total_s"] for t in parts.values())
-    assert inner <= whole["total_s"]
-    assert whole["total_s"] - inner < 0.02 * whole["total_s"] + 1e-3
-    # and the plan span holds them all
-    assert whole["total_s"] <= timers["plan"]["total_s"]
+# program -> launches, and segment -> launches, of one request
+LAUNCHES = {
+    "resident": (
+        {"srt_bucketed_join_span": 1, "srt_fused_plan": 1,
+         "srt_groupby_reduce": 1, "srt_bucketed_sort": 1},
+        # the span read comes before the plan is segmented
+        {"plan.segment.filter__join__groupby": 2, "plan.segment.sort_by": 1},
+    ),
+    "stream": (
+        {"srt_fused_plan": 1, "srt_groupby_reduce": 1},
+        {"plan.segment.filter__groupby": 2},
+    ),
+    # the stage's programs are built outside cached_jit (ROADMAP A2)
+    "mesh": ({}, {}),
+    "updown": ({}, {}),
+}
+
+
+def _device_timers(run):
+    timers = run["snap"]["timers"]
+    busy = timers.get("device.busy", {"count": 0, "total_s": 0.0})
+    idle = timers.get("device.idle", {"count": 0, "total_s": 0.0})
+    segs = {k[len("device."):]: t for k, t in timers.items()
+            if k.startswith("device.plan.segment.")}
+    progs = {k[len("device."):]: t for k, t in timers.items()
+             if k.startswith("device.") and k not in ("device.busy",)
+             and not k.startswith(("device.idle", "device.plan.segment."))}
+    return busy, idle, segs, progs
+
+
+@pytest.mark.parametrize("req", ALL)
+def test_every_launch_leaves_one_interval(anatomy, req):
+    """Under its program's name and under its segment's, the reduce half
+    and the span read included; the drain put them in the snapshot."""
+    busy, _, segs, progs = _device_timers(anatomy[req])
+    want_progs, want_segs = LAUNCHES[req]
+    assert {k: t["count"] for k, t in progs.items()} == want_progs
+    assert {k: t["count"] for k, t in segs.items()} == want_segs
+    assert busy["count"] == sum(want_progs.values())
+    assert len(anatomy[req]["device"]) == busy["count"]
+    assert anatomy[req]["snap"]["counters"].get("device.lost", 0) == 0
+
+
+def test_the_mesh_stage_is_silent_on_the_clock_not_wrong(anatomy):
+    """The stage's programs are built outside ``cached_jit`` (ROADMAP
+    A2): its host span is there, and the clock files nothing under it —
+    no interval, no gap, no loss — rather than a share of the work. The
+    move under ``cached_jit`` makes this case fail: it then joins the
+    two below."""
+    snap = anatomy["mesh"]["snap"]
+    assert snap["timers"]["plan.segment.mesh"]["count"] == 1
+    assert snap["counters"]["plan.mesh_segments"] == 1
+    assert not [k for k in snap["timers"] if k.startswith("device.")]
+    assert not [k for k in snap["gauges"] if k.startswith("device.")]
+    assert snap["counters"].get("device.lost", 0) == 0
+    assert anatomy["mesh"]["device"] == []
+    assert anatomy["mesh"]["stats_device"]["by_program"] == {}
+
+
+@pytest.mark.parametrize("req", ["resident", "stream"])
+def test_segment_device_timers_sum_to_device_busy(anatomy, req):
+    """``device.<program>`` sums to ``device.busy``; the segments' share
+    leaves out what was launched outside any (the span read); busy and
+    idle telescope to last completion - first enqueue."""
+    run = anatomy[req]
+    busy, idle, segs, progs = _device_timers(run)
+    assert sum(t["total_s"] for t in progs.values()) == pytest.approx(
+        busy["total_s"], abs=1e-9)
+    in_segments = sum(t["total_s"] for t in segs.values())
+    assert in_segments <= busy["total_s"] + 1e-9
+    outside = sum(t["total_s"] for k, t in progs.items()
+                  if k == "srt_bucketed_join_span")
+    assert in_segments + outside == pytest.approx(busy["total_s"], abs=1e-9)
+    recs = sorted(run["device"], key=lambda e: e["t_ns"])
+    assert len(recs) == busy["count"] > 0
+    extent = (recs[-1]["arg"]["end_ns"] - recs[0]["t_ns"]) / 1e9
+    assert busy["total_s"] + idle["total_s"] == pytest.approx(
+        extent, abs=1e-6)
+    # one device, one queue: no two intervals overlap
+    for a, b in zip(recs, recs[1:]):
+        assert a["arg"]["end_ns"] <= b["t_ns"], (a, b)
+
+
+@pytest.mark.parametrize("req", ["resident", "stream"])
+def test_device_records_carry_span_and_trace(anatomy, req):
+    """The ring's device records: start before end, the span that
+    launched them (by its qualified name) and the request's trace."""
+    run = anatomy[req]
+    names = {s["name"] for s in run["spans"]}
+    assert run["device"]
+    for e in run["device"]:
+        arg = e["arg"]
+        assert e["name"].startswith("device.srt_")
+        assert e["t_ns"] <= arg["end_ns"]
+        assert arg["span"] in names, arg
+        ctx = tracing.parse_traceparent(arg["tp"])
+        assert ctx is not None and ctx.trace_id == run["trace_id"]
+    tagged = [e for e in tracing.assign_trace_ids(run["device"])]
+    assert all(e["trace_id"] == run["trace_id"] for e in tagged)
+    in_segment = [e for e in run["device"]
+                  if "/plan.segment." in e["arg"]["span"]]
+    assert len(in_segment) == sum(LAUNCHES[req][1].values())
+
+
+def test_stats_carry_the_device_doc(anatomy):
+    doc = anatomy["resident"]["stats_device"]
+    busy, idle, _, progs = _device_timers(anatomy["resident"])
+    assert doc["busy_s"] == pytest.approx(busy["total_s"])
+    assert doc["idle_s"] == pytest.approx(idle["total_s"])
+    assert doc["lost"] == 0 and doc["longest_ms"] > 0.0
+    assert {k: v["count"] for k, v in doc["by_program"].items()} == {
+        k: t["count"] for k, t in progs.items()}
+    assert anatomy["updown"]["stats_device"]["by_program"] == {}
 
 
 def test_segment_names_come_from_the_plan(anatomy):
@@ -601,7 +706,7 @@ def test_mesh_recv_in_the_session_doc(anatomy):
 def test_mesh_path_is_inside_the_plan_span(anatomy):
     stage = [s for s in anatomy["mesh"]["spans"] if s["leaf"] == "mesh.stage"]
     assert stage[0]["name"].endswith(
-        "serving.stream/plan/plan.segment/plan.segment.mesh/mesh.stage")
+        "serving.stream/plan/plan.segment.mesh/mesh.stage")
     assert stage[0]["name"].startswith("serving.request/")
 
 

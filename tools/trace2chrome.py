@@ -16,7 +16,9 @@ Open the output at https://ui.perfetto.dev ("Open trace file") or
 chrome://tracing ("Load"). Spans appear as per-thread tracks grouped by
 subsystem category (dispatch, wire, bucketed, shuffle, ...); counter
 samples (``resident.live``, ``bucket.pad_waste_bytes``) appear as
-counter tracks.
+counter tracks; the completion clock's device intervals
+(``device.<program>``, utils/devclock.py) appear as a ``device`` lane
+beside the threads', each with the span that launched it.
 """
 
 from __future__ import annotations
