@@ -35,6 +35,17 @@ replays, the stage re-derives shard layout, counts, and capacities from
 the captured host-side lineage (the undonated input table + ops) at the
 new size and the bytes do not change.
 
+Built once, launched many times: a stage's device work is one of three
+cached, jitted ``shard_map`` programs (``_stage_program``:
+``srt_mesh_rowlocal``, ``srt_mesh_counts``, ``srt_mesh_exchange``)
+through ``buckets.cached_jit``, keyed by what its shape depends on —
+the op lists, the packed table's schema and bucketed shard width, the
+mesh's devices, the exchange's rounded capacities. Whatever is data (a
+range partition's splitters, the planned counts) is an argument, never
+a constant of the closure, so a request of a shape seen before traces,
+lowers and compiles nothing, and the completion clock
+(``utils/devclock.py``) sees every launch.
+
 Anything else — multi-table rest inputs, non-row-local chain ops, more
 than one partition boundary, padded inputs — raises
 :class:`MeshUnsupported` and the caller falls through to the ordinary
@@ -59,7 +70,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import planops
 from ..column import Column, Table
-from ..utils import metrics
+from ..utils import buckets, metrics
 from .mesh import SHUFFLE_AXIS, shard_map
 from .tolerant import MeshRunner, run_collective
 
@@ -129,9 +140,17 @@ def _check_supported(ops: Sequence[dict], table: Table,
 
 def _pack_sharded(table: Table, mesh, axis: str, n: int):
     """(padded sharded table, per-shard valid counts) for a contiguous
-    row-block layout — the host-side pack step."""
+    row-block layout — the host-side pack step.
+
+    A shard's physical width is the bucket (``buckets.bucket_for``) of
+    ``ceil(n / size)``, so a stream of unequal batches packs to one
+    shape a bucket and the stage programs keyed on it recur. Rows fill
+    the shards in order, ``per`` to a shard; the last real shard is
+    short and ``cnt`` carries every shard's real rows, so the in-order
+    prefix gather is the same bytes whatever the width."""
     size = int(mesh.shape[axis])
     per = -(-n // size)  # ceil: contiguous row blocks, one per dev
+    per = buckets.bucket_for(per) or per
     pad = per * size - n
 
     def padleaf(x):
@@ -183,9 +202,173 @@ def _gather_prefix(out_t: Table, out_c, size: int) -> Table:
     return Table(cols, names=out_t.names)
 
 
+def _stage_program(which: str, mesh, axis: str, pt: Table, pre,
+                   part: Optional[dict] = None, post=(),
+                   cap: Optional[int] = None,
+                   pair_cap: Optional[int] = None):
+    """One mesh stage launch as a cached, jitted ``shard_map`` program
+    (``buckets.cached_jit``): built on the first request of its shape,
+    launched on every one after. ``which`` names it:
+
+    * ``rowlocal`` ``(pt, cnt)`` -> ``(table, rows a shard)``: the
+      whole row-local chain ``pre``;
+    * ``counts`` ``(pt, cnt, splitters)`` -> ``(counts, recv, pair)``:
+      the scan-side chain and the planned (src, dst-device) send
+      counts, with what the host sizes the exchange from beside them
+      (rows a destination receives, the hottest pair's rows) so that
+      one read fetches both;
+    * ``exchange`` ``(pt, cnt, counts, splitters)`` -> ``(table, rows a
+      shard, worst overflow)``: scan-side chain, ragged exchange into
+      ``cap`` rows a device (``pair_cap`` a pair, where the
+      implementation shapes a buffer by it), stable pid sort,
+      merge-side chain.
+
+    The key holds everything static the body closes over: the op lists,
+    ``pt``'s schema and physical rows, the mesh (axis, size, platform
+    and device ids in mesh order: a degraded mesh is another program),
+    the exchange implementation and the two capacities. Data never is:
+    a range partition's ``splitters`` are sampled from the table and are
+    an ARGUMENT (a tuple, empty for a hash partition), as ``counts`` is.
+    Nothing is donated: the un-donated input is the stage's replay
+    lineage."""
+    from .shuffle import _ragged_impl, exchange_ragged
+
+    size = int(mesh.shape[axis])
+    impl = _ragged_impl(None) if which == "exchange" else None
+    if impl == "ragged":
+        # only the dense form shapes a buffer by the hottest pair
+        pair_cap = None
+    key = buckets.cache_key(
+        "mesh." + which,
+        {"pre": list(pre), "part": part, "post": list(post)},
+        (pt,),
+        (
+            axis, size, mesh.devices.flat[0].platform,
+            tuple(int(d.id) for d in mesh.devices.flat),
+            impl, cap, pair_cap,
+        ),
+    )
+
+    def build():
+        from .. import plan as plan_mod
+        from ..ops import partition as partition_mod
+
+        def sharded(body, replicated=0):
+            """``body(local table, its rows, *replicated)`` over the
+            mesh; every result is sharded."""
+            return shard_map(
+                body, mesh=mesh,
+                in_specs=(P(axis), P(axis)) + (P(),) * replicated,
+                out_specs=P(axis), check_vma=False,
+            )
+
+        def rows(n):
+            return jnp.reshape(n, (1,)).astype(jnp.int32)
+
+        if which == "rowlocal":
+            def rowlocal_body(local, c):
+                t2, n2 = plan_mod._run_segment_traced(pre, local, c[0])
+                return t2, rows(n2)
+
+            return sharded(rowlocal_body)
+
+        num = int(part["num"])
+        keys = list(part.get("keys", []))
+        hashed = part.get("kind", "hash") == "hash"
+
+        def pids_of(local: Table, splitters):
+            if hashed:
+                return partition_mod.partition_ids_hash(
+                    local, keys or None, num
+                )
+            return partition_mod.partition_ids_range(
+                local, keys, splitters
+            )
+
+        def scan(local, c, splitters):
+            """Scan-side chain -> (table, which rows are real, the
+            device each goes to)."""
+            t2, n2 = plan_mod._run_segment_traced(pre, local, c[0])
+            rv = jnp.arange(t2.row_count, dtype=jnp.int32) < n2
+            dd = (pids_of(t2, splitters) * size) // num
+            return t2, rv, dd.astype(jnp.int32)
+
+        if which == "counts":
+            def count_body(local, c, splitters):
+                with jax.named_scope("srt.partition"):
+                    _, rv, dd = scan(local, c, splitters)
+                    dd = jnp.where(rv, dd, size)
+                    return jnp.bincount(dd, length=size + 1)[:size].astype(
+                        jnp.int32
+                    )[None, :]
+
+            count = sharded(count_body, replicated=1)
+
+            def counts_program(packed, cnt, splitters):
+                counts = count(packed, cnt, splitters)
+                return counts, jnp.sum(counts, axis=0), jnp.max(counts)
+
+            return counts_program
+
+        def exchange_body(local, c, C, splitters):
+            t2, rv, dd = scan(local, c, splitters)
+            with jax.named_scope("srt.partition"):
+                out, occ, overflow = exchange_ragged(
+                    t2, dd, C, cap, axis, impl, row_valid=rv,
+                    pair_capacity=pair_cap,
+                )
+                # restore the exact path's order: received rows arrive
+                # in stable (src, in-src) order; a stable sort by
+                # recomputed pid (padding keyed past every real pid)
+                # makes this device hold its contiguous slice of the
+                # globally pid-sorted table
+                pid2 = pids_of(out, splitters)
+                skey = jnp.where(occ, pid2.astype(jnp.int32), num)
+                perm = jnp.argsort(skey, stable=True).astype(jnp.int32)
+                sorted_t = jax.tree_util.tree_map(
+                    lambda x: None if x is None else x[perm], out
+                )
+                n_recv = jnp.sum(occ.astype(jnp.int32))
+            t3, n3 = plan_mod._run_segment_traced(post, sorted_t, n_recv)
+            return t3, rows(n3), rows(overflow)
+
+        exchange = sharded(exchange_body, replicated=2)
+
+        def exchange_program(packed, cnt, counts, splitters):
+            t3, n3, overflow = exchange(packed, cnt, counts, splitters)
+            return t3, n3, jnp.max(overflow)
+
+        return exchange_program
+
+    return buckets.cached_jit(key, build, "srt_mesh_" + which)
+
+
+def _splitters_of(part: dict, table: Table) -> tuple:
+    """A range partition's splitters, from the full host-side exchange
+    input — the same deterministic sample the exact path draws, so
+    partition ids agree byte-for-byte (the scan-side chain is empty,
+    per ``_check_supported``); ``()`` for a hash partition."""
+    if part.get("kind", "hash") != "range":
+        return ()
+    from ..ops import partition as partition_mod
+
+    return tuple(partition_mod.range_splitters(
+        table, list(part.get("keys", [])), int(part["num"])
+    ))
+
+
+def _counts_pass(pre, part, spl: tuple, mesh, axis: str, pt, cnt):
+    """Scan-side chain + per-(src, dst-device) planned send counts —
+    the two-phase sizing pass, a shuffle-site replay boundary. Returns
+    the program's ``(counts, recv, pair)``, all still on the device."""
+    fn = _stage_program("counts", mesh, axis, pt, pre, part)
+    return run_collective(
+        "plan.partition_counts", lambda: fn(pt, cnt, spl), site="shuffle"
+    )
+
+
 def _rowlocal_stage(seg_ops, table: Table, n: int, axis: str):
     """Stage closure for a pure row-local plan (no exchange boundary)."""
-    from .. import plan as plan_mod
 
     def stage(mesh):
         # re-derived per replay: a smaller surviving mesh re-plans the
@@ -193,18 +376,9 @@ def _rowlocal_stage(seg_ops, table: Table, n: int, axis: str):
         size = int(mesh.shape[axis])
         with metrics.span("mesh.pack"):
             pt, cnt = _pack_sharded(table, mesh, axis, n)
-
-        def body(local, c):
-            t2, n2 = plan_mod._run_segment_traced(seg_ops, local, c[0])
-            return t2, jnp.reshape(n2, (1,)).astype(jnp.int32)
-
-        fn = shard_map(
-            body, mesh=mesh,
-            in_specs=(P(axis), P(axis)),
-            out_specs=(P(axis), P(axis)),
-            check_vma=False,
-        )
-        out_t, out_c = fn(pt, cnt)
+        out_t, out_c = _stage_program(
+            "rowlocal", mesh, axis, pt, seg_ops
+        )(pt, cnt)
         # no read between the launch and the gather: the gather's first
         # device_get waits for the stage's device work
         with metrics.span("mesh.gather"):
@@ -224,60 +398,18 @@ def _partition_stage(pre, part, post, table: Table, n: int, axis: str,
     executes on that same mesh; any replay on a degraded mesh
     re-derives both.
     """
-    from .. import plan as plan_mod
-    from ..ops import partition as partition_mod
     from ..utils import config, planstats
     from .shuffle import (
-        _ragged_impl,
         _round_capacity,
         check_overflow_compact,
-        exchange_ragged,
-        total_recv_capacity,
+        recv_capacity,
     )
 
-    num = int(part["num"])
-    keys = list(part.get("keys", []))
-    kind = part.get("kind", "hash")
-    impl = _ragged_impl(None)
-    # range splitters come from the full host-side exchange input — the
-    # same deterministic sample the exact path draws, so partition ids
-    # agree byte-for-byte (scan-side chain is empty, per _check_supported)
-    splitters = (
-        partition_mod.range_splitters(table, keys, num)
-        if kind == "range" else None
+    # data, so an argument of the programs; the same on any mesh
+    spl = (
+        prepared["splitters"] if prepared is not None
+        else _splitters_of(part, table)
     )
-
-    def pids_of(local: Table):
-        if kind == "hash":
-            return partition_mod.partition_ids_hash(
-                local, keys or None, num
-            )
-        return partition_mod.partition_ids_range(local, keys, splitters)
-
-    def counts_pass(mesh, pt, cnt, size):
-        """Scan-side chain + per-(src, dst-device) planned send counts
-        — the two-phase sizing pass, a shuffle-site replay boundary."""
-
-        def count_body(local, c):
-            t2, n2 = plan_mod._run_segment_traced(pre, local, c[0])
-            with jax.named_scope("srt.partition"):
-                rv = jnp.arange(t2.row_count, dtype=jnp.int32) < n2
-                pid = pids_of(t2)
-                dd = jnp.where(
-                    rv, (pid * size) // num, size
-                ).astype(jnp.int32)
-                return jnp.bincount(dd, length=size + 1)[:size].astype(
-                    jnp.int32
-                )[None, :]
-
-        fn = shard_map(
-            count_body, mesh=mesh,
-            in_specs=(P(axis), P(axis)), out_specs=P(axis),
-            check_vma=False,
-        )
-        return run_collective(
-            "plan.partition_counts", lambda: fn(pt, cnt), site="shuffle"
-        )
 
     def stage(mesh):
         size = int(mesh.shape[axis])
@@ -287,24 +419,24 @@ def _partition_stage(pre, part, post, table: Table, n: int, axis: str,
             and prepared.get("size") == size
         ):
             pt, cnt = prepared["pt"], prepared["cnt"]
-            counts = prepared["counts"]
+            planned = prepared["counts"]
         else:
             with metrics.span("mesh.pack"):
                 pt, cnt = _pack_sharded(table, mesh, axis, n)
-            counts = None
-        # the counts pass through its two read-backs: device-ended
+            planned = None
+        # the counts pass through its one read-back: device-ended
         with metrics.span("mesh.counts"):
-            if counts is None:
-                counts = counts_pass(mesh, pt, cnt, size)
-            cap = total_recv_capacity(counts)
-            # srt: allow-host-sync(two-phase sizing: the planning pass exists to produce this host capacity)
-            pair_cap = _round_capacity(int(jnp.max(counts)))
+            if planned is None:
+                planned = _counts_pass(pre, part, spl, mesh, axis, pt, cnt)
+            counts, *sizing = planned
             # observe (not split: a pure redistribution has no agg to
             # make salting lossless) planned recv skew across
             # destinations — the planstats drift surface for
             # partition-op plans, and the serving session's mesh_recv
-            # srt: allow-host-sync(two-phase sizing: the skew observation reads the planned counts)
-            recv = np.asarray(jax.device_get(jnp.sum(counts, axis=0)))
+            # srt: allow-host-sync(two-phase sizing: the planning pass exists to produce these host capacities and the skew observation)
+            recv, hottest_pair = jax.device_get(sizing)
+            cap = recv_capacity(int(recv.max()))
+            pair_cap = _round_capacity(int(hottest_pair.max()))
         _LAST_RECV.rows = recv
         mean = float(recv.mean()) if recv.size else 0.0
         factor = float(config.get_flag("SKEW_SPLIT_FACTOR"))
@@ -319,46 +451,14 @@ def _partition_stage(pre, part, post, table: Table, n: int, axis: str,
                 "devices": size,
             })
 
-        def body(local, c, C):
-            t2, n2 = plan_mod._run_segment_traced(pre, local, c[0])
-            with jax.named_scope("srt.partition"):
-                rv = jnp.arange(t2.row_count, dtype=jnp.int32) < n2
-                pid = pids_of(t2)
-                dd = ((pid * size) // num).astype(jnp.int32)
-                out, occ, overflow = exchange_ragged(
-                    t2, dd, C, cap, axis, impl, row_valid=rv,
-                    pair_capacity=pair_cap,
-                )
-                # restore the exact path's order: received rows arrive
-                # in stable (src, in-src) order; a stable sort by
-                # recomputed pid (padding keyed past every real pid)
-                # makes this device hold its contiguous slice of the
-                # globally pid-sorted table
-                pid2 = pids_of(out)
-                skey = jnp.where(occ, pid2.astype(jnp.int32), num)
-                perm = jnp.argsort(skey, stable=True).astype(jnp.int32)
-                sorted_t = jax.tree_util.tree_map(
-                    lambda x: None if x is None else x[perm], out
-                )
-                n_recv = jnp.sum(occ.astype(jnp.int32))
-            t3, n3 = plan_mod._run_segment_traced(post, sorted_t, n_recv)
-            return (
-                t3,
-                jnp.reshape(n3, (1,)).astype(jnp.int32),
-                jnp.reshape(overflow, (1,)).astype(jnp.int32),
-            )
-
-        fn = shard_map(
-            body, mesh=mesh,
-            in_specs=(P(axis), P(axis), P()),
-            out_specs=(P(axis), P(axis), P(axis)),
-            check_vma=False,
+        fn = _stage_program(
+            "exchange", mesh, axis, pt, pre, part, post, cap, pair_cap
         )
         # the exchange launch through the overflow read: device-ended
         with metrics.span("mesh.exchange"):
             out_t, out_c, out_ov = run_collective(
                 "plan.partition_exchange",
-                lambda: fn(pt, cnt, counts),
+                lambda: fn(pt, cnt, counts, spl),
                 site="shuffle",
             )
             # capacity came from the real counts, so overflow means a
@@ -389,8 +489,6 @@ def run_plan_mesh(
     :class:`~..utils.faults.Degraded` when the runner's ladder hits
     its device floor.
     """
-    from ..utils import buckets
-
     _LAST_RECV.rows = None
     pre, part, post = _check_supported(ops, table, rest)
     # a bucket-padded wire upload shrinks to its real rows first: the
@@ -416,8 +514,6 @@ def prepare_exchange(ops: Sequence[dict], table: Table,
     overlaps with the previous batch's exchange launch. Returns the
     prepared dict ``_partition_stage`` consumes, or None when the plan
     has no partition boundary (nothing worth staging ahead)."""
-    from ..utils import buckets
-
     pre, part, post = _check_supported(ops, table, ())
     if part is None:
         return None
@@ -428,40 +524,11 @@ def prepare_exchange(ops: Sequence[dict], table: Table,
     size = int(mesh.shape[axis])
     with metrics.span("mesh.pack"):
         pt, cnt = _pack_sharded(table, mesh, axis, n)
-    from .. import plan as plan_mod
-    from ..ops import partition as partition_mod
-
-    num = int(part["num"])  # srt: allow-host-sync(plan literal, not a device value)
-    keys = list(part.get("keys", []))
-    kind = part.get("kind", "hash")
-    splitters = (
-        partition_mod.range_splitters(table, keys, num)
-        if kind == "range" else None
-    )
-
-    def count_body(local, c):
-        t2, n2 = plan_mod._run_segment_traced(pre, local, c[0])
-        rv = jnp.arange(t2.row_count, dtype=jnp.int32) < n2
-        if kind == "hash":
-            pid = partition_mod.partition_ids_hash(t2, keys or None, num)
-        else:
-            pid = partition_mod.partition_ids_range(t2, keys, splitters)
-        dd = jnp.where(rv, (pid * size) // num, size).astype(jnp.int32)
-        return jnp.bincount(dd, length=size + 1)[:size].astype(
-            jnp.int32
-        )[None, :]
-
-    fn = shard_map(
-        count_body, mesh=mesh,
-        in_specs=(P(axis), P(axis)), out_specs=P(axis),
-        check_vma=False,
-    )
-    counts = run_collective(
-        "plan.partition_counts", lambda: fn(pt, cnt), site="shuffle"
-    )
+    spl = _splitters_of(part, table)
     return {
         "mesh": mesh, "size": size, "pt": pt, "cnt": cnt,
-        "counts": counts,
+        "splitters": spl,
+        "counts": _counts_pass(pre, part, spl, mesh, axis, pt, cnt),
     }
 
 
@@ -493,8 +560,6 @@ def run_plan_mesh_stream(
 
     def execute(prepped):
         b, prepared = prepped
-        from ..utils import buckets
-
         t = buckets.unpad_table(b)
         n = int(t.row_count)
         axis = runner.axis

@@ -218,7 +218,14 @@ def total_recv_capacity(counts) -> int:
     hottest destination's actual row total, NOT num_partitions x the
     hottest (src, dst) pair (the round-2 skew-OOM failure mode)."""
     # srt: allow-host-sync(two-phase sizing: the planning pass exists to produce this host capacity)
-    cap = _round_capacity(int(jnp.max(jnp.sum(counts, axis=0))))
+    return recv_capacity(int(jnp.max(jnp.sum(counts, axis=0))))
+
+
+def recv_capacity(max_recv: int) -> int:
+    """:func:`total_recv_capacity` of a hottest destination's row total
+    that is on the host already (the mesh stage's counts program returns
+    it beside the counts)."""
+    cap = _round_capacity(max_recv)
     if metrics.enabled():
         metrics.counter_add("shuffle.plans")
         metrics.gauge_set("shuffle.recv_capacity", cap)

@@ -520,11 +520,13 @@ def test_q3_join_materialise_compiles_at_the_cell_s_buckets(one_chip, as_tpu):
 def test_mesh_partition_exchange_compiles_for_four_chips(
     topo, as_tpu, monkeypatch
 ):
-    """filter -> hash partition over 4 chips: the stage's shard_map body
-    must lower to ``ragged-all-to-all`` with 64-bit columns in the table
-    (the TPU compiler has no X64 rewrite for that collective — the
-    exchange carries them as (n, 2) u32 words) and keep the fused murmur3
-    kernel inside."""
+    """filter -> hash partition over 4 chips: the two programs the stage
+    launches (``planmesh._stage_program``: the cached callables
+    themselves, lowered through their own ``.lower``) compile for the
+    described 2x2 mesh. The exchange must lower to ``ragged-all-to-all``
+    with 64-bit columns in the table (the TPU compiler has no X64
+    rewrite for that collective — the exchange carries them as (n, 2)
+    u32 words) and both keep the fused murmur3 kernel inside."""
     from jax.sharding import Mesh, NamedSharding
     from jax.sharding import PartitionSpec as P
 
@@ -546,34 +548,7 @@ def test_mesh_partition_exchange_compiles_for_four_chips(
         Column(sds((per * size,), np.dtype(d.storage_dtype), P(axis)), d, None)
         for d in fact
     ])
-    prepared = {
-        "mesh": mesh, "size": size, "pt": pt,
-        "cnt": sds((size,), jnp.int32, P(axis)),
-        # planned (src, dst) send counts: what the counts pass returns
-        "counts": jnp.full((size, size), per // size, jnp.int32),
-    }
-
-    class Compiled(Exception):
-        pass
-
-    real_shard_map = planmesh.shard_map
-
-    def compile_instead_of_launch(body, **kw):
-        fn = real_shard_map(body, **kw)
-
-        def launch(*args):
-            shapes = [
-                sds(a.shape, a.dtype, P()) if isinstance(a, jax.Array) else a
-                for a in args
-            ]
-            raise Compiled(jax.jit(fn).lower(*shapes).compile())
-
-        return launch
-
-    monkeypatch.setattr(planmesh, "shard_map", compile_instead_of_launch)
-    monkeypatch.setattr(
-        planmesh, "run_collective", lambda label, launch, site=None: launch()
-    )
+    cnt = sds((size,), jnp.int32, P(axis))
     monkeypatch.setattr(shuffle, "_ragged_impl", lambda impl: "ragged")
     monkeypatch.setattr(kernels, "on_tpu", lambda: True)
 
@@ -582,12 +557,20 @@ def test_mesh_partition_exchange_compiles_for_four_chips(
         {"op": "partition", "kind": "hash", "keys": [0], "num": size},
     ]
     pre, part, post = planmesh._split_at_exchange(ops)
-    stage = planmesh._partition_stage(
-        pre, part, post, None, per * size, axis, prepared=prepared
+    counts_fn = planmesh._stage_program("counts", mesh, axis, pt, pre, part)
+    assert counts_fn.__name__ == "srt_mesh_counts"
+    counted = counts_fn.lower(pt, cnt, ()).compile()
+    assert "tpu_custom_call" in counted.as_text()
+    _fits(counted)
+
+    # planned (src, dst) send counts, as the counts program returns them;
+    # every destination receives `per` rows: the capacities of that plan
+    counts = sds((size, size), jnp.int32, P(axis))
+    exchange_fn = planmesh._stage_program(
+        "exchange", mesh, axis, pt, pre, part, post, per, per // size
     )
-    with pytest.raises(Compiled) as got:
-        stage(mesh)
-    compiled = got.value.args[0]
+    assert exchange_fn.__name__ == "srt_mesh_exchange"
+    compiled = exchange_fn.lower(pt, cnt, counts, ()).compile()
     text = compiled.as_text()
     assert "ragged-all-to-all" in text
     assert "tpu_custom_call" in text

@@ -399,8 +399,12 @@ LAUNCHES = {
         {"srt_fused_plan": 1, "srt_groupby_reduce": 1},
         {"plan.segment.filter__groupby": 2},
     ),
-    # the stage's programs are built outside cached_jit (ROADMAP A2)
-    "mesh": ({}, {}),
+    # the stage's two cached shard_map programs (PR 43), both inside
+    # the whole-plan mesh segment
+    "mesh": (
+        {"srt_mesh_counts": 1, "srt_mesh_exchange": 1},
+        {"plan.segment.mesh": 2},
+    ),
     "updown": ({}, {}),
 }
 
@@ -430,23 +434,29 @@ def test_every_launch_leaves_one_interval(anatomy, req):
     assert anatomy[req]["snap"]["counters"].get("device.lost", 0) == 0
 
 
-def test_the_mesh_stage_is_silent_on_the_clock_not_wrong(anatomy):
-    """The stage's programs are built outside ``cached_jit`` (ROADMAP
-    A2): its host span is there, and the clock files nothing under it —
-    no interval, no gap, no loss — rather than a share of the work. The
-    move under ``cached_jit`` makes this case fail: it then joins the
-    two below."""
+def test_the_mesh_stage_is_on_the_clock(anatomy):
+    """The stage's programs go through ``cached_jit`` (PR 43; until then
+    the clock filed nothing under the stage): the warm request built
+    nothing and its two launches are under their programs' names, under
+    the mesh segment's and in the daemon's ``stats``."""
     snap = anatomy["mesh"]["snap"]
     assert snap["timers"]["plan.segment.mesh"]["count"] == 1
     assert snap["counters"]["plan.mesh_segments"] == 1
-    assert not [k for k in snap["timers"] if k.startswith("device.")]
-    assert not [k for k in snap["gauges"] if k.startswith("device.")]
+    assert snap["counters"].get("compile_cache.miss", 0) == 0
+    assert snap["counters"]["compile_cache.hit"] == 2
+    assert "jax.build" not in snap["timers"]
+    for name in ("device.srt_mesh_counts", "device.srt_mesh_exchange"):
+        assert snap["timers"][name]["total_s"] > 0.0
+    assert snap["timers"]["device.plan.segment.mesh"]["count"] == 2
     assert snap["counters"].get("device.lost", 0) == 0
-    assert anatomy["mesh"]["device"] == []
-    assert anatomy["mesh"]["stats_device"]["by_program"] == {}
+    assert [e["name"] for e in sorted(
+        anatomy["mesh"]["device"], key=lambda e: e["t_ns"])] == [
+        "device.srt_mesh_counts", "device.srt_mesh_exchange"]
+    assert sorted(anatomy["mesh"]["stats_device"]["by_program"]) == [
+        "srt_mesh_counts", "srt_mesh_exchange"]
 
 
-@pytest.mark.parametrize("req", ["resident", "stream"])
+@pytest.mark.parametrize("req", ["resident", "stream", "mesh"])
 def test_segment_device_timers_sum_to_device_busy(anatomy, req):
     """``device.<program>`` sums to ``device.busy``; the segments' share
     leaves out what was launched outside any (the span read); busy and
@@ -470,7 +480,7 @@ def test_segment_device_timers_sum_to_device_busy(anatomy, req):
         assert a["arg"]["end_ns"] <= b["t_ns"], (a, b)
 
 
-@pytest.mark.parametrize("req", ["resident", "stream"])
+@pytest.mark.parametrize("req", ["resident", "stream", "mesh"])
 def test_device_records_carry_span_and_trace(anatomy, req):
     """The ring's device records: start before end, the span that
     launched them (by its qualified name) and the request's trace."""
