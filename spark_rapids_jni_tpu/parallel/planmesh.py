@@ -76,16 +76,19 @@ from .tolerant import MeshRunner, run_collective
 
 
 # planned receive rows per device of the calling thread's last exchange
-# stage (the stage reads them to the host to size it): the serving
-# tier's work item moves them onto its session with take_recv()
-_LAST_RECV = threading.local()
+# stage (the stage reads them to the host to size it) and the two
+# capacities it rounded from them: the serving tier's work item moves
+# them onto its session with take_exchange()
+_LAST_EXCHANGE = threading.local()
 
 
-def take_recv():
-    """The planned receive rows per device of this thread's last
-    exchange stage, once (None when no exchange ran since)."""
-    rows, _LAST_RECV.rows = getattr(_LAST_RECV, "rows", None), None
-    return rows
+def take_exchange():
+    """``(planned receive rows per device, cap, pair_cap)`` of this
+    thread's last exchange stage, once (None when no exchange ran
+    since): ``cap`` the rows a device the exchange program was built
+    for, ``pair_cap`` the hottest (src, dst) pair's rounded rows."""
+    plan, _LAST_EXCHANGE.plan = getattr(_LAST_EXCHANGE, "plan", None), None
+    return plan
 
 
 class MeshUnsupported(Exception):
@@ -174,6 +177,10 @@ def _gather_prefix(out_t: Table, out_c, size: int) -> Table:
     # srt: allow-host-sync(result materialization: the stage's output IS these host bytes)
     got = np.asarray(jax.device_get(out_c))
     per_out = out_t.row_count // size
+    if metrics.enabled():
+        # every column is read whole; the prefixes are cut on the host
+        metrics.counter_add("mesh.gather.rows_read", size * per_out)
+        metrics.counter_add("mesh.gather.rows_kept", int(got.sum()))
 
     def take(x):
         if x is None:
@@ -437,10 +444,16 @@ def _partition_stage(pre, part, post, table: Table, n: int, axis: str,
             recv, hottest_pair = jax.device_get(sizing)
             cap = recv_capacity(int(recv.max()))
             pair_cap = _round_capacity(int(hottest_pair.max()))
-        _LAST_RECV.rows = recv
+        _LAST_EXCHANGE.plan = (recv, cap, pair_cap)
+        if metrics.enabled():
+            # what the program was built for against what it carries:
+            # every device runs at `cap` rows, whatever it receives
+            metrics.counter_add("mesh.exchange.slot_rows", size * cap)
+            metrics.counter_add("mesh.exchange.recv_rows", int(recv.sum()))
         mean = float(recv.mean()) if recv.size else 0.0
         factor = float(config.get_flag("SKEW_SPLIT_FACTOR"))
         if mean > 0 and float(recv.max()) > factor * mean:
+            metrics.counter_add("mesh.skew_observed")
             planstats.note_skew({
                 "site": "plan.partition",
                 "action": "observed",
@@ -489,7 +502,7 @@ def run_plan_mesh(
     :class:`~..utils.faults.Degraded` when the runner's ladder hits
     its device floor.
     """
-    _LAST_RECV.rows = None
+    _LAST_EXCHANGE.plan = None
     pre, part, post = _check_supported(ops, table, rest)
     # a bucket-padded wire upload shrinks to its real rows first: the
     # mesh stage derives its own shard padding, and the caller's padded

@@ -876,9 +876,9 @@ class Server:
                     if runner is not None:
                         from ..parallel import planmesh
 
-                        recv = planmesh.take_recv()
-                        if recv is not None:
-                            sess.note_mesh_recv(recv)
+                        plan = planmesh.take_exchange()
+                        if plan is not None:
+                            sess.note_mesh_recv(*plan)
                     return rb._table_to_wire(out)
 
                 return work

@@ -105,8 +105,9 @@ class Session:
         self._spilled_bytes = 0         # charged bytes currently off-device
         self._spilled_rb: set = set()   # rb ids of ours that are spilled
         # planned receive rows per device of the last served mesh
-        # exchange (parallel/planmesh.py reads them to the host anyway)
-        self._mesh_recv: Optional[list] = None
+        # exchange and the capacities rounded from them
+        # (parallel/planmesh.py reads them to the host anyway)
+        self._mesh_exchange: Optional[tuple] = None
         self._waits = deque(maxlen=4096)  # queue-wait seconds
         self._lats = deque(maxlen=4096)   # submit->done latency seconds
         self.stats = {
@@ -355,9 +356,13 @@ class Session:
         with self._lock:
             self.stats["shed"] += 1
 
-    def note_mesh_recv(self, rows) -> None:
+    def note_mesh_recv(self, rows, cap, pair_cap) -> None:
+        """The last mesh exchange's planned receive rows per device and
+        the capacities the stage chose (``planmesh.take_exchange``)."""
         with self._lock:
-            self._mesh_recv = [int(r) for r in rows]
+            self._mesh_exchange = (
+                [int(r) for r in rows], int(cap), int(pair_cap)
+            )
 
     def note_latency(self, seconds: float) -> None:
         """End-to-end submit->done latency of one scheduled request —
@@ -403,12 +408,22 @@ class Session:
                 "mesh_devices": self.mesh_devices,
                 **dict(self.stats),
             }
-            recv = self._mesh_recv
-        if recv:
+            exchange = self._mesh_exchange
+        if exchange:
+            recv, cap, pair_cap = exchange
             mean = sum(recv) / len(recv)
             doc["mesh_recv"] = {
                 "rows": recv,
                 "imbalance": (max(recv) / mean) if mean > 0 else 0.0,
+            }
+            # what the exchange program was built for: every device runs
+            # at `cap` rows, whatever it receives
+            slots = len(recv) * cap
+            doc["mesh_plan"] = {
+                "cap": cap,
+                "pair_cap": pair_cap,
+                "slot_rows": slots,
+                "pad_share": 1.0 - sum(recv) / slots,
             }
         doc["queue_wait"] = self.wait_percentiles()
         doc["latency"] = self.latency_percentiles()

@@ -93,7 +93,13 @@ def _intervals():
 
 
 def test_off_means_no_thread_and_no_record():
+    # its own starting point, whatever ran before it in this worker: the
+    # plane off by its own hand (`_clean` restores it), no clock thread,
+    # and an empty registry: another file's `device.*` timers outlive
+    # that file's flags
+    config.set_flag("METRICS", False)
     devclock.shutdown()
+    metrics.reset()
     fn = buckets.cached_jit(
         ("devclock.off",), lambda: (lambda x: x + 1), "srt_devclock_off")
     assert isinstance(fn, devclock.Launch)
