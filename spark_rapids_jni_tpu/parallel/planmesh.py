@@ -173,40 +173,47 @@ def _pack_sharded(table: Table, mesh, axis: str, n: int):
 
 def _gather_prefix(out_t: Table, out_c, size: int) -> Table:
     """Host-side gather: each shard's valid prefix, in mesh order —
-    exactly the single-device result for row-local segments."""
+    exactly the single-device result for row-local segments.
+
+    The result is HOST-backed: every leaf is a ``numpy`` array in the
+    device storage dtype (FLOAT64 as its uint64 bits), filled shard by
+    shard into one buffer of the kept rows. The stage's output is read
+    from the chips here, once; the wire serialises these buffers as
+    they are and a consumer that computes on them uploads at first use.
+    """
     # srt: allow-host-sync(result materialization: the stage's output IS these host bytes)
     got = np.asarray(jax.device_get(out_c))
     per_out = out_t.row_count // size
     if metrics.enabled():
-        # every column is read whole; the prefixes are cut on the host
+        # every shard is read whole; the prefixes are cut on the host
         metrics.counter_add("mesh.gather.rows_read", size * per_out)
         metrics.counter_add("mesh.gather.rows_kept", int(got.sum()))
+    ends = np.cumsum(got)
+    # every shard's transfer of every column starts before the first
+    # read waits for one
+    for x in jax.tree_util.tree_leaves(out_t):
+        for s in x.addressable_shards:
+            s.data.copy_to_host_async()
 
     def take(x):
         if x is None:
             return None
-        # srt: allow-host-sync(result materialization: gathering the sharded output to host)
-        full = np.asarray(jax.device_get(x))
-        return np.concatenate(
-            [full[i * per_out:i * per_out + int(got[i])]
-             for i in range(size)]
-        )
+        out = np.empty((int(ends[-1]),) + x.shape[1:], x.dtype)
+        for s in x.addressable_shards:
+            # placed by the shard's own rows, so mesh order holds
+            # whatever order the shards are listed in
+            i = (s.index[0].start or 0) // per_out
+            # srt: allow-host-sync(result materialization: gathering the sharded output to host)
+            out[ends[i] - got[i]:ends[i]] = np.asarray(s.data)[:got[i]]
+        return out
 
-    cols = []
-    for c in out_t.columns:
-        cols.append(Column(
-            data=jnp.asarray(take(c.data)),
-            dtype=c.dtype,
-            validity=(
-                None if c.validity is None
-                else jnp.asarray(take(c.validity))
-            ),
-            lengths=(
-                None if c.lengths is None
-                else jnp.asarray(take(c.lengths))
-            ),
-        ))
-    return Table(cols, names=out_t.names)
+    return Table(
+        [
+            Column(take(c.data), c.dtype, take(c.validity), take(c.lengths))
+            for c in out_t.columns
+        ],
+        names=out_t.names,
+    )
 
 
 def _stage_program(which: str, mesh, axis: str, pt: Table, pre,
@@ -497,7 +504,10 @@ def run_plan_mesh(
     boundary) data-parallel over ``runner``'s mesh.
 
     Never consumes ``table`` (the un-donated input IS the replay
-    lineage); returns the exact (unpadded) result table. Raises
+    lineage); returns the exact (unpadded) result table, HOST-backed:
+    its leaves are the ``numpy`` buffers the gather filled, which the
+    wire serialises as they are. A consumer that computes on the result
+    uploads it at first use, implicitly: the same bytes, later. Raises
     :class:`MeshUnsupported` when the plan has no mesh path and
     :class:`~..utils.faults.Degraded` when the runner's ladder hits
     its device floor.

@@ -325,12 +325,36 @@ def _column_to_wire(
     """
     out = _column_to_wire_impl(c, rows, ctx)
     if metrics.enabled():
-        metrics.bytes_add(
-            "wire.bytes_out",
-            len(out[2]) + (len(out[3]) if out[3] is not None else 0),
-        )
+        nbytes = _wire_column_bytes(out[2], out[3])
+        metrics.bytes_add("wire.bytes_out", nbytes)
         metrics.counter_add("wire.columns_out")
+        if _host_backed(c):
+            metrics.bytes_add("wire.bytes_out.host", nbytes)
+            metrics.counter_add("wire.columns_out.host")
     return out
+
+
+def _wire_column_bytes(data, valid) -> int:
+    return len(data) + (len(valid) if valid is not None else 0)
+
+
+def _host_backed(c: Column) -> bool:
+    """The column's leaves are host memory already (a mesh stage's
+    result, ``planmesh._gather_prefix``): serialising it transfers
+    nothing."""
+    return isinstance(c.data, np.ndarray)
+
+
+def _reply_host_bytes(t: Table, wire) -> tuple:
+    """``(bytes, bytes serialised from host-backed columns)`` of ``t``'s
+    wire 5-tuple ``wire``."""
+    total = host = 0
+    for c, d, v in zip(t.columns, wire[2], wire[3]):
+        n = _wire_column_bytes(d, v)
+        total += n
+        if _host_backed(c):
+            host += n
+    return total, host
 
 
 def _host_rows(arr: np.ndarray, rows: Optional[int]) -> np.ndarray:
@@ -376,9 +400,6 @@ def _column_to_wire_impl(
             ),
             valid,
         )
-    # tobytes() emits C-order bytes from any layout in one copy — an
-    # ascontiguousarray on top would only add a second copy for
-    # non-contiguous slices
     host = _host_rows(np.asarray(c.data), rows)
     valid = (
         None
@@ -386,10 +407,19 @@ def _column_to_wire_impl(
         else _host_rows(np.asarray(c.validity), rows)
         .astype(np.uint8).tobytes()
     )
+    if _host_backed(c) and host.flags.c_contiguous:
+        # the gather's own buffer goes to the frame: a byte view of it,
+        # which keeps it alive, and no copy
+        data = host.reshape(-1).view(np.uint8).data
+    else:
+        # tobytes() emits C-order bytes from any layout in one copy —
+        # an ascontiguousarray on top would only add a second copy for
+        # non-contiguous slices
+        data = host.tobytes()
     return (
         int(c.dtype.id.value),
         int(c.dtype.scale),
-        host.tobytes(),
+        data,
         valid,
     )
 

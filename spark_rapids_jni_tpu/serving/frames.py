@@ -145,6 +145,13 @@ def recv_frame(sock, span: str = "serving.recv",
 # ---------------------------------------------------------------------------
 
 
+def _as_buffer(b):
+    """``b`` as ``sendall`` takes it: a byte view of a host-backed
+    column (``runtime_bridge._column_to_wire_impl``) goes to the socket
+    as it is, anything else as ``bytes`` (which ``bytes`` already is)."""
+    return b if isinstance(b, memoryview) else bytes(b)
+
+
 def batch_to_parts(batch) -> Tuple[dict, List[bytes]]:
     """Wire 5-tuple -> (header meta dict, ordered buffer list)."""
     type_ids, scales, datas, valids, num_rows = batch
@@ -155,9 +162,9 @@ def batch_to_parts(batch) -> Tuple[dict, List[bytes]]:
         vl = -1 if v is None else len(v)
         lens.append([dl, vl])
         if d is not None:
-            buffers.append(bytes(d))
+            buffers.append(_as_buffer(d))
         if v is not None:
-            buffers.append(bytes(v))
+            buffers.append(_as_buffer(v))
     return (
         {
             "type_ids": [int(t) for t in type_ids],

@@ -879,7 +879,12 @@ class Server:
                         plan = planmesh.take_exchange()
                         if plan is not None:
                             sess.note_mesh_recv(*plan)
-                    return rb._table_to_wire(out)
+                    wire = rb._table_to_wire(out)
+                    if runner is not None:
+                        sess.note_mesh_reply(
+                            *rb._reply_host_bytes(out, wire)
+                        )
+                    return wire
 
                 return work
 

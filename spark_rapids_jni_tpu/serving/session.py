@@ -108,6 +108,7 @@ class Session:
         # exchange and the capacities rounded from them
         # (parallel/planmesh.py reads them to the host anyway)
         self._mesh_exchange: Optional[tuple] = None
+        self._mesh_reply: Optional[tuple] = None
         self._waits = deque(maxlen=4096)  # queue-wait seconds
         self._lats = deque(maxlen=4096)   # submit->done latency seconds
         self.stats = {
@@ -364,6 +365,13 @@ class Session:
                 [int(r) for r in rows], int(cap), int(pair_cap)
             )
 
+    def note_mesh_reply(self, nbytes: int, host_bytes: int) -> None:
+        """The last mesh reply's wire bytes and how many of them were
+        serialised from host-backed columns, with no transfer
+        (``runtime_bridge._reply_host_bytes``)."""
+        with self._lock:
+            self._mesh_reply = (int(nbytes), int(host_bytes))
+
     def note_latency(self, seconds: float) -> None:
         """End-to-end submit->done latency of one scheduled request —
         queue wait PLUS execution, the number the tenant experiences."""
@@ -409,6 +417,7 @@ class Session:
                 **dict(self.stats),
             }
             exchange = self._mesh_exchange
+            reply = self._mesh_reply
         if exchange:
             recv, cap, pair_cap = exchange
             mean = sum(recv) / len(recv)
@@ -424,6 +433,13 @@ class Session:
                 "pair_cap": pair_cap,
                 "slot_rows": slots,
                 "pad_share": 1.0 - sum(recv) / slots,
+            }
+        if reply:
+            nbytes, host_bytes = reply
+            doc["mesh_reply"] = {
+                "bytes": nbytes,
+                "host_bytes": host_bytes,
+                "host_share": host_bytes / nbytes if nbytes else 0.0,
             }
         doc["queue_wait"] = self.wait_percentiles()
         doc["latency"] = self.latency_percentiles()
