@@ -172,6 +172,15 @@ def groupby_turn():
             _GROUPBY_TURN.release()
 
 
+def group_bucket(groups: int, rows: int) -> int:
+    """The width a groupby's per-group half runs at: the bucket of its
+    group count, at least the smallest bucket (zero groups still return
+    the exact schema) and at most the ``rows`` its first half sorted
+    (every row its own group)."""
+    k = buckets.bucket_for(max(groups, 1))
+    return rows if k is None or k > rows else k
+
+
 def _reduce_groups(state, num_groups) -> Table:
     """Second half of a served groupby, launched at the bucket of the
     group count.
@@ -192,9 +201,7 @@ def _reduce_groups(state, num_groups) -> Table:
     n = int(state.perm.shape[0])
     # srt: allow-host-sync(bucketed-runner boundary: the first half's launch is done; one count read sizes the second half and the logical rows of its result)
     g = int(num_groups)
-    k = buckets.bucket_for(max(g, 1))
-    if k is None or k > n:
-        k = n
+    k = group_bucket(g, n)
 
     def build():
         def fn(st, ng):

@@ -1,4 +1,5 @@
-"""Mesh data-parallel plan execution for row-local segments.
+"""Mesh data-parallel plan execution: row-local chains, one exchange,
+one aggregate behind it.
 
 ``run_plan_mesh`` runs a plan whose every op is row-local (``cast``,
 ``project``, ``filter``, ``rlike`` — the ops ``planops.OPS`` marks
@@ -19,6 +20,22 @@ stage, so seeded shuffle faults replay losslessly from the host-side
 lineage and persistent failure walks the degradation ladder like any
 other stage.
 
+An aggregate behind the exchange (ISSUE 46): directly behind a HASH
+``partition`` the plan may carry ONE op whose ``OpSpec`` has a
+``behind_exchange`` rule that admits it — a ``groupby`` whose ``by``
+columns hold every partition key and whose aggregates are exact in any
+row layout (``planrules.groupby_behind_exchange``). Equal keys hash
+alike, so every group lies whole on one device: each device aggregates
+the rows it received and the union of the devices' results is the
+aggregate of the whole table, every row counted once. A row-local chain
+may follow it (a ``project`` and a ``filter``: the HAVING). The groupby
+is two launches here as everywhere (``bucketed._reduce_groups``): the
+exchange program ends with its sort half, the stage reads every
+device's group count in the one read that fetches the exchange's
+overflow, and a second sharded program (``srt_mesh_groupby``) runs the
+per-group half at the bucket of the LARGEST device's groups, then the
+chain behind it.
+
 Parity contract: row-local ops neither reorder rows nor look across
 them, so block-sharded execution followed by an in-order prefix gather
 is byte-identical to the single-device result — at ANY mesh size. The
@@ -35,21 +52,36 @@ replays, the stage re-derives shard layout, counts, and capacities from
 the captured host-side lineage (the undonated input table + ops) at the
 new size and the bytes do not change.
 
-Built once, launched many times: a stage's device work is one of three
-cached, jitted ``shard_map`` programs (``_stage_program``:
-``srt_mesh_rowlocal``, ``srt_mesh_counts``, ``srt_mesh_exchange``)
-through ``buckets.cached_jit``, keyed by what its shape depends on —
-the op lists, the packed table's schema and bucketed shard width, the
-mesh's devices, the exchange's rounded capacities. Whatever is data (a
-range partition's splitters, the planned counts) is an argument, never
-a constant of the closure, so a request of a shape seen before traces,
-lowers and compiles nothing, and the completion clock
-(``utils/devclock.py``) sees every launch.
+The order contract of an aggregate behind the exchange: its groups come
+back ordered by PARTITION ID, THEN KEY (the groupby's own key order
+inside one partition). A device's groupby leaves its groups in key
+order; where a device holds one partition (``num <= size``) that is the
+contract's order already, and where it holds several (the ladder's
+smaller meshes) the reduce program stable-sorts its groups by
+recomputed pid. The single-device path that answers the same plan after
+``faults.Degraded`` runs :func:`exact_ops`, the plan with the groups
+re-partitioned by the same hash behind the groupby — a stable reorder
+of key order by pid — so four devices, two and one return the same
+bytes. (A session WITHOUT a mesh runs the plan as written and gets key
+order: same rows, same values.)
 
-Anything else — multi-table rest inputs, non-row-local chain ops, more
-than one partition boundary, padded inputs — raises
-:class:`MeshUnsupported` and the caller falls through to the ordinary
-single-device plan path.
+Built once, launched many times: a stage's device work is one of four
+cached, jitted ``shard_map`` programs (``_stage_program``:
+``srt_mesh_rowlocal``, ``srt_mesh_counts``, ``srt_mesh_exchange``,
+``srt_mesh_groupby``) through ``buckets.cached_jit``, keyed by what its
+shape depends on — the op lists, the packed table's schema and bucketed
+shard width, the mesh's devices, the exchange's rounded capacities, the
+group bucket. Whatever is data (a range partition's splitters, the
+planned counts, the group counts) is an argument, never a constant of
+the closure, so a request of a shape seen before traces, lowers and
+compiles nothing, and the completion clock (``utils/devclock.py``) sees
+every launch.
+
+Anything else — multi-table rest inputs, any other op that is not
+row-local (a join, a second groupby, a groupby whose keys lack a
+partition key or that sits behind a range partition), more than one
+partition boundary, padded inputs — raises :class:`MeshUnsupported` and
+the caller falls through to the ordinary single-device plan path.
 
 ``run_plan_mesh_stream`` drives a SEQUENCE of batches through the same
 plan with exchange/compute overlap: batch N+1's scan-side counts pass
@@ -61,14 +93,14 @@ caller thread — the overlap shows up as ``pipeline.overlap_ms``.
 from __future__ import annotations
 
 import threading
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from .. import planops
+from .. import bucketed, plancheck, planops
 from ..column import Column, Table
 from ..utils import buckets, metrics
 from .mesh import SHUFFLE_AXIS, shard_map
@@ -77,8 +109,9 @@ from .tolerant import MeshRunner, run_collective
 
 # planned receive rows per device of the calling thread's last exchange
 # stage (the stage reads them to the host to size it) and the two
-# capacities it rounded from them: the serving tier's work item moves
-# them onto its session with take_exchange()
+# capacities it rounded from them, then the same for the groups of a
+# groupby behind it: the serving tier's work item moves them onto its
+# session with take_exchange()
 _LAST_EXCHANGE = threading.local()
 
 
@@ -86,7 +119,10 @@ def take_exchange():
     """``(planned receive rows per device, cap, pair_cap)`` of this
     thread's last exchange stage, once (None when no exchange ran
     since): ``cap`` the rows a device the exchange program was built
-    for, ``pair_cap`` the hottest (src, dst) pair's rounded rows."""
+    for, ``pair_cap`` the hottest (src, dst) pair's rounded rows. Where
+    a groupby rode the exchange, ``groups per device`` and ``group_cap``
+    follow: the candidate groups a device the reduce program was built
+    for, the bucket of the largest device's groups."""
     plan, _LAST_EXCHANGE.plan = getattr(_LAST_EXCHANGE, "plan", None), None
     return plan
 
@@ -111,23 +147,57 @@ def _split_at_exchange(ops: Sequence[dict]):
     return list(ops[:i]), ops[i], list(ops[i + 1:])
 
 
+class _Behind(NamedTuple):
+    """What runs a device at a time directly behind the exchange: the
+    ``op`` (a groupby) and ``again``, the exchange's partition restated
+    over the op's OUTPUT columns (the rule that admitted the op resolved
+    its keys there): what puts the groups in (pid, key) order."""
+
+    op: dict
+    again: dict
+
+
 def _check_supported(ops: Sequence[dict], table: Table,
                      rest: Sequence[Table]):
+    """``(pre, part, group, tail)``: the scan-side chain, the exchange,
+    what runs a device at a time directly behind it (:class:`_Behind`:
+    a groupby on the exchange's keys) and the chain behind that;
+    ``part`` and ``group`` None where the plan has none. Raises
+    :class:`MeshUnsupported` for every other plan."""
     if rest:
         raise MeshUnsupported("mesh plan path takes no rest tables")
     if not ops:
         raise MeshUnsupported("empty plan")
     if not table.columns or table.logical_row_count == 0:
         raise MeshUnsupported("empty table")
-    pre, part, post = _split_at_exchange(ops)
+    pre, part, tail = _split_at_exchange(ops)
+    group = None
+    head = planops.OPS.get(tail[0].get("op")) if part and tail else None
+    if head is not None and head.behind_exchange is not None:
+        op, tail = tail[0], tail[1:]
+        flowing = plancheck.schema_behind(
+            pre, plancheck.schema_of_table(table), table.names
+        )
+        keys, why = (
+            (None, "the scan-side chain's schema cannot be inferred")
+            if flowing is None
+            else head.behind_exchange(op, part, *flowing)
+        )
+        if why:
+            raise MeshUnsupported(
+                f"op {op['op']!r} cannot run behind this exchange: {why}"
+            )
+        group = _Behind(op, {"op": "partition", "kind": "hash",
+                             "keys": keys, "num": part["num"]})
     row_local = sorted(n for n, s in planops.OPS.items() if s.row_local)
-    for op in (*pre, *post):
+    for op in (*pre, *tail):
         name = op.get("op")
         if name not in row_local:
             raise MeshUnsupported(
                 f"op {name!r} is not row-local; mesh path handles "
-                f"{row_local} chains (around one "
-                "optional partition boundary) only"
+                f"{row_local} chains around one optional partition "
+                "boundary, and one groupby on the partition's keys "
+                "directly behind a hash partition"
             )
     if part is not None and part.get("kind", "hash") == "range" and pre:
         # range splitters are sampled from the exchange INPUT; with a
@@ -138,7 +208,20 @@ def _check_supported(ops: Sequence[dict], table: Table,
             "range partition needs an empty scan-side chain: splitters "
             "are sampled from the full exchange input"
         )
-    return pre, part, post
+    return pre, part, group, tail
+
+
+def exact_ops(ops: Sequence[dict], table: Table,
+              rest: Sequence[Table] = ()) -> list:
+    """The plan the single-device path runs in a mesh session's stead
+    after ``faults.Degraded``: ``ops``, with the groups of a groupby the
+    mesh stage ran behind its exchange re-partitioned by the same hash.
+    A stable reorder of the groupby's key order by partition id is
+    (pid, key): the order contract, whatever answers."""
+    pre, part, group, tail = _check_supported(ops, table, rest)
+    if group is None:
+        return list(ops)
+    return [*pre, part, group.op, group.again, *tail]
 
 
 def _pack_sharded(table: Table, mesh, axis: str, n: int):
@@ -216,7 +299,7 @@ def _gather_prefix(out_t: Table, out_c, size: int) -> Table:
     )
 
 
-def _stage_program(which: str, mesh, axis: str, pt: Table, pre,
+def _stage_program(which: str, mesh, axis: str, pt, pre,
                    part: Optional[dict] = None, post=(),
                    cap: Optional[int] = None,
                    pair_cap: Optional[int] = None):
@@ -232,10 +315,21 @@ def _stage_program(which: str, mesh, axis: str, pt: Table, pre,
       (rows a destination receives, the hottest pair's rows) so that
       one read fetches both;
     * ``exchange`` ``(pt, cnt, counts, splitters)`` -> ``(table, rows a
-      shard, worst overflow)``: scan-side chain, ragged exchange into
+      shard, worst overflow, rows a device holds behind the exchange as
+      it counted them)``: scan-side chain, ragged exchange into
       ``cap`` rows a device (``pair_cap`` a pair, where the
       implementation shapes a buffer by it), stable pid sort,
-      merge-side chain.
+      merge-side chain ``post``. Where ``post`` is the groupby that
+      rides the exchange, its sort half: the table is then its sorted
+      state (``ops.groupby.SortedGroups``) and the rows a shard its
+      groups, as a fused segment with a groupby tail leaves them;
+    * ``groupby`` ``(state, groups a shard)`` -> ``(table, rows a shard,
+      rows kept)``: ``pt`` is that sorted state, ``pre`` the groupby,
+      ``part`` the exchange's partition over the groupby's output
+      (``_Behind.again``); its per-group half at ``cap`` candidate
+      groups a device, the groups put in (pid, key) order where a
+      device holds several of ``part``'s partitions, then the chain
+      ``post``.
 
     The key holds everything static the body closes over: the op lists,
     ``pt``'s schema and physical rows, the mesh (axis, size, platform
@@ -252,15 +346,24 @@ def _stage_program(which: str, mesh, axis: str, pt: Table, pre,
     if impl == "ragged":
         # only the dense form shapes a buffer by the hottest pair
         pair_cap = None
+    if which == "groupby":
+        # a sorted state is no table: what ``bucketed._reduce_groups``
+        # keys its half by stands for its schema and rows
+        tables, shape = (), (
+            pt.slots, buckets.table_signature(pt.keys),
+            int(pt.perm.shape[0]),
+        )
+    else:
+        tables, shape = (pt,), ()
     key = buckets.cache_key(
         "mesh." + which,
         {"pre": list(pre), "part": part, "post": list(post)},
-        (pt,),
+        tables,
         (
             axis, size, mesh.devices.flat[0].platform,
             tuple(int(d.id) for d in mesh.devices.flat),
             impl, cap, pair_cap,
-        ),
+        ) + shape,
     )
 
     def build():
@@ -299,6 +402,39 @@ def _stage_program(which: str, mesh, axis: str, pt: Table, pre,
                 local, keys, splitters
             )
 
+        def by_pid(t: Table, occ, splitters=()):
+            """``t``'s occupied rows first, in stable partition-id
+            order (padding keyed past every real pid)."""
+            skey = jnp.where(
+                occ, pids_of(t, splitters).astype(jnp.int32), num
+            )
+            perm = jnp.argsort(skey, stable=True).astype(jnp.int32)
+            return jax.tree_util.tree_map(
+                lambda x: None if x is None else x[perm], t
+            )
+
+        if which == "groupby":
+            from ..ops.groupby import groupby_reduce
+
+            def groupby_body(state, g):
+                with jax.named_scope("srt.groupby"):
+                    t = groupby_reduce(state, g[0], cap)
+                if num > size:
+                    # several partitions on this device: the groupby's
+                    # key order -> (pid, key), the order contract
+                    with jax.named_scope("srt.partition"):
+                        t = by_pid(t, buckets.tail_valid(cap, g[0]))
+                t3, n3 = plan_mod._run_segment_traced(post, t, g[0])
+                return t3, rows(n3)
+
+            reduce = sharded(groupby_body)
+
+            def groupby_program(state, groups):
+                t3, n3 = reduce(state, groups)
+                return t3, n3, jnp.sum(n3)
+
+            return groupby_program
+
         def scan(local, c, splitters):
             """Scan-side chain -> (table, which rows are real, the
             device each goes to)."""
@@ -333,24 +469,18 @@ def _stage_program(which: str, mesh, axis: str, pt: Table, pre,
                 )
                 # restore the exact path's order: received rows arrive
                 # in stable (src, in-src) order; a stable sort by
-                # recomputed pid (padding keyed past every real pid)
-                # makes this device hold its contiguous slice of the
-                # globally pid-sorted table
-                pid2 = pids_of(out, splitters)
-                skey = jnp.where(occ, pid2.astype(jnp.int32), num)
-                perm = jnp.argsort(skey, stable=True).astype(jnp.int32)
-                sorted_t = jax.tree_util.tree_map(
-                    lambda x: None if x is None else x[perm], out
-                )
+                # recomputed pid makes this device hold its contiguous
+                # slice of the globally pid-sorted table
+                sorted_t = by_pid(out, occ, splitters)
                 n_recv = jnp.sum(occ.astype(jnp.int32))
             t3, n3 = plan_mod._run_segment_traced(post, sorted_t, n_recv)
-            return t3, rows(n3), rows(overflow)
+            return t3, rows(n3), rows(overflow), rows(n_recv)
 
         exchange = sharded(exchange_body, replicated=2)
 
         def exchange_program(packed, cnt, counts, splitters):
-            t3, n3, overflow = exchange(packed, cnt, counts, splitters)
-            return t3, n3, jnp.max(overflow)
+            t3, n3, overflow, held = exchange(packed, cnt, counts, splitters)
+            return t3, n3, jnp.max(overflow), held
 
         return exchange_program
 
@@ -401,11 +531,15 @@ def _rowlocal_stage(seg_ops, table: Table, n: int, axis: str):
     return stage
 
 
-def _partition_stage(pre, part, post, table: Table, n: int, axis: str,
-                     prepared: Optional[dict] = None):
+def _partition_stage(pre, part, group, post, table: Table, n: int,
+                     axis: str, prepared: Optional[dict] = None):
     """Stage closure for a plan with one partition boundary: scan-side
     chain -> counts pass -> ragged exchange -> stable pid sort ->
     merge-side chain, all re-derivable from the host-side lineage.
+    With a ``group`` op behind the exchange (:func:`_check_supported`)
+    the exchange program ends with its sort half and a second program
+    reduces every device's groups and runs the chain ``post``
+    (:func:`_groupby_stage`).
 
     ``prepared`` (from :func:`prepare_exchange`) carries a pack + counts
     pass already run for a specific mesh — reused only when the stage
@@ -472,15 +606,21 @@ def _partition_stage(pre, part, post, table: Table, n: int, axis: str,
             })
 
         fn = _stage_program(
-            "exchange", mesh, axis, pt, pre, part, post, cap, pair_cap
+            "exchange", mesh, axis, pt, pre, part,
+            post if group is None else [group.op], cap, pair_cap,
         )
         # the exchange launch through the overflow read: device-ended
         with metrics.span("mesh.exchange"):
-            out_t, out_c, out_ov = run_collective(
+            out_t, out_c, out_ov, held = run_collective(
                 "plan.partition_exchange",
                 lambda: fn(pt, cnt, counts, spl),
                 site="shuffle",
             )
+            if group is not None:
+                # the groupby's count read rides the overflow read, and
+                # with it the rows every device's sort half was handed
+                # srt: allow-host-sync(two-launch groupby: every device's group count sizes the per-group half, read once with the exchange's overflow)
+                out_ov, groups, held = jax.device_get((out_ov, out_c, held))
             # capacity came from the real counts, so overflow means a
             # bug — surface it loudly rather than gathering a truncated
             # result
@@ -488,10 +628,48 @@ def _partition_stage(pre, part, post, table: Table, n: int, axis: str,
         if metrics.enabled():
             metrics.counter_add("partition.mesh_segments")
             metrics.counter_add("partition.rows_exchanged", n)
+        if group is not None:
+            out_t, out_c = _groupby_stage(
+                mesh, axis, group, post, out_t, out_c, groups,
+                int(held.sum()),
+            )
         with metrics.span("mesh.gather"):
             return _gather_prefix(out_t, out_c, size)
 
     return stage
+
+
+def _groupby_stage(mesh, axis: str, group: _Behind, post, state, count,
+                   groups, rows_in: int):
+    """The per-group half of the groupby behind the exchange, then the
+    chain ``post``, as one sharded launch -> (sharded table, rows a
+    shard on the host). ``state`` is the exchange program's sorted
+    state, ``count`` every device's group count as it left it and
+    ``groups`` the same on the host, read already; ``rows_in`` the rows
+    the devices counted behind the exchange (what the aggregate was
+    handed, not what the counts pass planned). The program is built for
+    the bucket of the LARGEST (at most the rows a device holds, as
+    ``bucketed._reduce_groups`` sizes its half), so one program serves
+    every device whatever it received."""
+    size = int(mesh.shape[axis])
+    k = bucketed.group_bucket(
+        int(groups.max()), int(state.perm.shape[0]) // size
+    )
+    _LAST_EXCHANGE.plan = (*_LAST_EXCHANGE.plan, groups, k)
+    if metrics.enabled():
+        metrics.counter_add("mesh.groupby.stages")
+        metrics.counter_add("mesh.groupby.rows_in", rows_in)
+        metrics.counter_add("mesh.groupby.groups", int(groups.sum()))
+        # every device reduces at `k` candidate groups, whatever it holds
+        metrics.counter_add("mesh.groupby.slot_rows", size * k)
+    fn = _stage_program(
+        "groupby", mesh, axis, state, [group.op], group.again, post, cap=k
+    )
+    # the reduce launch through its count read: device-ended
+    with metrics.span("mesh.groupby", rows=k):
+        out_t, out_c, _ = fn(state, count)
+        # srt: allow-host-sync(stage boundary: the rows each device kept size the gather that follows)
+        return out_t, jax.device_get(out_c)
 
 
 def run_plan_mesh(
@@ -501,7 +679,8 @@ def run_plan_mesh(
     rest: Sequence[Table] = (),
 ) -> Table:
     """Run a row-local plan (optionally around one ``partition``
-    boundary) data-parallel over ``runner``'s mesh.
+    boundary, with one groupby on its keys behind it) data-parallel
+    over ``runner``'s mesh.
 
     Never consumes ``table`` (the un-donated input IS the replay
     lineage); returns the exact (unpadded) result table, HOST-backed:
@@ -513,7 +692,7 @@ def run_plan_mesh(
     its device floor.
     """
     _LAST_EXCHANGE.plan = None
-    pre, part, post = _check_supported(ops, table, rest)
+    pre, part, group, post = _check_supported(ops, table, rest)
     # a bucket-padded wire upload shrinks to its real rows first: the
     # mesh stage derives its own shard padding, and the caller's padded
     # input stays untouched (it is the fallback path's donation)
@@ -526,7 +705,7 @@ def run_plan_mesh(
         )
     return runner.run_stage(
         "plan.mesh.partition",
-        _partition_stage(pre, part, post, table, n, axis),
+        _partition_stage(pre, part, group, post, table, n, axis),
     )
 
 
@@ -537,7 +716,7 @@ def prepare_exchange(ops: Sequence[dict], table: Table,
     overlaps with the previous batch's exchange launch. Returns the
     prepared dict ``_partition_stage`` consumes, or None when the plan
     has no partition boundary (nothing worth staging ahead)."""
-    pre, part, post = _check_supported(ops, table, ())
+    pre, part, _, _ = _check_supported(ops, table, ())
     if part is None:
         return None
     table = buckets.unpad_table(table)
@@ -576,7 +755,7 @@ def run_plan_mesh_stream(
     batches = list(batches)
     if not batches:
         return []
-    pre, part, post = _check_supported(ops, batches[0], ())
+    pre, part, group, post = _check_supported(ops, batches[0], ())
 
     def prepare(b: Table):
         return (b, prepare_exchange(ops, b, runner))
@@ -592,7 +771,7 @@ def run_plan_mesh_stream(
             )
         return runner.run_stage(
             "plan.mesh.partition",
-            _partition_stage(pre, part, post, t, n, axis,
+            _partition_stage(pre, part, group, post, t, n, axis,
                              prepared=prepared),
         )
 

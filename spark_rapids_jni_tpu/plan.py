@@ -42,7 +42,13 @@ Telemetry (``plan.*``, through the metrics registry + flight recorder):
 ``plan.fused_ops``/``plan.exact_ops``/``plan.fallbacks``/
 ``plan.declined`` counters (plus ``plan.mesh_segments``/
 ``plan.mesh_declined``/``plan.mesh_fallbacks`` when a mesh runner is
-offered — see ``parallel/planmesh.py``), a ``plan`` span wrapping each run with one
+offered — see ``parallel/planmesh.py``, whose stage counts what it
+sized beside its ``mesh.pack``/``mesh.counts``/``mesh.exchange``/
+``mesh.groupby``/``mesh.gather`` spans: ``mesh.exchange.slot_rows``/
+``.recv_rows``, ``mesh.gather.rows_read``/``.rows_kept`` and, where a
+groupby rides the exchange, ``mesh.groupby.stages``/``.rows_in`` (the
+rows the devices counted behind the exchange)/``.groups``/``.slot_rows``
+(devices x the group bucket)), a ``plan`` span wrapping each run with one
 ``plan.segment.<sig>`` span per segment (its device-ended time is the
 completion clock's ``device.plan.segment.<sig>``, utils/devclock.py),
 ``plan.fallback`` flight instants,
@@ -372,8 +378,10 @@ def run_plan(
     here silently; a mesh whose degradation ladder hits its device
     floor falls back to this single-device exact path (metered as
     ``plan.mesh_fallbacks`` — the serving tier's keep-the-tenant
-    guarantee). The mesh path never consumes ``table``, so both
-    fallbacks are safe even with ``donate_input=True``.
+    guarantee), which then runs ``planmesh.exact_ops``: the same plan
+    in the mesh stage's row order. The mesh path never consumes
+    ``table``, so both fallbacks are safe even with
+    ``donate_input=True``.
 
     ``donate_input=True`` declares ``table`` consumed by this plan —
     nothing else holds its buffers (a wire upload, a resident id the
@@ -395,7 +403,7 @@ def run_plan(
     # path is a plan like any other to whoever reads the `plan` timer
     with metrics.span("plan", ops=len(ops)):
         if mesh_runner is not None:
-            out = _offer_mesh(ops, table, rest, mesh_runner)
+            out, ops = _offer_mesh(ops, table, rest, mesh_runner)
             if out is not None:
                 return out
         return _run_segments(ops, table, rest, donate_input)
@@ -409,8 +417,13 @@ def segment_sig(seg_ops: Sequence[dict]) -> str:
 
 
 def _offer_mesh(ops, table: Table, rest, mesh_runner):
-    """Offer the plan to the mesh data-parallel path -> the result, or
-    None where the single-device path below has to run it."""
+    """Offer the plan to the mesh data-parallel path -> ``(result,
+    ops)``: the result None where the single-device path below has to
+    answer, with the ops it then runs. A declined plan runs as written;
+    one the mesh stage took and gave up (``faults.Degraded``) runs
+    ``planmesh.exact_ops``, which keeps the stage's row order: an
+    aggregate behind an exchange comes back by (partition id, key) from
+    one device as from four."""
     from .parallel import planmesh
 
     # the mesh path runs the whole plan as ONE sharded stage, so it
@@ -435,7 +448,7 @@ def _offer_mesh(ops, table: Table, rest, mesh_runner):
             metrics.counter_add("plan.mesh_declined")
             profiler.segment_end(pseg)
             pseg = None
-            return None
+            return None, ops
         metrics.counter_add("plan.mesh_segments")
         planops.note_launched(ops)
         profiler.segment_end(
@@ -443,7 +456,7 @@ def _offer_mesh(ops, table: Table, rest, mesh_runner):
             out_bytes=hbm.table_bytes(out),
         )
         pseg = None
-        return out
+        return out, ops
     except faults.Degraded as e:
         # collective failures persisted down to the runner's device
         # floor: the single-device exact path IS the degradation
@@ -464,7 +477,7 @@ def _offer_mesh(ops, table: Table, rest, mesh_runner):
         # the thread-local binding never leaks past this plan
         if pseg is not None:
             profiler.segment_end(pseg)
-    return None
+    return None, planmesh.exact_ops(ops, table, rest)
 
 
 def _selecting_joins(ops, table: Table, orig_rest: tuple):

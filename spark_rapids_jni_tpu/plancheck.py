@@ -45,6 +45,7 @@ __all__ = [
     "PlanCheckError",
     "schema_from_wire",
     "schema_of_table",
+    "schema_behind",
     "predict_segments",
     "analyze",
     "check_plan",
@@ -83,6 +84,25 @@ def schema_of_table(table) -> List[ColType]:
         else:
             out.append(ColType(d.id, int(d.scale)))
     return out
+
+
+def schema_behind(ops, schema, names=None):
+    """``(schema, names)`` of the table that flows out of ``ops`` over an
+    input of ``schema``, by the ops' inference rules alone; None where a
+    rule rejects an op or loses the schema (the op's own path then says
+    why)."""
+    st = _State(list(schema), names, None, ())
+    for op in ops:
+        spec = planops.OPS.get(op.get("op")) if isinstance(op, dict) else None
+        if spec is None:
+            return None
+        try:
+            st.schema, st.names, st.rows = spec.infer(op, st)
+        except _Reject:
+            return None
+        if st.schema is None:
+            return None
+    return st.schema, st.names
 
 
 class PlanCheckError(ValueError):
@@ -148,7 +168,8 @@ _EXACT_REASONS = {
     "sample": "data-dependent gather: exact path only",
     "partition": "exchange boundary: exact path reorders in place; "
                  "the mesh path (planmesh) runs a counts-sized "
-                 "all-to-all here and fuses the chains either side",
+                 "all-to-all here, fuses the chains either side and "
+                 "runs a groupby on its keys a device at a time behind it",
     "to_rows": "row-format transpose: exact path only",
     "from_rows": "row-format transpose: exact path only",
 }

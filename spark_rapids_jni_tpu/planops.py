@@ -26,6 +26,13 @@
 * ``row_local`` — its output over a row range depends only on those
   rows (the OOM half-batch split and the mesh chains may chunk it);
   ``exchange`` — it is a mesh exchange boundary;
+  ``behind_exchange(op, part, schema, names) -> (keys, None) |
+  (None, reason)`` — may the op run a device at a time directly behind
+  that exchange on the mesh, the union of the devices' results being
+  its result over the whole table, and where among its output columns
+  the exchange's keys then lie (``planrules.groupby_behind_exchange``:
+  a groupby whose keys hold the exchange's). Every other op behind or
+  in front of an exchange there has to be ``row_local``;
 * ``counts`` — the traced body changes the row count, so the one-op
   program returns the new count and the runner reads it;
   ``program`` — the name the one-op runner compiles under;
@@ -72,6 +79,7 @@ class OpSpec:
     bucketable: Union[bool, Callable[[dict], bool]] = False
     row_local: bool = False
     exchange: bool = False
+    behind_exchange: Optional[Callable] = None
     counts: bool = False
     program: Optional[str] = None
     runner: Optional[Callable] = None
@@ -440,6 +448,7 @@ OPS: Dict[str, OpSpec] = {
     "groupby": OpSpec(
         rules._r_groupby, _x_groupby, _t_groupby, fusable=_no_collect,
         bucketable=_no_collect, runner=bucketed._r_groupby,
+        behind_exchange=rules.groupby_behind_exchange,
     ),
     "join": OpSpec(
         rules._r_join, _x_join, select=_s_join, bucketable=_bucketed_how,

@@ -414,6 +414,68 @@ def _r_groupby(op, st):
     return out, None, st.rows  # groups <= rows; output names dropped
 
 
+# aggregations whose value over a group is the same bits wherever the
+# group's rows lie among a device's other rows. A served float sum is a
+# cumsum difference over every row of the device (ops/groupby.py): its
+# last bits follow the layout, and so do the means and variances built
+# on one; collect_* have no traced body at all
+_LAYOUT_FREE_AGGS = frozenset(
+    {"sum", "count", "min", "max", "nunique", "first", "last"}
+)
+
+
+def groupby_behind_exchange(op, part, schema, names):
+    """May this groupby run a device at a time BEHIND the exchange
+    ``part`` on the mesh (``parallel/planmesh``), over a table of
+    ``schema`` (known: the stage holds the table)? ``(keys, None)`` when
+    it may: the exchange's keys as positions among the groupby's OUTPUT
+    columns (which start with its ``by`` columns in order), by which the
+    stage finds a group's partition again. ``(None, reason)`` when it
+    may not. ``planops.OPS`` names this as the groupby's
+    ``behind_exchange``.
+
+    It may where the exchange is by hash on keys that are all among the
+    grouping keys: equal grouping keys then hash alike, every group
+    lies whole on one device, and the union of the devices' results is
+    the aggregate of the whole table. And where every aggregate is free
+    of the layout (``_LAYOUT_FREE_AGGS``; no float sum), so that the
+    bytes are the same on four devices, on two and on one."""
+    if part.get("kind", "hash") != "hash":
+        return None, "a groupby rides a hash exchange only"
+    keys = list(part.get("keys") or [])
+    if not keys:
+        return None, (
+            "the exchange hashes every column, the groupby's keys only some"
+        )
+    try:
+        by = [
+            _key_ref(b, schema, names, what="groupby 'by' column")
+            for b in op.get("by") or []
+        ]
+        on = [_key_ref(k, schema, names, what="partition key") for k in keys]
+        aggs = [
+            (a["agg"], _key_ref(a["column"], schema, names,
+                                what="groupby agg column"))
+            for a in op.get("aggs") or []
+        ]
+    except (_Reject, KeyError, TypeError) as e:
+        return None, f"malformed groupby: {e}"
+    if not set(on) <= set(by):
+        return None, (
+            f"partition keys {keys!r} are not all among the groupby's "
+            f"'by' columns {op.get('by')!r}: a group could span devices"
+        )
+    for agg, ci in aggs:
+        if agg not in _LAYOUT_FREE_AGGS or (
+            agg == "sum" and schema[ci].is_floating
+        ):
+            return None, (
+                f"aggregation {agg!r} of column {ci!r} is not exact in "
+                "every row layout: its bytes would follow the mesh size"
+            )
+    return [by.index(k) for k in on], None
+
+
 _KNOWN_AGGS = frozenset(
     {
         "sum",

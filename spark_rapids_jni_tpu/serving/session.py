@@ -357,12 +357,17 @@ class Session:
         with self._lock:
             self.stats["shed"] += 1
 
-    def note_mesh_recv(self, rows, cap, pair_cap) -> None:
+    def note_mesh_recv(self, rows, cap, pair_cap, groups=None,
+                       group_cap=None) -> None:
         """The last mesh exchange's planned receive rows per device and
-        the capacities the stage chose (``planmesh.take_exchange``)."""
+        the capacities the stage chose, with every device's groups and
+        the group bucket where a groupby rode the exchange
+        (``planmesh.take_exchange``)."""
         with self._lock:
             self._mesh_exchange = (
-                [int(r) for r in rows], int(cap), int(pair_cap)
+                [int(r) for r in rows], int(cap), int(pair_cap),
+                None if groups is None else [int(g) for g in groups],
+                None if group_cap is None else int(group_cap),
             )
 
     def note_mesh_reply(self, nbytes: int, host_bytes: int) -> None:
@@ -419,7 +424,7 @@ class Session:
             exchange = self._mesh_exchange
             reply = self._mesh_reply
         if exchange:
-            recv, cap, pair_cap = exchange
+            recv, cap, pair_cap, groups, group_cap = exchange
             mean = sum(recv) / len(recv)
             doc["mesh_recv"] = {
                 "rows": recv,
@@ -434,6 +439,15 @@ class Session:
                 "slot_rows": slots,
                 "pad_share": 1.0 - sum(recv) / slots,
             }
+            if groups is not None:
+                # and what the reduce program was built for: every
+                # device reduces `group_cap` candidate groups
+                doc["mesh_plan"].update({
+                    "groups": groups,
+                    "group_cap": group_cap,
+                    "group_pad_share":
+                        1.0 - sum(groups) / (len(groups) * group_cap),
+                })
         if reply:
             nbytes, host_bytes = reply
             doc["mesh_reply"] = {

@@ -575,3 +575,69 @@ def test_mesh_partition_exchange_compiles_for_four_chips(
     assert "ragged-all-to-all" in text
     assert "tpu_custom_call" in text
     _fits(compiled)
+
+
+def test_mesh_groupby_stage_compiles_for_four_chips(topo, as_tpu, monkeypatch):
+    """partition -> groupby -> project -> filter over 4 chips (TPC-H
+    Q18's heavy stage, ISSUE 46): the exchange program whose merge side is the
+    groupby's sort half hands its sorted state, sharded, to
+    ``srt_mesh_groupby``, and both compile for the described 2x2 mesh.
+    At an 8,000,000-row batch's size (2^21 rows a shard, 2^19 candidate groups) the
+    pair compiled here in 199 s and 40 s and needs 4.1 GiB a chip (PR
+    46); the suite compiles 2^13 rows a shard."""
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from spark_rapids_jni_tpu import kernels
+    from spark_rapids_jni_tpu.parallel import mesh as mesh_mod
+    from spark_rapids_jni_tpu.parallel import planmesh, shuffle
+
+    axis = mesh_mod.SHUFFLE_AXIS
+    mesh = Mesh(np.array(topo.devices), (axis,))
+    size, per, group_cap = 4, 1 << 13, 1 << 11
+
+    def sds(shape, dtype, spec=P(axis)):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, spec)
+        )
+
+    pt = Table([
+        Column(sds((per * size,), np.dtype(d.storage_dtype)), d, None)
+        for d in (dt.INT64, dt.DType(dt.TypeId.DECIMAL64, -2))
+    ])
+    cnt = sds((size,), jnp.int32)
+    monkeypatch.setattr(shuffle, "_ragged_impl", lambda impl: "ragged")
+    monkeypatch.setattr(kernels, "on_tpu", lambda: True)
+    ops = [
+        {"op": "partition", "kind": "hash", "keys": [0], "num": size},
+        {"op": "groupby", "by": [0], "aggs": [{"column": 1, "agg": "sum"}]},
+        {"op": "project", "exprs": [
+            {"col": 0}, {"col": 1},
+            {"binary": "gt", "left": {"col": 1},
+             "right": {"lit": 30000, "type_id": 26, "scale": -2}}]},
+        {"op": "filter", "mask": 2},
+    ]
+    pre, part, group, tail = planmesh._check_supported(ops, pt, ())
+    assert (pre, group.op, len(tail)) == ([], ops[1], 2)
+
+    exchange_fn = planmesh._stage_program(
+        "exchange", mesh, axis, pt, pre, part, [group.op], per, per // size
+    )
+    lowered = exchange_fn.lower(pt, cnt, sds((size, size), jnp.int32), ())
+    compiled = lowered.compile()
+    assert "ragged-all-to-all" in compiled.as_text()
+    _fits(compiled)
+
+    state = jax.tree_util.tree_map(
+        lambda o: sds(o.shape, o.dtype), lowered.out_info[0]
+    )
+    groupby_fn = planmesh._stage_program(
+        "groupby", mesh, axis, state, [group.op], group.again, tail,
+        cap=group_cap,
+    )
+    assert groupby_fn.__name__ == "srt_mesh_groupby"
+    reduced = groupby_fn.lower(state, cnt).compile()
+    # the per-group half sorts nothing: searches and gathers at the
+    # group bucket over the state the exchange program sorted
+    assert " sort(" not in reduced.as_text()
+    _fits(reduced)
