@@ -238,7 +238,9 @@ class Client:
     def stream(self, ops: list, batches: Sequence,
                deadline_s: Optional[float] = None) -> List[tuple]:
         """Run ``ops`` (a plan: JSON-able list of op dicts) over wire
-        batches; returns one result 5-tuple per batch, in order.
+        batches; returns one result 5-tuple per batch, in order. A
+        result's buffers are byte views of the reply's one receive
+        buffer (``frames.recv_frame``), which they keep alive.
         ``deadline_s`` bounds this one request (overrides the session
         default from hello)."""
         metas, buffers = frames.batches_to_parts(batches)

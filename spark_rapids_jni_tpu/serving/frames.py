@@ -42,13 +42,36 @@ daemon's side, ``client.send`` / ``client.recv`` on the client's). The
 daemon's ``recv`` opens once the length prefix has arrived, so a client
 that is thinking between commands is not in it; the client's includes
 the wait for the daemon's reply.
+
+**A frame is received once.** ``recv_frame`` reads the two length words
+and the header as the few bytes they are, checks them, and only then
+allocates ONE buffer of exactly the payload's size and lets the socket
+write into it (``recv_into``). What it returns, ``(header, payload)``,
+is the header's ``dict`` and a byte-format (``'B'``) ``memoryview`` of
+that buffer (an empty view for a frame with no buffers), and
+``batch_from_parts`` hands every column's data and validity on as a
+slice of it: a view, never a copy. ``len()`` of either is bytes. A
+caller that needs ``bytes`` semantics (hashing, ``json``) takes
+``bytes(view)`` at that place.
+
+**The ownership rule:** a frame's buffer is written once, by
+``recv_into``, and never reused or mutated; whoever holds a view keeps
+it alive. There is no pooled or per-connection receive buffer: an
+uploaded table's ``np.frombuffer`` arrays over the frame go to
+``jax.device_put``, which may alias host memory (the CPU backend) or
+read it after the call returns (the TPU), so a later frame on the same
+connection must never be able to change a resident table or a stream's
+batch in flight.
 """
 
 from __future__ import annotations
 
 import json
+import socket
 import struct
 from typing import List, Optional, Sequence, Tuple
+
+import numpy as np
 
 from ..utils import metrics
 
@@ -64,19 +87,23 @@ class ProtocolError(RuntimeError):
     header that is not a JSON object."""
 
 
-def _recv_exact(sock, n: int) -> bytes:
-    """Read exactly ``n`` bytes or raise ConnectionError on EOF."""
-    chunks = []
+def _recv_new(sock, n: int) -> memoryview:
+    """Read exactly ``n`` bytes into ONE buffer made for them and return
+    it as a byte view, or raise ConnectionError on EOF. The buffer is
+    uninitialised memory the socket writes once (no zero fill, no chunk
+    list to join); nothing else ever writes to it. Each call asks for
+    all that is left and waits for it (``MSG_WAITALL``): one system
+    call a frame where nothing interrupts it, not one a segment."""
+    view = memoryview(np.empty(n, dtype=np.uint8))
     got = 0
     while got < n:
-        chunk = sock.recv(min(n - got, 1 << 20))
-        if not chunk:
+        k = sock.recv_into(view[got:], 0, socket.MSG_WAITALL)
+        if not k:
             raise ConnectionError(
                 f"connection closed mid-frame ({got}/{n} bytes)"
             )
-        chunks.append(chunk)
-        got += len(chunk)
-    return b"".join(chunks)
+        got += k
+    return view
 
 
 def send_frame(sock, header: dict, buffers: Sequence[bytes] = (),
@@ -99,39 +126,42 @@ def send_frame(sock, header: dict, buffers: Sequence[bytes] = (),
 
 
 def _recv_prefix(sock) -> int:
-    total = _U32.unpack(_recv_exact(sock, 4))[0]
+    total = _U32.unpack(_recv_new(sock, 4))[0]
     if total < 4 or total > MAX_FRAME_BYTES:
         raise ProtocolError(f"bad frame length {total}")
     return total
 
 
-def _recv_body(sock, total: int) -> Tuple[dict, bytes]:
-    body = _recv_exact(sock, total)
-    hdr_len = _U32.unpack_from(body)[0]
+def _recv_body(sock, total: int) -> Tuple[dict, memoryview]:
+    """Header, then payload, each checked before the next is allocated:
+    the payload's buffer holds nothing else, so it starts on the
+    allocator's alignment and no header has to be cut off its front."""
+    hdr_len = _U32.unpack(_recv_new(sock, 4))[0]
     if hdr_len > total - 4:
         raise ProtocolError(
             f"header length {hdr_len} exceeds frame body {total - 4}"
         )
+    raw = bytes(_recv_new(sock, hdr_len))
     try:
-        header = json.loads(body[4:4 + hdr_len].decode())
+        header = json.loads(raw.decode())
     except (UnicodeDecodeError, json.JSONDecodeError) as e:
         raise ProtocolError(f"undecodable frame header: {e}")
     if not isinstance(header, dict):
         raise ProtocolError(
             f"frame header must be a JSON object, got {type(header).__name__}"
         )
-    return header, body[4 + hdr_len:]
+    return header, _recv_new(sock, total - 4 - hdr_len)
 
 
 def recv_frame(sock, span: str = "serving.recv",
-               include_wait: bool = False) -> Tuple[dict, bytes]:
-    """Receive one frame -> ``(header, payload)`` where ``payload`` is
-    the concatenated buffer bytes after the header. The caller's
+               include_wait: bool = False) -> Tuple[dict, memoryview]:
+    """Receive one frame -> ``(header, payload)`` where ``payload`` is a
+    byte view of the ONE buffer the frame's concatenated buffers were
+    received into (the module docstring's ownership rule). The caller's
     ``span`` opens once the length prefix is here: it times the frame's
-    bytes coming off the socket (and the copy that splits header from
-    payload), not the wait for a peer to speak. ``include_wait=True``
-    opens it before the prefix instead — the client's side, where the
-    wait for the reply IS the request."""
+    bytes coming off the socket, not the wait for a peer to speak.
+    ``include_wait=True`` opens it before the prefix instead — the
+    client's side, where the wait for the reply IS the request."""
     if include_wait:
         with metrics.span(span):
             return _recv_body(sock, _recv_prefix(sock))
@@ -176,8 +206,11 @@ def batch_to_parts(batch) -> Tuple[dict, List[bytes]]:
     )
 
 
-def batch_from_parts(meta: dict, payload: bytes, offset: int):
-    """(header meta, payload, offset) -> (wire 5-tuple, next offset)."""
+def batch_from_parts(meta: dict, payload, offset: int):
+    """(header meta, payload, offset) -> (wire 5-tuple, next offset).
+    Every buffer of the tuple is a byte view of ``payload`` (a received
+    frame's, or any bytes-like): slicing moves no byte."""
+    payload = memoryview(payload)
     try:
         type_ids = meta["type_ids"]
         scales = meta["scales"]
@@ -190,24 +223,35 @@ def batch_from_parts(meta: dict, payload: bytes, offset: int):
             f"batch meta arity mismatch: {len(type_ids)} type_ids, "
             f"{len(scales)} scales, {len(lens)} lens"
         )
-    datas: List[Optional[bytes]] = []
-    valids: List[Optional[bytes]] = []
+    datas: List[Optional[memoryview]] = []
+    valids: List[Optional[memoryview]] = []
     for dl, vl in lens:
         if dl < 0:
             datas.append(None)
         else:
             if offset + dl > len(payload):
                 raise ProtocolError("truncated batch payload")
-            datas.append(bytes(payload[offset:offset + dl]))
+            datas.append(payload[offset:offset + dl])
             offset += dl
         if vl < 0:
             valids.append(None)
         else:
             if offset + vl > len(payload):
                 raise ProtocolError("truncated batch payload")
-            valids.append(bytes(payload[offset:offset + vl]))
+            valids.append(payload[offset:offset + vl])
             offset += vl
     return (type_ids, scales, datas, valids, num_rows), offset
+
+
+def view_bytes(batches, payload: memoryview) -> int:
+    """Bytes of ``batches``' buffers that are views of ``payload``'s
+    buffer and not copies: a slice's ``obj`` is the one object that
+    exports the memory."""
+    own = payload.obj
+    return sum(
+        len(b) for batch in batches for b in (*batch[2], *batch[3])
+        if isinstance(b, memoryview) and b.obj is own
+    )
 
 
 def batches_to_parts(batches) -> Tuple[List[dict], List[bytes]]:
@@ -221,8 +265,8 @@ def batches_to_parts(batches) -> Tuple[List[dict], List[bytes]]:
     return metas, buffers
 
 
-def batches_from_parts(metas, payload: bytes) -> list:
-    """(meta list, payload) -> list of wire 5-tuples."""
+def batches_from_parts(metas, payload) -> list:
+    """(meta list, payload) -> list of wire 5-tuples over views of it."""
     out = []
     offset = 0
     for m in metas:

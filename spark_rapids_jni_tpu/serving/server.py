@@ -803,11 +803,9 @@ class Server:
         it running against a dead peer while holding HBM charge."""
         ops = self._plan_ops(header)
         tok = self._request_token(header, sess)
-        # one host copy of the payload into per-column byte strings
-        with metrics.span("serving.request_split"):
-            batches = frames.batches_from_parts(
-                header.get("batches") or [], payload
-            )
+        batches = self._split_request(
+            sess, header.get("batches") or [], payload
+        )
         # pre-admission static analysis against the first batch's wire
         # schema: a plan that statically cannot run answers a typed
         # bad_request (tagged report attached) BEFORE any scheduler
@@ -824,7 +822,6 @@ class Server:
                 schema = None
                 report = plancheck.check_plan(ops)
         n = len(batches)
-        sess.stats["bytes_in"] += len(payload)
         scope = profiler.profile_session(
             ops, label=f"serve:{sess.name}", batches=n,
             schema=schema, static=report,
@@ -942,12 +939,23 @@ class Server:
             )
         return None
 
-    def _cmd_upload(self, sock, sess, header, payload) -> None:
+    @staticmethod
+    def _split_request(sess, metas, payload) -> list:
+        """A request's batches as views of its frame (no byte moves:
+        the span times slicing), and the session's and the registry's
+        count of how much of what came in was handed on that way."""
         with metrics.span("serving.request_split"):
-            batch = frames.batches_from_parts(
-                [header.get("batch") or {}], payload
-            )[0]
-        sess.stats["bytes_in"] += len(payload)
+            batches = frames.batches_from_parts(metas, payload)
+        held = frames.view_bytes(batches, payload)
+        sess.note_frame_in(len(payload), held)
+        metrics.bytes_add("frames.bytes_in", len(payload))
+        metrics.bytes_add("frames.bytes_in.view", held)
+        return batches
+
+    def _cmd_upload(self, sock, sess, header, payload) -> None:
+        batch = self._split_request(
+            sess, [header.get("batch") or {}], payload
+        )[0]
         est = estimate_request_bytes(batch)
         sess.admit(est)
         try:

@@ -109,6 +109,9 @@ class Session:
         # (parallel/planmesh.py reads them to the host anyway)
         self._mesh_exchange: Optional[tuple] = None
         self._mesh_reply: Optional[tuple] = None
+        # of `stats["bytes_in"]`, the bytes handed on as views of the
+        # frames they arrived in (serving/frames.py: no copy)
+        self._view_bytes_in = 0
         self._waits = deque(maxlen=4096)  # queue-wait seconds
         self._lats = deque(maxlen=4096)   # submit->done latency seconds
         self.stats = {
@@ -377,6 +380,14 @@ class Session:
         with self._lock:
             self._mesh_reply = (int(nbytes), int(host_bytes))
 
+    def note_frame_in(self, nbytes: int, view_bytes: int) -> None:
+        """One request frame's payload bytes and how many of them its
+        batches hold as views of the receive buffer
+        (``frames.view_bytes``)."""
+        with self._lock:
+            self.stats["bytes_in"] += int(nbytes)
+            self._view_bytes_in += int(view_bytes)
+
     def note_latency(self, seconds: float) -> None:
         """End-to-end submit->done latency of one scheduled request —
         queue wait PLUS execution, the number the tenant experiences."""
@@ -423,6 +434,13 @@ class Session:
             }
             exchange = self._mesh_exchange
             reply = self._mesh_reply
+            view_bytes_in = self._view_bytes_in
+        if doc["bytes_in"]:
+            doc["frames_in"] = {
+                "bytes": doc["bytes_in"],
+                "view_bytes": view_bytes_in,
+                "view_share": view_bytes_in / doc["bytes_in"],
+            }
         if exchange:
             recv, cap, pair_cap, groups, group_cap = exchange
             mean = sum(recv) / len(recv)

@@ -191,7 +191,7 @@ METRIC_NAMESPACES = frozenset({
     "shuffle", "distributed", "io", "probe", "bench", "groupby",
     "join", "sort", "profile", "stream", "checkpoint", "restore",
     "mesh", "planstats", "drift", "partition", "client", "compile",
-    "kernel", "project", "device", "jax",
+    "kernel", "project", "device", "jax", "frames",
 })
 METRIC_NAME_RE = re.compile(r"^[a-z0-9_]+(\.[a-z0-9_]+)*$")
 
