@@ -134,30 +134,47 @@ class _SerializePass:
         return buf
 
 
+def _start_transfers(t: Table) -> None:
+    """Start the transfer of every device leaf of ``t``, so that they
+    cross together and a read waits for one that is already under way
+    (a host-backed leaf has none to start). A pure read: the table's
+    leaves stay as they are."""
+    for c in t.columns:
+        for leaf in (c.data, c.validity, c.lengths):
+            if leaf is not None and not isinstance(leaf, np.ndarray):
+                leaf.copy_to_host_async()
+
+
 def _padded_to_offsets(
     mat: np.ndarray, lens: np.ndarray, ctx: Optional[_SerializePass] = None
-) -> bytes:
-    """(n, pad) matrix + lengths -> offsets+payload wire bytes."""
-    offs = np.zeros((lens.shape[0] + 1,), np.int32)
+) -> memoryview:
+    """(n, pad) matrix + lengths -> the offsets+payload wire buffer.
+
+    ONE buffer is made for both and each is written into it once: the
+    cumsum into its head, the payload, row-major from whatever layout
+    ``mat`` has, into its tail."""
+    n, pad = lens.shape[0], mat.shape[1]
+    total = int(lens.sum(dtype=np.int64))
+    head = 4 * (n + 1)
+    buf = np.empty(head + total * mat.dtype.itemsize, np.uint8)
+    offs = buf[:head].view(np.int32)
+    offs[0] = 0
     np.cumsum(lens, out=offs[1:])
-    if lens.shape[0] and int(offs[-1]) == lens.shape[0] * mat.shape[1]:
+    payload = buf[head:].view(mat.dtype)
+    if n and total == n * pad:
         # constant-width rows (every length == pad): the matrix IS the
         # payload — skip the row mask + fancy gather outright. Counted
         # as saved serialize bytes: the mask buffer was never built.
         if ctx is not None:
-            metrics.bytes_add(
-                "wire.serialize.saved_bytes",
-                lens.shape[0] * mat.shape[1],
-            )
-        return offs.tobytes() + mat.tobytes()
-    if ctx is not None:
-        mask = ctx.row_mask(lens, mat.shape[1])
+            metrics.bytes_add("wire.serialize.saved_bytes", n * pad)
+        np.copyto(payload.reshape(n, pad), mat)
     else:
-        mask = np.arange(mat.shape[1])[None, :] < lens[:, None]
-    # fancy indexing already yields a fresh contiguous array — no
-    # ascontiguousarray copy on top
-    flat = mat[mask]
-    return offs.tobytes() + flat.tobytes()
+        if ctx is not None:
+            mask = ctx.row_mask(lens, pad)
+        else:
+            mask = np.arange(pad)[None, :] < lens[:, None]
+        payload[:] = mat[mask]
+    return buf.data
 
 
 def _wire_validity(valid: Optional[bytes], num_rows: int):
@@ -313,7 +330,8 @@ def _column_to_wire(
     c: Column, rows: Optional[int] = None,
     ctx: Optional[_SerializePass] = None,
 ):
-    """(type_id, scale, data bytes, valid bytes | None).
+    """(type_id, scale, data, valid | None), each buffer ``bytes`` or a
+    byte ``memoryview`` (``_as_wire``).
 
     LIST columns use the convention documented in _column_from_wire:
     scale = child type id, data = int32 offsets then child values.
@@ -331,11 +349,39 @@ def _column_to_wire(
         if _host_backed(c):
             metrics.bytes_add("wire.bytes_out.host", nbytes)
             metrics.counter_add("wire.columns_out.host")
+        view = _view_bytes(out[0], out[2], out[3])
+        if view:
+            metrics.bytes_add("wire.bytes_out.view", view)
+            metrics.counter_add("wire.columns_out.view")
     return out
 
 
 def _wire_column_bytes(data, valid) -> int:
     return len(data) + (len(valid) if valid is not None else 0)
+
+
+_HOST_WRITTEN = (int(dt.TypeId.STRING), int(dt.TypeId.LIST))
+
+
+def _view_bytes(type_id, data, valid) -> int:
+    """Of one wire column's bytes, those no host copy touched: they
+    reached the frame as a view of the array they arrived in
+    (``_as_wire``). A STRING's or LIST's data is a view too, but of a
+    buffer the host wrote (``_padded_to_offsets``), and counts as the
+    copy it is."""
+    n = 0
+    if isinstance(data, memoryview) and type_id not in _HOST_WRITTEN:
+        n += len(data)
+    if isinstance(valid, memoryview):
+        n += len(valid)
+    return n
+
+
+def wire_view_bytes(wire) -> int:
+    """``_view_bytes`` over a wire 5-tuple's columns: what the
+    session's ``replies_out`` counts beside the reply's bytes."""
+    type_ids, _, datas, valids, _ = wire
+    return sum(map(_view_bytes, type_ids, datas, valids))
 
 
 def _host_backed(c: Column) -> bool:
@@ -361,65 +407,55 @@ def _host_rows(arr: np.ndarray, rows: Optional[int]) -> np.ndarray:
     return arr if rows is None else arr[:rows]
 
 
+def _own_memory(host: np.ndarray) -> bool:
+    """``host``'s bytes lie in memory numpy allocated for an array (its
+    own, or that of the array it is a view of) and nobody else's: not a
+    device buffer ``np.asarray`` aliased (the CPU backend), not a
+    caller's ``bytearray``."""
+    while isinstance(host.base, np.ndarray):
+        host = host.base
+    return host.base is None and host.flags.owndata
+
+
+def _as_wire(host: np.ndarray):
+    """``host``'s bytes in C order, as the frame takes them: a byte view
+    of the array where it lies that way in memory of its own (the view
+    keeps it alive, and nobody writes to it after this read); ``bytes``,
+    one copy, of anything else. On the TPU ``np.asarray`` of a device
+    leaf is such an array, and outlives the device buffer it was read
+    from (``table_free``, ``table_reclaim``, a donating plan)."""
+    if host.flags.c_contiguous and _own_memory(host):
+        return host.reshape(-1).view(np.uint8).data
+    return host.tobytes()
+
+
 def _column_to_wire_impl(
     c: Column, rows: Optional[int] = None,
     ctx: Optional[_SerializePass] = None,
 ):
-    if c.dtype.id == dt.TypeId.STRING:
-        valid = (
-            None
-            if c.validity is None
-            else _host_rows(np.asarray(c.validity), rows)
-            .astype(np.uint8).tobytes()
-        )
+    valid = None
+    if c.validity is not None:
+        valid = _host_rows(np.asarray(c.validity), rows)
+        if valid.dtype != np.bool_:
+            valid = valid.astype(np.uint8)
+        valid = _as_wire(valid)
+    if c.dtype.id in (dt.TypeId.STRING, dt.TypeId.LIST):
+        is_list = c.dtype.id == dt.TypeId.LIST
         return (
-            int(dt.TypeId.STRING),
-            0,
+            int(c.dtype.id),
+            int(c.list_child_dtype.id) if is_list else 0,
             _padded_to_offsets(
                 _host_rows(np.asarray(c.data), rows),
-                _host_rows(np.asarray(c.lengths), rows).astype(np.int32),
+                _host_rows(np.asarray(c.lengths), rows)
+                .astype(np.int32, copy=False),
                 ctx,
             ),
             valid,
         )
-    if c.dtype.id == dt.TypeId.LIST:
-        child = c.list_child_dtype
-        valid = (
-            None
-            if c.validity is None
-            else _host_rows(np.asarray(c.validity), rows)
-            .astype(np.uint8).tobytes()
-        )
-        return (
-            int(dt.TypeId.LIST),
-            int(child.id),
-            _padded_to_offsets(
-                _host_rows(np.asarray(c.data), rows),
-                _host_rows(np.asarray(c.lengths), rows).astype(np.int32),
-                ctx,
-            ),
-            valid,
-        )
-    host = _host_rows(np.asarray(c.data), rows)
-    valid = (
-        None
-        if c.validity is None
-        else _host_rows(np.asarray(c.validity), rows)
-        .astype(np.uint8).tobytes()
-    )
-    if _host_backed(c) and host.flags.c_contiguous:
-        # the gather's own buffer goes to the frame: a byte view of it,
-        # which keeps it alive, and no copy
-        data = host.reshape(-1).view(np.uint8).data
-    else:
-        # tobytes() emits C-order bytes from any layout in one copy —
-        # an ascontiguousarray on top would only add a second copy for
-        # non-contiguous slices
-        data = host.tobytes()
     return (
         int(c.dtype.id.value),
         int(c.dtype.scale),
-        data,
+        _as_wire(_host_rows(np.asarray(c.data), rows)),
         valid,
     )
 
@@ -474,14 +510,30 @@ def _table_from_wire_impl(
 def _table_to_wire(t: Table):
     """One wire-serialize pass -> the 5-tuple every wire entry returns
     (shape-bucket padding sliced away host-side; one shared
-    ``_SerializePass`` scratch across the table's columns). Pure reads
-    of device buffers, so the ``serde`` fault site retries here too."""
+    ``_SerializePass`` scratch across the table's columns). Each buffer
+    is ``bytes`` or a byte view of host memory the view keeps alive
+    (``_as_wire``): the daemon's frames take either as it is, the C
+    ABI's entries answer through ``_wire_bytes``. Pure reads of device
+    buffers, so the ``serde`` fault site retries here too."""
 
     def attempt():
         faults.inject("serde")
         return _table_to_wire_impl(t)
 
     return faults.run_with_retry(attempt, "wire.out")
+
+
+def _wire_bytes(wire):
+    """``wire`` with every buffer as ``bytes``: what the native runtime
+    takes (``src/cpp/jax_runtime.cpp`` holds ``PyBytes_Check`` on each
+    and copies it into a buffer of its own)."""
+    type_ids, scales, datas, valids, rows = wire
+
+    def own(bufs):
+        # bytes(b) of a bytes is that object: nothing is copied twice
+        return [None if b is None else bytes(b) for b in bufs]
+
+    return type_ids, scales, own(datas), own(valids), rows
 
 
 def _table_to_wire_impl(t: Table):
@@ -491,9 +543,9 @@ def _table_to_wire_impl(t: Table):
     t0 = _time.perf_counter() if prof else 0.0
     with metrics.span("wire.serialize"):
         # the wait for the device apart from the host's copy: the first
-        # np.asarray below would wait all the same, under the copy's
-        # name. Only a live span waits up front; with every plane off
-        # the copies wait column by column, as they always did
+        # read below would wait all the same, under the copy's name.
+        # Only a live span waits up front; with every plane off the
+        # transfers wait for the device themselves, as the copies did
         wait = metrics.span("wire.serialize.wait")
         if wait is not metrics.NULL_SPAN:
             with wait:
@@ -503,6 +555,7 @@ def _table_to_wire_impl(t: Table):
                     if b is not None
                 ])
         with metrics.span("wire.serialize.copy"):
+            _start_transfers(t)
             for c in t.columns:
                 ti, s, d, v = _column_to_wire(c, t.logical_rows, ctx)
                 out_t.append(ti)
@@ -543,7 +596,7 @@ def table_op_wire(
         type_ids, scales, datas, valids, num_rows, pad_to
     )
     result = planops.dispatch(op, tbl)
-    return _table_to_wire(result)
+    return _wire_bytes(_table_to_wire(result))
 
 
 def _plan_pad_to(ops, num_rows: int) -> Optional[int]:
@@ -595,7 +648,7 @@ def table_plan_wire(
             type_ids, scales, datas, valids, num_rows, pad_to,
         )
         result = plan_mod.run_plan(ops, tbl, donate_input=True)
-        return _table_to_wire(result)
+        return _wire_bytes(_table_to_wire(result))
 
 
 def table_stream_wire(plan_json: str, batches: Sequence) -> list:
@@ -605,7 +658,7 @@ def table_stream_wire(plan_json: str, batches: Sequence) -> list:
     ``batches`` is a sequence of ``(type_ids, scales, datas, valids,
     num_rows)`` wire tuples; each runs the same ``plan_json`` op list
     and the returned list carries one ``table_op_wire``-shaped 5-tuple
-    per batch, in input order. With ``SPARK_RAPIDS_TPU_PIPELINE`` on,
+    per batch (every buffer ``bytes``), in input order. With ``SPARK_RAPIDS_TPU_PIPELINE`` on,
     batch N+1's wire decode and batch N-1's wire encode run on
     background workers while batch N's fused-plan executable runs on
     the calling thread (pipeline.run_stream); with the pipeline off
@@ -652,7 +705,8 @@ def table_stream_wire(plan_json: str, batches: Sequence) -> list:
             "stream", batches=len(batches), depth=pipeline.depth()
         ):
             return pipeline.run_stream(
-                batches, decode, compute, _table_to_wire
+                batches, decode, compute,
+                lambda t: _wire_bytes(_table_to_wire(t)),
             )
 
 
@@ -1076,12 +1130,21 @@ _RESIDENT_READS_CV = lockcheck.make_condition(_RESIDENT_LOCK)
 
 
 def table_download_wire(table_id: int):
+    """C-ABI entry: resident table -> the wire 5-tuple of table_op_wire,
+    every buffer ``bytes`` (``table_download_views`` has the rest)."""
+    return _wire_bytes(table_download_views(table_id))
+
+
+def table_download_views(table_id: int):
     """Resident table -> the wire 5-tuple of table_op_wire (shape-bucket
-    padding sliced away host-side; the wire never sees it). One of the
-    two BLOCKING points of the pipelined plane: a pending chain is
-    waited for here and any worker failure is replayed synchronously so
-    the originating op's labeled error raises from this call. Raises
-    the labeled KeyError on an unknown or already-freed id."""
+    padding sliced away host-side; the wire never sees it), its buffers
+    as ``_table_to_wire`` leaves them: ``bytes``, or byte views of host
+    memory that outlive the table (the daemon's download; a frame sends
+    either). One of the two BLOCKING points of the pipelined plane: a
+    pending chain is waited for here and any worker failure is replayed
+    synchronously so the originating op's labeled error raises from
+    this call. Raises the labeled KeyError on an unknown or
+    already-freed id."""
     tid = int(table_id)
     with _RESIDENT_LOCK:
         t = _RESIDENT.get(tid)

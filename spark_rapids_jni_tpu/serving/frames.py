@@ -62,6 +62,16 @@ uploaded table's ``np.frombuffer`` arrays over the frame go to
 read it after the call returns (the TPU), so a later frame on the same
 connection must never be able to change a resident table or a stream's
 batch in flight.
+
+**The send side of the rule:** a reply's buffer is ``bytes`` or a byte
+view of host memory that the view keeps alive, written by nobody after
+it was filled: the read-only host array a device leaf was downloaded
+into or the mesh gather's buffer (``runtime_bridge._as_wire``), or the
+one buffer a LIST's or STRING's offsets and payload were written into
+(``_padded_to_offsets``). ``send_frame`` hands either to ``sendall`` as
+it is. A view is taken only of memory that is the host's own: never of
+a device buffer that ``np.asarray`` aliased, which ``table_reclaim`` or
+a donating plan may delete before the frame is sent.
 """
 
 from __future__ import annotations
@@ -176,9 +186,9 @@ def recv_frame(sock, span: str = "serving.recv",
 
 
 def _as_buffer(b):
-    """``b`` as ``sendall`` takes it: a byte view of a host-backed
-    column (``runtime_bridge._column_to_wire_impl``) goes to the socket
-    as it is, anything else as ``bytes`` (which ``bytes`` already is)."""
+    """``b`` as ``sendall`` takes it: a byte view of a reply's host
+    memory (``runtime_bridge._as_wire``) goes to the socket as it is,
+    anything else as ``bytes`` (which ``bytes`` already is)."""
     return b if isinstance(b, memoryview) else bytes(b)
 
 

@@ -933,7 +933,10 @@ class Server:
             scope.__exit__(None, None, None)
         with metrics.span("serving.reply_serialize", session=sess.name):
             metas, buffers = frames.batches_to_parts(results)
-            sess.stats["bytes_out"] += sum(len(b) for b in buffers)
+            sess.note_reply_out(
+                sum(len(b) for b in buffers),
+                sum(map(rb.wire_view_bytes, results)),
+            )
             frames.send_frame(
                 sock, {"ok": True, "results": metas}, buffers
             )
@@ -1072,12 +1075,14 @@ class Server:
     def _cmd_download(self, sock, sess, header) -> None:
         rb_id = sess.rb_id(header.get("table"))
         t = self.scheduler.submit(
-            sess, lambda: rb.table_download_wire(rb_id),
+            sess, lambda: rb.table_download_views(rb_id),
             cost=1, label="download",
         )
         result = t.result()
         meta, buffers = frames.batch_to_parts(result)
-        sess.stats["bytes_out"] += sum(len(b) for b in buffers)
+        sess.note_reply_out(
+            sum(len(b) for b in buffers), rb.wire_view_bytes(result)
+        )
         frames.send_frame(sock, {"ok": True, "result": meta}, buffers)
 
     def _cmd_drain(self, sock, header) -> None:

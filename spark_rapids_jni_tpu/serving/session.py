@@ -112,6 +112,10 @@ class Session:
         # of `stats["bytes_in"]`, the bytes handed on as views of the
         # frames they arrived in (serving/frames.py: no copy)
         self._view_bytes_in = 0
+        # of `stats["bytes_out"]`, the bytes that reached a reply's
+        # frame as views of the host arrays the download read them into
+        # (runtime_bridge._as_wire: no host copy)
+        self._view_bytes_out = 0
         self._waits = deque(maxlen=4096)  # queue-wait seconds
         self._lats = deque(maxlen=4096)   # submit->done latency seconds
         self.stats = {
@@ -388,6 +392,13 @@ class Session:
             self.stats["bytes_in"] += int(nbytes)
             self._view_bytes_in += int(view_bytes)
 
+    def note_reply_out(self, nbytes: int, view_bytes: int) -> None:
+        """One reply frame's buffers: their bytes, and how many of them
+        no host copy touched (``runtime_bridge.wire_view_bytes``)."""
+        with self._lock:
+            self.stats["bytes_out"] += int(nbytes)
+            self._view_bytes_out += int(view_bytes)
+
     def note_latency(self, seconds: float) -> None:
         """End-to-end submit->done latency of one scheduled request —
         queue wait PLUS execution, the number the tenant experiences."""
@@ -435,11 +446,18 @@ class Session:
             exchange = self._mesh_exchange
             reply = self._mesh_reply
             view_bytes_in = self._view_bytes_in
+            view_bytes_out = self._view_bytes_out
         if doc["bytes_in"]:
             doc["frames_in"] = {
                 "bytes": doc["bytes_in"],
                 "view_bytes": view_bytes_in,
                 "view_share": view_bytes_in / doc["bytes_in"],
+            }
+        if doc["bytes_out"]:
+            doc["replies_out"] = {
+                "bytes": doc["bytes_out"],
+                "view_bytes": view_bytes_out,
+                "view_share": view_bytes_out / doc["bytes_out"],
             }
         if exchange:
             recv, cap, pair_cap, groups, group_cap = exchange

@@ -540,9 +540,14 @@ class TestReconnect:
             frames.send_frame(
                 c._sock, {"cmd": "upload", "batch": meta, "req": "u-77"},
                 buffers)
+            # applied = the table is registered AND the request id is
+            # recorded: the handler does both before it replies, one
+            # after the other, and a kill between the two is another
+            # window (a daemon that died mid-command)
             deadline = time.monotonic() + 10
             while time.monotonic() < deadline:
-                if any(s["tables"] for s in srv.stats()["sessions"]):
+                if any(s.dedup_get("u-77")
+                       for s in list(srv._sessions.values())):
                     break
                 time.sleep(0.01)
             c.kill()
