@@ -22,24 +22,11 @@ python3 -m pytest tests/ -q
 XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
   python3 -c "from __graft_entry__ import dryrun_multichip; dryrun_multichip(8)"
 
-# Nightly bench record (BENCH_nightly.json artifact).
-# bench.py re-prints its headline line after every config (kill-proof);
-# the artifact is the LAST PARSEABLE line, kept as a single JSON doc.
-# `|| true`: a bench killed mid-run must still publish the lines it
-# flushed (the very scenario the re-emit design exists to survive).
-python3 bench.py | tee BENCH_nightly.jsonl || true
-python3 - <<'PYEOF'
-import json
-last = None
-with open("BENCH_nightly.jsonl") as f:
-    for line in f:
-        try:
-            doc = json.loads(line)
-        except json.JSONDecodeError:
-            continue  # e.g. a final line truncated by the kill
-        last = doc
-if last is None:
-    raise SystemExit("no parseable bench line")
-with open("BENCH_nightly.json", "w") as f:
-    json.dump(last, f)
-PYEOF
+# Every cell of BENCHMARK.json end to end at its rehearsal size on the
+# CPU (four virtual devices for the mesh cells). A rehearsal prints no
+# metric: the cells are measured on the chip.
+for cell in $(python3 -c 'import json
+for w in json.load(open("BENCHMARK.json"))["workloads"]: print(w["name"])'); do
+  XLA_FLAGS=--xla_force_host_platform_device_count=4 JAX_PLATFORMS=cpu \
+    python3 -m perfbench.run --workload "$cell" --seed 0 --rehearse
+done

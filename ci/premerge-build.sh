@@ -25,16 +25,16 @@ build/dependency-check
 # static half): repo-invariant AST passes — env reads outside the
 # config plane, broad excepts that bypass the faults taxonomy, hot-path
 # env reads, wall clocks in replay-critical modules, retry on donated
-# call sites, metric-name conventions, un-tiered bench arms. Exits
-# nonzero on any finding not grandfathered in
-# tools/srt_check_baseline.json; the one-line summary is the last line.
+# call sites, metric-name conventions. Exits nonzero on any finding not
+# grandfathered in tools/srt_check_baseline.json; the one-line summary
+# is the last line.
 # SRT009 (implicit host-sync hazards in hot paths) rides the same gate.
 python3 tools/srt_check.py
 
-# Plan-literal gate: every plan literal in the bench arms and smoke
-# scripts must tag clean under the plan-time analyzer (the GpuOverrides
-# analog) — a driver must never ship a plan the runtime would reject.
-python3 tools/plancheck_literals.py bench.py ci/smoke-chaos.sh \
+# Plan-literal gate: every plan literal in the smoke scripts must tag
+# clean under the plan-time analyzer (the GpuOverrides analog) — a
+# driver must never ship a plan the runtime would reject.
+python3 tools/plancheck_literals.py ci/smoke-chaos.sh \
   ci/smoke-chaos-mesh.sh ci/smoke-spill.sh ci/smoke-restart.sh \
   ci/smoke-drift.sh ci/smoke-skew.sh ci/smoke-trace.sh \
   ci/smoke-kernels.sh
@@ -64,11 +64,6 @@ fn, args = entry()
 jax.block_until_ready(jax.jit(fn)(*args))
 print('entry OK')
 "
-
-# Observability smoke: a tiny bench config with tracing + the flight
-# recorder on must leave parseable telemetry artifacts that convert
-# into a Perfetto-loadable Chrome trace (the crash-postmortem contract).
-bash ci/smoke-observability.sh
 
 # Chaos smoke: a served stream under a seeded fault plan must recover
 # byte-identical with nonzero retry counters, the circuit breaker must
@@ -123,5 +118,11 @@ bash ci/smoke-trace.sh
 # typed DRIFT[skew] finding.
 bash ci/smoke-skew.sh
 
-# Bench smoke on whatever device this node has.
-python3 bench.py
+# Benchmark smoke: every cell of BENCHMARK.json end to end at its
+# rehearsal size on the CPU (four virtual devices for the mesh cells).
+# A rehearsal prints no metric: the cells are measured on the chip.
+for cell in $(python3 -c 'import json
+for w in json.load(open("BENCHMARK.json"))["workloads"]: print(w["name"])'); do
+  XLA_FLAGS=--xla_force_host_platform_device_count=4 JAX_PLATFORMS=cpu \
+    python3 -m perfbench.run --workload "$cell" --seed 0 --rehearse
+done

@@ -11,9 +11,6 @@ fusing multi-column passes matters:
   kernel pair's TPU replacement; 48 KB shared memory -> VMEM blocks, warp
   ballots -> vectorized bit-weight reductions).
 * ``hashing`` — fused multi-column Murmur3 table hashing in one VMEM pass.
-* ``bitonic_sort`` — batched VMEM-resident bitonic sort networks.
-* ``hash_table`` — VMEM-resident open-addressing hash build/probe (the
-  join/groupby inner loop).
 * ``registry`` — the kernel tier: one dispatchable entry per accelerated
   inner loop, selected under ``SPARK_RAPIDS_TPU_KERNELS`` with
   exact-path-fallback discipline.
@@ -32,8 +29,7 @@ import importlib
 
 import jax
 
-_SUBMODULES = ("bitonic_sort", "hash_table", "hashing", "registry",
-               "row_transpose")
+_SUBMODULES = ("hashing", "registry", "row_transpose")
 
 
 def on_tpu() -> bool:
@@ -82,6 +78,5 @@ def __dir__():
     return sorted(set(globals()) | set(_SUBMODULES))
 
 
-__all__ = ["bitonic_sort", "hash_table", "hashing", "registry",
-           "row_transpose", "on_tpu", "default_interpret",
-           "pallas_capability"]
+__all__ = ["hashing", "registry", "row_transpose", "on_tpu",
+           "default_interpret", "pallas_capability"]

@@ -23,9 +23,7 @@ tier can change performance, never bytes.
 
 Only kernels the chip's compiler accepts are registered:
 ``tests/test_chip_compile.py`` compiles every entry for a described
-v5e. The sort network (``bitonic_sort.py``) and the hash table
-(``hash_table.py``) are not entries — Mosaic refuses their in-kernel
-gathers — so no plan op tries them and falls back.
+v5e.
 
 ``KERNEL_NAMES`` is the SRT012 parity anchor: srt_check statically
 cross-checks it against this module's ``_REGISTRY`` literal, plancheck's

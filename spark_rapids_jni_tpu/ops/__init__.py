@@ -13,10 +13,9 @@ mirroring the reference's two-phase 2GB batching discipline
 
 Two scale disciplines sit above the per-op level (round 4):
 
-* ``*_chunked`` / ``*_batches`` forms split giant inputs into
-  VMEM-/fault-sized pieces automatically (groupby_chunked.py, the
-  join's chunk-probed paths) — the batching the reference applies at
-  INT_MAX bytes, applied at TPU limits; and
+* ``*_batches`` forms split giant inputs into fault-sized pieces
+  automatically (the join's chunk-probed paths) — the batching the
+  reference applies at INT_MAX bytes, applied at TPU limits; and
 * the HBM footprint planner (utils/hbm.py) sizes those pieces from a
   per-chip budget instead of constants.
 """
@@ -32,10 +31,6 @@ from .gather import gather_table, gather_column
 from .sort import sort_table, argsort_table, SortKey, is_sorted, merge_sorted
 from .hashing import murmur3_column, murmur3_table
 from .groupby import groupby_aggregate, GroupbyAgg
-from .groupby_chunked import (
-    groupby_aggregate_chunked,
-    groupby_aggregate_capped_chunked,
-)
 from .join import (
     inner_join,
     inner_join_batched,
@@ -142,8 +137,6 @@ __all__ = [
     "murmur3_column",
     "murmur3_table",
     "groupby_aggregate",
-    "groupby_aggregate_chunked",
-    "groupby_aggregate_capped_chunked",
     "inner_join_batches",
     "GroupbyAgg",
     "inner_join",

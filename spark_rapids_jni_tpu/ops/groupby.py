@@ -12,8 +12,7 @@ the group count; ``groupby_aggregate_capped`` is fully jittable with
 at the input's row count) then ``groupby_reduce`` (everything per
 group, ``num_segments`` wide) in one trace; the served runners launch
 the two apart and size the second from the group count
-(bucketed.py). Large decomposable aggregations route through the
-two-level chunked design (ops/groupby_chunked.py).
+(bucketed.py).
 
 Design note — string keys are NOT auto-dictionary-encoded here (unlike
 joins, ops/join.py): encoding costs a full-width sort of its own, the
@@ -114,7 +113,6 @@ def _segment_ids(
     key_cols: Sequence[Column],
     row_valid: Optional[jax.Array] = None,
     payload: Sequence[jax.Array] = (),
-    values_via: str = "sort",
 ):
     """(perm, seg_ids, num_groups_device, sorted_payload): stable sort +
     boundary scan.
@@ -124,13 +122,8 @@ def _segment_ids(
     garbage keys may split into any number of trailing segments; the group
     count is therefore the highest segment id holding a valid row.
 
-    ``values_via`` routes the ``payload`` arrays to sorted order:
-    ``"sort"`` rides them through the variadic sort as non-key operands
-    (each payload then pays every one of the network's O(log^2 n)
-    passes); ``"gather"`` sorts only the key words + iota and applies
-    the permutation with one O(n) gather per payload. Which wins on
-    TPU is a measured A/B (bench ``groupby16m``/``_gather`` rungs) —
-    the flat-packed CPU A/B had gather 3.5x ahead.
+    The ``payload`` arrays ride through the variadic sort as non-key
+    operands.
     """
     words, occupied_from = _key_words(key_cols, row_valid)
     # one variadic stable sort carries the iota along, yielding the
@@ -138,23 +131,13 @@ def _segment_ids(
     # re-gather of each word (jnp.lexsort would return only the perm)
     n_rows = words[0].shape[0]
     iota = jnp.arange(n_rows, dtype=jnp.int32)
-    if values_via == "sort":
-        sorted_all = jax.lax.sort(
-            tuple(words) + (iota,) + tuple(payload),
-            num_keys=len(words),
-        )
-        sorted_words = list(sorted_all[: len(words)])
-        perm = sorted_all[len(words)]
-        sorted_payload = list(sorted_all[len(words) + 1 :])
-    elif values_via == "gather":
-        sorted_all = jax.lax.sort(
-            tuple(words) + (iota,), num_keys=len(words)
-        )
-        sorted_words = list(sorted_all[: len(words)])
-        perm = sorted_all[len(words)]
-        sorted_payload = [jnp.take(p, perm, axis=0) for p in payload]
-    else:
-        raise ValueError(f"unknown values_via {values_via!r}")
+    sorted_all = jax.lax.sort(
+        tuple(words) + (iota,) + tuple(payload),
+        num_keys=len(words),
+    )
+    sorted_words = list(sorted_all[: len(words)])
+    perm = sorted_all[len(words)]
+    sorted_payload = list(sorted_all[len(words) + 1 :])
     boundary = jnp.zeros(perm.shape, dtype=jnp.bool_).at[0].set(True)
     for w in sorted_words:
         boundary = boundary | jnp.concatenate(
@@ -471,7 +454,6 @@ def groupby_sort(
     by: Sequence[Union[int, str]],
     aggs: Sequence[GroupbyAgg],
     row_valid: Optional[jax.Array] = None,
-    values_via: str = "sort",
 ) -> tuple[SortedGroups, jax.Array]:
     """First half of the capped groupby, all of it at the input's row
     count: the variadic stable sort with the value columns as payload,
@@ -526,7 +508,7 @@ def groupby_sort(
              col.dtype) + distinct[id(col)]
         )
     perm, seg, num_groups, sorted_payload = _segment_ids(
-        key_cols, row_valid, payload, values_via=values_via
+        key_cols, row_valid, payload
     )
     state = SortedGroups(
         Table(key_cols, key_names), perm, seg, tuple(sorted_payload),
@@ -610,7 +592,6 @@ def groupby_aggregate_capped(
     num_segments: int,
     row_valid: Optional[jax.Array] = None,
     return_collect_overflow: bool = False,
-    values_via: str = "sort",
 ) -> tuple[Table, jax.Array]:
     """Jittable groupby: (padded result of ``num_segments`` rows, count).
 
@@ -630,25 +611,13 @@ def groupby_aggregate_capped(
     two-phase counts let callers detect overflow — so callers that
     need losslessness check ``overflow <= list_capacity`` and resize
     (r3 advisor finding)."""
-    state, num_groups = groupby_sort(
-        table, by, aggs, row_valid=row_valid, values_via=values_via
-    )
+    state, num_groups = groupby_sort(table, by, aggs, row_valid=row_valid)
     if return_collect_overflow:
         out, overflow = groupby_reduce(
             state, num_groups, num_segments, return_collect_overflow=True
         )
         return out, num_groups, overflow
     return groupby_reduce(state, num_groups, num_segments), num_groups
-
-
-# above this, SPARK_RAPIDS_TPU_GROUPBY_FORMULATION=packed/chunked can
-# route decomposable aggregations through the two-level designs. The
-# default stays on the single variadic sort: a builder's measurement
-# before this round (not reproducible) had it 2.9x/7x AHEAD of the
-# packed/chunked bets at 16M rows — XLA's batched small sorts are
-# not VMEM-resident, so the two-level constant only comes back via the
-# explicit Pallas engines, which are still an A/B in progress.
-CHUNKED_MIN_ROWS = 4_000_000
 
 
 def groupby_aggregate(
@@ -658,37 +627,7 @@ def groupby_aggregate(
 ) -> Table:
     """Eager groupby with exact output size (one host sync). Collect
     aggregations without an explicit ``list_capacity`` get sized from
-    the largest group's valid-row count (a cheap count pre-pass).
-
-    Large inputs route by SPARK_RAPIDS_TPU_GROUPBY_FORMULATION:
-    the default "single" keeps the one-variadic-sort path that won the
-    round-5 on-chip A/B; "packed"/"chunked" opt into the two-level
-    designs (exact-or-fallback) for measurement."""
-    formulation = "single"
-    if table.row_count > CHUNKED_MIN_ROWS:
-        from ..utils.config import get_flag
-
-        formulation = get_flag("GROUPBY_FORMULATION")
-    if formulation == "packed":
-        from .groupby_packed import (
-            groupby_aggregate_packed,
-            packed_groupby_supported,
-        )
-
-        if packed_groupby_supported(table, by, aggs):
-            out = groupby_aggregate_packed(table, by, aggs)
-            if out is not None:
-                return out
-    if formulation in ("packed", "chunked"):
-        from .groupby_chunked import (
-            chunked_groupby_supported,
-            groupby_aggregate_chunked,
-        )
-
-        if chunked_groupby_supported(table, aggs):
-            out = groupby_aggregate_chunked(table, by, aggs)
-            if out is not None:
-                return out
+    the largest group's valid-row count (a cheap count pre-pass)."""
     if table.row_count == 0:
         # 0 rows -> 0 groups, but the output SCHEMA must still be exact:
         # run the real pipeline on one all-null dummy row (which forms

@@ -77,8 +77,9 @@ def column_order_fields(col: Column) -> list[tuple[jax.Array, int]]:
     """:func:`column_order_keys` with each word's width in bits: a
     column narrower than 64 bits gives a word that fills only its own
     width (BOOL8 one bit, INT8 eight), so that a sort can fold several
-    narrow keys into one word (:func:`fold_fields`) instead of comparing
-    a 64-bit word a key. Every other column gives its 64-bit words."""
+    narrow keys into one word (``ops/groupby._key_words``) instead of
+    comparing a 64-bit word a key. Every other column gives its 64-bit
+    words."""
     d = col.dtype
     if d.is_boolean:
         return [(col.data.astype(jnp.uint64), 1)]
@@ -143,41 +144,3 @@ def rows_equal(
     for ak, bk in zip(a_keys, b_keys):
         eq = eq & (ak[a_idx] == bk[b_idx])
     return eq
-
-
-@jax.jit
-def _minmax_jit(kw):
-    return jnp.min(kw), jnp.max(kw)
-
-
-def minmax_host(kw):
-    """Host (int, int) min/max of a key-order word — the eager range
-    probe every packed-key router shares."""
-    lo, hi = _minmax_jit(kw)
-    return int(lo), int(hi)
-
-
-def fold_fields(rels, field_bits):
-    """Pack parallel relative-key u64 arrays as bit fields of ONE word
-    (first field in the high bits): lexicographic order of the tuple ==
-    numeric order of the composite. Callers validate that each rel fits
-    its declared width — the shared primitive of the packed
-    groupby/join/sort formulations."""
-    out = jnp.zeros(rels[0].shape, jnp.uint64)
-    for r, b in zip(rels, field_bits):
-        out = (out << jnp.uint64(b)) | r
-    return out
-
-
-def peel_fields(word, field_bits):
-    """Inverse of :func:`fold_fields`: the per-key relative fields."""
-    shift = 0
-    fields = []
-    for b in reversed(field_bits):
-        fields.append(
-            (word >> jnp.uint64(shift))
-            & ((jnp.uint64(1) << jnp.uint64(b)) - jnp.uint64(1))
-        )
-        shift += b
-    fields.reverse()
-    return fields

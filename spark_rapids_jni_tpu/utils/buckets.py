@@ -24,8 +24,7 @@ centrally in this module:
   ``(op, schema signature, bucket)``; a hit means the XLA executable is
   reused outright. ``compile_cache.hit``/``compile_cache.miss`` counters,
   the ``bucket.pad_waste_bytes`` counter and per-bucket histograms feed
-  the PR-1 metrics registry so ``tools/analyze_bench.py`` can report
-  cache efficiency next to throughput.
+  the metrics registry.
 
 Debugging: ``SPARK_RAPIDS_TPU_BUCKETS=off`` disables the whole plane —
 every dispatch then runs the exact-shape path, which remains the
@@ -117,8 +116,7 @@ def _parse_spec(raw: str) -> BucketPolicy:
         return BucketPolicy(enabled=True, floor=floor, growth=growth, cap=cap)
     except ValueError:
         # a typo'd bucket spec must fail loudly, not silently measure /
-        # serve with the default ladder under the wrong label (the
-        # GROUPBY_FORMULATION discipline)
+        # serve with the default ladder under the wrong label
         raise ValueError(
             f"SPARK_RAPIDS_TPU_BUCKETS must be 'floor:growth[:cap]', an "
             f"explicit 'a,b,c' list, or off|none|0 — got {raw!r}"

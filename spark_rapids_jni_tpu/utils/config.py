@@ -42,24 +42,11 @@ def _as_bool(v: str) -> bool:
     return v.strip().lower() in ("1", "true", "yes", "on")
 
 
-def _parse_formulation(v: str) -> str:
-    got = v.strip().lower()
-    if got not in ("single", "packed", "chunked"):
-        # a typo'd A/B arm must fail loudly, not silently measure
-        # the default formulation under the wrong label
-        raise ValueError(
-            f"GROUPBY_FORMULATION must be single|packed|chunked, "
-            f"got {v!r}"
-        )
-    return got
-
-
 def _parse_kernels(v: str) -> str:
     got = v.strip().lower()
     if got not in ("on", "off", "auto"):
         # a typo'd A/B arm must fail loudly, not silently measure the
-        # default routing under the wrong label (GROUPBY_FORMULATION
-        # precedent)
+        # default routing under the wrong label
         raise ValueError(
             f"KERNELS must be on|off|auto, got {v!r}"
         )
@@ -225,12 +212,6 @@ _FLAGS = {
             "(utils/hbm.py); 0 = backend default (v5e: 16)",
         ),
         Flag(
-            "GROUPBY_FORMULATION", "single", _parse_formulation,
-            "large-input eager groupby routing: single (one variadic "
-            "sort - the round-5 on-chip winner) | packed | chunked "
-            "(the two-level designs, kept for A/B)",
-        ),
-        Flag(
             "KERNELS", "auto", _parse_kernels,
             "Pallas kernel tier (kernels/registry.py): on = try every "
             "applicable hand-written kernel runner (interpret-mode off "
@@ -251,8 +232,7 @@ _FLAGS = {
         Flag(
             "FLIGHT_DUMP", "", str,
             "path to write the flight-recorder tail JSON at process "
-            "exit (atexit) and from the bench SIGTERM handler; a "
-            "non-empty path implies FLIGHT",
+            "exit (atexit); a non-empty path implies FLIGHT",
         ),
         Flag(
             "BUCKETS", "", str,
@@ -281,8 +261,7 @@ _FLAGS = {
         Flag(
             "PROFILE_DUMP", "", str,
             "path to write finished profile sessions as JSON at "
-            "process exit (atexit) and from the bench SIGTERM handler; "
-            "a non-empty path implies PROFILE",
+            "process exit (atexit); a non-empty path implies PROFILE",
         ),
         Flag(
             "PLANSTATS", False, _as_bool,
@@ -560,7 +539,7 @@ def place_compile_cache() -> None:
     and nothing is set in code. Otherwise the cache lives at the fixed
     path ``<checkout>/.jax_cache`` — the path is part of the cache key,
     so it never carries a temp name, a pid or a time. Entry points
-    (``chip_smoke.py``, ``benchmarks/run.py``) call this before their
+    (``chip_smoke.py``, ``perfbench/run.py``) call this before their
     first compile; the package itself never does."""
     if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return

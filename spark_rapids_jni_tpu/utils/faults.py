@@ -10,9 +10,7 @@ one file so every dispatch boundary shares a single vocabulary:
   :class:`PermanentError`, :class:`ResourceExhausted`,
   :class:`Cancelled`, :class:`DeadlineExceeded`, plus the serving-only
   :class:`Degraded` shed state — with :func:`classify` mapping raw
-  jax/XLA/runtime exceptions onto it by type and message markers (the
-  same markers bench.py's ad-hoc unreachable heuristic used; the
-  heuristic now routes through here).
+  jax/XLA/runtime exceptions onto it by type and message markers.
 * a **deterministic fault-injection harness** —
   ``SPARK_RAPIDS_TPU_FAULTS="[seed=N,]site:kind:prob[:count],..."``
   registers seeded fault rules against the named injection sites
@@ -92,8 +90,7 @@ class Degraded(FaultError):
     device. Answers immediately — a degraded daemon never hangs."""
 
 
-# message markers for transient device failures — the superset
-# of bench.py's historical _UNREACHABLE_MARKERS (gRPC/absl capitalize
+# message markers for transient device failures (gRPC/absl capitalize
 # freely, so matching is casefolded)
 _TRANSIENT_MARKERS = (
     "unreachable", "unavailable", "deadline_exceeded",
@@ -117,8 +114,8 @@ _OOM_MARKERS = (
 
 def classify_text(type_name: str, message: str) -> type:
     """Map an exception's (type name, message) onto a taxonomy CLASS —
-    the string form shared with bench.py, whose failure records carry
-    text, not live exceptions. Unrecognized input is PermanentError:
+    the string form, for records that carry text, not live
+    exceptions. Unrecognized input is PermanentError:
     retrying an unknown failure is how retry storms start."""
     msg = f"{type_name} {message}".lower()
     if any(m in msg for m in _OOM_MARKERS):

@@ -15,9 +15,8 @@ NVTX-timeline-in-Nsight workflow:
   event costs O(100ns) and the recorder can stay on under production
   traffic;
 * a **dump plane**: ``SPARK_RAPIDS_TPU_FLIGHT_DUMP`` names a file the
-  tail is written to at interpreter exit (atexit) and from the bench
-  SIGTERM handler — the two windows a killed run still owns. The dump
-  is the input of ``tools/trace2chrome.py`` / ``tracing.to_chrome_trace``
+  tail is written to at interpreter exit (atexit). The dump is the
+  input of ``tools/trace2chrome.py`` / ``tracing.to_chrome_trace``
   which turn it into a chrome://tracing / Perfetto timeline;
 * **exit sections**: subsystems register callables whose results ride
   along in the dump (``runtime_bridge`` contributes the resident-table
@@ -27,8 +26,7 @@ Gating follows the registry's ship-it-disabled discipline:
 ``SPARK_RAPIDS_TPU_FLIGHT`` truthy (or an integer ring capacity), or a
 configured ``FLIGHT_DUMP`` path, turns the recorder on; the disabled
 ``record()`` costs one cached generation compare (~100ns, asserted in
-tests/test_flight.py). ``bench.py`` forces it on the way it forces
-METRICS on.
+tests/test_flight.py).
 
 Event wire format (one tuple per slot, JSON-ified by ``tail_records``):
 
@@ -72,7 +70,7 @@ _ANCHOR_NS = time.perf_counter_ns()
 
 # ring state — (re)built under _SETUP_LOCK on config-generation change;
 # the record() hot path reads the module globals without taking it.
-# RLock: the bench SIGTERM handler dumps from the main thread and must
+# RLock: a dump from a signal handler runs on the main thread and must
 # not self-deadlock if the signal lands inside _refresh()
 _SETUP_LOCK = threading.RLock()
 _SLOTS: Optional[list] = None

@@ -6,7 +6,7 @@ byte; here XLA/PJRT owns allocation, so the observable planes are the
 ones THIS runtime owns: the ante-hoc HBM footprint planner's
 plan-vs-budget decisions (``utils/hbm.py``), live resident-table /
 native-handle counts (``runtime_bridge.py``, the leak-report analog),
-and device probe/retry events (``bench.py``).
+and device probe/retry events.
 
 One knob gates everything::
 

@@ -24,9 +24,7 @@ no-ops when it is false, so instrumented hot paths cost a couple of
 dict lookups when shipped disabled — the reference's ship-it-disabled
 default. :func:`snapshot` returns a JSON-able dict; when a dump path is
 configured the snapshot is also written there at interpreter exit
-(atexit), and ``bench.py`` embeds it per config so
-``tools/analyze_bench.py`` can correlate throughput with op counts and
-bytes moved.
+(atexit).
 """
 
 from __future__ import annotations
@@ -52,11 +50,10 @@ from . import tracing
 # registry state — one lock guards every table; mutations are a few dict
 # ops so contention stays negligible even under the concurrent-dispatch
 # test tier (tests/test_metrics.py hammers it from many threads).
-# RLock, not Lock: the bench SIGTERM handler runs on the MAIN thread and
-# calls snapshot()/dump() — if the signal lands while that same thread
-# is inside a mutator's critical section, a non-reentrant lock would
-# self-deadlock the handler (and the process would hang to SIGKILL
-# without re-printing the headline line).
+# RLock, not Lock: the package flushes its dumps at exit (atexit), and
+# an embedder's signal handler that calls snapshot()/dump() runs on the
+# MAIN thread — if the signal lands while that same thread is inside a
+# mutator's critical section, a non-reentrant lock would self-deadlock.
 # ---------------------------------------------------------------------------
 
 _LOCK = threading.RLock()
@@ -69,8 +66,7 @@ _GAUGES: Dict[str, List[float]] = {}
 # name -> {"bounds": tuple, "counts": list, "count": int, "sum": float}
 _HISTS: Dict[str, dict] = {}
 # name -> [count, total_s] of span SELF time (duration minus enclosed
-# child spans on the same thread) — what analyze_bench's
-# top-ops-by-self-time table ranks; total time alone buries the hot
+# child spans on the same thread); total time alone buries the hot
 # leaf under its wrappers
 _SELF: Dict[str, List[float]] = {}
 
@@ -280,9 +276,9 @@ NULL_SPAN = _NullSpan()
 
 # span-duration histogram edges in MILLISECONDS: ~x3 rungs from 10us to
 # 30s + overflow — wide enough for a cold compile, fine enough that
-# analyze_bench's p50/p95 estimates are meaningful. Public: subsystem-
-# owned duration histograms (pipeline.stall_ms / pipeline.overlap_ms)
-# share these edges so analyze_bench percentiles line up across planes.
+# p50/p95 estimates are meaningful. Public: subsystem-owned duration
+# histograms (pipeline.stall_ms / pipeline.overlap_ms) share these
+# edges so percentiles line up across planes.
 SPAN_MS_BOUNDS = (
     0.01, 0.03, 0.1, 0.3, 1.0, 3.0, 10.0, 30.0, 100.0, 300.0,
     1000.0, 3000.0, 10000.0, 30000.0,

@@ -851,8 +851,8 @@ def merge_sessions(docs: Sequence) -> dict:
 
 def summarize(docs: Optional[Sequence[dict]] = None) -> dict:
     """Aggregate per-segment summary across session docs — the compact
-    ``profile`` block bench.py embeds per config (full session docs
-    would bloat a many-batch config's record)."""
+    ``profile`` block (full session docs would bloat a many-batch
+    record)."""
     if docs is None:
         docs = sessions()
     segs: Dict[tuple, dict] = {}

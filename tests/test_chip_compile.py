@@ -186,22 +186,8 @@ def test_registered_kernel_compiles_at_smoke_size(one_chip, as_tpu, name):
 
 
 # ---------------------------------------------------------------------------
-# unregistered engines that do compile (kept honest for the PR that
-# points a kernel at them) and the one the partition op runs on TPU
+# the unregistered kernel the partition op runs on TPU
 # ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("engine", ["u64", "u32"])
-def test_bitonic_roll_engines_compile(one_chip, engine):
-    from spark_rapids_jni_tpu.kernels import bitonic_sort as bs
-
-    c, t = 64, 1024
-    a = jax.ShapeDtypeStruct((c, t), jnp.uint32, sharding=one_chip)
-    if engine == "u64":
-        # hi, lo + one 64-bit payload split in two
-        bs._sort_call(2, t, False).lower(a, a, a, a).compile()
-    else:
-        bs._sort_call_u32(1, t, False).lower(a, a).compile()
 
 
 def test_fused_murmur3_kernel_compiles(one_chip):

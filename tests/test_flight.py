@@ -6,8 +6,7 @@ overhead bound on the disabled path, the dump plane
 (``SPARK_RAPIDS_TPU_FLIGHT_DUMP`` + atexit + exit sections), the
 Chrome-trace exporter (golden file, schema validity, nesting, the
 crash-shaped unterminated/truncated span repairs), the
-``tools/trace2chrome.py`` CLI, the resident-table leak report, and the
-bench ``flight_tail`` failure-record field.
+``tools/trace2chrome.py`` CLI, and the resident-table leak report.
 """
 
 import json
@@ -566,43 +565,3 @@ class TestLeakReport:
         assert all(
             r["table_id"] != tid for r in rb.leak_report()
         )
-
-
-class TestBenchFlightTail:
-    def test_failure_record_grows_flight_tail(self):
-        """Satellite acceptance: 'device unreachable' is never again a
-        bare string — the failure record carries the last flight events."""
-        import bench
-
-        config.set_flag("FLIGHT", True)
-        flight.record("I", "probe.device_failed", 1)
-        flight.record("I", "probe.device_retry")
-        rec = bench._failure_record(
-            "join", "device unreachable", exc_type="DeviceUnreachable",
-        )
-        tail = rec["failure"]["flight_tail"]
-        assert [e["name"] for e in tail[-2:]] == [
-            "probe.device_failed", "probe.device_retry",
-        ]
-        json.dumps(rec)
-
-    def test_failure_record_without_flight_stays_lean(self):
-        import bench
-
-        assert not flight.enabled()
-        rec = bench._failure_record("join", ValueError("boom"))
-        assert "flight_tail" not in rec["failure"]
-
-    def test_skip_records_stay_lean(self):
-        """A fast-fail batch creates N skip records back to back — each
-        embedding the same 40-event tail would multiply the headline
-        JSON for zero information. Only ran-and-died records carry it."""
-        import bench
-
-        config.set_flag("FLIGHT", True)
-        flight.record("I", "device.unreachable", "join")
-        rec = bench._failure_record(
-            "sort", "skipped: device unreachable (fast-fail after join)",
-            exc_type="DeviceUnreachable", skipped=True,
-        )
-        assert "flight_tail" not in rec["failure"]

@@ -6,12 +6,10 @@ nesting + exception-path duration recording, thread safety under
 concurrent ``_dispatch`` calls (the Python-tier sibling of
 tests/test_concurrency.py), the resident-table round-trip acceptance
 snapshot, stdout hygiene (LOG_LEVEL=TRACE + a metrics dump must never
-touch stdout — the bench-JSON wire protocol), the bench structured
-failure records, and analyze_bench's metrics summarization.
+touch stdout).
 """
 
 import contextlib
-import importlib.util
 import json
 import os
 import subprocess
@@ -38,8 +36,7 @@ def _metrics_isolated(monkeypatch):
     monkeypatch.delenv("SPARK_RAPIDS_TPU_FLIGHT_DUMP", raising=False)
     monkeypatch.delenv("SPARK_RAPIDS_TPU_PLANSTATS", raising=False)
     monkeypatch.delenv("SPARK_RAPIDS_TPU_PLANSTATS_DIR", raising=False)
-    # flag overrides leaked by an earlier module (bench helpers run
-    # in-process set METRICS/FLIGHT/PROFILE/PLANSTATS_DIR) beat the env
+    # flag overrides leaked by an earlier module beat the env
     for f in ("METRICS", "METRICS_DUMP", "FLIGHT", "FLIGHT_DUMP",
               "PROFILE", "PROFILE_DUMP", "PLANSTATS", "PLANSTATS_DIR"):
         config.clear_flag(f)
@@ -441,216 +438,3 @@ class TestCaptureTrace:
         with tracing.capture_trace(target):
             pass
         assert "[srt][trace][WARN]" not in capsys.readouterr().err
-
-
-class TestBenchFailureRecords:
-    def test_failure_record_shape(self):
-        import bench
-
-        r = bench._failure_record(
-            "join", ValueError("boom"), elapsed_s=1.234, retries=2
-        )
-        assert r["name"] == "join"
-        assert r["error"] == "boom"
-        assert r["failure"] == {
-            "type": "ValueError",
-            "message": "boom",
-            "class": "PermanentError",
-            "elapsed_s": 1.234,
-            "retries": 2,
-            "backoff_ms": 0.0,
-            "skipped": False,
-        }
-        json.dumps(r)
-
-    def test_unreachable_ladder_is_structured(self, monkeypatch, tmp_path):
-        """Acceptance: every config entry carries either a metrics block
-        or a structured failure record — no bare error strings."""
-        import io
-
-        import bench
-
-        monkeypatch.setattr(bench, "_probe_device", lambda *a, **k: False)
-        monkeypatch.setattr(bench, "_stop_daemon", lambda: None)
-        monkeypatch.setattr(bench, "_STATE_PATH", str(tmp_path / "s.json"))
-        monkeypatch.setenv("SRT_BENCH_DEADLINE_S", "-1")
-        # pre-set the store dir so monkeypatch restores it: bench's
-        # _metrics_enable exports it (setdefault) for its subprocesses
-        monkeypatch.setenv(
-            "SPARK_RAPIDS_TPU_PLANSTATS_DIR", str(tmp_path / "planstats")
-        )
-        buf = io.StringIO()
-        # a run that finds no device fails — after printing the ladder
-        with contextlib.redirect_stdout(buf), pytest.raises(SystemExit) as ex:
-            bench.main()
-        assert ex.value.code == 1
-        last = json.loads(buf.getvalue().strip().splitlines()[-1])
-        assert last["headline_source"] == "none" and last["value"] is None
-        by_name = {e["name"]: e for e in last["configs"]}
-        # every ladder arm is present, plus the mesh tail's typed skip
-        # records (the arms never vanish into bare progress lines)
-        assert set(bench._LADDER) <= set(by_name)
-        for e in last["configs"]:
-            assert "metrics" in e or "failure" in e, e
-        for arm in bench._LADDER:
-            f = by_name[arm]["failure"]
-            assert f["type"] == "DeviceUnreachable"
-            assert f["message"] == "device unreachable"
-            assert f["elapsed_s"] is not None
-            assert f["retries"] == 1
-        extra = set(by_name) - set(bench._LADDER)
-        for arm in extra:
-            f = by_name[arm]["failure"]
-            assert f["skipped"] is True
-            assert f["type"] in ("BudgetExceeded", "OptInSkipped")
-
-
-def _analyze_mod():
-    spec = importlib.util.spec_from_file_location(
-        "analyze_bench", os.path.join(_ROOT, "tools", "analyze_bench.py")
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-class TestAnalyzeBench:
-    def test_merge_and_summarize_metrics(self, capsys):
-        mod = _analyze_mod()
-        block = {
-            "timers": {"dispatch.groupby": {"count": 3, "total_s": 1.5}},
-            "bytes": {"wire.bytes_in": 1_000_000},
-            "counters": {"op.groupby.calls": 3},
-        }
-        raw = [
-            {"name": "a", "seconds_median": 1.0, "metrics": block},
-            # same snapshot shared by a sibling entry: folded once
-            {"name": "b", "seconds_median": 2.0, "metrics": block},
-            {"name": "old-entry-without-metrics", "seconds_median": 3.0},
-        ]
-        merged = mod._merge_metrics(raw)
-        assert merged["timers"]["dispatch.groupby"]["count"] == 3
-        assert merged["bytes"]["wire.bytes_in"] == 1_000_000
-        mod.summarize_metrics(raw)
-        out = capsys.readouterr().out
-        assert "dispatch.groupby" in out
-        assert "wire.bytes_in" in out
-        assert "groupby" in out
-
-    def test_tolerates_old_entries(self, capsys):
-        mod = _analyze_mod()
-        mod.summarize_metrics([{"name": "x", "seconds_median": 1.0}])
-        assert "no metrics blocks" in capsys.readouterr().out
-
-    def test_hist_percentile_upper_edges(self):
-        mod = _analyze_mod()
-        # 3 observations, one per bucket: p50 lands on the 2nd edge
-        assert mod._hist_percentile([1, 10, 100], [1, 1, 1, 0], 0.5) == 10.0
-        # all mass in the overflow bucket: percentile is ">max"
-        assert mod._hist_percentile([1, 10], [0, 0, 5], 0.95) == float("inf")
-        assert mod._hist_percentile([1], [0, 0], 0.5) is None
-
-    def test_summarize_spans_percentiles_and_self_time(self, capsys):
-        mod = _analyze_mod()
-        block = {
-            "timers": {
-                "dispatch.sort_by": {
-                    "count": 3, "total_s": 1.0, "min_s": 0.1, "max_s": 0.7,
-                },
-            },
-            "histograms": {
-                "span_ms.dispatch.sort_by": {
-                    "bounds": [1, 10, 100], "counts": [1, 1, 1, 0],
-                    "count": 3, "sum": 60.0,
-                },
-                # non-span histogram must not rank as a span
-                "dispatch.rows_in": {
-                    "bounds": [1], "counts": [1, 0], "count": 1, "sum": 1.0,
-                },
-            },
-            "span_self": {
-                "dispatch.sort_by": {"count": 3, "self_s": 0.4},
-            },
-        }
-        mod.summarize_spans([{"name": "a", "metrics": block}])
-        out = capsys.readouterr().out
-        assert "span durations" in out
-        assert "dispatch.sort_by" in out
-        assert "rows_in" not in out
-        assert "top 5 ops by self time" in out
-        assert "40% of span" in out
-
-    def test_summarize_spans_tolerates_old_files(self, capsys):
-        mod = _analyze_mod()
-        # pre-flight-recorder metrics blocks and metric-less entries
-        # produce NO span section (quiet skip, not a crash)
-        mod.summarize_spans([
-            {"name": "x", "seconds_median": 1.0},
-            {"name": "y", "metrics": {"timers": {}, "bytes": {}}},
-        ])
-        assert capsys.readouterr().out == ""
-
-    def test_load_bench_file_with_failures(self, tmp_path, capsys):
-        mod = _analyze_mod()
-        doc = {
-            "metric": "groupby_sum_100M_int64",
-            "configs": [
-                {"name": "groupby_sum_16M", "seconds_median": 1.0},
-                {
-                    "name": "join",
-                    "error": "timeout 60s",
-                    "failure": {
-                        "type": "TimeoutExpired",
-                        "message": "timeout 60s",
-                        "elapsed_s": 60.0,
-                        "retries": 1,
-                    },
-                },
-            ],
-        }
-        p = tmp_path / "bench.json"
-        p.write_text(json.dumps(doc))
-        entries, raw, drift = mod._load(str(p))
-        assert "groupby_sum_16M" in entries
-        assert "join" not in entries  # failures never rank in the A/B
-        assert drift is None  # pre-planstats file: no drift block
-        mod.summarize_failures(raw)
-        out = capsys.readouterr().out
-        assert "TimeoutExpired" in out and "join" in out
-
-    def test_load_surfaces_headline_drift_block(self, tmp_path):
-        mod = _analyze_mod()
-        doc = {
-            "metric": "groupby_sum_100M_int64",
-            "drift": {"records": 6, "plans": 2,
-                      "findings": {"cardinality": 1}},
-            "configs": [{"name": "a", "seconds_median": 1.0}],
-        }
-        p = tmp_path / "bench.json"
-        p.write_text(json.dumps(doc))
-        _, _, drift = mod._load(str(p))
-        assert drift == {"records": 6, "plans": 2,
-                         "findings": {"cardinality": 1}}
-
-    def test_summarize_drift_with_findings(self, capsys):
-        mod = _analyze_mod()
-        mod.summarize_drift(
-            {"records": 6, "plans": 2,
-             "findings": {"cardinality": 1, "hbm": 2}}
-        )
-        out = capsys.readouterr().out
-        assert "6 stats record(s) over 2 plan group(s)" in out
-        assert "cardinality=1" in out and "hbm=2" in out
-        assert "explain.py --drift" in out
-
-    def test_summarize_drift_clean_store(self, capsys):
-        mod = _analyze_mod()
-        mod.summarize_drift({"records": 3, "plans": 1, "findings": {}})
-        out = capsys.readouterr().out
-        assert "no drift findings" in out
-
-    def test_summarize_drift_tolerates_old_files(self, capsys):
-        # pre-planstats BENCH files pass None through _load: quiet skip
-        mod = _analyze_mod()
-        mod.summarize_drift(None)
-        assert capsys.readouterr().out == ""

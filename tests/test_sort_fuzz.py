@@ -2,9 +2,7 @@
 
 Random multi-key sorts — mixed directions, explicit and Spark-default
 null placement, int/float/string keys, duplicate keys (stability) —
-against ``DataFrame.sort_values`` with matching na_position. The
-packed fast path and the general path are both pinned: the router's
-choice must never change the answer."""
+against ``DataFrame.sort_values`` with matching na_position."""
 
 import numpy as np
 import pandas as pd
@@ -12,7 +10,6 @@ import pytest
 
 from spark_rapids_jni_tpu.column import Column, Table
 from spark_rapids_jni_tpu.ops.sort import SortKey, sort_table
-from spark_rapids_jni_tpu.ops.sort_packed import sort_table_packed
 
 
 def _frame(rng, n, with_nulls):
@@ -96,30 +93,3 @@ def test_string_key_nulls_ordered_by_secondary():
     out = sort_table(t, [SortKey("k", True, None), SortKey("r")])
     assert out["k"].to_pylist() == [None, None, None, "aa", "mm", "zz"]
     assert out["r"].to_pylist() == [1, 3, 5, 2, 4, 0]
-
-
-@pytest.mark.parametrize("seed", [0, 1])
-def test_packed_router_parity(seed):
-    """sort_table_packed (when eligible) must equal the general path."""
-    rng = np.random.default_rng(seed + 20)
-    n = 500
-    k = rng.integers(-1000, 1000, n, dtype=np.int64)
-    w = rng.integers(0, 50, n, dtype=np.int64)
-    v = rng.standard_normal(n)
-    t = Table(
-        [Column.from_numpy(k), Column.from_numpy(w),
-         Column.from_numpy(v)],
-        ["k", "w", "v"],
-    )
-    keys = [SortKey("k", False), SortKey("w")]
-    general = sort_table(t, keys)
-    for via in ("sort", "gather"):
-        packed = sort_table_packed(t, keys, values_via=via)
-        assert packed is not None
-        for name in t.names:
-            np.testing.assert_array_equal(
-                np.asarray(packed[name].data),
-                np.asarray(general[name].data),
-                err_msg=f"{via}:{name}",
-            )
-            assert packed[name].to_pylist() == general[name].to_pylist()

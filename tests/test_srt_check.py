@@ -144,7 +144,7 @@ class TestBroadExcept:
         assert got == []
 
     def test_outside_package_not_flagged(self, tmp_path):
-        # bench.py / tools are offline drivers without the taxonomy
+        # tools are offline drivers without the taxonomy
         got = scan(tmp_path, "tools/foo.py", """
             def f():
                 try:
@@ -271,58 +271,6 @@ class TestMetricNameConvention:
             from .utils import metrics
             def f(name):
                 metrics.counter_add("op." + name)
-        """)
-        assert got == []
-
-
-BENCH_OK = """
-    _SUBPROCESS_CONFIGS = {
-        "groupby": lambda p: None,
-        "join": lambda p: None,
-    }
-    _ARM_TIERS = {
-        "groupby": "headline",
-        "join": "manual",
-    }
-"""
-
-
-class TestBenchArmTier:
-    def test_missing_table_flagged(self, tmp_path):
-        got = scan(tmp_path, "mybench.py", """
-            _SUBPROCESS_CONFIGS = {"groupby": lambda p: None}
-        """)
-        assert passes_of(got) == ["SRT007"]
-
-    def test_untiered_arm_flagged(self, tmp_path):
-        got = scan(tmp_path, "mybench.py", """
-            _SUBPROCESS_CONFIGS = {
-                "groupby": lambda p: None,
-                "join": lambda p: None,
-            }
-            _ARM_TIERS = {"groupby": "headline"}
-        """)
-        assert passes_of(got) == ["SRT007"]
-        assert "join" in got[0].message
-
-    def test_invalid_tier_and_stale_entry_flagged(self, tmp_path):
-        got = scan(tmp_path, "mybench.py", """
-            _SUBPROCESS_CONFIGS = {"groupby": lambda p: None}
-            _ARM_TIERS = {
-                "groupby": "nightly",
-                "ghost": "extended",
-            }
-        """)
-        assert sorted(passes_of(got)) == ["SRT007", "SRT007"]
-        msgs = " ".join(f.message for f in got)
-        assert "nightly" in msgs and "ghost" in msgs
-
-    def test_full_table_clean(self, tmp_path):
-        assert scan(tmp_path, "mybench.py", BENCH_OK) == []
-
-    def test_non_bench_module_exempt(self, tmp_path):
-        got = scan(tmp_path, f"{PKG}/foo.py", """
-            X = 1
         """)
         assert got == []
 
@@ -502,13 +450,6 @@ class TestRepoClean:
         new = [f.render() for f in findings
                if f.fingerprint not in baseline]
         assert new == []
-
-    def test_bench_tiers_cover_every_arm(self):
-        # import-light re-statement of SRT007 against the real bench.py
-        findings = srt.scan_file(
-            os.path.join(REPO_ROOT, "bench.py"), REPO_ROOT
-        )
-        assert [f for f in findings if f.pass_id == "SRT007"] == []
 
 
 class TestHostSync:

@@ -67,8 +67,8 @@ from spark_rapids_jni_tpu.utils.tracing import (  # noqa: E402
 
 
 def load_doc(path: str):
-    """One JSON doc from ``path``, or the LAST parseable line (bench
-    stdout / BENCH_r*.json — the analyze_bench discipline)."""
+    """One JSON doc from ``path``, or the LAST parseable line of a
+    file of JSON lines."""
     with open(path) as f:
         text = f.read()
     try:

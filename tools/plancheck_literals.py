@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """Run the plan-time analyzer over every plan LITERAL in the repo's
-drivers — the CI gate that keeps bench arms and smoke scripts inside
+drivers — the CI gate that keeps the smoke scripts inside
 the dispatch plane's statically-supported surface.
 
 Scans the given files for plan literals — a list literal whose elements
@@ -18,7 +18,7 @@ embed their plans.
 
 Usage::
 
-    python tools/plancheck_literals.py bench.py ci/smoke-chaos.sh ...
+    python tools/plancheck_literals.py chip_smoke.py ci/smoke-chaos.sh ...
 """
 
 from __future__ import annotations
@@ -154,7 +154,7 @@ def _modules_in(path: str) -> List[Tuple[str, ast.Module]]:
 def main(argv=None) -> int:
     from spark_rapids_jni_tpu import plancheck
 
-    paths = (argv if argv is not None else sys.argv[1:]) or ["bench.py"]
+    paths = (argv if argv is not None else sys.argv[1:]) or ["chip_smoke.py"]
     total = 0
     bad = 0
     for path in paths:

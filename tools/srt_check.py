@@ -35,11 +35,6 @@ Passes (each emits ``file:line:col`` findings):
   don't match the dotted-name convention (``^[a-z0-9_]+(\\.[a-z0-9_]+
   )*$``) or whose first segment isn't a registered namespace. One
   typo'd namespace splits a counter across two dashboard rows forever.
-* **SRT007 bench-arm-tier** — every ``bench.py`` arm in
-  ``_SUBPROCESS_CONFIGS`` must declare a tier (headline | extended |
-  manual) in ``_ARM_TIERS``: un-tiered arms are how bench rounds
-  r04/r05 silently blew the ``SRT_BENCH_BUDGET_S`` wall budget
-  (rc=124, headline parsed=null).
 * **SRT009 host-sync** — implicit device->host synchronizations in the
   hot dispatch modules (``plan.py``, ``bucketed.py``): ``bool()``/
   ``int()``/``float()`` over device values (``.data``/``.validity``/
@@ -117,7 +112,7 @@ DEFAULT_BASELINE = os.path.join(
 
 # scan roots relative to the repo root (tests are exempt: test code
 # legitimately monkeypatches environs and provokes broad failures)
-DEFAULT_ROOTS = ("spark_rapids_jni_tpu", "tools", "bench.py")
+DEFAULT_ROOTS = ("spark_rapids_jni_tpu", "tools")
 
 ENV_PREFIX = "SPARK_RAPIDS_TPU_"
 CONFIG_MODULE = os.path.join("spark_rapids_jni_tpu", "utils", "config.py")
@@ -188,7 +183,7 @@ METRIC_NAMESPACES = frozenset({
     "op", "wire", "resident", "dispatch", "plan", "bucket",
     "compile_cache", "pipeline", "hbm", "span", "span_ms", "serving",
     "session", "retry", "faults", "breaker", "fault", "spill", "lock",
-    "shuffle", "distributed", "io", "probe", "bench", "groupby",
+    "shuffle", "distributed", "io", "probe", "groupby",
     "join", "sort", "profile", "stream", "checkpoint", "restore",
     "mesh", "planstats", "drift", "partition", "client", "compile",
     "kernel", "project", "device", "jax", "frames",
@@ -215,8 +210,6 @@ _MINT_CALLS = frozenset({
     "getrandbits",
 })
 
-BENCH_TIERS = frozenset({"headline", "extended", "manual"})
-
 # pass -> pragma slug; a suppression comment is "srt:" then
 # "allow-" + slug + "(reason)" (see the module docstring)
 PASS_PRAGMAS = {
@@ -226,7 +219,6 @@ PASS_PRAGMAS = {
     "SRT004": "wallclock",
     "SRT005": "retry-donated",
     "SRT006": "metric-name",
-    "SRT007": "untiered-arm",
     "SRT009": "host-sync",
     "SRT010": "stats-append",
     "SRT011": "trace-context",
@@ -552,8 +544,8 @@ class _FileChecker(ast.NodeVisitor):
 
     def visit_ExceptHandler(self, node):
         # SRT002 applies to the runtime package, where the faults
-        # taxonomy lives; bench.py / tools are offline drivers whose
-        # broad excepts are best-effort harness resilience by design
+        # taxonomy lives; tools are offline drivers whose broad
+        # excepts are best-effort harness resilience by design
         broad = (
             self._broad_types(node)
             if node.type is not None and self.in_package else []
@@ -815,84 +807,6 @@ class _FileChecker(ast.NodeVisitor):
                         "reuse an existing namespace",
                     )
         self.generic_visit(node)
-
-
-# ---------------------------------------------------------------------------
-# SRT007: bench arm tier table
-# ---------------------------------------------------------------------------
-
-
-def _dict_str_keys(node: ast.Dict) -> List[Tuple[str, ast.AST]]:
-    out = []
-    for k, v in zip(node.keys, node.values):
-        if isinstance(k, ast.Constant) and isinstance(k.value, str):
-            out.append((k.value, v))
-    return out
-
-
-def check_bench_tiers(relpath: str, tree: ast.Module,
-                      pragmas: _Pragmas) -> List[Finding]:
-    configs: Optional[ast.Dict] = None
-    tiers: Optional[ast.Dict] = None
-    configs_line = 1
-    for node in tree.body:
-        if isinstance(node, ast.Assign) and len(node.targets) == 1 \
-                and isinstance(node.targets[0], ast.Name):
-            tgt = node.targets[0].id
-            if tgt == "_SUBPROCESS_CONFIGS" and isinstance(
-                node.value, ast.Dict
-            ):
-                configs = node.value
-                configs_line = node.lineno
-            elif tgt == "_ARM_TIERS" and isinstance(node.value, ast.Dict):
-                tiers = node.value
-    if configs is None:
-        return []  # not a bench module
-    findings: List[Finding] = []
-
-    def emit(pass_id, node, msg):
-        line = getattr(node, "lineno", configs_line)
-        if not pragmas.suppresses(pass_id, line):
-            findings.append(Finding(
-                pass_id, relpath, line,
-                getattr(node, "col_offset", 0), msg,
-            ))
-
-    if tiers is None:
-        emit(
-            "SRT007", configs,
-            "_SUBPROCESS_CONFIGS has no _ARM_TIERS table: every arm "
-            "must declare headline|extended|manual so the ladder walk "
-            "can budget (r04/r05 rc=124 postmortem)",
-        )
-        return findings
-    arm_names = {k for k, _ in _dict_str_keys(configs)}
-    tier_entries = _dict_str_keys(tiers)
-    tier_names = set()
-    for arm, v in tier_entries:
-        tier_names.add(arm)
-        tier = v.value if isinstance(v, ast.Constant) else None
-        if tier not in BENCH_TIERS:
-            emit(
-                "SRT007", v,
-                f"arm {arm!r} declares invalid tier {tier!r} "
-                f"(must be one of {sorted(BENCH_TIERS)})",
-            )
-        if arm not in arm_names:
-            emit(
-                "SRT007", v,
-                f"_ARM_TIERS names unknown arm {arm!r} (not in "
-                "_SUBPROCESS_CONFIGS) — stale entry?",
-            )
-    for k, v in _dict_str_keys(configs):
-        if k not in tier_names:
-            emit(
-                "SRT007", v,
-                f"bench arm {k!r} missing from _ARM_TIERS: un-tiered "
-                "arms silently eat the SRT_BENCH_BUDGET_S wall budget "
-                "— declare headline|extended|manual",
-            )
-    return findings
 
 
 # ---------------------------------------------------------------------------
@@ -1178,7 +1092,6 @@ def scan_file(path: str, repo_root: str = REPO_ROOT) -> List[Finding]:
     checker = _FileChecker(relpath, source, pragmas)
     checker.visit(tree)
     findings = checker.findings
-    findings.extend(check_bench_tiers(relpath, tree, pragmas))
     findings.extend(check_stats_append(relpath, tree, pragmas))
     findings.extend(check_kernel_parity(
         relpath, tree, pragmas,

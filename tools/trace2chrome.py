@@ -71,9 +71,8 @@ def _events_from(doc) -> list:
 
 
 def load_doc(path: str):
-    """Parse ``path`` as one JSON doc, or line-wise (bench stdout /
-    BENCH_r*.json: take the LAST parseable line, the analyze_bench
-    discipline)."""
+    """Parse ``path`` as one JSON doc, or line-wise (a file of JSON
+    lines: take the LAST parseable line)."""
     with open(path) as f:
         text = f.read()
     try:
