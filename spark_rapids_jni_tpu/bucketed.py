@@ -283,6 +283,28 @@ def _r_groupby(op: dict, table: Table, rest) -> Table:
 # its ``bucketable``
 JOIN_HOWS = frozenset({"inner", "left", "semi", "anti"})
 
+# logical rows of the two sides and of the result of the calling
+# thread's last served join and the bucket its output ran at, all host
+# integers `_r_join` holds anyway: the serving tier's work item moves
+# them onto its session with take_join() (planmesh.take_exchange's
+# sibling)
+_LAST_JOIN = threading.local()
+
+
+def take_join():
+    """``(probe_rows, build_rows, output_rows, cap)`` of this thread's
+    last served join, once (None when none ran since): ``cap`` the
+    output's bucket, the rows its materialise (a semi / anti join: its
+    compaction) ran at."""
+    plan, _LAST_JOIN.plan = getattr(_LAST_JOIN, "plan", None), None
+    return plan
+
+
+def _note_join(lt: Table, rt: Table, total: int, cap: int) -> None:
+    _LAST_JOIN.plan = (
+        lt.logical_row_count, rt.logical_row_count, total, cap
+    )
+
 
 def _build_key_facts(rt: Table, on: list) -> tuple:
     """What a `direct_key` join's build side shows, as host integers:
@@ -436,6 +458,10 @@ def _r_join(op: dict, table: Table, rest) -> Table:
     )
     if narrow:
         metrics.counter_add("join.probe.narrow")
+    if table_size is not None and not unique:
+        # the span read showed a valid build key twice: the table holds
+        # a run's head and its length (a search never asks)
+        metrics.counter_add("join.build.repeats")
     # logical rows of the two sides and (below) of the result, a served
     # join, and the entries of the table it probed (none: a search):
     # beside groupby.input_rows / groupby.reduce_rows
@@ -478,6 +504,7 @@ def _r_join(op: dict, table: Table, rest) -> Table:
         # srt: allow-host-sync(bucketed-runner boundary: the compiled launch is done; one count read sizes the logical rows of the padded result)
         total = int(count)
         metrics.counter_add("join.output_rows", total)
+        _note_join(lt, rt, total, lt.row_count)
         return _finish(out, total)
 
     # inner/left: two-phase sizing. Phase 1 (probe) compiles per input
@@ -503,6 +530,8 @@ def _r_join(op: dict, table: Table, rest) -> Table:
         # graphs the cap exists to avoid — the exact path (with its
         # fenced batched-probe routing) owns those shapes
         raise _Decline
+    _note_join(lt, rt, total, cap)
+    metrics.counter_add("join.mat.cap_rows", cap)
     p2 = buckets.cached_jit(
         _key("join.mat." + how, {"on": on}, lt, rt, extra=(cap,)),
         lambda: join_mat_program(on, cap, how == "left"),

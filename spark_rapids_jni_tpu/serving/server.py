@@ -50,7 +50,9 @@ import uuid
 from collections import deque
 from typing import Optional
 
-from .. import pipeline, plan as plan_mod, plancheck, runtime_bridge as rb
+from .. import (
+    bucketed, pipeline, plan as plan_mod, plancheck, runtime_bridge as rb,
+)
 from ..utils import (
     config,
     devclock,
@@ -1036,10 +1038,17 @@ class Server:
         est = int(hbm.table_bytes(head))
         sess.admit(est)
         plan_json = json.dumps(ops)
+
+        def work():
+            out_id = rb.table_plan_resident(plan_json, rb_ids, donate)
+            join = bucketed.take_join()
+            if join is not None:
+                sess.note_join(*join)
+            return out_id
+
         try:
             t = self.scheduler.submit(
-                sess,
-                lambda: rb.table_plan_resident(plan_json, rb_ids, donate),
+                sess, work,
                 cost=max(est // 64, 1), label="plan", charge=est,
                 token=tok,
             )

@@ -109,6 +109,10 @@ class Session:
         # (parallel/planmesh.py reads them to the host anyway)
         self._mesh_exchange: Optional[tuple] = None
         self._mesh_reply: Optional[tuple] = None
+        # rows of the two sides and of the result of the last served
+        # boundary join and its output's bucket (bucketed._r_join holds
+        # them on the host anyway)
+        self._join: Optional[tuple] = None
         # of `stats["bytes_in"]`, the bytes handed on as views of the
         # frames they arrived in (serving/frames.py: no copy)
         self._view_bytes_in = 0
@@ -377,6 +381,14 @@ class Session:
                 None if group_cap is None else int(group_cap),
             )
 
+    def note_join(self, probe_rows, build_rows, output_rows, cap) -> None:
+        """The last served boundary join's logical rows, in and out,
+        and the bucket its output ran at (``bucketed.take_join``)."""
+        with self._lock:
+            self._join = (
+                int(probe_rows), int(build_rows), int(output_rows), int(cap)
+            )
+
     def note_mesh_reply(self, nbytes: int, host_bytes: int) -> None:
         """The last mesh reply's wire bytes and how many of them were
         serialised from host-backed columns, with no transfer
@@ -445,6 +457,7 @@ class Session:
             }
             exchange = self._mesh_exchange
             reply = self._mesh_reply
+            join = self._join
             view_bytes_in = self._view_bytes_in
             view_bytes_out = self._view_bytes_out
         if doc["bytes_in"]:
@@ -484,6 +497,19 @@ class Session:
                     "group_pad_share":
                         1.0 - sum(groups) / (len(groups) * group_cap),
                 })
+        if join:
+            probe_rows, build_rows, output_rows, cap = join
+            # how far the join multiplied its rows, and the share of
+            # its output's row slots (and of every slot of the segment
+            # behind it) that hold no row
+            doc["join_plan"] = {
+                "probe_rows": probe_rows,
+                "build_rows": build_rows,
+                "output_rows": output_rows,
+                "cap": cap,
+                "fanout": output_rows / probe_rows,
+                "pad_share": 1.0 - output_rows / cap,
+            }
         if reply:
             nbytes, host_bytes = reply
             doc["mesh_reply"] = {

@@ -473,28 +473,77 @@ def test_q3_search_probe_compiles_past_the_table_s_bound(one_chip, as_tpu):
     assert len(_wide_gathers(compiled, SMOKE_BUCKET)) == 2
 
 
+def _compile_join_mat(one_chip, probe_fn, probe, build, cap):
+    """`bucketed.join_mat_program` at output bucket ``cap``, over the
+    ranges ``probe_fn`` (the join's own probe program) hands it."""
+    from spark_rapids_jni_tpu import bucketed
+
+    n32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    perm_r, lo, counts = (
+        jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+        for x in jax.eval_shape(probe_fn, probe, build, n32, n32)[:3]
+    )
+    fn = bucketed.join_mat_program([0], cap, False)
+    compiled = (
+        jax.jit(fn).lower(probe, build, perm_r, lo, counts, n32).compile()
+    )
+    _fits(compiled)
+    return compiled
+
+
 def test_q3_join_materialise_compiles_at_the_cell_s_buckets(one_chip, as_tpu):
     """...and its materialise: ~40,000 matched rows (bucket 2^16) of
     the 2^23-row probe side, each with the build side's date and
     priority, which the next op reads as group keys. Nothing in it is
     as wide as the probe side but the cumsum that places the rows: every
     gather is at the OUTPUT's bucket."""
-    from spark_rapids_jni_tpu import bucketed
-
-    probe = _table(one_chip, Q3_PROBE, SMOKE_BUCKET)
-    build = _table(one_chip, Q3_BUILD, Q3_BUILD_BUCKET)
-    n32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    perm_r, lo, counts = (
-        jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
-        for x in jax.eval_shape(_q3_probe(), probe, build, n32, n32)[:3]
+    compiled = _compile_join_mat(
+        one_chip, _q3_probe(),
+        _table(one_chip, Q3_PROBE, SMOKE_BUCKET),
+        _table(one_chip, Q3_BUILD, Q3_BUILD_BUCKET), Q3_OUT_BUCKET,
     )
-    fn = bucketed.join_mat_program([0], Q3_OUT_BUCKET, False)
-    compiled = (
-        jax.jit(fn).lower(probe, build, perm_r, lo, counts, n32).compile()
-    )
-    _fits(compiled)
     assert not _wide_gathers(compiled, SMOKE_BUCKET)
     assert _wide_gathers(compiled, Q3_OUT_BUCKET)
+
+
+# perfbench's ``tpcds-q95-wswh.selfjoin-resident`` cell: one table as both
+# sides, a build key that repeats 8..16 times, an output 12.5 times the
+# input (PR 50)
+Q95_SIDE = (dt.INT64, dt.INT64)   # ws_order_number, ws_warehouse_sk
+Q95_BUCKET = 1 << 20              # 648,000 rows
+Q95_TABLE = 1 << 24               # the partition's order numbers span 10.8M
+
+
+def _q95_probe():
+    """The cell's probe program: the table for a key that REPEATS, at a
+    build bucket of 2^16 rows or more, so the run's length is a second
+    probe-wide gather. Only its output shapes are taken here. Compiled
+    for the described chip (a scratch compile, PERF.md section 6, PR 50)
+    it is two sorts, ONE scatter into `s32[2^24]`, six gathers at 2^20
+    and no loop, in 167 s, most of them the build side's sort, which
+    this file's other sort-bearing cases already pay for: the chip run
+    holds the program itself."""
+    from spark_rapids_jni_tpu import bucketed
+
+    return bucketed.join_probe_program([0], Q95_TABLE, False, False)
+
+
+def test_self_join_materialise_compiles_at_the_ladder_s_top(
+    one_chip, as_tpu
+):
+    """The self-join's materialise at the ladder's top: 8,128,758 pairs
+    in 2^23 slots from 2^20 counts, three INT64 columns out (the key and
+    both sides' warehouse), every gather at the OUTPUT's width: ten of
+    them (three of indices, `s32`: each slot's start, its first match
+    and its build row; seven of 32-bit words of the three INT64 columns
+    and the permutation), which is what the cell's `dev_join_mat_ms` is
+    made of."""
+    side = _table(one_chip, Q95_SIDE, Q95_BUCKET)
+    compiled = _compile_join_mat(
+        one_chip, _q95_probe(), side, side, SMOKE_BUCKET
+    )
+    assert len(_wide_gathers(compiled, SMOKE_BUCKET)) == 10
+    assert not _wide_gathers(compiled, Q95_BUCKET)
 
 
 # ---------------------------------------------------------------------------
