@@ -444,6 +444,8 @@ def join_mat_program(on: list, cap: int, left_outer: bool):
 
 
 def _r_join(op: dict, table: Table, rest) -> Table:
+    from .ops.join import mat_spreads
+
     how = op.get("how", "inner")
     if how not in JOIN_HOWS or not rest:
         # right/full build on the exact outer machinery; argument
@@ -532,6 +534,11 @@ def _r_join(op: dict, table: Table, rest) -> Table:
         raise _Decline
     _note_join(lt, rt, total, cap)
     metrics.counter_add("join.mat.cap_rows", cap)
+    if mat_spreads(cap, lt.row_count):
+        # the form the materialise compiles to below, by the same
+        # predicate: an output wider than its probe side spreads the
+        # probe side's values and gathers only the build side's
+        metrics.counter_add("join.mat.spread")
     p2 = buckets.cached_jit(
         _key("join.mat." + how, {"on": on}, lt, rt, extra=(cap,)),
         lambda: join_mat_program(on, cap, how == "left"),

@@ -6,9 +6,22 @@ binary-searches lower/upper bounds lexicographically over the u64 key
 words (log2(m) rounds of gathers, fully vectorized over probe rows).
 Output cardinality is data-dependent, so materialization is two-phase:
 count matches on device, size the output (host sync in the eager API, a
-static capacity in the ``*_capped`` jittable variants), then expand with
-``jnp.repeat(..., total_repeat_length=...)`` — the XLA-static equivalent
-of the reference's two-phase batching (row_conversion.cu:505-511).
+static capacity in the ``*_capped`` jittable variants), then expand
+(`_expand` + `_join_output`) — the XLA-static equivalent of the
+reference's two-phase batching (row_conversion.cu:505-511).
+
+The expansion has two forms, and `mat_spreads` chooses between them from
+the two widths that are static in the program, the output's and the
+probe side's. Where the output is no wider than the probe side it is
+the GATHER form: ``jnp.repeat(..., total_repeat_length=...)`` makes each
+slot's probe row (one scatter of a probe side's worth of updates, one
+cumsum and one gather), and everything a slot needs of that row is one
+more gather at the output's width. Where the output is WIDER, a slot's
+probe row is constant along a run of slots, and a run-length expansion
+is not a gather: each 32-bit word of the row's values goes out as ONE
+scatter of its first differences at the runs' starts and ONE cumsum
+(`_Runs.spread`, exact in wrapping u32 arithmetic), so only the build
+side is still gathered.
 
 Nulls: null join keys never match (Spark inner-join semantics); left joins
 still emit their left rows with a null right side.
@@ -26,7 +39,7 @@ import numpy as np
 from ..column import Column, Table
 from . import compute
 from . import keys as keys_mod
-from .gather import gather_table
+from .gather import gather_column, gather_table
 
 # The fence is on the SEARCH probe's fused single-shot graph (key
 # normalization + lexsort + `_lex_searchsorted` in one compiled region):
@@ -667,10 +680,137 @@ def _match_ranges_safe(
     )
 
 
+def mat_spreads(total: int, n_left: int) -> bool:
+    """Which form a materialise of ``total`` output slots from
+    ``n_left`` probe rows takes: True for the SPREAD form (`_Runs`),
+    False for the gather form. Both widths are static in the program
+    (the output's bucket, or the exact count; the probe side's bucket),
+    so every caller of `_expand` and the host that counts
+    ``join.mat.spread`` ask this ONE function and nothing else decides.
+
+    A word spread costs one update a PROBE row and a cumsum over the
+    output, a word gathered one read an OUTPUT slot (TPU v5e, PERF.md
+    §6, PR 51: a scatter 8.7 ns an update, a cumsum 0.19 ns a slot, a
+    gather 7-9 ns a slot from the fast memory space and 21-24 from
+    HBM). The ladder's buckets grow by 2, so a wider output is at least
+    twice the probe side and the spread wins by 2x at the very least;
+    at equal widths the two are within each other's error, no cell
+    measures it, and the case stays the gather form's, as does every
+    output narrower than its probe side (TPC-H Q3's joins place 2^16
+    rows from 2^23: a scatter of 2^23 updates a word against gathers
+    that cost next to nothing)."""
+    return total > n_left
+
+
+def _as_words(x) -> list:
+    """A 1-D fixed-width leaf as u32 words a row: a 64-bit value's low
+    and high halves (no carry crosses them in `_Runs.spread`), a 32-bit
+    one's bits, a narrower one's or a bool's widened."""
+    if x.dtype == jnp.bool_:
+        return [x.astype(jnp.uint32)]
+    size = x.dtype.itemsize
+    bits = jax.lax.bitcast_convert_type(x, jnp.dtype(f"uint{8 * size}"))
+    if size == 8:
+        return [bits.astype(jnp.uint32), (bits >> 32).astype(jnp.uint32)]
+    return [bits.astype(jnp.uint32)]
+
+
+def _from_words(words: list, like):
+    """`_as_words`' inverse, for a leaf of ``like``'s dtype."""
+    if like.dtype == jnp.bool_:
+        return words[0] != 0
+    size = like.dtype.itemsize
+    if size == 8:
+        bits = words[0].astype(jnp.uint64) | (
+            words[1].astype(jnp.uint64) << 32
+        )
+    else:
+        bits = words[0].astype(jnp.dtype(f"uint{8 * size}"))
+    return jax.lax.bitcast_convert_type(bits, like.dtype)
+
+
+def _spreadable(x) -> bool:
+    """A leaf `_as_words` takes: one value a row, of 8 to 64 bits. A
+    STRING's or LIST's padded payload and DECIMAL128's limbs (2-D) keep
+    their gather."""
+    return x.ndim == 1 and x.dtype.itemsize in (1, 2, 4, 8)
+
+
+class _Runs:
+    """The probe rows' runs of output slots, where `mat_spreads`: row
+    ``i`` owns the ``emit[i]`` slots from ``start[i] = sum(emit[:i])``,
+    and what `_expand` returns in the place of ``left_idx``.
+
+    ``start`` is non-decreasing, and slot ``s`` belongs to the last row
+    ``j`` with ``start[j] <= s`` and ``emit[j] > 0``: every row up to
+    ``j`` starts at or before ``s`` and every row behind it after (a row
+    that emits nothing shares its successor's start). So the sum of the
+    first differences ``v[i] - v[i - 1]`` over the rows that start at or
+    before ``s`` telescopes to ``v[j]``: scatter-add each row's
+    difference at its start, cumsum over the slots, and slot ``s`` holds
+    its row's word, bit for bit in u32 arithmetic, wrap and all. Slots
+    past ``sum(emit)`` hold the last row's word, as ``jnp.repeat`` pads;
+    a row that starts past ``total`` (a capacity under the count) is
+    dropped."""
+
+    def __init__(self, start, total: int):
+        self.start = start
+        self.total = total
+
+    def spread(self, word):
+        """A per-row ``u32[n_left]`` word -> the per-slot ``u32[total]``
+        word: one scatter-add of a probe side's worth of updates and one
+        cumsum over the slots. A word at a time: stacked with another
+        (slots on the lanes) a word's scatter costs 2.3x as much and its
+        cumsum the same (TPU v5e, PERF.md §6, PR 51)."""
+        diff = word - jnp.pad(word[:-1], (1, 0))
+        placed = jnp.zeros((self.total,), jnp.uint32).at[self.start].add(
+            diff, mode="drop", indices_are_sorted=True
+        )
+        return jnp.cumsum(placed, dtype=jnp.uint32)
+
+    def leaf(self, x):
+        """A 1-D fixed-width leaf of the probe side, spread word by
+        word."""
+        return _from_words([self.spread(w) for w in _as_words(x)], x)
+
+    @functools.cached_property
+    def index(self):
+        """Each slot's probe row, `_expand`'s ``left_idx`` of the gather
+        form, for what is not spread: the scatter and the cumsum of
+        ``jnp.repeat`` without its closing gather of an ``iota``."""
+        n_left = self.start.shape[0]
+        return self.spread(jnp.arange(n_left, dtype=jnp.uint32)).astype(
+            jnp.int32
+        )
+
+    def column(self, col: Column) -> Column:
+        """``gather_column(col, self.index)`` with every leaf spread
+        where all of them are fixed-width; a column with any other leaf
+        keeps that gather whole."""
+        leaves = col.data, col.validity, col.lengths
+        if not all(x is None or _spreadable(x) for x in leaves):
+            return gather_column(col, self.index)
+        data, validity, lengths = (
+            None if x is None else self.leaf(x) for x in leaves
+        )
+        return Column(data, col.dtype, validity, lengths)
+
+
+def _left_index(rows):
+    """`_expand`'s first result as the index array of the gather form,
+    whichever form it took."""
+    return rows.index if isinstance(rows, _Runs) else rows
+
+
 def _expand(
     perm_r, lo, counts, total: int, left_outer: bool, emit=None
 ):
-    """Materialize (left_idx, right_idx, right_valid) pair arrays.
+    """Materialize (left rows, right_idx, right_valid, in_range) over
+    ``total`` output slots. The left rows are ``left_idx``, each slot's
+    probe row, or where `mat_spreads` the `_Runs` that spread a probe
+    row's values without it (`_join_output` takes either, `_left_index`
+    gives the array).
 
     ``emit`` overrides the per-left-row output count (used by the capped
     left join to skip shuffle-padding rows entirely)."""
@@ -678,16 +818,27 @@ def _expand(
     if emit is None:
         emit = jnp.maximum(counts, 1) if left_outer else counts
     start = jnp.cumsum(emit) - emit
-    left_idx = jnp.repeat(
-        jnp.arange(n_left, dtype=jnp.int32), emit, total_repeat_length=total
-    )
-    k = jnp.arange(total, dtype=jnp.int32) - start[left_idx]
-    matched = k < counts[left_idx]
-    r_sorted_pos = jnp.clip(lo[left_idx] + k, 0, max(perm_r.shape[0] - 1, 0))
+    if mat_spreads(total, n_left):
+        # a slot's place in the sorted build side is lo + (slot - start)
+        # and it is matched while slot < start + counts: each ONE word
+        # of its row (the one nothing reads is no program's work)
+        left = _Runs(start, total)
+        slot = jnp.arange(total, dtype=jnp.int32)
+        matched = slot < left.leaf(start + counts)
+        pos = slot + left.leaf(lo - start)
+    else:
+        left = jnp.repeat(
+            jnp.arange(n_left, dtype=jnp.int32), emit,
+            total_repeat_length=total,
+        )
+        k = jnp.arange(total, dtype=jnp.int32) - start[left]
+        matched = k < counts[left]
+        pos = lo[left] + k
+    r_sorted_pos = jnp.clip(pos, 0, max(perm_r.shape[0] - 1, 0))
     right_idx = perm_r[r_sorted_pos]
     # pairs beyond the emitted total (possible when total is a capacity)
     in_range = jnp.arange(total, dtype=jnp.int32) < jnp.sum(emit)
-    return left_idx, right_idx, matched & in_range, in_range
+    return left, right_idx, matched & in_range, in_range
 
 
 def _join_output(
@@ -707,11 +858,14 @@ def _join_output(
                 drop.add(right.names.index(c))
         else:
             drop.add(c)
-    # left_idx None: every left row where it is (`lookup_unique`)
-    out_cols = list(
-        left.columns if left_idx is None
-        else gather_table(left, left_idx, None).columns
-    )
+    # left_idx None: every left row where it is (`lookup_unique`);
+    # a `_Runs`: the form of `_expand` that gathers no left column
+    if left_idx is None:
+        out_cols = list(left.columns)
+    elif isinstance(left_idx, _Runs):
+        out_cols = [left_idx.column(c) for c in left.columns]
+    else:
+        out_cols = list(gather_table(left, left_idx, None).columns)
     out_names = list(left.names) if left.names else [f"l{i}" for i in range(left.num_columns)]
     for j, c in enumerate(right.columns):
         if j in drop:
@@ -1220,7 +1374,7 @@ def right_join(
     )
     run_idx = jnp.nonzero(run, size=n_run)[0].astype(jnp.int32)
     left_idx = jnp.concatenate(
-        [left_idx, jnp.zeros((n_run,), jnp.int32)]
+        [_left_index(left_idx), jnp.zeros((n_run,), jnp.int32)]
     )
     right_idx = jnp.concatenate([right_idx, run_idx])
     left_ok = jnp.concatenate(
@@ -1252,7 +1406,7 @@ def full_join(
     )
     run_idx = jnp.nonzero(run, size=n_run)[0].astype(jnp.int32)
     left_idx = jnp.concatenate(
-        [left_idx, jnp.zeros((n_run,), jnp.int32)]
+        [_left_index(left_idx), jnp.zeros((n_run,), jnp.int32)]
     )
     right_idx = jnp.concatenate([right_idx, run_idx])
     left_ok = jnp.concatenate(

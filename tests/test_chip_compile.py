@@ -502,6 +502,11 @@ def test_q3_join_materialise_compiles_at_the_cell_s_buckets(one_chip, as_tpu):
         _table(one_chip, Q3_PROBE, SMOKE_BUCKET),
         _table(one_chip, Q3_BUILD, Q3_BUILD_BUCKET), Q3_OUT_BUCKET,
     )
+    from spark_rapids_jni_tpu.ops.join import mat_spreads
+
+    # an output narrower than its probe side: the gather form, the
+    # parent's program (a spread would scatter 2^23 updates a word)
+    assert not mat_spreads(Q3_OUT_BUCKET, SMOKE_BUCKET)
     assert not _wide_gathers(compiled, SMOKE_BUCKET)
     assert _wide_gathers(compiled, Q3_OUT_BUCKET)
 
@@ -533,17 +538,36 @@ def test_self_join_materialise_compiles_at_the_ladder_s_top(
 ):
     """The self-join's materialise at the ladder's top: 8,128,758 pairs
     in 2^23 slots from 2^20 counts, three INT64 columns out (the key and
-    both sides' warehouse), every gather at the OUTPUT's width: ten of
-    them (three of indices, `s32`: each slot's start, its first match
-    and its build row; seven of 32-bit words of the three INT64 columns
-    and the permutation), which is what the cell's `dev_join_mat_ms` is
-    made of."""
+    both sides' warehouse). The output is wider than the probe side, so
+    `ops.join.mat_spreads` answers "spread" (PR 51): the probe side's
+    two INT64 columns and each slot's place in the build side go out as
+    scatters of 2^20 first differences and cumsums over the slots, and
+    exactly THREE gathers at the output's width are left, all of the
+    build side: the sort's permutation at each slot's place (`perm_r`,
+    one `s32` word) and the two 32-bit words of the right warehouse by
+    the row that gives. Ten before (PR 50), seven of them indexed by
+    the slot's probe row; the three are what the cell's
+    `dev_join_mat_ms` is made of now."""
+    import re
+
+    from spark_rapids_jni_tpu.ops.join import mat_spreads
+
+    assert mat_spreads(SMOKE_BUCKET, Q95_BUCKET)
     side = _table(one_chip, Q95_SIDE, Q95_BUCKET)
     compiled = _compile_join_mat(
         one_chip, _q95_probe(), side, side, SMOKE_BUCKET
     )
-    assert len(_wide_gathers(compiled, SMOKE_BUCKET)) == 10
+    assert len(_wide_gathers(compiled, SMOKE_BUCKET)) == 3
     assert not _wide_gathers(compiled, Q95_BUCKET)
+    # the spreads: every scatter adds u32 words into an operand that
+    # has the output's slots on its minor dimension
+    scatters = re.findall(
+        r"= (\w+)\[([\d,]+)\]\S* scatter\(", compiled.as_text()
+    )
+    assert scatters and all(
+        t == "u32" and shape.split(",")[-1] == str(SMOKE_BUCKET)
+        for t, shape in scatters
+    ), scatters
 
 
 # ---------------------------------------------------------------------------

@@ -12,7 +12,10 @@ the bucketed runner declines and the exact path answers; no match at
 all; and ``tpcds-q95-wswh.selfjoin-resident``'s whole plan at its
 rehearsal size, with the session's ``join_plan`` and the counters
 ``join.build.repeats`` / ``join.mat.cap_rows`` reading what the data
-says.
+says. Every one of these outputs is wider than its probe side's bucket,
+so its materialise takes the spread form (PR 51; ``join.mat.spread``
+beside ``join.materialised``; ``tests/test_join_spread.py`` holds the
+two forms to each other).
 """
 
 import json
@@ -30,7 +33,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 JOIN = [{"op": "join", "on": [0], "how": "inner"}]
 WATCHED = [
     "join.probe.direct", "join.probe.search", "join.build.repeats",
-    "join.mat.cap_rows", "join.output_rows", "join.probe_rows",
+    "join.mat.cap_rows", "join.mat.spread", "join.materialised",
+    "join.output_rows", "join.probe_rows",
     "join.build_rows", "bucket.declined", "bucket.fallback_errors",
     "plan.fallbacks", "project.calls", "filter.deferred",
 ]
@@ -115,7 +119,8 @@ def test_a_repeating_build_key(client, orders, bucket, probe):
     assert moved["join.build.repeats"] == (probe == "direct")
     assert moved["bucket.declined"] == moved["bucket.fallback_errors"] == 0
     cap = buckets.bucket_for(table_rows(want))
-    assert moved["join.mat.cap_rows"] == cap
+    assert moved["join.mat.cap_rows"] == cap > bucket
+    assert moved["join.mat.spread"] == moved["join.materialised"] == 1
     assert sess["join_plan"] == {
         "probe_rows": table_rows(t), "build_rows": table_rows(t),
         "output_rows": table_rows(want), "cap": cap,
@@ -157,6 +162,8 @@ def test_an_output_at_its_bucket(client, lines, total):
     got, moved, sess = served(client, JOIN, [t, t])
     assert table_rows(got) == total and mismatched(got, want) == 0
     assert moved["join.mat.cap_rows"] == sess["join_plan"]["cap"] == 2048
+    # 128 rows sit in the ladder's first bucket, 1,024: the output is wider
+    assert moved["join.mat.spread"] == moved["join.materialised"] == 1
     assert sess["join_plan"]["pad_share"] == 1.0 - total / 2048
     assert moved["bucket.declined"] == 0
 
@@ -174,8 +181,41 @@ def test_an_output_past_the_ladder_s_top_is_declined_cleanly(client):
     assert mismatched(got, want) == 0
     assert moved["bucket.declined"] == 1
     assert moved["join.output_rows"] == 10_000
-    assert moved["join.mat.cap_rows"] == 0
+    assert moved["join.mat.cap_rows"] == moved["join.mat.spread"] == 0
     assert moved["bucket.fallback_errors"] == moved["plan.fallbacks"] == 0
+
+
+def test_a_left_join_that_widens(client):
+    """A left outer join whose build key repeats: the unmatched probe
+    rows emit once with a null build side, the others as often as their
+    key has build rows, and the output (wider than the probe side's
+    bucket: the spread form, its mask ONE spread word) is pandas' row
+    for row."""
+    import pandas as pd
+
+    a = lines_table([3, 2, 4, 1, 2], first=48_000_001, step=2, seed=1)
+    b = lines_table([5, 6, 7], first=48_000_003, step=2, seed=2)
+    df = pd.DataFrame({"k": a[0].values, "v": a[1].values}).merge(
+        pd.DataFrame({"k": b[0].values, "w": b[1].values}).astype(
+            {"w": "Int64"}), on="k", how="left")
+    assert len(df) == 3 + 2 * 5 + 4 * 6 + 1 * 7 + 2 > 1024 // 32
+    config.set_flag("BUCKETS", "8:2:8388608")
+    got, moved, sess = served(
+        client, [{"op": "join", "on": [0], "how": "left"}], [a, b])
+    assert table_rows(got) == len(df)
+    assert moved["join.mat.cap_rows"] == 64 > buckets.bucket_for(12)
+    assert moved["join.mat.spread"] == moved["join.materialised"] == 1
+    assert moved["bucket.fallback_errors"] == moved["plan.fallbacks"] == 0
+    rows = sorted(zip(
+        got[0].values.tolist(), got[1].values.tolist(),
+        [w if ok else None for w, ok in zip(
+            got[2].values.tolist(),
+            np.ones(len(df), bool) if got[2].valid is None else got[2].valid)],
+    ), key=str)
+    want = sorted(zip(
+        df.k.tolist(), df.v.tolist(),
+        [None if x is pd.NA else int(x) for x in df.w]), key=str)
+    assert rows == want
 
 
 def test_no_match_at_all(client):
@@ -220,6 +260,8 @@ def test_the_cell_s_plan_at_its_rehearsal_size(client, seed):
     assert moved == {
         "join.probe.direct": 1, "join.probe.search": 0,
         "join.build.repeats": 1, "join.mat.cap_rows": cap,
+        # 6,000 rows in bucket 2^13, their pairs in 2^17: the new form
+        "join.mat.spread": 1, "join.materialised": 1,
         "join.output_rows": pairs, "join.probe_rows": rows,
         "join.build_rows": rows, "bucket.declined": 0,
         "bucket.fallback_errors": 0, "plan.fallbacks": 0,
